@@ -6,10 +6,11 @@ functional train steps for performance; XLA collectives for distribution.
 """
 __version__ = '0.1.0'
 
-# persistent XLA compilation cache (docs/performance.md): no-op unless
-# PTPU_COMPILE_CACHE_DIR is set; must run before the first jit compile
+# persistent XLA compilation cache (docs/performance.md): under
+# JAX_COMPILATION_CACHE_DIR where set, else <repo>/.jax_cache; must run
+# before the first jit compile
 from .core import compile_cache as _compile_cache
-_compile_cache.enable_from_env()
+_compile_cache.install()
 
 from .core import dtypes as _dtypes_mod
 from .core.dtypes import (bool_ as bool, uint8, int8, int16, int32, int64,  # noqa

@@ -177,8 +177,8 @@ def layer_group_fn(name, shape=None, dtype=None):
 
 def ensure_overlap_xla_flags():
     """Best-effort XLA scheduling flags for comm/compute overlap: the
-    latency-hiding scheduler + async collective fusion. XLA_FLAGS is
-    read once at backend initialization and engine builds run after
+    latency-hiding scheduler + async collective fusion. LIBTPU_INIT_ARGS
+    is read once at backend initialization and engine builds run after
     it, so for the flags to reach THIS process's compiler the
     launcher must export PTPU_COMM_OVERLAP=1 — core/flags.py honors
     that at first import, before any backend exists. This call (from

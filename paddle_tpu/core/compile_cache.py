@@ -1,13 +1,21 @@
-"""Persistent XLA compilation cache (ISSUE 4 satellite).
+"""Persistent XLA compilation cache: where it lives, and its traffic.
 
-Enables JAX's on-disk compilation cache behind `PTPU_COMPILE_CACHE_DIR`
-and surfaces its traffic as `ptpu_compile_cache_*` metrics beside the
+One directory, placed from OUTSIDE the program: where
+`JAX_COMPILATION_CACHE_DIR` is set JAX has already read it, and nothing
+here sets another. Where it is not, the cache goes to one fixed path
+inside the checkout (`<repo>/.jax_cache`, git-ignored) — fixed because
+a directory that moves between runs never hits. `install()` runs at
+`import paddle_tpu`, so the trainer, the server, `chip_smoke.py`, the
+`bench.py` leg children and replica workers all share the one cache;
+it touches only `jax.config` and initializes no backend. What gets
+cached follows JAX's own thresholds (`JAX_PERSISTENT_CACHE_*`).
+
+Traffic is surfaced as `ptpu_compile_cache_*` metrics beside the
 executor's in-process fingerprint-cache counters
-(STAT_executor_cache_hit/miss): at GPT scale one warm cache turns the
+(STAT_executor_cache_hit/miss): at GPT scale a warm cache turns the
 minutes-long first dispatch into a disk read, and the gauges make the
-saving visible in StepTelemetry / bench records / health_dump.
-
-jax 0.4.x emits monitoring events for the cache
+saving visible in StepTelemetry / bench records / health_dump. JAX
+emits monitoring events for the cache
 (`/jax/compilation_cache/compile_requests_use_cache`, `.../cache_hits`,
 and the `.../compile_time_saved_sec` duration); there is no miss event,
 so misses are derived as requests - hits.
@@ -15,9 +23,12 @@ so misses are derived as requests - hits.
 import os
 import threading
 
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), '.jax_cache')
+
 _lock = threading.Lock()
 _installed = False
-_enabled_dir = None
 
 
 def _install_listeners():
@@ -48,47 +59,19 @@ def _install_listeners():
     monitoring.register_event_duration_secs_listener(on_duration)
 
 
-def enable(cache_dir, min_compile_seconds=0.0):
-    """Point jax at an on-disk compilation cache and install the
-    metric listeners. `min_compile_seconds=0` caches every program
-    (jax's default of 1s would skip the small ones tests compile)."""
-    global _enabled_dir
+def install():
+    """Called at `import paddle_tpu`: place the cache (module
+    docstring) and install the metric listeners."""
     import jax
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update('jax_compilation_cache_dir', str(cache_dir))
-    try:
-        jax.config.update('jax_persistent_cache_min_compile_time_secs',
-                          float(min_compile_seconds))
-        jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)
-    except Exception:
-        pass   # older jax: defaults still cache the big programs
+    if not os.environ.get('JAX_COMPILATION_CACHE_DIR'):
+        jax.config.update('jax_compilation_cache_dir', DEFAULT_DIR)
     _install_listeners()
-    _enabled_dir = str(cache_dir)
-    return True
 
 
-def enable_from_env():
-    """Called at `import paddle_tpu`; no-op unless
-    PTPU_COMPILE_CACHE_DIR is set. A bad dir or malformed min-seconds
-    must not kill every `import paddle_tpu` over an optional perf
-    feature — warn and run uncached instead."""
-    d = os.environ.get('PTPU_COMPILE_CACHE_DIR')
-    if not d:
-        return False
-    try:
-        mins = float(os.environ.get('PTPU_COMPILE_CACHE_MIN_COMPILE_SECS',
-                                    0.0) or 0.0)
-        return enable(d, min_compile_seconds=mins)
-    except Exception as e:   # noqa: BLE001
-        import warnings
-        warnings.warn(
-            f'PTPU_COMPILE_CACHE_DIR={d!r}: persistent compile cache '
-            f'disabled ({e!r})', RuntimeWarning)
-        return False
-
-
-def enabled():
-    return _enabled_dir is not None
+def cache_dir():
+    """The directory JAX caches into (None = caching off)."""
+    import jax
+    return jax.config.jax_compilation_cache_dir
 
 
 def snapshot():
@@ -103,9 +86,10 @@ def snapshot():
         return sum(c.value() for c in m._series().values())
     requests = int(total('ptpu_compile_cache_requests_total'))
     hits = int(total('ptpu_compile_cache_hits_total'))
+    d = cache_dir()
     return {
-        'enabled': enabled(),
-        'dir': _enabled_dir,
+        'enabled': d is not None,
+        'dir': d,
         'requests': requests,
         'hits': hits,
         'misses': max(requests - hits, 0),

@@ -72,7 +72,7 @@ _FLAGS = {
     'FLAGS_op_error_context': False,
     # XLA scheduling knobs for communication/compute overlap (ISSUE 10,
     # docs/performance.md#comm-overlap). None = leave the compiler
-    # default; True/False edit XLA_FLAGS in the environment on set —
+    # default; True/False edit LIBTPU_INIT_ARGS in the environment on set —
     # effective only BEFORE backend initialization, so launchers export
     # PTPU_COMM_OVERLAP=1 (honored at this module's import, below) or
     # set FLAGS_xla_*/the env tokens directly. Engine builds also call
@@ -82,7 +82,7 @@ _FLAGS = {
     'FLAGS_xla_async_collectives': None,
 }
 
-# FLAGS_* -> the xla option tokens they drive in XLA_FLAGS
+# FLAGS_* -> the libtpu option tokens they drive in LIBTPU_INIT_ARGS
 _XLA_FLAG_TOKENS = {
     'FLAGS_xla_latency_hiding_scheduler': (
         'xla_tpu_enable_latency_hiding_scheduler',),
@@ -91,37 +91,25 @@ _XLA_FLAG_TOKENS = {
 }
 
 
-def _tpu_plausible():
-    """True when this process could plausibly initialize a TPU backend.
-    The xla_tpu_* option names only exist in TPU-enabled XLA builds —
-    a CPU-only jaxlib ABORTS the process on unknown XLA_FLAGS tokens,
-    and the env is inherited by every subprocess, so exporting them
-    unconditionally would be a landmine."""
-    plat = os.environ.get('JAX_PLATFORMS', '')
-    if plat:
-        return 'tpu' in plat.lower()
-    try:
-        import importlib.util
-        return importlib.util.find_spec('libtpu') is not None
-    except Exception:
-        return False
-
-
 def _apply_xla_flag(name, value):
-    """Reflect a True/False XLA flag into the XLA_FLAGS environment
-    (replacing any prior token for the same option). The backend reads
-    XLA_FLAGS once at initialization; a set after init is recorded in
-    the registry but cannot reach the already-built client. On a
-    non-TPU platform the registry records the value but the TPU-only
-    tokens are NOT exported (see _tpu_plausible)."""
-    if value is None or not _tpu_plausible():
+    """Reflect a True/False scheduling flag into the LIBTPU_INIT_ARGS
+    environment (replacing any prior token for the same option).
+    xla_tpu_* options belong to libtpu, which reads LIBTPU_INIT_ARGS
+    once when it initializes; they must NOT go into XLA_FLAGS — that is
+    parsed by jaxlib, which knows no xla_tpu_* option and aborts the
+    process on an unknown flag (jaxlib 0.9.0: "Unknown flag in
+    XLA_FLAGS"), in this process and in every child that inherits the
+    env. A set after backend init is recorded in the registry but
+    cannot reach the already-built client; a process that never loads
+    libtpu ignores the variable."""
+    if value is None:
         return
     val = 'true' if value else 'false'
-    toks = [t for t in os.environ.get('XLA_FLAGS', '').split()
+    toks = [t for t in os.environ.get('LIBTPU_INIT_ARGS', '').split()
             if not any(t.startswith(f'--{opt}=')
                        for opt in _XLA_FLAG_TOKENS[name])]
     toks += [f'--{opt}={val}' for opt in _XLA_FLAG_TOKENS[name]]
-    os.environ['XLA_FLAGS'] = ' '.join(toks)
+    os.environ['LIBTPU_INIT_ARGS'] = ' '.join(toks)
 
 
 def _seed_from_env():

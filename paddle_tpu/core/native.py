@@ -3,7 +3,7 @@
 Reference parity: the pybind layer (paddle/fluid/pybind — N33) for the
 runtime-services subset that stays native in the TPU rebuild: data feed
 (N19), TCP store rendezvous (N8/N9), sparse PS table (N30), host profiler
-(N4). Builds csrc/ on demand with make (g++ only — no pybind11 dependency;
+(N4). Builds csrc/ with make at first load (g++ only — no pybind11 dependency;
 plain C ABI + ctypes).
 """
 import ctypes
@@ -19,19 +19,23 @@ _SO = os.path.join(_CSRC, 'libpaddle_tpu_native.so')
 
 
 def load_native(required=False):
-    """Load (building if needed) the native library. Returns None when
+    """Load the native library, letting `make` decide whether csrc/
+    needs (re)building — the .so is untracked, so a binary left on disk
+    by an older csrc/*.cc must not be what loads. Returns None when
     unavailable and not required."""
     global _LIB
     if _LIB is not None:
         return _LIB
-    if not os.path.exists(_SO):
-        try:
-            subprocess.run(['make', '-C', _CSRC], check=True,
-                           capture_output=True)
-        except Exception as e:
-            if required:
-                raise RuntimeError(f"native build failed: {e}")
-            return None
+    try:
+        subprocess.run(['make', '-C', _CSRC], check=True,
+                       capture_output=True)
+    except (OSError, subprocess.CalledProcessError) as e:
+        if required:
+            detail = getattr(e, 'stderr', b'') or b''
+            raise RuntimeError(
+                f"native build failed: {e}\n"
+                f"{detail.decode(errors='replace')}")
+        return None
     try:
         lib = ctypes.CDLL(_SO)
     except OSError as e:

@@ -132,15 +132,14 @@ class HybridCommunicateGroup:
         self._register_mesh()
 
     def _register_mesh(self):
-        import jax
         names, sizes = [], []
         for pname in self._topo.get_hybrid_group_names():
             d = self._topo.get_dim(pname)
             names.append(_MESH_AXIS.get(pname, pname))
             sizes.append(d)
-        total = int(np.prod(sizes))
-        if total <= len(jax.devices()):
-            topology_runtime.build_mesh(names, sizes)
+        # raises when the topology asks for more devices than there
+        # are: the engines must never run on a stale, smaller mesh
+        topology_runtime.build_mesh(names, sizes)
 
     def _get_parallel_id(self, axis):
         coord = self._topo.get_coord(self.global_rank)

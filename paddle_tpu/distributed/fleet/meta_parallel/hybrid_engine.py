@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P, NamedSharding
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ....core import rng as rng_mod
 from ....core import autograd
@@ -654,7 +654,7 @@ class HybridParallelTrainStep(A_.AsyncDispatchMixin, EngineTeardown):
                  'params': dict.fromkeys(names, 0),
                  'grad_norm_sq': 0}),)
         mapped = shard_map(step, mesh=self.mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
+                           out_specs=out_specs, check_vma=False)
         return jax.jit(mapped, donate_argnums=(0, 1))
 
     def _update_one(self, p, g, st, lr):

@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P, NamedSharding
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ....core import rng as rng_mod
 from ....core import autograd
@@ -1192,7 +1192,7 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
                  'params': dict.fromkeys(keys, 0),
                  'grad_norm_sq': 0}),)
         mapped = shard_map(step, mesh=self.mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
+                           out_specs=out_specs, check_vma=False)
         return jax.jit(mapped, donate_argnums=(0, 1))
 
     @staticmethod
@@ -1248,11 +1248,11 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
                 needed.update(v for v in eqn.invars
                               if not hasattr(v, 'val'))
         keep.reverse()
-        try:     # jax>=0.4.36 asserts debug_info paths match outvars
-            pruned = jaxpr.replace(eqns=keep, outvars=want,
-                                   debug_info=None)
-        except (TypeError, AssertionError):
-            pruned = jaxpr.replace(eqns=keep, outvars=want)
+        # the traced debug_info names one result path per ORIGINAL
+        # outvar; the pruned jaxpr has fewer, so drop the paths
+        pruned = jaxpr.replace(
+            eqns=keep, outvars=want,
+            debug_info=jaxpr.debug_info._replace(result_paths=None))
         flat_args = jax.tree_util.tree_leaves(args)
         inv_vals = jax.core.eval_jaxpr(pruned, closed.consts, *flat_args)
         values = [None] * len(flags)

@@ -328,9 +328,7 @@ class TrainStep(_AsyncDispatchMixin):
         self.flush()
         write_back(self.model, self._params, self._buffers)
 
-    # -- multi-step: k steps per dispatch (amortizes host→device launch; on
-    # a tunneled/remote chip this is the difference between RTT-bound and
-    # compute-bound) ---------------------------------------------------------
+    # -- multi-step: k steps per dispatch (amortizes host→device launch) ----
     def compile_multi_step(self, k=None):
         if getattr(self, '_multi', None) is not None:
             return  # jax.jit caches per input shape — one jit covers all k

@@ -39,22 +39,11 @@ from . import scaffold
 
 NEG_INF = -1e30
 
-# default VMEM tile extents — 512x512 measured best at GPT shapes
-# (L=2048, d=128): 64.7% vs 58.8% step MFU with 256 tiles (fewer grid
-# programs + fori iterations per program amortize the per-block
-# epilogue). Env override for experiments, read once at import; a
-# malformed value falls back instead of breaking package import.
-
-
-def _env_block(name, default):
-    try:
-        return int(os.environ.get(name, default))
-    except ValueError:
-        return default
-
-
-_BLOCK_Q = _env_block('PTPU_FLASH_BLOCK_Q', 512)
-_BLOCK_K = _env_block('PTPU_FLASH_BLOCK_K', 512)
+# default VMEM tile extents (fewer grid programs + fori iterations per
+# program amortize the per-block epilogue). Env override for
+# experiments, read once at import.
+_BLOCK_Q = int(os.environ.get('PTPU_FLASH_BLOCK_Q', 512))
+_BLOCK_K = int(os.environ.get('PTPU_FLASH_BLOCK_K', 512))
 
 
 # tile fitting + interpret-mode forcing live in the shared scaffolding
@@ -63,6 +52,30 @@ _BLOCK_K = _env_block('PTPU_FLASH_BLOCK_K', 512)
 # silently misaligning the mask (true for ANY block size)
 _fit_block = scaffold.fit_block
 _interpret = scaffold.interpret_mode
+
+
+def _call(kernel, grid, in_specs, args, out_specs, out_shape):
+    """pl.pallas_call with the scoped-VMEM limit sized from the call's
+    blocks: the packed kernels hold whole-sequence [L, H*D] K/V (bwd:
+    Q/dO) slabs — 40-52 MiB double-buffered at L=2048, H*D=2048 bf16,
+    above Mosaic's 16 MiB default."""
+    outs, ospecs = out_shape, out_specs
+    if not isinstance(out_shape, tuple):
+        outs, ospecs = (out_shape,), (out_specs,)
+    return pl.pallas_call(
+        kernel, out_shape=out_shape, grid=grid, in_specs=in_specs,
+        out_specs=out_specs,
+        compiler_params=scaffold.compiler_params(in_specs, args, ospecs,
+                                                 outs),
+        interpret=_interpret())(*args)
+
+
+def _keep_factor(mask8, inv_keep):
+    """0/1 int8 keep mask -> fp32 {0, 1/keep} factor. A convert and a
+    multiply, not `where(mask8 != 0, ...)`: Mosaic cannot relayout the
+    int8-tiled i1 compare result to the fp32 tile ("Invalid relayout
+    ... vector<512x512xi1>")."""
+    return mask8.astype(jnp.float32) * inv_keep
 
 
 def _flash_fwd_kernel(*refs, block_k, seq_len, scale, causal, has_bias,
@@ -121,8 +134,8 @@ def _flash_fwd_kernel(*refs, block_k, seq_len, scale, causal, has_bias,
         l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
         pv = p
         if mask_ref is not None:
-            mblk = mask_ref[:, pl.ds(k_start, block_k)]
-            pv = p * jnp.where(mblk != 0, inv_keep, 0.0)
+            pv = p * _keep_factor(
+                mask_ref[:, pl.ds(k_start, block_k)], inv_keep)
         acc_new = acc * alpha + jax.lax.dot_general(
             pv, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -176,8 +189,8 @@ def _flash_bwd_dq_kernel(*refs, block_k, seq_len, scale, causal, has_bias,
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         if mask_ref is not None:
-            mblk = mask_ref[:, pl.ds(k_start, block_k)]
-            dp = dp * jnp.where(mblk != 0, inv_keep, 0.0)
+            dp = dp * _keep_factor(
+                mask_ref[:, pl.ds(k_start, block_k)], inv_keep)
         ds = p * (dp - delta)
         return dq + scale * jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
@@ -230,8 +243,8 @@ def _flash_bwd_dkv_kernel(*refs, block_q, seq_len, scale, causal, has_bias,
             s = jnp.where(rows >= cols, s, NEG_INF)
         p = jnp.exp(s - lse)  # [bq, bk]
         if mask_ref is not None:
-            mblk = mask_ref[pl.ds(q_offset, block_q), :]
-            d_keep = jnp.where(mblk != 0, inv_keep, 0.0)
+            d_keep = _keep_factor(
+                mask_ref[pl.ds(q_offset, block_q), :], inv_keep)
         else:
             d_keep = None
         dv_new = dv + jax.lax.dot_general(
@@ -264,6 +277,16 @@ def _bias_spec(num_heads, L):
     return pl.BlockSpec(
         (None, 1, L),
         lambda b, i, nh=num_heads: (jax.lax.div(b, jnp.int32(nh)), 0, 0))
+
+
+def _packed_fits(L, hd, num_heads, dtype):
+    """Whether the packed kernels' whole-sequence slabs fit the VMEM
+    cap, judged on their largest call (the dk/dv backward: Q and dO
+    whole, K/V/dK/dV blocks, lse/delta whole)."""
+    blk = _fit_block(_BLOCK_K, L)
+    return scaffold.vmem_need(
+        [((L, hd), dtype)] * 2 + [((blk, hd), dtype)] * 4
+        + [((L, num_heads), jnp.float32)] * 2) <= scaffold.VMEM_CAP_BYTES
 
 
 # -- PACKED layout (transpose-free MHA path) ----------------------------------
@@ -486,18 +509,12 @@ def _flash_forward(q, k, v, bias=None, num_heads=1, causal=True,
         in_specs.append(pl.BlockSpec((None, block_q, L),
                                      lambda b, i: (b, i, 0)))
         args.append(dropout_mask)
-    o, lse = pl.pallas_call(
-        kernel,
-        out_shape=(jax.ShapeDtypeStruct((bh, L, d), q.dtype),
-                   jax.ShapeDtypeStruct((bh, L, 1), jnp.float32)),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((None, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_q, 1), lambda b, i: (b, i, 0)),
-        ),
-        interpret=_interpret(),
-    )(*args)
+    o, lse = _call(
+        kernel, grid, in_specs, args,
+        (pl.BlockSpec((None, block_q, d), lambda b, i: (b, i, 0)),
+         pl.BlockSpec((None, block_q, 1), lambda b, i: (b, i, 0))),
+        (jax.ShapeDtypeStruct((bh, L, d), q.dtype),
+         jax.ShapeDtypeStruct((bh, L, 1), jnp.float32)))
     return (o, lse) if with_lse else o
 
 
@@ -527,19 +544,13 @@ def _flash_forward_packed(q, k, v, bias=None, num_heads=1, head_dim=64,
         in_specs.append(pl.BlockSpec((None, 1, L),
                                      lambda b, i: (b, 0, 0)))
         args.append(bias)
-    o, lse = pl.pallas_call(
-        kernel,
-        out_shape=(jax.ShapeDtypeStruct((B, L, hd), q.dtype),
-                   jax.ShapeDtypeStruct((B, L, num_heads), jnp.float32)),
-        grid=(B, pl.cdiv(L, block_q)),
-        in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((None, block_q, hd), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_q, num_heads),
-                         lambda b, i: (b, i, 0)),
-        ),
-        interpret=_interpret(),
-    )(*args)
+    o, lse = _call(
+        kernel, (B, pl.cdiv(L, block_q)), in_specs, args,
+        (pl.BlockSpec((None, block_q, hd), lambda b, i: (b, i, 0)),
+         pl.BlockSpec((None, block_q, num_heads),
+                      lambda b, i: (b, i, 0))),
+        (jax.ShapeDtypeStruct((B, L, hd), q.dtype),
+         jax.ShapeDtypeStruct((B, L, num_heads), jnp.float32)))
     return (o, lse) if with_lse else o
 
 
@@ -577,17 +588,13 @@ def _flash_backward_packed(q, k, v, o, lse, do, bias=None, num_heads=1,
         dq_args.append(bias)
     dq_in_specs += [row_spec, stat_blk, stat_blk]
     dq_args += [do, lse, delta]
-    dq = pl.pallas_call(
+    dq = _call(
         functools.partial(_flash_bwd_dq_kernel_packed, block_k=block_k,
                           seq_len=L, scale=scale, causal=causal,
                           has_bias=has_bias, num_heads=num_heads,
                           head_dim=d),
-        out_shape=jax.ShapeDtypeStruct((B, L, hd), q.dtype),
-        grid=(B, pl.cdiv(L, block_q)),
-        in_specs=dq_in_specs,
-        out_specs=row_spec,
-        interpret=_interpret(),
-    )(*dq_args)
+        (B, pl.cdiv(L, block_q)), dq_in_specs, dq_args, row_spec,
+        jax.ShapeDtypeStruct((B, L, hd), q.dtype))
 
     dkv_in_specs = [full_spec, kvblk_spec, kvblk_spec]
     dkv_args = [q, k, v]
@@ -596,18 +603,15 @@ def _flash_backward_packed(q, k, v, o, lse, do, bias=None, num_heads=1,
         dkv_args.append(bias)
     dkv_in_specs += [full_spec, stat_full, stat_full]
     dkv_args += [do, lse, delta]
-    dk, dv = pl.pallas_call(
+    dk, dv = _call(
         functools.partial(_flash_bwd_dkv_kernel_packed, block_q=block_q,
                           seq_len=L, scale=scale, causal=causal,
                           has_bias=has_bias, num_heads=num_heads,
                           head_dim=d),
-        out_shape=(jax.ShapeDtypeStruct((B, L, hd), k.dtype),
-                   jax.ShapeDtypeStruct((B, L, hd), v.dtype)),
-        grid=(B, pl.cdiv(L, block_k)),
-        in_specs=dkv_in_specs,
-        out_specs=(kvblk_spec, kvblk_spec),
-        interpret=_interpret(),
-    )(*dkv_args)
+        (B, pl.cdiv(L, block_k)), dkv_in_specs, dkv_args,
+        (kvblk_spec, kvblk_spec),
+        (jax.ShapeDtypeStruct((B, L, hd), k.dtype),
+         jax.ShapeDtypeStruct((B, L, hd), v.dtype)))
     return dq, dk, dv
 
 
@@ -651,16 +655,13 @@ def _flash_backward(q, k, v, o, lse, do, bias=None, num_heads=1,
     ]
     dq_args += [do, lse, delta]
 
-    dq = pl.pallas_call(
+    dq = _call(
         functools.partial(_flash_bwd_dq_kernel, block_k=block_k, seq_len=L,
                           scale=scale, causal=causal, has_bias=has_bias,
                           has_dropout=has_dropout, inv_keep=inv_keep),
-        out_shape=jax.ShapeDtypeStruct((bh, L, d), q.dtype),
-        grid=(bh, pl.cdiv(L, block_q)),
-        in_specs=dq_in_specs,
-        out_specs=pl.BlockSpec((None, block_q, d), lambda b, i: (b, i, 0)),
-        interpret=_interpret(),
-    )(*dq_args)
+        (bh, pl.cdiv(L, block_q)), dq_in_specs, dq_args,
+        pl.BlockSpec((None, block_q, d), lambda b, i: (b, i, 0)),
+        jax.ShapeDtypeStruct((bh, L, d), q.dtype))
 
     dkv_in_specs = [
         pl.BlockSpec((None, L, d), lambda b, j: (b, 0, 0)),
@@ -682,20 +683,15 @@ def _flash_backward(q, k, v, o, lse, do, bias=None, num_heads=1,
     ]
     dkv_args += [do, lse, delta]
 
-    dk, dv = pl.pallas_call(
+    dk, dv = _call(
         functools.partial(_flash_bwd_dkv_kernel, block_q=block_q, seq_len=L,
                           scale=scale, causal=causal, has_bias=has_bias,
                           has_dropout=has_dropout, inv_keep=inv_keep),
-        out_shape=(jax.ShapeDtypeStruct((bh, L, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, L, d), v.dtype)),
-        grid=(bh, pl.cdiv(L, block_k)),
-        in_specs=dkv_in_specs,
-        out_specs=(
-            pl.BlockSpec((None, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, j: (b, j, 0)),
-        ),
-        interpret=_interpret(),
-    )(*dkv_args)
+        (bh, pl.cdiv(L, block_k)), dkv_in_specs, dkv_args,
+        (pl.BlockSpec((None, block_k, d), lambda b, j: (b, j, 0)),
+         pl.BlockSpec((None, block_k, d), lambda b, j: (b, j, 0))),
+        (jax.ShapeDtypeStruct((bh, L, d), k.dtype),
+         jax.ShapeDtypeStruct((bh, L, d), v.dtype)))
     return dq, dk, dv
 
 
@@ -868,7 +864,8 @@ def causal_attention(qkv, num_heads, head_dim, dropout=0.0,
     ((head, 3, hd) Megatron packing — TP-shardable) → context
     [B, L, nh*hd]. Default route is the packed transpose-free kernel
     (q/k/v stay in [B, L, H*D]; only the cheap qkv un-interleave slice
-    remains); FLAGS_flash_packed_causal=False restores the BHLD route.
+    remains) while its whole-sequence slabs fit VMEM, the per-head BHLD
+    kernel beyond that; FLAGS_flash_packed_causal=False forces BHLD.
 
     Nonzero `dropout` routes through the dropout-fused BHLD kernels
     (ISSUE 12): the int8 keep mask is drawn HERE with `dropout_key` —
@@ -907,7 +904,9 @@ def causal_attention(qkv, num_heads, head_dim, dropout=0.0,
             return o.reshape(B, L, num_heads * head_dim)
         return run_op('flash_attention', fn_drop, [qkv])
     scaffold.record_route('flash_attention', True)
-    packed = bool(flags.flag('FLAGS_flash_packed_causal', True))
+    packed = bool(flags.flag('FLAGS_flash_packed_causal', True)) \
+        and _packed_fits(qkv.shape[1], num_heads * head_dim, num_heads,
+                         qkv.data.dtype)
 
     def fn(a):
         B, L, _ = a.shape

@@ -2,10 +2,11 @@
 dropout+residual-add — on the shared Pallas scaffolding (TPP,
 arXiv:2104.05755).
 
-bias_gelu: y = gelu(x + bias). The forward kernel computes the add and
-the activation in the INPUT dtype via `jax.nn.gelu` traced into the
-kernel body — the same expression the reference path runs, so routes
-agree at the bf16 cast points. The backward kernel recomputes u = x + b
+bias_gelu: y = gelu(x + bias). The forward kernel computes the add in
+the INPUT dtype and the tanh-form activation via `jax.nn.gelu` traced
+into the kernel body — the same expression the reference path runs, so
+routes agree at the bf16 cast points; the exact (erf) form runs an fp32
+rational-polynomial erf, which Pallas TPU cannot lower from lax.erf. The backward kernel recomputes u = x + b
 once, applies the analytic gelu derivative in fp32, streams dx out per
 row block, and accumulates dbias across the sequential grid in VMEM
 scratch (one pass; XLA autodiff instead re-materializes tanh and runs a
@@ -54,16 +55,46 @@ def _gelu_grad(u, approximate):
         return 0.5 * (1.0 + t) + 0.5 * u * (1.0 - t * t) * c * \
             (1.0 + 3 * 0.044715 * u ** 2)
     phi = jnp.exp(-0.5 * u * u) * (1.0 / math.sqrt(2.0 * math.pi))
-    cdf = 0.5 * (1.0 + jax.lax.erf(u * (1.0 / math.sqrt(2.0))))
+    cdf = 0.5 * (1.0 + _erf(u * (1.0 / math.sqrt(2.0))))
     return cdf + u * phi
+
+
+def _erf(x):
+    """fp32 erf as a clamped rational polynomial x*P(x^2)/Q(x^2) (the
+    Eigen/XLA single-precision form, max abs error 2.3e-7 — the same as
+    lax.erf's own). Pallas TPU has no lowering for lax.erf / lax.erfc
+    ("Unimplemented primitive ... erf"), so the exact-GELU kernels
+    carry it as plain VPU mul/add/div."""
+    x = jnp.clip(x, -4.0, 4.0)
+    x2 = x * x
+    p = jnp.float32(-2.72614225801306e-10)
+    for c in (2.77068142495902e-08, -2.10102402082508e-06,
+              -5.69250639462346e-05, -7.34990630326855e-04,
+              -2.95459980854025e-03, -1.60960333262415e-02):
+        p = p * x2 + jnp.float32(c)
+    q = jnp.float32(-1.45660718464996e-05)
+    for c in (-2.13374055278905e-04, -1.68282697438203e-03,
+              -7.37332916720468e-03, -1.42647390514189e-02):
+        q = q * x2 + jnp.float32(c)
+    return x * p / q
+
+
+def _gelu(u, approximate):
+    """gelu(u) in u's dtype: the tanh form is jax.nn.gelu itself (the
+    reference route's expression); the exact form goes through `_erf`
+    in fp32."""
+    if approximate:
+        return jax.nn.gelu(u, approximate=True)
+    u32 = u.astype(jnp.float32)
+    return (0.5 * u32 * (1.0 + _erf(u32 * (1.0 / math.sqrt(2.0))))) \
+        .astype(u.dtype)
 
 
 # ---------------------------------------------------------------------------
 # bias + gelu
 # ---------------------------------------------------------------------------
 def _bg_fwd_kernel(x_ref, b_ref, o_ref, *, approximate):
-    o_ref[...] = jax.nn.gelu(x_ref[...] + b_ref[...],
-                             approximate=approximate)
+    o_ref[...] = _gelu(x_ref[...] + b_ref[...], approximate)
 
 
 def _bg_bwd_kernel(x_ref, b_ref, dy_ref, dx_ref, db_ref, db_s, *,

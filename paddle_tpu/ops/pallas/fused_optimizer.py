@@ -12,7 +12,7 @@ that chain into one read and one write per operand:
     scalars every step needs before it can touch the params: the
     global-clip sum-of-squares contribution and the nonfinite count
     (GradScaler found-inf). Accumulates across the sequential TPU grid
-    into (1, 1) outputs.
+    into (1, 1) SMEM outputs.
   * `fused_shard_update` — ONE pass per bucket shard applying
     unscale/clip prefactor + decay-into-grad + the optimizer's own
     `update` rule + the found-inf no-op guard + the fp32-master
@@ -24,8 +24,8 @@ body calls `optimizer.update(p32, g32, state, lr)` directly — for an
 elementwise rule that is pure jnp elementwise code, which Pallas traces
 into the kernel like any other body. Vector states stream as row blocks
 beside the params; scalar states (Adam beta powers) ride in a packed
-(1, NS) fp32 block and their updated values are written through (1, 1)
-accumulator outputs (every grid step writes the same value). Optimizers
+(1, NS) fp32 SMEM array and their updated values are written through
+(1, 1) SMEM outputs (every grid step writes the same value). Optimizers
 opt in with `_pallas_fusible = True` (optimizer.py tags SGD, Momentum,
 Adam/AdamW, Adamax, Adagrad, RMSProp, Adadelta, DecayedAdagrad);
 anything untagged —
@@ -103,7 +103,7 @@ def grad_stats_pallas(flat):
         _stats_kernel,
         grid=(rows // br,),
         in_specs=[scaffold.row_spec(br, scaffold.LANES)],
-        out_specs=(scaffold.acc_spec(), scaffold.acc_spec()),
+        out_specs=(scaffold.scalar_spec(), scaffold.scalar_spec()),
         out_shape=(jax.ShapeDtypeStruct((1, 1), jnp.float32),
                    jax.ShapeDtypeStruct((1, 1), jnp.float32)),
         interpret=scaffold.interpret_mode(),
@@ -195,10 +195,9 @@ def fused_shard_update(optimizer, p_shard, g32_shard, st, lr,
     sc = jnp.stack(scalars).reshape(1, -1)
 
     blk = scaffold.row_spec(br, scaffold.LANES)
-    in_specs = [scaffold.bcast_spec(1, sc.shape[1])] \
-        + [blk] * len(vecs2d)
+    in_specs = [scaffold.scalar_spec()] + [blk] * len(vecs2d)
     out_specs = [blk] * (1 + (1 if has_master else 0) + len(vec_keys)) \
-        + [scaffold.acc_spec()] * len(scalar_keys)
+        + [scaffold.scalar_spec()] * len(scalar_keys)
     shp2d = vecs2d[0].shape
     out_shape = [jax.ShapeDtypeStruct(shp2d, p_shard.dtype)]
     if has_master:

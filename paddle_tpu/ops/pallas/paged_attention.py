@@ -56,8 +56,10 @@ quantizes each new token's per-head K/V row at scatter time;
 dequantization happens INSIDE the kernel (per-page VMEM block, one
 multiply per head slice — free next to the MXU dot) and inside the
 dense fallback, so attention math stays fp32 while the pool pays 1
-byte/element + 4 bytes/head/slot. On TPU note the int8 min tile is
-(32, 128): page_size >= 32 keeps the int8 page blocks tile-aligned.
+byte/element + 4 bytes/head/slot. The int8 min tile is (32, 128), so
+page_size >= 32 keeps the int8 page blocks tile-aligned; Mosaic (libtpu
+0.0.34, v5e) also compiles 16- and 8-slot int8 pages, and 16-slot pages
+match the dense reference on the chip (chip_smoke.py `kernels`).
 """
 import functools
 import math

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # f32 VPU lane width; flat-buffer kernels reshape 1-D buckets to
 # [rows, LANES] so blocks are tile-aligned on TPU
@@ -99,6 +100,62 @@ def use_kernel(primitive, flag=None, supported=True, record=True):
 
 
 # ---------------------------------------------------------------------------
+# VMEM budget
+# ---------------------------------------------------------------------------
+# Mosaic's default scoped-VMEM limit is 16 MiB (libtpu 0.0.34 on v5e);
+# a kernel whose pipelined blocks pass half of it asks for its own
+# limit, up to this cap — the chip has 128 MiB, and the rest stays with
+# XLA's fusions around the custom call
+VMEM_CAP_BYTES = 100 * 2 ** 20
+# on top of the double-buffered blocks: room for the kernel body's own
+# temporaries (fp32 score/prob tiles, upcast operands)
+_VMEM_BODY_BYTES = 16 * 2 ** 20
+
+
+def block_bytes(shape, dtype):
+    """VMEM bytes of one pipelined block: the minor dim pads to the
+    128-lane tile, the second-minor to the dtype's sublane tile."""
+    item = jnp.dtype(dtype).itemsize
+    dims = [d for d in shape if d is not None]
+    lanes = -(-dims[-1] // LANES) * LANES
+    sub = 32 // item
+    rows = -(-dims[-2] // sub) * sub if len(dims) > 1 else 1
+    lead = 1
+    for d in dims[:-2]:
+        lead *= d
+    return lead * rows * lanes * item
+
+
+def vmem_need(blocks):
+    """Scoped-VMEM bytes a call with these (block_shape, dtype) blocks
+    needs: the pipeline double-buffers every block."""
+    return 2 * sum(block_bytes(s, d) for s, d in blocks) \
+        + _VMEM_BODY_BYTES
+
+
+def compiler_params(in_specs, inputs, out_specs, out_shape):
+    """pltpu.CompilerParams sizing the scoped-VMEM limit from the
+    call's own blocks (left at Mosaic's default when the need is under
+    it). A need above VMEM_CAP_BYTES raises NotImplementedError naming
+    the blocks on TPU — the kernel cannot run at that shape, and no
+    reference path takes over quietly."""
+    blocks = [(sp.block_shape, a.dtype)
+              for sp, a in zip(list(in_specs) + list(out_specs),
+                               list(inputs) + list(out_shape))
+              if sp.block_shape is not None]
+    need = vmem_need(blocks)
+    if need <= _VMEM_BODY_BYTES + 8 * 2 ** 20:
+        return pltpu.CompilerParams()
+    if need > VMEM_CAP_BYTES and not interpret_mode():
+        raise NotImplementedError(
+            f'Pallas kernel needs {need / 2 ** 20:.0f} MiB of VMEM '
+            f'(cap {VMEM_CAP_BYTES / 2 ** 20:.0f} MiB) for blocks '
+            f'{[(tuple(s), jnp.dtype(d).name) for s, d in blocks]}')
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=int(min(need, VMEM_CAP_BYTES)))
+
+
+# ---------------------------------------------------------------------------
 # layout helpers
 # ---------------------------------------------------------------------------
 def to_rows(flat, block_rows=ROW_BLOCK, lanes=LANES):
@@ -139,9 +196,11 @@ def pick_block_rows(ncols, want):
     """Rows per grid block for a [R, ncols] kernel, shrunk so one block
     stays around `want` x LANES elements regardless of the feature dim
     (a fixed row count would grow VMEM use linearly with ncols — at
-    ffn_hidden 32k a 128-row fp32 block is 16 MB per ref). Floor of 8
-    keeps f32 sublane tiling."""
-    return min(want, max(8, (want * LANES) // max(ncols, 1)))
+    ffn_hidden 32k a 128-row fp32 block is 16 MB per ref). Always a
+    multiple of 8, floor 8: Mosaic refuses a block whose second-minor
+    dim is neither a multiple of 8 nor the whole array (hidden 768 used
+    to get 21 rows)."""
+    return min(want, max(8, (want * LANES) // max(ncols, 1) // 8 * 8))
 
 
 def row_spec(block_rows, ncols):
@@ -154,10 +213,12 @@ def bcast_spec(nrows, ncols):
     return pl.BlockSpec((nrows, ncols), lambda i: (0, 0))
 
 
-def acc_spec():
-    """(1, 1) accumulator output revisited by every program (the
-    sequential TPU grid keeps it resident; interpret mode matches)."""
-    return pl.BlockSpec((1, 1), lambda i: (0, 0))
+def scalar_spec():
+    """Whole small array in SMEM — packed scalar inputs and (1, 1)
+    scalar accumulators/outputs. Mosaic loads and stores scalars only
+    through SMEM ("Cannot store scalars to VMEM"); the array stays
+    resident across the sequential grid and is written back once."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 # ---------------------------------------------------------------------------

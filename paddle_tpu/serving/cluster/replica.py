@@ -565,8 +565,9 @@ class RemoteReplica:
                '--hang-timeout', str(hang_timeout_s),
                '--model-config', json.dumps(model_config),
                '--engine-config', json.dumps(engine_config or {})]
+        # the worker runs on the platform its environment names — a
+        # CPU worker is asked for through `env`, never defaulted to
         full_env = dict(os.environ)
-        full_env.setdefault('JAX_PLATFORMS', 'cpu')
         full_env.update(env or {})
         proc = subprocess.Popen(cmd, env=full_env,
                                 stdout=subprocess.PIPE,

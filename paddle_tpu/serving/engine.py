@@ -1287,7 +1287,7 @@ class ServingEngine:
             # tiny host-built operands (tokens/tables/lens/key)
             # replicated; the sampled tokens come back replicated
             # (every shard gathers the full vocab)
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
             kv_specs = [tuple(P(None, None, 'mp') for _ in layer)
                         for layer in self.pool.kv]
@@ -1295,7 +1295,7 @@ class ServingEngine:
                         P(), P(), P(), P(), P(), P(), P(), P())
             out_specs = (P(), kv_specs)
             step = shard_map(step, mesh=self.mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
+                             out_specs=out_specs, check_vma=False)
         jitted = jax.jit(step, donate_argnums=donate)
 
         def run(*args):
@@ -1405,7 +1405,7 @@ class ServingEngine:
 
         donate = (1,) if jax.default_backend() != 'cpu' else ()
         if mp > 1:
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
             kv_specs = [tuple(P(None, None, 'mp') for _ in layer)
                         for layer in self.pool.kv]
@@ -1414,7 +1414,7 @@ class ServingEngine:
                         P())
             out_specs = (P(), kv_specs)
             step = shard_map(step, mesh=self.mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
+                             out_specs=out_specs, check_vma=False)
         jitted = jax.jit(step, donate_argnums=donate)
 
         def run(*args):

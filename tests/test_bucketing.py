@@ -491,38 +491,57 @@ class TestBucketedAmpAndClip:
 
 
 class TestCompileCache:
-    def test_persistent_cache_hits_and_gauges(self, tmp_path):
-        """Second compile of the same program in a fresh process must
-        hit the on-disk cache and bump ptpu_compile_cache_* gauges."""
-        code = r'''
+    _CODE = r'''
 import json, os, sys
 os.environ['JAX_PLATFORMS'] = 'cpu'
 sys.path.insert(0, %(root)r)
+import paddle_tpu
 from paddle_tpu.core import compile_cache
-assert compile_cache.enable_from_env()
-assert compile_cache.enabled()
 import jax, jax.numpy as jnp
 f = jax.jit(lambda x: (x * 3 + jnp.sin(x)).sum())
 f(jnp.arange(1717, dtype=jnp.float32)).block_until_ready()
 print('SNAP:' + json.dumps(compile_cache.snapshot()))
 '''
-        env = dict(os.environ)
-        env['PTPU_COMPILE_CACHE_DIR'] = str(tmp_path)
-        env['PTPU_COMPILE_CACHE_MIN_COMPILE_SECS'] = '0'
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-        def run():
-            p = subprocess.run(
-                [sys.executable, '-c', code % {'root': root}], env=env,
-                capture_output=True, text=True, timeout=300)
-            assert p.returncode == 0, (p.stdout or '') + (p.stderr or '')
-            line = [l for l in p.stdout.splitlines()
-                    if l.startswith('SNAP:')][-1]
-            return json.loads(line[len('SNAP:'):])
-        first = run()
+    def _run(self, env):
+        p = subprocess.run(
+            [sys.executable, '-c', self._CODE % {'root': self._ROOT}],
+            env=env, capture_output=True, text=True, timeout=300)
+        assert p.returncode == 0, (p.stdout or '') + (p.stderr or '')
+        line = [l for l in p.stdout.splitlines()
+                if l.startswith('SNAP:')][-1]
+        return json.loads(line[len('SNAP:'):])
+
+    def test_persistent_cache_hits_and_gauges(self, tmp_path):
+        """JAX_COMPILATION_CACHE_DIR places the cache: compile_cache
+        leaves the directory as JAX read it, every file lands under it,
+        and the second compile of the same program in a fresh process
+        hits it and bumps the ptpu_compile_cache_* gauges."""
+        env = dict(os.environ)
+        env['JAX_COMPILATION_CACHE_DIR'] = str(tmp_path)
+        env['JAX_ENABLE_COMPILATION_CACHE'] = 'true'
+        env['JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS'] = '0'
+        env['JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES'] = '-1'
+        first = self._run(env)
         assert first['enabled'] and first['dir'] == str(tmp_path)
         assert first['requests'] >= 1
-        second = run()
+        assert any(tmp_path.iterdir())
+        second = self._run(env)
         assert second['hits'] >= 1, second
         assert second['seconds_saved'] >= 0.0
         assert second['misses'] == second['requests'] - second['hits']
+
+    def test_unset_env_uses_fixed_in_checkout_path(self, monkeypatch):
+        """No JAX_COMPILATION_CACHE_DIR: one fixed path inside the
+        checkout — never a temp name, pid or time."""
+        import jax
+        from paddle_tpu.core import compile_cache
+        prior = jax.config.jax_compilation_cache_dir
+        monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR', raising=False)
+        try:
+            compile_cache.install()
+            assert compile_cache.cache_dir() == os.path.join(
+                self._ROOT, '.jax_cache')
+        finally:
+            jax.config.update('jax_compilation_cache_dir', prior)

@@ -90,11 +90,14 @@ class TestPsSubprocess:
                                    f'127.0.0.1:{ps_port}'})
                 for r in range(2)]
             outs = _gather(trainers)
-            for out in outs:
-                losses = _json_line(out, 'LOSSES:')
-                # shared table: both trainers converge toward w_true
-                assert losses[-1] < 0.1 * losses[0], (losses[0],
-                                                      losses[-1])
+            curves = [_json_line(out, 'LOSSES:') for out in outs]
+            # shared table: both trainers converge toward w_true. The
+            # table's starting loss is what the FIRST puller saw: a
+            # trainer whose process starts after the other's 60 steps
+            # finds the table already converged.
+            start = max(c[0] for c in curves)
+            for losses in curves:
+                assert losses[-1] < 0.1 * start, (start, losses[-1])
         finally:
             srv.kill()
             srv.wait(timeout=30)

@@ -317,7 +317,7 @@ class TestCollectiveAPI:
 
     def test_allreduce_allgather_inside_spmd(self):
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from paddle_tpu.distributed import collective as C
         mesh = topology_runtime.build_mesh(['x'], [8])
         data = np.arange(32, dtype='float32').reshape(8, 4)
@@ -329,14 +329,14 @@ class TestCollectiveAPI:
                                                   axis_name='x'))
                 return t.data[None]
         out = jax.jit(shard_map(f, mesh=mesh, in_specs=P('x'),
-                                out_specs=P('x'), check_rep=False))(data)
+                                out_specs=P('x'), check_vma=False))(data)
         ref = data.sum(0)
         for row in np.asarray(out):
             np.testing.assert_allclose(row, ref)
 
     def test_ppermute_ring(self):
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from paddle_tpu.distributed import collective as C
         mesh = topology_runtime.build_mesh(['x'], [8])
         data = np.arange(8, dtype='float32').reshape(8, 1)
@@ -346,7 +346,7 @@ class TestCollectiveAPI:
                 t = C.shift(Tensor(a), offset=1)
                 return t.data
         out = jax.jit(shard_map(f, mesh=mesh, in_specs=P('x'),
-                                out_specs=P('x'), check_rep=False))(data)
+                                out_specs=P('x'), check_vma=False))(data)
         np.testing.assert_allclose(np.asarray(out).ravel(),
                                    np.roll(np.arange(8), 1))
 
@@ -399,7 +399,7 @@ class TestSequenceParallel:
 
     def test_ring_attention_matches_dense(self):
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from paddle_tpu.ops import ring_attention as ra
         from paddle_tpu.ops.pallas.flash_attention import (
             _reference_attention)
@@ -416,7 +416,7 @@ class TestSequenceParallel:
         out = jax.jit(shard_map(f, mesh=mesh,
                                 in_specs=(P(None, None, 'sp'),) * 3,
                                 out_specs=P(None, None, 'sp'),
-                                check_rep=False))(q, k, v)
+                                check_vma=False))(q, k, v)
         ref = _reference_attention(
             jnp.asarray(q).reshape(B * nh, L, hd),
             jnp.asarray(k).reshape(B * nh, L, hd),
@@ -427,7 +427,7 @@ class TestSequenceParallel:
 
     def test_ring_attention_grads_match(self):
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from paddle_tpu.ops import ring_attention as ra
         from paddle_tpu.ops.pallas.flash_attention import (
             _reference_attention)
@@ -445,7 +445,7 @@ class TestSequenceParallel:
                 return jnp.sum(o * o)
             f = shard_map(lambda a, b, c: jnp.array([inner(a, b, c)]),
                           mesh=mesh, in_specs=(P(None, None, 'sp'),) * 3,
-                          out_specs=P('sp'), check_rep=False)
+                          out_specs=P('sp'), check_vma=False)
             return jnp.sum(f(q_, k_, v_))
 
         g_ring = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
@@ -463,7 +463,7 @@ class TestSequenceParallel:
 
     def test_ulysses_matches_dense(self):
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from paddle_tpu.ops import ring_attention as ra
         from paddle_tpu.ops.pallas.flash_attention import (
             _reference_attention)
@@ -481,7 +481,7 @@ class TestSequenceParallel:
             return t.data
         out = jax.jit(shard_map(f, mesh=mesh, in_specs=P(None, 'sp'),
                                 out_specs=P(None, 'sp'),
-                                check_rep=False))(qkv)
+                                check_vma=False))(qkv)
         x5 = jnp.asarray(qkv).reshape(B, L, nh, 3, hd)
         q = x5[:, :, :, 0].transpose(0, 2, 1, 3).reshape(B * nh, L, hd)
         k = x5[:, :, :, 1].transpose(0, 2, 1, 3).reshape(B * nh, L, hd)

@@ -305,13 +305,19 @@ class TestBenchCompare:
         assert bc._verdict('lower', +0.05, 0.02) == 'regression'
         assert bc._verdict('higher', 0.01, 0.02) == 'flat'
 
-    def test_repo_artifacts_r04_r05(self):
+    def test_legacy_driver_artifacts(self, tmp_path):
+        """Two legacy-shape driver artifacts (record under `parsed`,
+        legs nested in detail) load, normalize and compare."""
         bc = self._bc()
-        root = os.path.dirname(HERE)
-        a = bc.normalize(bc.load_record(
-            os.path.join(root, 'BENCH_r04.json')))
-        b = bc.normalize(bc.load_record(
-            os.path.join(root, 'BENCH_r05.json')))
+        recs = []
+        for name, doc in (('old.json', bc.legacy_fixture(0.60, 1300.0)),
+                          ('new.json', bc.legacy_fixture(0.57, 1368.4))):
+            path = tmp_path / name
+            path.write_text(json.dumps(doc))
+            recs.append(bc.normalize(bc.load_record(str(path))))
+        a, b = recs
+        assert a['schema_version'] == 1
+        assert 'lenet_mnist' in a['legs']        # error legs lift too
         doc = bc.compare(a, b)
         head = {m['name']: m for leg in doc['legs']
                 for m in leg['metrics'] if leg['leg'] == bc.HEADLINE_LEG}

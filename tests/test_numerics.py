@@ -366,7 +366,9 @@ class TestGuardLifecycle:
         opt = paddle.optimizer.SGD(learning_rate=0.1,
                                    parameters=net.parameters())
         x = paddle.to_tensor(np.ones((2, 2), np.float32))
-        loss = paddle.exp(net(x).sum() * 1e9)       # overflow -> inf
+        # |sum|: the overflow must not hang on the sign the seeded init
+        # happens to draw (jax 0.9's stream makes the sum negative)
+        loss = paddle.exp(paddle.abs(net(x).sum()) * 1e9)   # -> inf
         loss.backward()
         scaler = GradScaler(init_loss_scaling=2.0,
                             decr_every_n_nan_or_inf=1)

@@ -105,7 +105,7 @@ class TestChunkedCollectives:
 
     def test_chunked_rs_ag_bit_exact(self):
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         mesh = _mesh(['dp'], [8])
         rng = np.random.RandomState(0)
         flat = jnp.asarray(rng.randn(8, 64), jnp.float32)
@@ -120,7 +120,7 @@ class TestChunkedCollectives:
                 return sh[None], full[None]
             return shard_map(body, mesh=mesh, in_specs=P('dp'),
                              out_specs=(P('dp'), P('dp')),
-                             check_rep=False)
+                             check_vma=False)
 
         base_sh, base_full = mk(None)(flat)
         for chunk in (16, 24):
@@ -260,64 +260,40 @@ class TestTrainStepOverlapNoop:
 
 
 class TestXlaFlagPlumbing:
-    def test_set_flags_edits_xla_flags_env_on_tpu(self, monkeypatch):
+    ENV = 'LIBTPU_INIT_ARGS'
+
+    def test_set_flags_edits_libtpu_init_args(self, monkeypatch):
+        """The xla_tpu_* tokens travel in LIBTPU_INIT_ARGS and never in
+        XLA_FLAGS (jaxlib aborts on an unknown XLA_FLAGS token, in this
+        process and in every child that inherits the env)."""
         from paddle_tpu.core import flags
-        saved_env = os.environ.get('XLA_FLAGS')
         saved = flags.get_flags(['FLAGS_xla_latency_hiding_scheduler',
                                  'FLAGS_xla_async_collectives'])
+        monkeypatch.setenv(self.ENV, '--keep_me=1')
+        xla_before = os.environ.get('XLA_FLAGS')
         try:
-            # the xla_tpu_* tokens only exist in TPU builds: they are
-            # exported on a TPU-plausible platform only (a CPU jaxlib
-            # ABORTS on unknown XLA_FLAGS, and children inherit env)
-            monkeypatch.setenv('JAX_PLATFORMS', 'tpu')
             flags.set_flags({'FLAGS_xla_latency_hiding_scheduler': True})
             assert '--xla_tpu_enable_latency_hiding_scheduler=true' \
-                in os.environ.get('XLA_FLAGS', '')
+                in os.environ[self.ENV]
             flags.set_flags(
                 {'FLAGS_xla_latency_hiding_scheduler': False})
-            env = os.environ.get('XLA_FLAGS', '')
+            env = os.environ[self.ENV]
             assert '--xla_tpu_enable_latency_hiding_scheduler=false' \
                 in env
             assert env.count('xla_tpu_enable_latency_hiding_scheduler')\
                 == 1
-        finally:
-            # restore the registry FIRST (it may re-edit XLA_FLAGS
-            # while the platform monkeypatch is still active), then
-            # put the env back exactly as found
-            flags.set_flags(saved)
-            if saved_env is None:
-                os.environ.pop('XLA_FLAGS', None)
-            else:
-                os.environ['XLA_FLAGS'] = saved_env
-
-    def test_cpu_platform_never_exports_tpu_tokens(self, monkeypatch):
-        from paddle_tpu.core import flags
-        saved_env = os.environ.get('XLA_FLAGS')
-        saved = flags.get_flags(['FLAGS_xla_latency_hiding_scheduler'])
-        try:
-            monkeypatch.setenv('JAX_PLATFORMS', 'cpu')
-            flags.set_flags({'FLAGS_xla_latency_hiding_scheduler': True})
-            # registry records the intent; env stays clean (a CPU-only
-            # jaxlib would fatally abort on the unknown token)
-            assert flags.flag('FLAGS_xla_latency_hiding_scheduler') \
-                is True
-            assert 'xla_tpu_enable_latency_hiding_scheduler' not in \
-                os.environ.get('XLA_FLAGS', '')
+            assert '--keep_me=1' in env
+            assert os.environ.get('XLA_FLAGS') == xla_before
         finally:
             flags.set_flags(saved)
-            if saved_env is None:
-                os.environ.pop('XLA_FLAGS', None)
-            else:
-                os.environ['XLA_FLAGS'] = saved_env
 
     def test_import_time_overlap_env_export(self, monkeypatch):
         """PTPU_COMM_OVERLAP=1 is honored at flags-module import —
-        the only point early enough to reach the backend's one-shot
-        XLA_FLAGS read (engine builds always run after init)."""
+        the only point early enough to reach libtpu's one-shot
+        LIBTPU_INIT_ARGS read (engine builds always run after init)."""
         import importlib.util
-        monkeypatch.setenv('JAX_PLATFORMS', 'tpu')
         monkeypatch.setenv('PTPU_COMM_OVERLAP', '1')
-        monkeypatch.setenv('XLA_FLAGS', '')
+        monkeypatch.setenv(self.ENV, '')
 
         def load(name):
             path = os.path.join(os.path.dirname(__file__), '..',
@@ -331,18 +307,18 @@ class TestXlaFlagPlumbing:
         assert mod.flag('FLAGS_xla_latency_hiding_scheduler') is True
         assert mod.flag('FLAGS_xla_async_collectives') is True
         assert '--xla_tpu_enable_latency_hiding_scheduler=true' in \
-            os.environ['XLA_FLAGS']
+            os.environ[self.ENV]
         # an explicit FLAGS_xla_* env pin beats the overlap default
         monkeypatch.setenv('FLAGS_xla_latency_hiding_scheduler', '0')
-        monkeypatch.setenv('XLA_FLAGS', '')
+        monkeypatch.setenv(self.ENV, '')
         mod2 = load('ptpu_flags_isolated2')
         assert mod2.flag('FLAGS_xla_latency_hiding_scheduler') is False
         assert '--xla_tpu_enable_latency_hiding_scheduler=false' in \
-            os.environ['XLA_FLAGS']
+            os.environ[self.ENV]
 
-    def test_ensure_overlap_flags_respects_user_pin(self):
+    def test_ensure_overlap_flags_respects_user_pin(self, monkeypatch):
         from paddle_tpu.core import flags
-        saved_env = os.environ.get('XLA_FLAGS')
+        monkeypatch.setenv(self.ENV, '')
         saved = flags.get_flags(['FLAGS_xla_latency_hiding_scheduler',
                                  'FLAGS_xla_async_collectives'])
         try:
@@ -357,10 +333,6 @@ class TestXlaFlagPlumbing:
             assert got['FLAGS_xla_latency_hiding_scheduler'] is False
             assert got['FLAGS_xla_async_collectives'] is True
         finally:
-            if saved_env is None:
-                os.environ.pop('XLA_FLAGS', None)
-            else:
-                os.environ['XLA_FLAGS'] = saved_env
             flags.set_flags(saved)
 
 

@@ -7,7 +7,7 @@ relative threshold — plus the step-time ledger breakdown side by side
 when either round carries one — so a bench round produces attributable
 numbers instead of a flat headline.
 
-    python tools/bench_compare.py BENCH_r04.json BENCH_r05.json
+    python tools/bench_compare.py BENCH_old.json BENCH_new.json
     python tools/bench_compare.py A.json B.json --json --threshold 0.05
     python tools/bench_compare.py --selftest
 
@@ -293,8 +293,24 @@ def render(cmp_doc):
 # ---------------------------------------------------------------------------
 # selftest
 # ---------------------------------------------------------------------------
-def _repo_root():
-    return os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+def legacy_fixture(mfu, ms_per_step):
+    """A driver artifact in the pre-schema (v1) shape: the record under
+    `parsed`, satellite legs — and their errors — nested inside the
+    headline's detail. The values are made up: this is a SHAPE fixture
+    for normalize()/compare(), not a measurement."""
+    return {'n': 0, 'cmd': 'python bench.py', 'rc': 0, 'tail': '',
+            'parsed': {
+                'metric': 'gpt1.3b_adamw_trainstep_mfu', 'value': mfu,
+                'unit': 'fraction', 'vs_baseline': round(mfu / 0.45, 4),
+                'detail': {
+                    'ms_per_step': ms_per_step,
+                    'tokens_per_sec': round(16384e3 / ms_per_step, 1),
+                    'params': 1315577856, 'seq_len': 2048,
+                    'microbatches': 4,
+                    'optimizer': 'adamw_bf16_moments',
+                    'gpt1.3b_sgd': {'mfu': mfu + 0.01,
+                                    'ms_per_step': ms_per_step - 20.0},
+                    'lenet_mnist': {'error': 'RESOURCE_EXHAUSTED'}}}}
 
 
 def selftest():
@@ -379,14 +395,18 @@ def selftest():
     assert 'serve ledger' in stext and 'page_stream' in stext, stext
     assert 'goodput_frac' in stext and 'host_bound' in stext, stext
 
-    # 2) the real r04 -> r05 artifacts: legacy-shape normalization and
-    # the asserted regression verdict (r05's headline MFU dropped 2.3%,
-    # past the 2% default threshold)
-    root = _repo_root()
-    r04 = os.path.join(root, 'BENCH_r04.json')
-    r05 = os.path.join(root, 'BENCH_r05.json')
-    a = normalize(load_record(r04))
-    b = normalize(load_record(r05))
+    # 2) two legacy-shape driver artifacts: normalization and the
+    # asserted regression verdict (headline MFU down 5%, past the 2%
+    # default threshold)
+    import tempfile
+    with tempfile.TemporaryDirectory() as tmp:
+        paths = []
+        for name, doc in (('old.json', legacy_fixture(0.60, 1300.0)),
+                          ('new.json', legacy_fixture(0.57, 1368.4))):
+            paths.append(os.path.join(tmp, name))
+            with open(paths[-1], 'w') as f:
+                json.dump(doc, f)
+        a, b = (normalize(load_record(p)) for p in paths)
     assert HEADLINE_LEG in a['legs'] and HEADLINE_LEG in b['legs']
     doc = compare(a, b)
     head = {m['name']: m for leg in doc['legs'] for m in leg['metrics']
