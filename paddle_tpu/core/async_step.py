@@ -38,6 +38,8 @@ import os
 import threading
 import time
 
+from ..profiler import RecordEvent
+
 
 DEFAULT_DISPATCH_WINDOW = 2
 DEFAULT_PREFETCH_DEPTH = 2
@@ -429,7 +431,8 @@ class AsyncDispatchMixin:
         happen here, never in the dispatch hot loop. The flush waits
         are a deliberate barrier — excluded from the next dispatch's
         host-gap sample."""
-        drained = self._inflight.flush()
+        with RecordEvent('train::flush', event_type='train'):
+            drained = self._inflight.flush()
         self._gap.drain_point()
         self._gap.publish()
         led = getattr(self, '_ledger', None)
@@ -460,12 +463,18 @@ class DispatchWindow:
 
     def push(self, result):
         self._q.append(result)
-        while len(self._q) > self.size:
-            # peek-then-pop: if the deferred drain work raises (e.g. a
-            # deferred NumericsError), the step STAYS at the head so a
-            # later flush() retries its remaining accounting
-            self._q[0].wait()
-            self._q.popleft()
+        if len(self._q) > self.size:
+            # the wait on step i-k: nested in the engine's
+            # train::dispatch span, so dispatch minus this is the
+            # host's own work
+            with RecordEvent('train::window_wait', event_type='train'):
+                while len(self._q) > self.size:
+                    # peek-then-pop: if the deferred drain work raises
+                    # (e.g. a deferred NumericsError), the step STAYS at
+                    # the head so a later flush() retries its remaining
+                    # accounting
+                    self._q[0].wait()
+                    self._q.popleft()
         return result
 
     def flush(self):

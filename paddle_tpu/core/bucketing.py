@@ -578,6 +578,7 @@ def grad_stats(flat):
                                    .astype(jnp.float32))
 
 
+@jax.named_scope('optimizer')
 def shard_update(optimizer, p_shard, g32_shard, st, lr, prefactor=None,
                  found_inf=None):
     """One bucket-shard optimizer update with fp32-master handling —

@@ -657,6 +657,7 @@ class HybridParallelTrainStep(A_.AsyncDispatchMixin, EngineTeardown):
                            out_specs=out_specs, check_vma=False)
         return jax.jit(mapped, donate_argnums=(0, 1))
 
+    @jax.named_scope('optimizer')
     def _update_one(self, p, g, st, lr):
         """Per-shard optimizer update with fp32 master handling (the same
         rule functional_apply uses, inlined for shard-level application)."""
@@ -764,7 +765,10 @@ class HybridParallelTrainStep(A_.AsyncDispatchMixin, EngineTeardown):
         in-flight window (PTPU_DISPATCH_WINDOW) lets the host run ahead,
         draining the oldest step — and its deferred taps work — as the
         window fills. `flush()` drains everything."""
-        return self._inflight.push(self._dispatch(batch))
+        from .... import profiler as _prof
+        with _prof.RecordEvent('train::dispatch', event_type='train',
+                               engine='hybrid', step=self._step_count):
+            return self._inflight.push(self._dispatch(batch))
 
     # -- DeviceLoader contract ------------------------------------------------
     def _input_spec(self, idx, nd):

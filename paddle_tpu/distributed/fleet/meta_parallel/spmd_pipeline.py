@@ -2110,6 +2110,7 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
 
         return self._finalize(step, dp_on)
 
+    @jax.named_scope('optimizer')
     def _update_one(self, p, g, st, lr):
         opt = self.optimizer
         low = p.dtype != jnp.float32
@@ -2273,7 +2274,12 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
         per-step path — scale-induced overflows can therefore resolve
         one window later; docs/performance.md#async-dispatch).
         `flush()` drains everything."""
-        return self._inflight.push(self._dispatch(data, scaler=scaler))
+        from .... import profiler as _prof
+        with _prof.RecordEvent('train::dispatch', event_type='train',
+                               engine='pipeline',
+                               step=getattr(self, '_pp_step', 0) + 1):
+            return self._inflight.push(
+                self._dispatch(data, scaler=scaler))
 
     def input_sharding(self, index, ndim):
         """DeviceLoader contract: batch tensors are dp-sharded on axis 0

@@ -36,6 +36,7 @@ import threading
 import numpy as np
 
 from ..core import async_step as _async
+from ..profiler import RecordEvent
 from ..core.tensor import Tensor
 
 
@@ -220,7 +221,10 @@ class DeviceLoader:
                         return
                     staged, slot_idx = self._stage(
                         self._host_arrays(batch))
-                    put_stop_aware(self._transfer(staged, slot_idx))
+                    with RecordEvent('loader::stage', event_type='loader',
+                                     bytes=sum(b.nbytes for b in staged)):
+                        item = self._transfer(staged, slot_idx)
+                    put_stop_aware(item)
             except Exception as e:          # surfaced on the consumer side
                 err.append(e)
             finally:
@@ -243,14 +247,15 @@ class DeviceLoader:
                 # producer whose sentinel was suppressed by the stop
                 # signal) must end the iteration, not deadlock a
                 # consumer blocked in a plain get()
-                while True:
-                    try:
-                        item = q.get(timeout=0.2)
-                        break
-                    except _queue.Empty:
-                        if stop.is_set() or not t.is_alive():
-                            item = sentinel
+                with RecordEvent('loader::wait', event_type='loader'):
+                    while True:
+                        try:
+                            item = q.get(timeout=0.2)
                             break
+                        except _queue.Empty:
+                            if stop.is_set() or not t.is_alive():
+                                item = sentinel
+                                break
                 # queue wait = the transfer is in flight on the producer
                 # thread, not idle host work: attribute it as blocked
                 # time for the next dispatch's host-gap sample (the

@@ -315,7 +315,10 @@ class TrainStep(_AsyncDispatchMixin):
         """Async dispatch (docs/performance.md#async-dispatch): returns
         an AsyncResult; the bounded in-flight window
         (PTPU_DISPATCH_WINDOW) drains the oldest step as it fills."""
-        return self._inflight.push(self._dispatch(batch))
+        from .. import profiler as _prof
+        with _prof.RecordEvent('train::dispatch', event_type='train',
+                               engine='jit', step=self._step_i):
+            return self._inflight.push(self._dispatch(batch))
 
     def input_sharding(self, index, ndim):
         """DeviceLoader contract: single-program step — batches go to

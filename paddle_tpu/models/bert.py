@@ -6,6 +6,7 @@ heads, trained via fleet sharding (dist_sharding tests pattern).
 """
 import math
 
+import jax
 import jax.numpy as jnp
 
 from .. import nn
@@ -55,6 +56,7 @@ class BertEmbeddings(nn.Layer):
                                        epsilon=config.layer_norm_eps)
         self.dropout = nn.Dropout(config.hidden_dropout)
 
+    @jax.named_scope('embed')
     def forward(self, input_ids, token_type_ids=None, position_ids=None):
         L = input_ids.shape[-1]
         if position_ids is None:
@@ -115,6 +117,11 @@ class BertForPretraining(nn.Layer):
         returns the pretraining loss, computed through the chunked fused
         projection-xent so the [B*L, vocab] logits never materialize."""
         seq, pooled = self.bert(input_ids, token_type_ids, attention_mask)
+        with jax.named_scope('head_loss'):
+            return self._heads(seq, pooled, masked_lm_labels,
+                               next_sentence_label)
+
+    def _heads(self, seq, pooled, masked_lm_labels, next_sentence_label):
         h = self.mlm_norm(F.gelu(self.mlm_transform(seq)))
         w = self.bert.embeddings.word_embeddings.weight
         nsp_logits = self.nsp(pooled)

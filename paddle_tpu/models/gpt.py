@@ -104,6 +104,7 @@ class GPTEmbeddings(nn.Layer):
             weight_attr=nn.ParamAttr(initializer=init))
         self.dropout = nn.Dropout(config.hidden_dropout)
 
+    @jax.named_scope('embed')
     def forward(self, input_ids, position_ids=None):
         if position_ids is None:
             L = input_ids.shape[-1]
@@ -378,23 +379,36 @@ class GPTDecoderLayer(nn.Layer):
         return F.dropout_add(sub_out, residual, p=self.hidden_dropout,
                              training=self.training)
 
+    # jax.named_scope puts `ln1/attn/ln2/mlp` on the op names of the
+    # profile (metadata.op_name); a Pallas call keeps its own `name=`
+    def _norm_attn(self, x, attn, *args, **kwargs):
+        with jax.named_scope('ln1'):
+            h = self.ln1(x)
+        with jax.named_scope('attn'):
+            return attn(h, *args, **kwargs)
+
+    def _norm_mlp(self, x):
+        with jax.named_scope('ln2'):
+            h = self.ln2(x)
+        with jax.named_scope('mlp'):
+            return self.mlp(h)
+
     def forward(self, x, cache=None, cache_len=None):
         if cache is not None:
-            a, new_cache = self.attn(self.ln1(x), cache=cache,
-                                     cache_len=cache_len)
+            a, new_cache = self._norm_attn(x, self.attn, cache=cache,
+                                           cache_len=cache_len)
             x = self._join(a, x)
-            x = self._join(self.mlp(self.ln2(x)), x)
+            x = self._join(self._norm_mlp(x), x)
             return x, new_cache
-        x = self._join(self.attn(self.ln1(x)), x)
-        x = self._join(self.mlp(self.ln2(x)), x)
+        x = self._join(self._norm_attn(x, self.attn), x)
+        x = self._join(self._norm_mlp(x), x)
         return x
 
     def forward_paged(self, x, kv, page_tables, seq_lens, q_lens):
-        a, new_kv = self.attn.forward_paged(self.ln1(x), kv,
-                                            page_tables, seq_lens,
-                                            q_lens)
+        a, new_kv = self._norm_attn(x, self.attn.forward_paged, kv,
+                                    page_tables, seq_lens, q_lens)
         x = self._join(a, x)
-        x = self._join(self.mlp(self.ln2(x)), x)
+        x = self._join(self._norm_mlp(x), x)
         return x, new_kv
 
 
@@ -421,7 +435,8 @@ class GPTModel(nn.Layer):
             for layer, c in zip(self.layers, caches):
                 x, nc = layer(x, cache=c, cache_len=cache_len)
                 new_caches.append(nc)
-            return self.final_norm(x), new_caches
+            with jax.named_scope('final_norm'):
+                return self.final_norm(x), new_caches
         qkv = self.layers[0].attn.qkv_proj if self.layers else None
         seqp = (_mp_seq_active() and qkv is not None
                 and qkv.world_size > 1)
@@ -437,7 +452,8 @@ class GPTModel(nn.Layer):
             x = C._c_slice_seq(x, group=qkv.group)
         for layer in self.layers:
             x = layer(x)
-        x = self.final_norm(x)
+        with jax.named_scope('final_norm'):
+            x = self.final_norm(x)
         if seqp:
             from ..distributed import collective as C
             x = C._c_gather_seq_replicated(x, group=qkv.group)
@@ -454,7 +470,8 @@ class GPTModel(nn.Layer):
             x, nc = layer.forward_paged(x, c, page_tables, seq_lens,
                                         q_lens)
             new_kv.append(nc)
-        return self.final_norm(x), new_kv
+        with jax.named_scope('final_norm'):
+            return self.final_norm(x), new_kv
 
     def init_caches(self, batch, max_len, dtype=None):
         import jax.numpy as _jnp
@@ -493,7 +510,8 @@ class GPTForCausalLM(nn.Layer):
             hidden = C._c_identity(
                 hidden, group=self.gpt.embeddings.word_embeddings.group)
         w = self.gpt.embeddings.word_embeddings.weight  # [V(/mp local), H]
-        logits = M.matmul(hidden, w, transpose_y=True)
+        with jax.named_scope('head'):
+            logits = M.matmul(hidden, w, transpose_y=True)
         return logits  # class dim vocab-parallel under mp
 
     @staticmethod
@@ -765,6 +783,7 @@ class GPTLMHead(nn.Layer):
             has_bias=False, gather_output=False)
         self.ce = ParallelCrossEntropy()
 
+    @jax.named_scope('head_loss')
     def forward(self, hidden, labels):
         h = self.norm(hidden)
         from ..distributed import collective as C

@@ -10,6 +10,7 @@ sequences when no additive mask is provided.
 """
 import collections
 
+import jax
 import jax.numpy as jnp
 
 from ...core.tensor import Tensor
@@ -241,15 +242,22 @@ class TransformerEncoderLayer(Layer):
         self.dropout2 = Dropout(dropout, mode="upscale_in_train")
         self.activation = getattr(F, activation)
 
+    def _norm(self, which, x):
+        # jax.named_scope: `norm1/attn/norm2/mlp` on the profile's op
+        # names (metadata.op_name); a Pallas call keeps its own `name=`
+        with jax.named_scope(which):
+            return getattr(self, which)(x)
+
     def forward(self, src, src_mask=None, cache=None):
         residual = src
         if self.normalize_before:
-            src = self.norm1(src)
-        if cache is None:
-            src = self.self_attn(src, src, src, src_mask)
-        else:
-            src, incremental_cache = self.self_attn(src, src, src, src_mask,
-                                                    cache)
+            src = self._norm('norm1', src)
+        with jax.named_scope('attn'):
+            if cache is None:
+                src = self.self_attn(src, src, src, src_mask)
+            else:
+                src, incremental_cache = self.self_attn(
+                    src, src, src, src_mask, cache)
         # remat boundary tag (docs/performance.md#remat-policy): the
         # attention output is a contraction boundary — saved under the
         # attn_mlp_boundaries policy, the joins/norms recompute
@@ -264,25 +272,26 @@ class TransformerEncoderLayer(Layer):
                             training=self.training,
                             mode=self.dropout1.mode)
         if not self.normalize_before:
-            src = self.norm1(src)
+            src = self._norm('norm1', src)
 
         residual = src
         if self.normalize_before:
-            src = self.norm2(src)
-        if self.activation is F.gelu and self.linear1.bias is not None:
-            h = F.bias_gelu(
-                _remat_tag(F.linear(src, self.linear1.weight),
-                           'mlp_fc1'),
-                self.linear1.bias)
-        else:
-            h = self.activation(
-                _remat_tag(self.linear1(src), 'mlp_fc1'))
-        src = _remat_tag(self.linear2(self.dropout(h)), 'mlp_out')
+            src = self._norm('norm2', src)
+        with jax.named_scope('mlp'):
+            if self.activation is F.gelu and self.linear1.bias is not None:
+                h = F.bias_gelu(
+                    _remat_tag(F.linear(src, self.linear1.weight),
+                               'mlp_fc1'),
+                    self.linear1.bias)
+            else:
+                h = self.activation(
+                    _remat_tag(self.linear1(src), 'mlp_fc1'))
+            src = _remat_tag(self.linear2(self.dropout(h)), 'mlp_out')
         src = F.dropout_add(src, residual, p=self.dropout2.p,
                             training=self.training,
                             mode=self.dropout2.mode)
         if not self.normalize_before:
-            src = self.norm2(src)
+            src = self._norm('norm2', src)
         return src if cache is None else (src, incremental_cache)
 
     def gen_cache(self, src):

@@ -54,17 +54,18 @@ _fit_block = scaffold.fit_block
 _interpret = scaffold.interpret_mode
 
 
-def _call(kernel, grid, in_specs, args, out_specs, out_shape):
-    """pl.pallas_call with the scoped-VMEM limit sized from the call's
-    blocks: the packed kernels hold whole-sequence [L, H*D] K/V (bwd:
+def _call(name, kernel, grid, in_specs, args, out_specs, out_shape):
+    """pl.pallas_call under `name` (the HLO instruction's name, so the
+    device trace's row) with the scoped-VMEM limit sized from the
+    call's blocks: the packed kernels hold whole-sequence [L, H*D] K/V (bwd:
     Q/dO) slabs — 40-52 MiB double-buffered at L=2048, H*D=2048 bf16,
     above Mosaic's 16 MiB default."""
     outs, ospecs = out_shape, out_specs
     if not isinstance(out_shape, tuple):
         outs, ospecs = (out_shape,), (out_specs,)
-    return pl.pallas_call(
+    return scaffold.pallas_call(
         kernel, out_shape=out_shape, grid=grid, in_specs=in_specs,
-        out_specs=out_specs,
+        out_specs=out_specs, name=name,
         compiler_params=scaffold.compiler_params(in_specs, args, ospecs,
                                                  outs),
         interpret=_interpret())(*args)
@@ -510,6 +511,7 @@ def _flash_forward(q, k, v, bias=None, num_heads=1, causal=True,
                                      lambda b, i: (b, i, 0)))
         args.append(dropout_mask)
     o, lse = _call(
+        'flash_attention_fwd',
         kernel, grid, in_specs, args,
         (pl.BlockSpec((None, block_q, d), lambda b, i: (b, i, 0)),
          pl.BlockSpec((None, block_q, 1), lambda b, i: (b, i, 0))),
@@ -545,6 +547,7 @@ def _flash_forward_packed(q, k, v, bias=None, num_heads=1, head_dim=64,
                                      lambda b, i: (b, 0, 0)))
         args.append(bias)
     o, lse = _call(
+        'flash_attention_fwd',
         kernel, (B, pl.cdiv(L, block_q)), in_specs, args,
         (pl.BlockSpec((None, block_q, hd), lambda b, i: (b, i, 0)),
          pl.BlockSpec((None, block_q, num_heads),
@@ -589,6 +592,7 @@ def _flash_backward_packed(q, k, v, o, lse, do, bias=None, num_heads=1,
     dq_in_specs += [row_spec, stat_blk, stat_blk]
     dq_args += [do, lse, delta]
     dq = _call(
+        'flash_attention_bwd_dq',
         functools.partial(_flash_bwd_dq_kernel_packed, block_k=block_k,
                           seq_len=L, scale=scale, causal=causal,
                           has_bias=has_bias, num_heads=num_heads,
@@ -604,6 +608,7 @@ def _flash_backward_packed(q, k, v, o, lse, do, bias=None, num_heads=1,
     dkv_in_specs += [full_spec, stat_full, stat_full]
     dkv_args += [do, lse, delta]
     dk, dv = _call(
+        'flash_attention_bwd_dkv',
         functools.partial(_flash_bwd_dkv_kernel_packed, block_q=block_q,
                           seq_len=L, scale=scale, causal=causal,
                           has_bias=has_bias, num_heads=num_heads,
@@ -656,6 +661,7 @@ def _flash_backward(q, k, v, o, lse, do, bias=None, num_heads=1,
     dq_args += [do, lse, delta]
 
     dq = _call(
+        'flash_attention_bwd_dq',
         functools.partial(_flash_bwd_dq_kernel, block_k=block_k, seq_len=L,
                           scale=scale, causal=causal, has_bias=has_bias,
                           has_dropout=has_dropout, inv_keep=inv_keep),
@@ -684,6 +690,7 @@ def _flash_backward(q, k, v, o, lse, do, bias=None, num_heads=1,
     dkv_args += [do, lse, delta]
 
     dk, dv = _call(
+        'flash_attention_bwd_dkv',
         functools.partial(_flash_bwd_dkv_kernel, block_q=block_q, seq_len=L,
                           scale=scale, causal=causal, has_bias=has_bias,
                           has_dropout=has_dropout, inv_keep=inv_keep),

@@ -126,13 +126,13 @@ def _bg_fwd_impl(x, bias, approximate):
     br = scaffold.pick_block_rows(N, ROW_BLOCK)
     x2 = scaffold.pad_rows(x.reshape(-1, N), br)
     rows = x2.shape[0]
-    o = pl.pallas_call(
+    o = scaffold.pallas_call(
         functools.partial(_bg_fwd_kernel, approximate=approximate),
         grid=(rows // br,),
         in_specs=[scaffold.row_spec(br, N), scaffold.bcast_spec(1, N)],
         out_specs=scaffold.row_spec(br, N),
         out_shape=jax.ShapeDtypeStruct((rows, N), x.dtype),
-        interpret=scaffold.interpret_mode(),
+        interpret=scaffold.interpret_mode(), name='bias_gelu_fwd',
     )(x2, bias.astype(x.dtype).reshape(1, N))
     R = x.reshape(-1, N).shape[0]
     return o[:R].reshape(shape)
@@ -150,7 +150,7 @@ def _bg_bwd(approximate, res, g):
     x2 = scaffold.pad_rows(x.reshape(-1, N), br)
     dy2 = scaffold.pad_rows(g.reshape(-1, N), br)
     rows = x2.shape[0]
-    dx, db = pl.pallas_call(
+    dx, db = scaffold.pallas_call(
         functools.partial(_bg_bwd_kernel, approximate=approximate),
         grid=(rows // br,),
         in_specs=[scaffold.row_spec(br, N), scaffold.bcast_spec(1, N),
@@ -159,7 +159,7 @@ def _bg_bwd(approximate, res, g):
         out_shape=(jax.ShapeDtypeStruct((rows, N), x.dtype),
                    jax.ShapeDtypeStruct((1, N), jnp.float32)),
         scratch_shapes=[pltpu.VMEM((1, N), jnp.float32)],
-        interpret=scaffold.interpret_mode(),
+        interpret=scaffold.interpret_mode(), name='bias_gelu_bwd',
     )(x2, bias.astype(x.dtype).reshape(1, N), dy2)
     R = x.reshape(-1, N).shape[0]
     return dx[:R].reshape(shape), db.reshape(N).astype(bias.dtype)
@@ -198,17 +198,17 @@ def dropout_add(x, residual, mask, p):
     return _da_fwd_impl(x, residual, mask, p)
 
 
-def _da_call(kernel, args, shape, dtype, n_in):
+def _da_call(name, kernel, args, shape, dtype, n_in):
     N = shape[-1]
     rows = args[0].shape[0]
     br = scaffold.pick_block_rows(N, ROW_BLOCK)
-    return pl.pallas_call(
+    return scaffold.pallas_call(
         kernel,
         grid=(rows // br,),
         in_specs=[scaffold.row_spec(br, N)] * n_in,
         out_specs=scaffold.row_spec(br, N),
         out_shape=jax.ShapeDtypeStruct((rows, N), dtype),
-        interpret=scaffold.interpret_mode(),
+        interpret=scaffold.interpret_mode(), name=name,
     )(*args)
 
 
@@ -217,7 +217,8 @@ def _da_fwd_impl(x, residual, mask, p):
     N = shape[-1]
     br = scaffold.pick_block_rows(N, ROW_BLOCK)
     pad = lambda a: scaffold.pad_rows(a.reshape(-1, N), br)
-    o = _da_call(functools.partial(_da_fwd_kernel, keep_prob=1.0 - p),
+    o = _da_call('dropout_add_fwd',
+                 functools.partial(_da_fwd_kernel, keep_prob=1.0 - p),
                  [pad(x), pad(residual), pad(mask)], shape, x.dtype, 3)
     R = x.reshape(-1, N).shape[0]
     return o[:R].reshape(shape)
@@ -232,7 +233,8 @@ def _da_bwd(p, mask, g):
     N = shape[-1]
     br = scaffold.pick_block_rows(N, ROW_BLOCK)
     pad = lambda a: scaffold.pad_rows(a.reshape(-1, N), br)
-    dx = _da_call(functools.partial(_da_bwd_kernel, keep_prob=1.0 - p),
+    dx = _da_call('dropout_add_bwd',
+                  functools.partial(_da_bwd_kernel, keep_prob=1.0 - p),
                   [pad(mask), pad(g)], shape, g.dtype, 2)
     R = g.reshape(-1, N).shape[0]
     return dx[:R].reshape(shape), g, jnp.zeros_like(mask)

@@ -94,7 +94,7 @@ def _fwd_pallas(x2, w, b, eps):
     br = scaffold.pick_block_rows(N, ROW_BLOCK)
     xp = scaffold.pad_rows(x2, br)
     rows = xp.shape[0]
-    o, mean, rstd = pl.pallas_call(
+    o, mean, rstd = scaffold.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
         grid=(rows // br,),
         in_specs=[scaffold.row_spec(br, N), scaffold.bcast_spec(1, N),
@@ -104,7 +104,7 @@ def _fwd_pallas(x2, w, b, eps):
         out_shape=(jax.ShapeDtypeStruct((rows, N), x2.dtype),
                    jax.ShapeDtypeStruct((rows, 1), jnp.float32),
                    jax.ShapeDtypeStruct((rows, 1), jnp.float32)),
-        interpret=scaffold.interpret_mode(),
+        interpret=scaffold.interpret_mode(), name='layer_norm_fwd',
     )(xp, w.reshape(1, N), b.reshape(1, N))
     return o[:R], mean, rstd
 
@@ -117,7 +117,7 @@ def _bwd_pallas(x2, w, dy2, mean, rstd):
     xp = scaffold.pad_rows(x2, br)
     dyp = scaffold.pad_rows(dy2, br)
     rows = xp.shape[0]
-    dx, dw, db = pl.pallas_call(
+    dx, dw, db = scaffold.pallas_call(
         _bwd_kernel,
         grid=(rows // br,),
         in_specs=[scaffold.row_spec(br, N), scaffold.bcast_spec(1, N),
@@ -130,7 +130,7 @@ def _bwd_pallas(x2, w, dy2, mean, rstd):
                    jax.ShapeDtypeStruct((1, N), jnp.float32)),
         scratch_shapes=[pltpu.VMEM((1, N), jnp.float32),
                         pltpu.VMEM((1, N), jnp.float32)],
-        interpret=scaffold.interpret_mode(),
+        interpret=scaffold.interpret_mode(), name='layer_norm_bwd',
     )(xp, w.reshape(1, N), dyp, mean, rstd)
     return dx[:R], dw.reshape(N), db.reshape(N)
 

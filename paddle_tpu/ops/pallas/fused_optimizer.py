@@ -99,14 +99,14 @@ def grad_stats_pallas(flat):
     x2 = scaffold.to_rows(flat.reshape(-1))
     rows = x2.shape[0]
     br = min(scaffold.ROW_BLOCK, rows)
-    s, c = pl.pallas_call(
+    s, c = scaffold.pallas_call(
         _stats_kernel,
         grid=(rows // br,),
         in_specs=[scaffold.row_spec(br, scaffold.LANES)],
         out_specs=(scaffold.scalar_spec(), scaffold.scalar_spec()),
         out_shape=(jax.ShapeDtypeStruct((1, 1), jnp.float32),
                    jax.ShapeDtypeStruct((1, 1), jnp.float32)),
-        interpret=scaffold.interpret_mode(),
+        interpret=scaffold.interpret_mode(), name='grad_stats',
     )(x2)
     return s[0, 0], c[0, 0]
 
@@ -212,13 +212,13 @@ def fused_shard_update(optimizer, p_shard, g32_shard, st, lr,
         scalar_keys=tuple(scalar_keys), has_master=has_master,
         use_pref=prefactor is not None, use_fi=found_inf is not None,
         wd=wd)
-    outs = pl.pallas_call(
+    outs = scaffold.pallas_call(
         kernel,
         grid=(rows // br,),
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
-        interpret=scaffold.interpret_mode(),
+        interpret=scaffold.interpret_mode(), name='fused_shard_update',
     )(sc, *vecs2d)
 
     o = 0

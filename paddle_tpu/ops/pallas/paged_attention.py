@@ -199,11 +199,12 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, page_tables,
         _ragged_paged_kernel, page_size=ps, num_heads=num_heads,
         head_dim=head_dim, pages_per_seq=P, quantized=quantized)
     out_dtype = q.dtype
-    return pl.pallas_call(
+    return scaffold.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, T, HD), out_dtype),
         interpret=_interpret() if interpret is None else interpret,
+        name='paged_attention',
     )(*inputs)
 
 

@@ -29,7 +29,9 @@ skeleton:
 Adding a kernel on this scaffolding costs the kernel body plus a
 ~20-line wrapper: pick a primitive name, call `use_kernel(name, flag)`
 to route, `to_rows`/`from_rows` or `row_spec`/`bcast_spec` for layout,
-and pass `interpret=interpret_mode()` to `pl.pallas_call`
+and call `scaffold.pallas_call(kernel, name=..., interpret=
+interpret_mode(), ...)` — the package's one `pl.pallas_call` site, which
+makes the kernel's name the device trace's row
 (docs/performance.md#fused-primitives walks through one).
 """
 import jax
@@ -48,6 +50,23 @@ ROW_BLOCK = 256
 
 KERNEL = 'kernel'
 FALLBACK = 'fallback'
+
+
+def pallas_call(kernel, *, name, **kwargs):
+    """`pl.pallas_call` whose Mosaic call is named `name` in the
+    compiled program, whatever transform surrounds it. XLA names the
+    instruction after the innermost component of the JAX name stack
+    (pallas_call pushes `name` there) — and a scope pushed directly
+    inside a transform renders as `transpose(jvp(name))`. The `pallas`
+    scope takes that wrapping instead, so the instruction, and with it
+    the profile's row, is `name` bare, under jax.grad and under
+    jax.checkpoint (tests/test_kernel_names_aot.py)."""
+    call = pl.pallas_call(kernel, name=name, **kwargs)
+
+    def named(*args):
+        with jax.named_scope('pallas'):
+            return call(*args)
+    return named
 
 
 def interpret_mode():

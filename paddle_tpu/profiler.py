@@ -3,18 +3,26 @@
 Reference parity: python/paddle/profiler (Profiler:331, make_scheduler,
 RecordEvent, export_chrome_tracing handlers) layered over the fluid-era
 API (profiler:314 context manager) and platform/profiler.cc +
-device_tracer.cc (N4). Two recorders share one API:
+device_tracer.cc (N4).
 
-  * native fast path — csrc/profiler.cc via ctypes when
-    libpaddle_tpu_native.so is present (the reference's C++ host-event
-    tables; drives the legacy summary()/export_chrome_tracing());
-  * pure-Python fallback — a thread-aware ring buffer of nested spans
-    (parent ids, depth, categories, kwargs args) that the v2 Profiler
-    always records into, so the chrome-trace/JSON exporters can emit
-    nesting and metadata the flat native table can't hold.
+One recorder. `RecordEvent` is the program's only span primitive and it
+ALWAYS records into one process-wide ring of completed spans (id,
+parent id, name, category, start and duration on
+`time.perf_counter_ns`, thread, depth, args) — a tuple per span, one
+lock acquisition, capacity fixed (`RING_CAPACITY`), overwritten spans
+counted. While a `jax.profiler` session is live
+(`jax.profiler.TraceAnnotation.is_enabled()`) the same span is also
+entered as a `TraceAnnotation`, so it sits in the xplane's host plane on
+the device trace's clock; with no session that costs one static call.
 
-Device-side timing is delegated to jax.profiler (XLA xplane), as the
-reference's device_tracer correlates CUPTI with host events —
+`spans(since_id)` reads the ring; `record_span` adds a span whose two
+ends lie in different calls (a request's life). The v2 `Profiler`'s
+RECORD windows and the fluid-era start/stop_profiler are VIEWS over the
+ring: they mark the next span id where the window opens and take the
+spans begun after it when it closes.
+
+Device-side timing is jax.profiler's (XLA xplane), as the reference's
+device_tracer correlates CUPTI with host events —
 `Profiler(targets=[ProfilerTarget.TPU])` brackets the RECORD window
 with jax.profiler.start_trace/stop_trace and stamps the logdir into the
 exported trace metadata.
@@ -26,90 +34,127 @@ FLOP estimates into core.monitor gauges — consumed by the hapi
 """
 import collections
 import contextlib
+import itertools
 import json
 import os
 import threading
 import time
 
-from .core.native import load_native
+from jax.profiler import TraceAnnotation as _Annotation
+
 from .core import monitor as _monitor
 
 _PID = os.getpid()
+_now_ns = time.perf_counter_ns
+_session_live = _Annotation.is_enabled      # a jax.profiler session is on
+
+# One record is a 10-tuple of small ints, two interned strings and an
+# optional args dict: ~250 bytes, ~500 with args. 32768 of them stay
+# under about 16 MB and hold 800 serving steps of 40 spans.
+RING_CAPACITY = 32768
+
+Span = collections.namedtuple(
+    'Span', 'id parent name cat start_ns dur_ns tid tname depth args')
 
 
 # ---------------------------------------------------------------------------
-# recorder state
+# the ring
 # ---------------------------------------------------------------------------
-class _SpanBuffer:
-    """Pure-Python ring buffer of completed spans (thread-safe)."""
+class _SpanRing:
+    """Fixed-capacity ring of completed spans, in order of completion."""
 
-    def __init__(self, capacity=200000):
-        self._spans = collections.deque(maxlen=capacity)
+    def __init__(self, capacity=RING_CAPACITY):
+        self.capacity = int(capacity)
+        self._slots = [None] * self.capacity
+        self._n = 0                      # spans appended since clear()
         self._lock = threading.Lock()
-        self._next_id = 0
 
-    def new_id(self):
+    def append(self, record):
         with self._lock:
-            self._next_id += 1
-            return self._next_id
+            n = self._n
+            self._slots[n % self.capacity] = record
+            self._n = n + 1
 
-    def append(self, span):
+    def snapshot(self, since_id=0):
         with self._lock:
-            self._spans.append(span)
-
-    def drain(self):
-        with self._lock:
-            out = list(self._spans)
-            self._spans.clear()
+            n, cap = self._n, self.capacity
+            if n <= cap:
+                out = self._slots[:n]
+            else:
+                cut = n % cap
+                out = self._slots[cut:] + self._slots[:cut]
+        if since_id:
+            out = [r for r in out if r[0] > since_id]
         return out
-
-    def snapshot(self):
-        with self._lock:
-            return list(self._spans)
 
     def clear(self):
         with self._lock:
-            self._spans.clear()
+            self._slots = [None] * self.capacity
+            self._n = 0
+
+    def overwritten(self):
+        return max(0, self._n - self.capacity)
 
     def __len__(self):
-        with self._lock:
-            return len(self._spans)
+        return min(self._n, self.capacity)
 
 
-_buffer = _SpanBuffer()
-_tls = threading.local()                 # per-thread open-span stack
-_legacy_on = False                       # fluid-era start/stop_profiler
-_tracer_depth = 0                        # v2 Profiler RECORD windows
-_force_python = os.environ.get(
-    'PADDLE_TPU_PROFILER_FORCE_PYTHON', '0') == '1'
+_ring = _SpanRing()
+_ids = itertools.count(1)                # next() is atomic in CPython
+_tls = threading.local()         # per-thread (open-span stack, tid, name)
+_legacy_mark = 0                         # fluid-era start_profiler's view
 
 
-def _native_lib():
-    if _force_python:
-        return None
-    return load_native()
+def _thread_state():
+    try:
+        return _tls.state
+    except AttributeError:
+        t = threading.current_thread()
+        _tls.state = state = ([], t.ident, t.name)
+        return state
 
 
-def use_native_recorder(flag):
-    """Force the pure-Python recorder off/on (tests exercise the
-    fallback path this way even when the .so is present)."""
-    global _force_python
-    _force_python = not flag
+def spans(since_id=0):
+    """The ring's spans in order of completion, as `Span` records;
+    `since_id` keeps those begun after that id (see `mark()`)."""
+    return [Span._make(r) for r in _ring.snapshot(since_id)]
 
 
-def _tracing_on():
-    return _legacy_on or _tracer_depth > 0
+def mark():
+    """An id that every span begun from now on exceeds: the start of a
+    view (`spans(since_id=mark())` later)."""
+    return next(_ids)
 
 
-def _now_us():
-    return time.perf_counter_ns() // 1000
+def overwritten_spans():
+    """Spans the ring has dropped (oldest first) since the last
+    reset_profiler()."""
+    return _ring.overwritten()
 
 
-def _stack():
-    st = getattr(_tls, 'stack', None)
-    if st is None:
-        st = _tls.stack = []
-    return st
+def record_span(name, start_ns, end_ns, event_type=None, **args):
+    """A span whose ends lie in different calls: stamp
+    `time.perf_counter_ns()` at each and record it here at the second.
+    It has no parent and is not mirrored into the device trace (an
+    annotation cannot be backdated). Returns the span's id."""
+    sid = next(_ids)
+    _, tid, tname = _thread_state()
+    _ring.append((sid, 0, name, event_type or 'python', int(start_ns),
+                  int(end_ns) - int(start_ns), tid, tname, 0, args or None))
+    return sid
+
+
+def _as_dict(r):
+    """The dict the exporters and ProfilerResult.spans have always
+    carried (microseconds)."""
+    return {'name': r[2], 'cat': r[3], 'ts': r[4] / 1000.0,
+            'dur': r[5] / 1000.0, 'tid': r[6], 'tname': r[7], 'id': r[0],
+            'parent': r[1], 'depth': r[8], 'args': r[9]}
+
+
+def span_dicts(since_id=0):
+    """`spans()` in the chrome-trace-ready dict form."""
+    return [_as_dict(r) for r in _ring.snapshot(since_id)]
 
 
 # ---------------------------------------------------------------------------
@@ -118,51 +163,47 @@ def _stack():
 class RecordEvent:
     """Parity: paddle.profiler.RecordEvent / platform::RecordEvent RAII.
 
-    Extra kwargs are recorded as chrome-trace `args` on the span
-    (byte counts, cache keys, shapes...). Usable as a context manager
-    or via explicit begin()/end().
+    Extra kwargs are recorded as the span's `args` (byte counts, cache
+    keys, shapes...). Usable as a context manager or via explicit
+    begin()/end(). Always recorded; mirrored into the device trace
+    while a jax.profiler session is live.
     """
 
-    __slots__ = ('name', 'event_type', 'args', '_start', '_id', '_lib')
+    __slots__ = ('name', 'event_type', 'args', '_start', '_id', '_ann')
 
     def __init__(self, name, event_type=None, **kwargs):
         self.name = name
         self.event_type = event_type
         self.args = kwargs or None
         self._start = None
-        self._id = None
-        self._lib = None
+        self._id = 0
+        self._ann = None
 
     def begin(self):
-        if not _tracing_on():
-            return
-        self._lib = _native_lib()
-        self._start = _now_us()
-        self._id = _buffer.new_id()
-        _stack().append(self._id)
+        if _session_live():
+            self._ann = _Annotation(self.name, **(self.args or {}))
+            self._ann.__enter__()
+        self._id = sid = next(_ids)
+        _thread_state()[0].append(sid)
+        self._start = _now_ns()
 
     def end(self):
-        if self._start is None:
+        end_ns = _now_ns()
+        start = self._start
+        if start is None:
             return
-        end_us = _now_us()
-        st = _stack()
+        self._start = None
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        st, tid, tname = _thread_state()
         if st and st[-1] == self._id:
             st.pop()
-        parent = st[-1] if st else 0
-        t = threading.current_thread()
-        _buffer.append({
-            'name': self.name, 'cat': self.event_type or 'python',
-            'ts': self._start, 'dur': end_us - self._start,
-            'tid': t.ident or 0, 'tname': t.name,
-            'id': self._id, 'parent': parent, 'depth': len(st),
-            'args': self.args,
-        })
-        if self._lib is not None and _legacy_on:
-            # native fast path mirrors the flat record (legacy
-            # summary()/export readers)
-            self._lib.ptpu_profiler_record(self.name.encode(),
-                                           self._start, end_us)
-        self._start = None
+        elif self._id in st:        # a child was begun and never ended
+            del st[st.index(self._id):]
+        _ring.append((self._id, st[-1] if st else 0, self.name,
+                      self.event_type or 'python', start, end_ns - start,
+                      tid, tname, len(st), self.args))
 
     def __enter__(self):
         self.begin()
@@ -173,76 +214,49 @@ class RecordEvent:
         return False
 
 
-@contextlib.contextmanager
-def record_function(name, **kwargs):
-    """Convenience alias (torch-style name) for RecordEvent."""
-    with RecordEvent(name, **kwargs):
-        yield
+record_function = RecordEvent       # torch-style alias
 
 
 # ---------------------------------------------------------------------------
-# legacy fluid-era API (kept verbatim in behavior)
+# legacy fluid-era API: a view over the ring since start_profiler()
 # ---------------------------------------------------------------------------
 def start_profiler(state='All', tracer_option='Default'):
-    global _legacy_on
-    _legacy_on = True
-    lib = _native_lib()
-    if lib is not None:
-        lib.ptpu_profiler_enable(1)
+    global _legacy_mark
+    _legacy_mark = mark()
 
 
 def stop_profiler(sorted_key=None, profile_path='/tmp/profile'):
-    global _legacy_on
-    _legacy_on = False
-    lib = _native_lib()
-    if lib is not None:
-        lib.ptpu_profiler_enable(0)
     print(summary())
     if profile_path:
         export_chrome_tracing(profile_path + '.json')
 
 
 def reset_profiler():
-    _buffer.clear()
-    lib = _native_lib()
-    if lib is not None:
-        lib.ptpu_profiler_clear()
+    _ring.clear()
 
 
 def summary():
-    """Aggregated name → calls/total/avg/min/max table. Native table
-    when the .so is present (fluid parity), else computed from the
-    Python ring buffer."""
-    lib = _native_lib()
-    if lib is not None:
-        import ctypes
-        cap = 1 << 20
-        buf = ctypes.create_string_buffer(cap)
-        lib.ptpu_profiler_summary(buf, cap)
-        return buf.value.decode()
+    """Aggregated name → calls/total/avg/min/max table of the spans
+    since start_profiler() (the whole ring if it was never called)."""
     agg = {}
-    for s in _buffer.snapshot():
-        a = agg.setdefault(s['name'], [0, 0, float('inf'), 0])
+    for r in _ring.snapshot(_legacy_mark):
+        dur = r[5] / 1000.0
+        a = agg.setdefault(r[2], [0, 0.0, float('inf'), 0.0])
         a[0] += 1
-        a[1] += s['dur']
-        a[2] = min(a[2], s['dur'])
-        a[3] = max(a[3], s['dur'])
+        a[1] += dur
+        a[2] = min(a[2], dur)
+        a[3] = max(a[3], dur)
     lines = ['name\tcalls\ttotal_ms\tavg_us\tmin_us\tmax_us']
     for name in sorted(agg):
         c, tot, mn, mx = agg[name]
         lines.append(f'{name}\t{c}\t{tot / 1000.0:.3f}\t{tot / c:.1f}'
-                     f'\t{mn}\t{mx}')
+                     f'\t{mn:.1f}\t{mx:.1f}')
     return '\n'.join(lines) + '\n'
 
 
 def export_chrome_tracing(path):
-    """Legacy flat export: native recorder's events when present, else
-    the Python buffer rendered to the same chrome-trace shape."""
-    lib = _native_lib()
-    if lib is not None:
-        lib.ptpu_profiler_export(path.encode())
-        return path
-    _write_chrome_trace(path, _buffer.snapshot())
+    """Legacy flat export of the same view as summary()."""
+    _write_chrome_trace(path, span_dicts(_legacy_mark))
     return path
 
 
@@ -255,16 +269,6 @@ def profiler(state='All', sorted_key=None, profile_path='/tmp/profile',
         yield
     finally:
         stop_profiler(sorted_key, profile_path)
-
-
-def native_dropped_events():
-    """Events the native ring buffer discarded since the last clear
-    (csrc/profiler.cc caps at ~1M events so a forgotten-enabled
-    profiler can't grow without bound)."""
-    lib = _native_lib()
-    if lib is None or not hasattr(lib, 'ptpu_profiler_dropped'):
-        return 0
-    return int(lib.ptpu_profiler_dropped())
 
 
 # ---- device-side (XLA) trace ------------------------------------------------
@@ -283,7 +287,7 @@ def stop_device_trace():
 # ---------------------------------------------------------------------------
 # chrome-trace / JSON writers
 # ---------------------------------------------------------------------------
-def _chrome_events(spans, metadata=None):
+def _chrome_events(spans):
     # spans may carry an explicit 'pid'/'pname' (synthetic track
     # groups — the serving request tracer puts each request on its own
     # virtual thread of a 'serving requests' pseudo-process so request
@@ -352,8 +356,8 @@ def _device_chrome_events(trace_dir):
     return events
 
 
-def _write_chrome_trace(path, spans, metadata=None):
-    doc = {'traceEvents': _chrome_events(spans)}
+def _write_chrome_trace(path, spans, metadata=None, device_events=()):
+    doc = {'traceEvents': _chrome_events(spans) + list(device_events)}
     if metadata:
         doc['metadata'] = metadata
     d = os.path.dirname(os.path.abspath(path))
@@ -438,12 +442,10 @@ def export_chrome_tracing_handler(dir_name, worker_name=None):
 class ProfilerResult:
     """Spans collected for one RECORD window, plus metadata."""
 
-    def __init__(self, spans, step_range=(0, 0), device_trace_dir=None,
-                 native_events=None):
+    def __init__(self, spans, step_range=(0, 0), device_trace_dir=None):
         self.spans = spans
         self.step_range = tuple(step_range)
         self.device_trace_dir = device_trace_dir
-        self.native_events = native_events or []
 
     def events(self):
         return list(self.spans)
@@ -456,20 +458,13 @@ class ProfilerResult:
         return md
 
     def export_chrome_tracing(self, path):
-        spans = self.spans + self.native_events
-        doc = {'traceEvents': _chrome_events(spans),
-               'metadata': self._metadata()}
         # best-effort merge of device-side events: TB/XLA profiler runs
         # that produced chrome-format dumps (*.trace.json[.gz]) fold in
         # under their own pids; xplane.pb-only runs stay referenced via
         # metadata.device_trace_dir (open with TB's profile plugin)
-        for ev in _device_chrome_events(self.device_trace_dir):
-            doc['traceEvents'].append(ev)
-        d = os.path.dirname(os.path.abspath(path))
-        os.makedirs(d, exist_ok=True)
-        with open(path, 'w') as f:
-            json.dump(doc, f)
-        return path
+        return _write_chrome_trace(
+            path, self.spans, self._metadata(),
+            _device_chrome_events(self.device_trace_dir))
 
     def export_json(self, path):
         d = os.path.dirname(os.path.abspath(path))
@@ -495,8 +490,8 @@ class ProfilerResult:
 
 class Profiler:
     """Parity: paddle.profiler.Profiler (2.x) — scheduler-driven RECORD
-    windows, on_trace_ready handlers, chrome/JSON export. The host
-    tracer is the Python span buffer; `targets` containing TPU/GPU also
+    windows, on_trace_ready handlers, chrome/JSON export. A window is a
+    view over the always-on span ring; `targets` containing TPU/GPU also
     brackets RECORD windows with jax.profiler device traces."""
 
     def __init__(self, targets=None, scheduler=None, on_trace_ready=None,
@@ -524,6 +519,7 @@ class Profiler:
         self.current_state = ProfilerState.CLOSED
         self._step_num = 0
         self._window_start = 0
+        self._window_mark = 0
         self._running = False
 
     # -- device bracket ------------------------------------------------------
@@ -553,36 +549,29 @@ class Profiler:
             self._device_tracing = False
 
     # -- state machine -------------------------------------------------------
-    def _tracer_enable(self):
-        global _tracer_depth
-        _tracer_depth += 1
-
-    def _tracer_disable(self):
-        global _tracer_depth
-        _tracer_depth = max(0, _tracer_depth - 1)
-
     def _transition(self, new_state):
         old = self.current_state
         rec = (ProfilerState.RECORD, ProfilerState.RECORD_AND_RETURN)
         if old not in rec and new_state in rec:
-            _buffer.drain()          # discard warmup noise
-            self._window_start = self._step_num
-            self._tracer_enable()
-            self._device_begin()
+            self._open_window()
         if old == ProfilerState.RECORD_AND_RETURN or \
                 (old in rec and new_state not in rec):
             self._device_end()
-            self._tracer_disable()
             self._collect()
             if new_state in rec:     # back-to-back windows (repeat)
-                self._window_start = self._step_num
-                self._tracer_enable()
-                self._device_begin()
+                self._open_window()
         self.current_state = new_state
+
+    def _open_window(self):
+        """A RECORD window is a view over the ring: the spans begun
+        after this mark, taken when the window closes."""
+        self._window_start = self._step_num
+        self._window_mark = mark()
+        self._device_begin()
 
     def _collect(self):
         self.profiler_result = ProfilerResult(
-            _buffer.drain(),
+            span_dicts(self._window_mark),
             step_range=(self._window_start, self._step_num),
             device_trace_dir=(self._device_trace_dir
                               if self._wants_device() else None))
@@ -612,7 +601,6 @@ class Profiler:
         rec = (ProfilerState.RECORD, ProfilerState.RECORD_AND_RETURN)
         if self.current_state in rec:
             self._device_end()
-            self._tracer_disable()
             self._collect()
         self.current_state = ProfilerState.CLOSED
         self._running = False
@@ -653,8 +641,13 @@ def compile_with_telemetry(jitted, label, args, kwargs=None):
     the plain jitted fn (ok=False). Callers keep `jitted` as dispatch
     fallback for signature drift."""
     kwargs = kwargs or {}
+    c_low = _monitor.counter('ptpu_lower_seconds_total',
+                             help='cumulative trace + lower seconds '
+                                  '(Python tracing to StableHLO)',
+                             labelnames=('site',))
     c_sec = _monitor.counter('ptpu_compile_seconds_total',
-                             help='cumulative XLA compile seconds',
+                             help='cumulative XLA compile seconds '
+                                  '(lowered.compile() alone)',
                              labelnames=('site',))
     c_num = _monitor.counter('ptpu_compiles_total',
                              help='XLA compilations', labelnames=('site',))
@@ -662,10 +655,11 @@ def compile_with_telemetry(jitted, label, args, kwargs=None):
         t0 = time.perf_counter()
         with RecordEvent(f'{label}::lower', event_type='compile'):
             lowered = jitted.lower(*args, **kwargs)
+        t1 = time.perf_counter()
+        c_low.inc(t1 - t0, site=label)
         with RecordEvent(f'{label}::compile', event_type='compile'):
             compiled = lowered.compile()
-        dt = time.perf_counter() - t0
-        c_sec.inc(dt, site=label)
+        c_sec.inc(time.perf_counter() - t1, site=label)
         c_num.inc(1, site=label)
         # buffer-assignment census: the executable's temp (activation)
         # bytes — the resident set remat policies shrink (ISSUE 12;
@@ -912,6 +906,6 @@ __all__ = [
     'export_chrome_tracing_handler', 'start_profiler', 'stop_profiler',
     'reset_profiler', 'summary', 'export_chrome_tracing', 'profiler',
     'start_device_trace', 'stop_device_trace', 'compile_with_telemetry',
-    'device_memory_stats', 'StepTelemetry', 'use_native_recorder',
-    'native_dropped_events',
+    'device_memory_stats', 'StepTelemetry', 'Span', 'spans', 'span_dicts',
+    'mark', 'record_span', 'overwritten_spans', 'RING_CAPACITY',
 ]

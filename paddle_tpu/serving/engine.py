@@ -63,7 +63,7 @@ from . import metrics as _metrics
 from .ledger import ServeLedger
 from ..core import monitor as _monitor
 from ..core.async_step import HostGapMonitor, unregister_monitor
-from ..profiler import RecordEvent
+from ..profiler import RecordEvent, record_span
 
 
 def _host_fetch(x):
@@ -417,6 +417,7 @@ class ServingEngine:
         self._next_sample_ord = 0
         self._jnp = jnp
         self._jax = jax
+        self._step_ordinal = 0          # the serve::step span's `step`
         # lifetime accounting for stats()/metrics
         self._decode_time = 0.0
         self._decode_tokens = 0
@@ -689,6 +690,7 @@ class ServingEngine:
             req.sample_ord = self._next_sample_ord
             self._next_sample_ord += 1
         self.scheduler.submit(req)
+        req.submit_ns = time.perf_counter_ns()
         self._submitted += 1
         if req.tenant_id is not None:
             self._tstat(req.tenant_id)['submitted'] += 1
@@ -733,7 +735,15 @@ class ServingEngine:
         """One scheduler iteration: admit waiting requests, advance one
         prefill chunk per prefilling request, then one batched decode
         step for the running set. Records a timeline entry, runs the
-        stalled-request watchdog, publishes metrics."""
+        stalled-request watchdog, publishes metrics. The whole of it is
+        one `serve::step` span; its children are the span table of
+        docs/serving.md#spans."""
+        self._step_ordinal += 1
+        with RecordEvent('serve::step', event_type='serve',
+                         step=self._step_ordinal):
+            self._step()
+
+    def _step(self):
         completed_before = self._completed
         preempt_before = self.scheduler.preemptions
         t_begin = self._gap.dispatch_begin()
@@ -746,8 +756,11 @@ class ServingEngine:
         self._it_prefill_ctx = 0
         t_sched = time.perf_counter()
         with RecordEvent('serve::schedule', event_type='serve'):
-            self._check_stalled()
-            admitted = self._admit()
+            with RecordEvent('serve::check_stalled', event_type='serve'):
+                self._check_stalled()
+            with RecordEvent('serve::admit', event_type='serve') as ev:
+                admitted = self._admit()
+                ev.args = {'admitted': admitted}
         sched_dt = time.perf_counter() - t_sched
         prefilling = [r for r in self.scheduler.slots
                       if r is not None and r.state == RequestState.PREFILL]
@@ -766,6 +779,23 @@ class ServingEngine:
                 # the surviving rows, tokens what they emitted (> slots
                 # when speculative decoding accepts drafts)
                 decode_slots, decode_tokens = self._decode_step()
+        fused = self._fused_last
+        self._fused_last = None
+        wall = time.perf_counter() - t_begin
+        with RecordEvent('serve::telemetry', event_type='serve'):
+            self._step_telemetry(
+                fused, wall, sched_dt, admitted, prefill_tokens,
+                decode_slots, decode_tokens,
+                self.scheduler.preemptions - preempt_before,
+                self._completed != completed_before)
+
+    def _step_telemetry(self, fused, wall, sched_dt, admitted,
+                        prefill_tokens, decode_slots, decode_tokens,
+                        preempted, retired):
+        """Everything in a step that observes and serves nothing (the
+        `serve::telemetry` span; ROADMAP D5): the timeline and ledger
+        records, the degrade ladder's pressure reading, host-tier
+        close-out, the gap monitor's close and the metrics publish."""
         # one observability record per decode ITERATION: a fused
         # window runs n_iter device iterations inside one dispatch,
         # and the timeline / ladder / ledger must see the same per-
@@ -776,10 +806,7 @@ class ServingEngine:
         # rules, ledger decode throughput) would read a kx-slower
         # engine. Admissions/preemptions/prefill attribute to the
         # first entry only: they happened once, before the window.
-        fused = self._fused_last
-        self._fused_last = None
         n_iter = fused['iters'] if fused else 1
-        wall = time.perf_counter() - t_begin
         for j in range(n_iter):
             first = (j == 0)
             self._observe_pressure()
@@ -792,8 +819,7 @@ class ServingEngine:
                 decode_tokens=(fused['rows'][j] if fused
                                else decode_tokens),
                 admissions=admitted if first else 0,
-                preemptions=(self.scheduler.preemptions - preempt_before
-                             if first else 0),
+                preemptions=preempted if first else 0,
                 waiting=len(self.scheduler.waiting),
                 pool_pages_in_use=self.pool.pages_in_use,
                 pool_pages_total=self.pool.num_pages,
@@ -849,7 +875,7 @@ class ServingEngine:
         # read), never to config.clock — an injected deterministic
         # clock, or fused windows retiring k tokens per step, must not
         # let gauge freshness lapse into `metrics_stale` alerts.
-        if (self._completed != completed_before
+        if (retired
                 or not self.scheduler.has_work
                 or (_monitor._time_fn() - self._last_publish_wall
                     >= self.PUBLISH_INTERVAL_S)):
@@ -1015,6 +1041,13 @@ class ServingEngine:
             req.quota_deferred = False
             budget -= need
             n_admitted += 1
+            if req.admit_ns is None and req.submit_ns is not None:
+                # first admit: the request's queueing ends here, on the
+                # span ring's clock (config.clock may be injected)
+                req.admit_ns = time.perf_counter_ns()
+                record_span('serve::request.queue', req.submit_ns,
+                            req.admit_ns, event_type='serve', req=req.id,
+                            prompt_tokens=len(req.prompt))
             if blocked_head is not None:
                 n_bypassed += 1
             self._trace(req,
@@ -1500,10 +1533,26 @@ class ServingEngine:
         self._it_decode_s += t1 - t0
         self._it_fetch += t2 - t1
         self._decode_time += t2 - t0
-        # host accept replays the serial append-then-check loop per
-        # row, so eos / max_new cuts truncate exactly where K serial
-        # iterations would have stopped (the device done-mask already
-        # idled the row past that point)
+        return len(rows), self._accepted(self._accept_fused, nxt, rows, K)
+
+    def _accepted(self, accept, *args):
+        """Run one of the host accept loops under its `serve::accept`
+        span (from the fetch's return to the end of the decode body);
+        returns the tokens it emitted."""
+        with RecordEvent('serve::accept', event_type='serve') as ev:
+            done_before = self._completed
+            emitted = accept(*args)
+            ev.args = {'emitted': emitted,
+                       'retired': self._completed - done_before}
+        return emitted
+
+    def _accept_fused(self, nxt, rows, K):
+        """Host accept of one fused window's [B, K] fetch. It replays
+        the serial append-then-check loop per row, so eos / max_new
+        cuts truncate exactly where K serial iterations would have
+        stopped (the device done-mask already idled the row past that
+        point). Returns the tokens emitted."""
+        B = self.config.max_batch_size
         emitted_total = 0
         per_iter_rows = [0] * K
         accepted = {}
@@ -1551,7 +1600,7 @@ class ServingEngine:
                 self._retire(req)
         self._fused_last = {'k': K, 'iters': iters_run,
                             'rows': per_iter_rows[:iters_run]}
-        return len(rows), emitted_total
+        return emitted_total
 
     def _page_row(self, req):
         row = self.pool.page_table(req.id)
@@ -1616,6 +1665,7 @@ class ServingEngine:
         req.prefilled = start + n
         self._prefill_tokens += n
         self._prefill_chunks += 1
+        req.prefill_chunks += 1
         # goodput: positions below the request's computed high-water
         # mark were forward-passed before (then destroyed by a
         # preemption release) — this chunk re-derives them, priced as
@@ -1648,20 +1698,33 @@ class ServingEngine:
             with RecordEvent('serve::sample_fetch', event_type='serve'):
                 tok = int(_host_fetch(nxt)[0])  # the sampled-token fetch
             self._it_fetch += time.perf_counter() - tf0
-            req.generated.append(tok)
-            if req.first_token_time is None:
-                req.first_token_time = self._clock()
-                ttft = req.first_token_time - req.submit_time
-                self._ttfts_s.append(ttft)
-                self._new_ttfts_s.append(ttft)
-                self._trace(req, 'first_token',
-                            t=req.first_token_time, tokens_generated=1,
-                            pages=len(self.pool.page_table(req.id)))
-            if req.done:
-                self._retire(req)
-            else:
-                req.state = RequestState.RUNNING
+            with RecordEvent('serve::accept', event_type='serve',
+                             req=req.id) as ev:
+                self._accept_first(req, tok)
+                ev.args.update(emitted=1, retired=int(req.done))
         return n
+
+    def _accept_first(self, req, tok):
+        """Host accept of the token a request's last prefill chunk
+        sampled: its first token (or, after a preemption, its next)."""
+        req.generated.append(tok)
+        if req.first_token_time is None:
+            req.first_token_time = self._clock()
+            if req.admit_ns is not None:
+                record_span('serve::request.prefill', req.admit_ns,
+                            time.perf_counter_ns(), event_type='serve',
+                            req=req.id, prompt_tokens=len(req.prompt),
+                            chunks=req.prefill_chunks)
+            ttft = req.first_token_time - req.submit_time
+            self._ttfts_s.append(ttft)
+            self._new_ttfts_s.append(ttft)
+            self._trace(req, 'first_token',
+                        t=req.first_token_time, tokens_generated=1,
+                        pages=len(self.pool.page_table(req.id)))
+        if req.done:
+            self._retire(req)
+        else:
+            req.state = RequestState.RUNNING
 
     def _decode_step(self):
         """One batched decode dispatch. With spec_k=0 every running
@@ -1785,6 +1848,15 @@ class ServingEngine:
         self._decode_steps += 1
         self._occupancy_sum += len(active) / B
         self._util_sum += self.pool.utilization()
+        emitted_total = self._accepted(self._accept_decode, nxt, active,
+                                       verify, T)
+        self._decode_tokens += emitted_total
+        return len(active), emitted_total
+
+    def _accept_decode(self, nxt, active, verify, T):
+        """Host accept of one [B, T] decode/verify fetch: token append,
+        done/EOS checks, draft rollback, prefix registration, retire.
+        Returns the tokens emitted."""
         emitted_total = 0
         for i, req, drafts in active:
             spec_m = None
@@ -1843,8 +1915,7 @@ class ServingEngine:
                         pages=len(self.pool.page_table(req.id)))
             if req.done:
                 self._retire(req)
-        self._decode_tokens += emitted_total
-        return len(active), emitted_total
+        return emitted_total
 
     def _retire(self, req):
         self.pool.release(req.id)
@@ -2122,8 +2193,8 @@ class ServingEngine:
             out['jsonl'] = self.tracer.export_jsonl(jsonl_path)
         if chrome_path:
             from .. import profiler as _prof
-            spans = [s for s in _prof._buffer.snapshot()
-                     if s.get('cat') == 'serve']
+            spans = [s for s in _prof.span_dicts()
+                     if s['cat'] == 'serve']
             out['chrome'] = self.tracer.export_chrome_tracing(
                 chrome_path, extra_spans=spans)
         return out

@@ -347,6 +347,12 @@ class Request:
         self.state = RequestState.WAITING
         self.submit_time = None
         self.admit_time = None           # first admit (queue-wait end)
+        # the same two instants on the span ring's clock
+        # (time.perf_counter_ns, engine-stamped) and the prefill chunks
+        # run: the serve::request.queue / .prefill spans
+        self.submit_ns = None
+        self.admit_ns = None
+        self.prefill_chunks = 0
         self.admit_bypasses = 0          # followers admitted past this
                                          # request while it sat at the
                                          # queue head over-budget

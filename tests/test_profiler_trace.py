@@ -1,6 +1,5 @@
 """Observability v2 tests: span tracer, scheduler state machine,
-chrome-trace/JSON export (with and without the native recorder),
-executor compile-cache counters, Prometheus exposition, and the
+chrome-trace/JSON export, executor compile-cache counters, Prometheus exposition, and the
 end-to-end acceptance run (training under Profiler produces nested
 executor/compile/dataloader/collective spans + a metrics snapshot with
 compile-cache hit/miss, step throughput and per-collective bytes)."""
@@ -23,10 +22,9 @@ S = prof.ProfilerState
 
 @pytest.fixture
 def python_recorder():
-    """Force the pure-Python ring-buffer fallback (native lib off)."""
-    prof.use_native_recorder(False)
+    """There is one recorder (the span ring); kept so the tests that
+    named the old Python fallback read as before."""
     yield
-    prof.use_native_recorder(True)
 
 
 @pytest.fixture
@@ -88,10 +86,26 @@ class TestSpans:
         assert by_name['in_thread']['parent'] == 0
 
     def test_no_recording_when_closed(self, python_recorder):
+        """The ring is always on; a Profiler's result is a view that
+        holds no span from outside its windows — not one that ended
+        before, not one that straddles the window's start, not one
+        after its end."""
         with prof.RecordEvent('outside_any_window'):
             pass
-        res = _record_window(lambda: None)
-        assert all(s['name'] != 'outside_any_window' for s in res.spans)
+        straddler = prof.RecordEvent('begun_before_the_window')
+        straddler.begin()
+        p = prof.Profiler()
+        p.start()
+        straddler.end()
+        with prof.RecordEvent('inside'):
+            pass
+        p.stop()
+        with prof.RecordEvent('after_the_window'):
+            pass
+        assert [s['name'] for s in p.profiler_result.spans] == ['inside']
+        ring = [s.name for s in prof.spans()]
+        assert {'outside_any_window', 'begun_before_the_window',
+                'after_the_window'} <= set(ring)
 
 
 # ---------------------------------------------------------------------------
@@ -180,13 +194,6 @@ class TestExport:
         doc = self._trace(tmp_path, 'json', 'raw.json')
         assert [s['name'] for s in doc['spans']] == ['sub', 'work']
 
-    def test_chrome_trace_with_native_recorder(self, tmp_path):
-        """Default path: the native lib (when present) keeps serving the
-        legacy flat export; the v2 exporter is unaffected."""
-        doc = self._trace(tmp_path, 'chrome', 'n.trace.json')
-        assert {e['name'] for e in doc['traceEvents']
-                if e['ph'] == 'X'} == {'work', 'sub'}
-
     def test_export_handler_writes_file(self, tmp_path, python_recorder):
         handler = prof.export_chrome_tracing_handler(str(tmp_path / 'd'))
         p = prof.Profiler(on_trace_ready=handler)
@@ -199,7 +206,7 @@ class TestExport:
 
     def test_legacy_fallback_summary_and_export(self, tmp_path,
                                                 python_recorder):
-        """fluid-era API on the pure-Python recorder (.so absent)."""
+        """fluid-era API: a view over the ring since start_profiler()."""
         prof.reset_profiler()
         prof.start_profiler()
         try:
@@ -291,7 +298,7 @@ class TestPrometheus:
 
 
 # ---------------------------------------------------------------------------
-# end-to-end acceptance: training under Profiler (pure-Python recorder)
+# end-to-end acceptance: training under Profiler
 # ---------------------------------------------------------------------------
 class TestEndToEndTrace:
     def test_training_trace_and_metrics(self, tmp_path, python_recorder,

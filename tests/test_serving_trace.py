@@ -183,7 +183,6 @@ class TestRequestTracing:
             page_size=8, max_batch_size=3, prefill_chunk=8, clock=clock))
         # record the engine-phase spans so the chrome export carries
         # both requests (tracks) and serve::* steps
-        prof.use_native_recorder(False)
         p = prof.Profiler(scheduler=None, timer_only=True)
         p.start()
         eng.generate(mixed_prompts[:3], max_new_tokens=4, top_k=0)
@@ -191,7 +190,6 @@ class TestRequestTracing:
         chrome = str(tmp_path / 'serve.trace.json')
         paths = eng.export_trace(jsonl_path=jsonl, chrome_path=chrome)
         p.stop()
-        prof.use_native_recorder(True)
 
         header, events = load_trace(paths['jsonl'])
         assert header['schema'] == 'paddle_tpu.serve_trace/6'

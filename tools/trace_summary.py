@@ -24,15 +24,25 @@ back to the file stem), with SLO percentiles over the whole cluster:
 
     python tools/trace_summary.py --serve r0.jsonl r1.jsonl
 
+With `--xplane DIR` it reads a jax.profiler trace directory instead
+(the `.xplane.pb` under it): the device's busy union on the `XLA Ops`
+line, every idle gap charged to the innermost PROGRAM span (any
+`prefix::name` the span ring mirrored into the host plane, PR 25) that
+holds the gap's midpoint, the host spans' own totals, and device time by
+kernel — the table `benchmarks/trace_reduce.py` cannot give while it
+keeps only `bench::` spans.
+
 Usage:
     python tools/trace_summary.py TRACE.json [--top 15] [--json]
     python tools/trace_summary.py SERVE_TRACE.jsonl [...] [--json]
+    python tools/trace_summary.py --xplane TRACE_DIR [--top 15] [--json]
     python tools/trace_summary.py --selftest    # CI smoke: generate a
                                                 # tiny trace, summarize it
 """
 import argparse
 import json
 import os
+import re
 import sys
 
 
@@ -455,6 +465,127 @@ def _serve_selftest():
     print('trace_summary serve selftest: OK')
 
 
+# ---------------------------------------------------------------------------
+# device traces (.xplane.pb): idle gaps by program span, time by kernel
+# ---------------------------------------------------------------------------
+# a span of the program's ring or of the benchmark: lower-case
+# `prefix::name`; the runtime's own C++ TraceMes (`Foo::Bar`) are not
+PROGRAM_SPAN = re.compile(r'^[a-z0-9_.]+::[a-z0-9_.]+$')
+
+
+def load_device_trace(trace_dir):
+    """(device ops per chip {n: [[hlo text, start_ns, dur_ns]]}, program
+    spans [[name, start_ns, dur_ns]]) of the newest xplane under
+    `trace_dir`. Op classes and the planes' names are the benchmark's
+    (benchmarks/trace_reduce.py), so the rows match the ledger's."""
+    import warnings
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmarks import trace_reduce as tr
+    from jax.profiler import ProfileData
+    chips, spans = {}, []
+    with warnings.catch_warnings():
+        warnings.simplefilter('ignore')
+        data = ProfileData.from_file(tr.find_xplane(trace_dir))
+        for plane in data.planes:
+            device = tr.DEVICE_PLANE.match(plane.name)
+            for line in plane.lines:
+                if device and line.name == tr.OPS_LINE:
+                    chips[int(device.group(1))] = [
+                        [e.name, float(e.start_ns), float(e.duration_ns)]
+                        for e in line.events]
+                elif plane.name == tr.HOST_PLANE:
+                    spans += [[e.name, float(e.start_ns),
+                               float(e.duration_ns)] for e in line.events
+                              if PROGRAM_SPAN.match(e.name)]
+    return chips, spans
+
+
+def summarize_device_trace(chips, spans, top=15):
+    """Seconds throughout. Per chip the window (first op start to last
+    op end), the busy union, time by class of op, and the idle gaps
+    charged by program span; `host_spans` are the spans' own totals
+    inside the device window of chip 0."""
+    import numpy as np
+    from benchmarks import trace_reduce as tr
+    starts = np.array([s for _, s, _ in spans])
+    durs = np.array([d for _, _, d in spans])
+    out = {'chips': {}}
+    for n, events in sorted(chips.items()):
+        if not events:
+            continue
+        ops = {}
+        for text, _, dur in events:
+            if not any(mark in text for mark in tr.CONTAINERS):
+                cls = tr.op_class(text)
+                ops[cls] = ops.get(cls, 0.0) + dur * 1e-9
+        busy = tr._union([s, s + d] for _, s, d in events)
+        gaps, counts = {}, {}
+        for (_, end), (start, _) in zip(busy, busy[1:]):
+            name = 'unattributed'
+            if len(spans):
+                t = 0.5 * (end + start)
+                held = np.nonzero((starts <= t) & (t <= starts + durs))[0]
+                if len(held):
+                    name = spans[held[np.argmin(durs[held])]][0]
+            gaps[name] = gaps.get(name, 0.0) + (start - end) * 1e-9
+            counts[name] = counts.get(name, 0) + 1
+        window = (busy[-1][1] - busy[0][0]) * 1e-9
+        busy_s = sum(e - s for s, e in busy) * 1e-9
+
+        def ranked(table):
+            return sorted(table.items(), key=lambda kv: -kv[1])[:top]
+        out['chips'][n] = {
+            'window_s': window, 'busy_s': busy_s,
+            'idle_s': window - busy_s,
+            'idle_gaps': [[k, v, counts[k]] for k, v in ranked(gaps)],
+            'device_ops': [[k, v] for k, v in ranked(ops)]}
+    first = min(chips) if chips else None
+    host = {}
+    if first is not None and chips[first]:
+        lo = min(s for _, s, _ in chips[first])
+        hi = max(s + d for _, s, d in chips[first])
+        for name, s, d in spans:
+            if lo <= s and s + d <= hi:
+                calls, total = host.get(name, (0, 0.0))
+                host[name] = (calls + 1, total + d * 1e-9)
+    out['host_spans'] = [[k, c, t] for k, (c, t) in sorted(
+        host.items(), key=lambda kv: -kv[1][1])[:max(top, 24)]]
+    return out
+
+
+def render_device_trace(summary):
+    out = []
+    for n, c in summary['chips'].items():
+        idle = c['idle_s']
+        out.append(f'-- chip {n}: window {c["window_s"]:.4f} s, busy '
+                   f'{c["busy_s"]:.4f} s, idle {idle:.4f} s '
+                   f'({100 * idle / c["window_s"]:.2f} %) ' + '-' * 8)
+        # mean_us tells the device's own gaps between the ops of one
+        # program (microseconds, thousands of them) from the host
+        # holding the device up (milliseconds, one a step)
+        out.append(f"{'idle gaps by innermost program span':<40} "
+                   f"{'gaps':>6} {'idle_ms':>10} {'of idle':>8} "
+                   f"{'mean_us':>9}")
+        for name, s, count in c['idle_gaps']:
+            out.append(f'{name[:40]:<40} {count:>6} {s * 1e3:>10.3f} '
+                       f'{100 * s / idle if idle else 0:>7.1f}% '
+                       f'{s / count * 1e6:>9.1f}')
+        out.append('')
+        out.append(f"{'device time by op class':<48} {'ms':>10} "
+                   f"{'of busy':>8}")
+        for name, s in c['device_ops']:
+            out.append(f'{name[:48]:<48} {s * 1e3:>10.3f} '
+                       f'{100 * s / c["busy_s"]:>7.1f}%')
+        out.append('')
+    out.append(f"{'program spans inside the device window':<40} "
+               f"{'calls':>6} {'total_ms':>10} {'avg_us':>10}")
+    for name, calls, total in summary['host_spans']:
+        out.append(f'{name[:40]:<40} {calls:>6} {total * 1e3:>10.3f} '
+                   f'{total / calls * 1e6:>10.1f}')
+    return '\n'.join(out)
+
+
 def _selftest():
     """CI smoke: record a trace through the real tracer, export both
     formats, summarize, and assert the breakdown is sane."""
@@ -463,7 +594,6 @@ def _selftest():
         os.path.abspath(__file__))))
     import paddle_tpu.profiler as prof
 
-    prof.use_native_recorder(False)
     results = []
     p = prof.Profiler(on_trace_ready=lambda pr: results.append(
         pr.profiler_result))
@@ -477,7 +607,6 @@ def _selftest():
         with prof.RecordEvent('dataloader::next', event_type='dataloader'):
             pass
     p.stop()
-    prof.use_native_recorder(True)
 
     with tempfile.TemporaryDirectory() as d:
         ok = True
@@ -512,13 +641,21 @@ def main(argv=None):
                     help='machine-readable output')
     ap.add_argument('--serve', action='store_true',
                     help='force serve-trace (per-request SLO) mode')
+    ap.add_argument('--xplane', metavar='DIR',
+                    help='a jax.profiler trace directory: idle gaps by '
+                         'program span and device time by kernel')
     ap.add_argument('--selftest', action='store_true',
                     help='generate a synthetic trace and summarize it')
     args = ap.parse_args(argv)
     if args.selftest:
         return _selftest()
+    if args.xplane:
+        s = summarize_device_trace(*load_device_trace(args.xplane),
+                                   top=args.top)
+        print(json.dumps(s) if args.json else render_device_trace(s))
+        return 0
     if not args.trace:
-        ap.error('trace path required (or --selftest)')
+        ap.error('trace path required (or --selftest / --xplane)')
     if args.serve or all(_looks_like_serve_trace(p)
                          for p in args.trace):
         s = summarize_serve(args.trace)
