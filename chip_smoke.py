@@ -259,14 +259,18 @@ def phase_kernels(size):
     P = -(-(size['prompt_hi'] + size['new_tokens']) // size['page_size'])
     Bs = size['batch']
 
-    def paged_case(Bq, T, ps, int8):
+    def paged_case(Bq, T, ps, int8, every_seq=None):
         n_pages = Bq * P + 3
         cap = P * ps
-        seq = rng.randint(T, cap + 1, Bq).astype(np.int32)
-        ql = rng.randint(1, T + 1, Bq).astype(np.int32)
-        ql[0] = T
-        if Bq > 1:          # an idle slot, as the engine pads them
-            seq[-1], ql[-1] = 1, 0
+        if every_seq is None:
+            seq = rng.randint(T, cap + 1, Bq).astype(np.int32)
+            ql = rng.randint(1, T + 1, Bq).astype(np.int32)
+            ql[0] = T
+            if Bq > 1:      # an idle slot, as the engine pads them
+                seq[-1], ql[-1] = 1, 0
+        else:               # every row at one length, no idle slot
+            seq = np.full(Bq, every_seq, np.int32)
+            ql = np.full(Bq, min(T, every_seq), np.int32)
         pt = np.stack([rng.permutation(n_pages)[:P] for _ in range(Bq)]) \
             .astype(np.int32)
         qq = rand((Bq, T, HD))
@@ -287,7 +291,8 @@ def phase_kernels(size):
         ref = ref_call(call(pa.ragged_paged_attention_dense), *args)
         valid = (np.arange(T)[None, :] < ql[:, None])[..., None]
         record(f'paged_attention B={Bq} T={T} ps={ps} '
-               f'{"int8" if int8 else "bf16"}',
+               f'{"int8" if int8 else "bf16"}'
+               + ('' if every_seq is None else f' seq={every_seq}'),
                np.where(valid, np.asarray(got, np.float32), 0),
                np.where(valid, np.asarray(ref, np.float32), 0), TOL_BF16)
 
@@ -297,6 +302,12 @@ def phase_kernels(size):
         # the aligned shape; the engine's default 16-slot pages too
         paged_case(Bq, T, 2 * size['page_size'], True)
         paged_case(Bq, T, size['page_size'], True)
+    # the page loop's two ends: every table slot live (the last DMA wave
+    # whole), and one token (one page of a wave, the rest never copied)
+    for int8 in (False, True):
+        paged_case(Bs, 1, size['page_size'], int8,
+                   every_seq=P * size['page_size'])
+        paged_case(Bs, 1, size['page_size'], int8, every_seq=1)
 
     # -- fused optimizer step + grad stats vs core.bucketing.shard_update --
     n = size['opt_elems']

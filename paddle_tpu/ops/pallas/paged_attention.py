@@ -9,17 +9,50 @@ page table; the kernel gathers that row's pages and applies causal
 attention *within the sequence*, so the compiled step has one fixed
 shape regardless of how ragged the batch is.
 
-TPU-native shape: a `PrefetchScalarGridSpec` grid over (batch_row,
-page). The page table and the per-row lengths are scalar-prefetched, so
-the BlockSpec index map for K/V resolves `page_tables[b, p]` *before*
-the kernel body runs — the pages stream HBM→VMEM exactly like the flash
-kernel's K/V blocks, no host gather and no [B, max_len, H*D]
-materialization (that is the dense fallback below). Online-softmax
-state (running max / normalizer / fp32 accumulator) persists in VMEM
-scratch across a row's page steps; heads run as static column slices of
-the packed [T, H*D] slab (the flash_attention.py packed-layout idiom —
-Tensor Processing Primitives, arXiv:2104.05755: one small reusable
-kernel beside the existing ones, not a monolith).
+TPU-native shape: work in proportion to each row's OWN context. A
+`PrefetchScalarGridSpec` grid over batch rows only; the page table and
+the per-row lengths are scalar-prefetched into SMEM and the K/V pools
+stay in HBM (`memory_space=HBM`, no BlockSpec window). Inside a row's
+program a `fori_loop` runs over ceil(live_pages / W) DMA WAVES, where
+live_pages = ceil(seq_len / page_size): a wave starts one
+`make_async_copy` per live page of K and of V (page ids read from the
+SMEM table) into one of the two slots of a `[2, W, page_size, H*D]`
+VMEM scratch, the next wave in flight while this one is folded into
+the online softmax — and under a row's last wave the next row's first,
+so a row does not open on a cold copy (0.40 -> 0.34 ms for the 1.3B
+decode call at ragged 400-token contexts, PERF.md section 5). No page
+past seq_len is copied or scheduled, table slots past
+it are never read (sentinels there are never dereferenced), and a row
+with q_len == 0 starts no copy and writes zeros. A partial last wave
+leaves its other pages as the previous wave left them; `key_pos <
+seq_len` masks them (the V slots are zeroed once, so 0 * v never meets
+an uninitialised NaN).
+
+W (pages of K, and as many of V, per wave) is derived in the wrapper,
+`_wave_pages`: `_WAVE_BYTES` (1 MiB of K+V) over one page's bytes —
+8 pages = 128 keys at 16 slots x 2048 x bf16 — shrunk until the two
+slots fit `_VMEM_BUDGET` beside the double-buffered q / out blocks,
+the fp32 accumulator [rows, H*D] and, for int8 pools, the row's scale
+blocks; rounded down to a power of two; the call's `vmem_limit_bytes`
+follows from the same sum. They are constants of this file, not flags.
+
+Two score products, chosen by the static query width T:
+  * T*H <= `_BATCHED_ROWS` (decode, speculative verify): the wrapper
+    lays q out block-diagonally, row t*H+h = query t masked to head
+    h's columns, so ONE [T*H, H*D] x [H*D, keys] product scores every
+    head against the wave, the softmax state is a dense [T*H, 1]
+    column and p.v is one [T*H, keys] x [keys, H*D] product whose
+    diagonal blocks the wrapper keeps. With T = 1 a per-head product
+    has one row; batched, the MXU sees 16.
+  * otherwise (the prefill chunk): heads run as static column slices
+    of the packed [T, H*D] slab, each an MXU-shaped [T, D] x [D, keys]
+    product (the flash_attention.py packed-layout idiom — Tensor
+    Processing Primitives, arXiv:2104.05755).
+q.k takes its operands in their stored dtype with fp32 accumulation
+(products of bf16 values are exact in fp32) and 1/sqrt(D) applied to
+the fp32 scores; the softmax state, the probabilities, p.v and the
+accumulator are fp32. On the v5e the decode call is bound by its page
+copies, not its products (PERF.md section 5).
 
 Routing mirrors nn/layer/transformer.py's flash routing: the Pallas
 kernel on TPU, a dense `lax` fallback on CPU / tiny shapes, overridable
@@ -30,8 +63,8 @@ Layouts:
   q           [B, T, H*D]   new-token queries, right-padded to T per row
   k_pages     [N_pages, page_size, H*D]   the pool's device arrays
   v_pages     [N_pages, page_size, H*D]
-  page_tables int32 [B, pages_per_seq]    pool page ids (unused slots
-                                          must hold a valid id, e.g. 0)
+  page_tables int32 [B, pages_per_seq]    pool page ids (slots past a
+                                          row's live pages: anything)
   seq_lens    int32 [B]  context length INCLUDING this step's new tokens
   q_lens      int32 [B]  valid new tokens this step (<= T)
 
@@ -53,13 +86,17 @@ Quantized pages (ISSUE 7, `kv_dtype='int8'`): k_pages/v_pages are int8
 and carry sibling fp32 scale buffers `[N_pages, page_size, H]` — one
 abs-max scale per (token slot, head). `write_kv_pages_quantized`
 quantizes each new token's per-head K/V row at scatter time;
-dequantization happens INSIDE the kernel (per-page VMEM block, one
-multiply per head slice — free next to the MXU dot) and inside the
-dense fallback, so attention math stays fp32 while the pool pays 1
-byte/element + 4 bytes/head/slot. The int8 min tile is (32, 128), so
-page_size >= 32 keeps the int8 page blocks tile-aligned; Mosaic (libtpu
-0.0.34, v5e) also compiles 16- and 8-slot int8 pages, and 16-slot pages
-match the dense reference on the chip (chip_smoke.py `kernels`).
+dequantization happens INSIDE the kernel (the wave's pages upcast in
+VMEM, one multiply per head slice) and inside the dense fallback, so
+attention math stays fp32 while the pool pays 1 byte/element + 4
+bytes/head/slot. The int8 pages ride the same DMA waves. The scales do
+not: Mosaic (libtpu 0.0.34) refuses to slice a DMA source whose minor
+dim (H) is under the 128-lane tile, so XLA gathers each row's
+`[P*page_size, H]` scales (ids clamped; slots past seq_len are masked)
+and the grid's pipeline brings the row's block — work over every table
+slot, for the scales only. The int8 min tile is (32, 128), so page_size
+>= 32 keeps the int8 pages tile-aligned; 16-slot int8 pages compile and
+match the dense reference on the chip too (chip_smoke.py `kernels`).
 """
 import functools
 import math
@@ -77,81 +114,191 @@ NEG_INF = -1e30
 _interpret = scaffold.interpret_mode
 
 
-def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_ref, v_ref, *rest,
-                         page_size, num_heads, head_dim, pages_per_seq,
-                         quantized=False):
-    """One (batch_row, page) program.
+# K + V bytes one DMA wave moves HBM->VMEM: 8 pages of K and 8 of V at
+# the 1.3B server's shape (16 slots x 2048 x bf16 = 64 KB a page), so a
+# wave is 128 keys — one lane-dense score tile
+_WAVE_BYTES = 2 ** 20
+# scoped VMEM the wave slots may take together with the q / out blocks
+# and the fp32 accumulator (Mosaic's default limit is 16 MiB; the rest
+# is the body's fp32 score / prob / upcast tiles)
+_VMEM_BUDGET = 10 * 2 ** 20
+# up to this many (query, head) rows the heads are batched into one
+# block-diagonal score product; above it (the prefill chunk) each head
+# runs its own MXU-shaped [T, D] x [D, keys] product
+_BATCHED_ROWS = 128
 
-    pt_ref/ln_ref are scalar-prefetched (page tables, [B, 2] lens); the
-    K/V BlockSpecs already resolved this program's page id, so k_ref /
-    v_ref hold one [page_size, H*D] page in VMEM. Scratch carries the
-    online-softmax state across a row's page steps (the page grid
-    iterates fastest, so p==0 re-arms and the last page finalizes).
-    With `quantized` the K/V blocks are int8 and two extra refs hold
-    this page's [page_size, H] fp32 scales; dequantization is one
-    broadcast multiply per head slice, fused into the fp32 upcast the
-    kernel already pays.
+
+def _wave_pages(page_size, HD, kv_dtype, rows, q_dtype, P, num_heads,
+                quantized):
+    """(W, vmem bytes): pages of K (and as many of V) per DMA wave —
+    _WAVE_BYTES over one page's K+V bytes, shrunk until two wave slots
+    fit _VMEM_BUDGET beside the double-buffered q / out blocks, the
+    fp32 accumulator and (int8) the row's scale blocks; a power of two
+    so `W * page_size` keys tile the lanes; never more than a row's
+    page-table slots."""
+    page = 2 * scaffold.block_bytes((page_size, HD), kv_dtype)
+    fixed = 4 * scaffold.block_bytes((rows, HD), q_dtype) \
+        + scaffold.block_bytes((rows, HD), jnp.float32)
+    if quantized:
+        fixed += 4 * scaffold.block_bytes((P * page_size, num_heads),
+                                          jnp.float32)
+    room = max(_VMEM_BUDGET - fixed, 2 * page)
+    want = max(1, min(_WAVE_BYTES // page, room // (2 * page), P))
+    W = 1 << (want.bit_length() - 1)
+    # what the call holds: blocks, accumulator, two slots, and the
+    # body's fp32 copies of one wave of K and V plus score tiles
+    need = fixed + 2 * W * page \
+        + 3 * scaffold.block_bytes((W * page_size, HD), jnp.float32)
+    return W, need
+
+
+def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, v_hbm, *rest,
+                         page_size, num_heads, head_dim, wave_pages,
+                         batched, quantized=False):
+    """One batch row: a loop over the row's OWN live pages.
+
+    pt_ref/ln_ref are scalar-prefetched (page tables, [B, 2] lens);
+    k_hbm / v_hbm are the whole pools, left in HBM. Wave w copies pages
+    [w*W, (w+1)*W) of the row's table — only those below
+    cdiv(seq_len, page_size) — into one of the two VMEM slots while
+    the wave before it is folded into the online softmax; under a
+    row's last wave the NEXT row's first wave is started (`nxt`, SMEM,
+    carries its slot to that row's program), so the copies form one
+    stream over the batch. A row with q_len == 0 starts no copy and
+    writes zeros.
+
+    `batched`: q_ref holds [T*H, H*D] block-diagonal rows (row t*H+h =
+    query t masked to head h's columns), so ONE product scores every
+    head against the wave and the softmax state is a dense [T*H, 1]
+    column. Otherwise q_ref is [T, H*D] and heads run as static column
+    slices with [T, H] state, as flash_attention.py's packed layout.
+    With `quantized` the pools are int8 and two more refs hold the
+    row's [P*page_size, H] fp32 scales in VMEM, applied per head slice
+    to the wave's upcast pages.
     """
     if quantized:
-        ks_ref, vs_ref, o_ref, m_s, l_s, acc_s = rest
+        ks_ref, vs_ref, o_ref, kbuf, vbuf, sem, nxt, m_s, l_s, acc_s = rest
     else:
-        ks_ref = vs_ref = None
-        o_ref, m_s, l_s, acc_s = rest
+        o_ref, kbuf, vbuf, sem, nxt, m_s, l_s, acc_s = rest
     b = pl.program_id(0)
-    p = pl.program_id(1)
-    T = q_ref.shape[0]
-    D = head_dim
-    seq_len = ln_ref[b, 0]
-    q_len = ln_ref[b, 1]
-    page_start = p * page_size
+    B = pl.num_programs(0)
+    R = q_ref.shape[0]
+    W, ps, H, D = wave_pages, page_size, num_heads, head_dim
+    keys = W * ps
     scale = 1.0 / math.sqrt(D)
 
-    @pl.when(p == 0)
-    def _():
-        m_s[:] = jnp.full_like(m_s, NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc_s[:] = jnp.zeros_like(acc_s)
+    def live_pages(row):
+        """Pages the row's loop visits: none without a query."""
+        return jnp.where(ln_ref[row, 1] > 0,
+                         pl.cdiv(ln_ref[row, 0], ps), 0)
 
-    @pl.when(page_start < seq_len)
+    seq_len = ln_ref[b, 0]
+    q_len = ln_ref[b, 1]
+    n_pages = live_pages(b)
+    n_waves = pl.cdiv(n_pages, W)
+    # the row after this one (its first wave is started under this
+    # row's last, so a row does not open on a cold copy)
+    after = jnp.minimum(b + 1, B - 1)
+    after_pages = jnp.where(b + 1 < B, live_pages(after), 0)
+
+    def wave_dma(row, wave, slot, pages, start):
+        """Start (or wait for) the copies of wave `wave` of `row`: its
+        pages below `pages`, none when the wave lies past them. Table
+        slots at or past a row's live pages are never read: what they
+        hold (sentinels, another request's page) is never dereferenced."""
+        def page_dma(j, carry):
+            # a wait needs the copy's shape, not its source
+            page_id = pt_ref[row, wave * W + j] if start else 0
+            for i, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                copy = pltpu.make_async_copy(
+                    hbm.at[page_id], buf.at[slot, j], sem.at[slot, i])
+                copy.start() if start else copy.wait()
+            return carry
+        jax.lax.fori_loop(0, jnp.clip(pages - wave * W, 0, W), page_dma, 0)
+
+    @pl.when(b == 0)
     def _():
-        # global positions: rows = this step's queries, cols = this
-        # page's keys; causal within the sequence + ragged length mask
-        q_pos = (seq_len - q_len
-                 + jax.lax.broadcasted_iota(jnp.int32, (T, page_size), 0))
-        key_pos = page_start + jax.lax.broadcasted_iota(
-            jnp.int32, (T, page_size), 1)
+        # a partial wave leaves the slot's other pages as the last
+        # wave left them: masked scores give p == 0 there, and 0 * v
+        # must not meet the NaN an uninitialised VMEM word can hold
+        vbuf[...] = jnp.zeros_like(vbuf)
+        nxt[0] = 0          # the slot this row's wave 0 takes
+        nxt[1] = 0          # 1: the row before has started it already
+
+    m_s[...] = jnp.full_like(m_s, NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
+    slot0 = nxt[0]
+
+    @pl.when((n_waves > 0) & (nxt[1] == 0))
+    def _():
+        wave_dma(b, 0, slot0, n_pages, start=True)
+
+    def dequant(x, scales):
+        """[keys, H*D] int8 x [keys, H] fp32 -> fp32 [keys, H*D]."""
+        return jnp.concatenate(
+            [x[:, h * D:(h + 1) * D].astype(jnp.float32)
+             * scales[:, h:h + 1] for h in range(H)], axis=-1)
+
+    # one group per score product: (q/k/v columns, state column)
+    groups = [(slice(None), 0)] if batched else \
+        [(slice(h * D, (h + 1) * D), h) for h in range(H)]
+
+    def wave_body(w, carry):
+        slot = (slot0 + w) % 2
+        # the next wave of the stream goes into the other slot: this
+        # row's wave w+1 or, under its last wave, the next row's first
+        last = w + 1 == n_waves
+        wave_dma(jnp.where(last, after, b), jnp.where(last, 0, w + 1),
+                 1 - slot, jnp.where(last, after_pages, n_pages),
+                 start=True)
+        wave_dma(b, w, slot, n_pages, start=False)
+        k = kbuf[slot].reshape(keys, H * D)
+        v = vbuf[slot].reshape(keys, H * D)
+        if quantized:
+            at = pl.ds(pl.multiple_of(w * keys, keys), keys)
+            k = dequant(k, ks_ref[at, :])
+            # past seq_len the gathered scales are some other page's:
+            # p == 0 there, and 0 * v must stay 0 whatever they hold
+            live = w * keys + jax.lax.broadcasted_iota(
+                jnp.int32, (keys, 1), 0) < seq_len
+            v = dequant(v, jnp.where(live, vs_ref[at, :], 0.0))
+        v = v.astype(jnp.float32)
+        # global positions: rows = this step's queries (a batched row
+        # is query row // H), cols = this wave's keys; causal within
+        # the sequence + ragged length mask
+        row = jax.lax.broadcasted_iota(jnp.int32, (R, keys), 0)
+        q_pos = seq_len - q_len + (row // H if batched else row)
+        key_pos = w * keys + jax.lax.broadcasted_iota(
+            jnp.int32, (R, keys), 1)
         valid = (key_pos < seq_len) & (key_pos <= q_pos)
-        for h in range(num_heads):
-            q = q_ref[:, h * D:(h + 1) * D].astype(jnp.float32) * scale
-            k = k_ref[:, h * D:(h + 1) * D].astype(jnp.float32)
-            v = v_ref[:, h * D:(h + 1) * D].astype(jnp.float32)
-            if quantized:
-                k = k * ks_ref[:, h:h + 1]
-                v = v * vs_ref[:, h:h + 1]
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
+        for cols, g in groups:
+            # operands in their stored dtype (bf16 products are exact
+            # in the fp32 accumulation); 1/sqrt(D) on the fp32 scores
+            s = jax.lax.dot_general(
+                q_ref[:, cols].astype(k.dtype), k[:, cols],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
             s = jnp.where(valid, s, NEG_INF)
-            m_prev = m_s[:, h:h + 1]
-            l_prev = l_s[:, h:h + 1]
+            m_prev = m_s[:, g:g + 1]
             m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
             pexp = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)
-            acc = acc_s[:, h * D:(h + 1) * D]
-            acc_s[:, h * D:(h + 1) * D] = \
-                acc * alpha + jax.lax.dot_general(
-                    pexp, v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            m_s[:, h:h + 1] = m_new
-            l_s[:, h:h + 1] = alpha * l_prev + jnp.sum(pexp, -1,
-                                                       keepdims=True)
+            acc_s[:, cols] = acc_s[:, cols] * alpha + jax.lax.dot_general(
+                pexp, v[:, cols], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            l_s[:, g:g + 1] = alpha * l_s[:, g:g + 1] \
+                + jnp.sum(pexp, -1, keepdims=True)
+            m_s[:, g:g + 1] = m_new
+        return carry
 
-    @pl.when(p == pages_per_seq - 1)
-    def _():
-        l_safe = jnp.maximum(l_s[:], 1e-30)
-        for h in range(num_heads):
-            o_ref[:, h * D:(h + 1) * D] = (
-                acc_s[:, h * D:(h + 1) * D] / l_safe[:, h:h + 1]
-            ).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_waves, wave_body, 0)
+    nxt[0] = (slot0 + n_waves) % 2
+    nxt[1] = ((n_waves > 0) & (after_pages > 0)).astype(jnp.int32)
+    l_safe = jnp.maximum(l_s[...], 1e-30)
+    for cols, g in groups:
+        o_ref[:, cols] = (acc_s[:, cols] / l_safe[:, g:g + 1]) \
+            .astype(o_ref.dtype)
 
 
 def ragged_paged_attention_pallas(q, k_pages, v_pages, page_tables,
@@ -159,53 +306,101 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, page_tables,
                                   head_dim, k_scales=None,
                                   v_scales=None, interpret=None):
     """Pallas route (interpret-mode on CPU). See module docstring for
-    layouts; k_scales/v_scales engage the int8 dequantizing body."""
+    layouts; k_scales/v_scales engage the int8 dequantizing body.
+
+    What the shapes decide is decided here; the call itself is one
+    jitted function, so a model's layers — the same shapes 24 times in
+    one step program — share ONE trace of the kernel body and ONE
+    Mosaic lowering (jit caches both by shapes and static arguments)
+    where each layer used to pay its own."""
+    T, HD = q.shape[1:]
+    batched = T * num_heads <= _BATCHED_ROWS
+    W, need = _wave_pages(
+        k_pages.shape[1], HD, k_pages.dtype,
+        T * num_heads if batched else T, q.dtype, page_tables.shape[1],
+        num_heads, k_scales is not None)
+    return _paged_call(
+        q, k_pages, v_pages, page_tables, seq_lens, q_lens, k_scales,
+        v_scales, num_heads=num_heads, head_dim=head_dim, wave_pages=W,
+        batched=batched, vmem_bytes=need,
+        interpret=_interpret() if interpret is None else interpret)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    'num_heads', 'head_dim', 'wave_pages', 'batched', 'vmem_bytes',
+    'interpret'))
+def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
+                k_scales, v_scales, *, num_heads, head_dim, wave_pages,
+                batched, vmem_bytes, interpret):
+    """The block-diagonal q (when `batched`), the Mosaic call and the
+    diagonal blocks of its output, as one jitted function of the
+    shapes and the wrapper's static choices."""
     B, T, HD = q.shape
-    ps = k_pages.shape[1]
+    N, ps = k_pages.shape[:2]
     P = page_tables.shape[1]
+    H, W = num_heads, wave_pages
     quantized = k_scales is not None
+    pt = page_tables.astype(jnp.int32)
     lens = jnp.stack([seq_lens.astype(jnp.int32),
                       q_lens.astype(jnp.int32)], axis=1)       # [B, 2]
-    # unused page-table slots may carry sentinels; the index map still
-    # fetches them, so clamp to valid pool ids (compute is masked off)
-    pt = jnp.clip(page_tables.astype(jnp.int32), 0,
-                  k_pages.shape[0] - 1)
-    page_spec = pl.BlockSpec((None, ps, HD),
-                             lambda b, p, pt, ln: (pt[b, p], 0, 0))
-    in_specs = [
-        pl.BlockSpec((None, T, HD), lambda b, p, pt, ln: (b, 0, 0)),
-        page_spec,
-        page_spec,
-    ]
+    if batched:
+        # row t*H+h = query t masked to head h's columns
+        own = (jnp.arange(HD, dtype=jnp.int32)[None, :] // head_dim
+               == jnp.arange(H, dtype=jnp.int32)[:, None])      # [H, HD]
+        q = jnp.where(own, q[:, :, None, :], 0).reshape(B, T * H, HD)
+    R = q.shape[1]
+    row_spec = pl.BlockSpec((None, R, HD), lambda b, pt, ln: (b, 0, 0))
+    pool_spec = pl.BlockSpec(memory_space=pltpu.HBM)
+    in_specs = [row_spec, pool_spec, pool_spec]
     inputs = [pt, lens, q, k_pages, v_pages]
     if quantized:
-        scale_spec = pl.BlockSpec(
-            (None, ps, num_heads), lambda b, p, pt, ln: (pt[b, p], 0, 0))
+        # Mosaic cannot slice a DMA source whose minor dim (H) is under
+        # the 128-lane tile, so the scales do not ride the page copies:
+        # XLA gathers each row's [P*ps, H] (padded to whole waves; ids
+        # clamped, every slot past seq_len is masked) and the pipeline
+        # brings the row's block
+        Pw = -(-P // W) * W
+        ids = jnp.pad(jnp.clip(pt, 0, N - 1), ((0, 0), (0, Pw - P)))
+        scale_spec = pl.BlockSpec((None, Pw * ps, H),
+                                  lambda b, pt, ln: (b, 0, 0))
         in_specs += [scale_spec, scale_spec]
-        inputs += [k_scales, v_scales]
+        inputs += [k_scales[ids].reshape(B, Pw * ps, H),
+                   v_scales[ids].reshape(B, Pw * ps, H)]
+    G = 1 if batched else H
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, P),
+        grid=(B,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, T, HD),
-                               lambda b, p, pt, ln: (b, 0, 0)),
+        out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((T, num_heads), jnp.float32),   # running max
-            pltpu.VMEM((T, num_heads), jnp.float32),   # normalizer
-            pltpu.VMEM((T, HD), jnp.float32),          # accumulator
+            pltpu.VMEM((2, W, ps, HD), k_pages.dtype),     # K wave slots
+            pltpu.VMEM((2, W, ps, HD), v_pages.dtype),     # V wave slots
+            pltpu.SemaphoreType.DMA((2, 2)),               # [slot, K|V]
+            pltpu.SMEM((2,), jnp.int32),       # next wave-0 slot, started
+            pltpu.VMEM((R, G), jnp.float32),               # running max
+            pltpu.VMEM((R, G), jnp.float32),               # normalizer
+            pltpu.VMEM((R, HD), jnp.float32),              # accumulator
         ],
     )
     kernel = functools.partial(
-        _ragged_paged_kernel, page_size=ps, num_heads=num_heads,
-        head_dim=head_dim, pages_per_seq=P, quantized=quantized)
-    out_dtype = q.dtype
-    return scaffold.pallas_call(
+        _ragged_paged_kernel, page_size=ps, num_heads=H,
+        head_dim=head_dim, wave_pages=W, batched=batched,
+        quantized=quantized)
+    out = scaffold.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, T, HD), out_dtype),
-        interpret=_interpret() if interpret is None else interpret,
+        out_shape=jax.ShapeDtypeStruct((B, R, HD), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary',),
+            vmem_limit_bytes=int(min(max(vmem_bytes, 16 * 2 ** 20),
+                                     scaffold.VMEM_CAP_BYTES))),
+        interpret=interpret,
         name='paged_attention',
     )(*inputs)
+    if batched:
+        # each head's output is its own diagonal block of the rows
+        out = jnp.where(own, out.reshape(B, T, H, HD), 0).sum(axis=2)
+    return out
 
 
 def _dequant_gathered(pages, scales, H):

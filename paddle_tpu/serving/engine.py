@@ -514,6 +514,8 @@ class ServingEngine:
         self._it_fetch = 0.0
         self._it_decode_s = 0.0
         self._it_kv_read_tokens = 0
+        self._it_live_pages = 0
+        self._it_page_slots = 0
         self._it_prefill_tokens = 0
         self._it_prefill_s = 0.0
         self._it_prefill_ctx = 0
@@ -751,6 +753,8 @@ class ServingEngine:
         self._it_fetch = 0.0
         self._it_decode_s = 0.0
         self._it_kv_read_tokens = 0
+        self._it_live_pages = 0
+        self._it_page_slots = 0
         self._it_prefill_tokens = 0
         self._it_prefill_s = 0.0
         self._it_prefill_ctx = 0
@@ -841,6 +845,8 @@ class ServingEngine:
                 schedule=sched_dt / n_iter,
                 decode_seconds=self._it_decode_s / n_iter,
                 kv_read_tokens=self._it_kv_read_tokens // n_iter,
+                paged_live_pages=self._it_live_pages if first else 0,
+                paged_page_slots=self._it_page_slots if first else 0,
                 prefill_tokens=self._it_prefill_tokens if first else 0,
                 prefill_seconds=self._it_prefill_s if first else 0.0,
                 prefill_ctx_tokens=self._it_prefill_ctx if first else 0)
@@ -1512,6 +1518,10 @@ class ServingEngine:
                 # context_len + j KV tokens
                 self._it_kv_read_tokens += \
                     w * req.context_len + w * (w - 1) // 2
+                self._it_live_pages += sum(
+                    self.pool.pages_for(req.context_len + j)
+                    for j in range(w))
+            self._it_page_slots += K * page_tables.size
         sample = any(r.top_k > 0 for _, r, _ in rows)
         fn = self._fused_fn(B, K, sample)
         t0 = time.perf_counter()
@@ -1661,6 +1671,8 @@ class ServingEngine:
         self._it_prefill_s += tc1 - tc0
         self._it_prefill_tokens += n
         self._it_prefill_ctx += n * (start + n)
+        self._it_live_pages += self.pool.pages_for(start + n)
+        self._it_page_slots += self.max_pages_per_seq
         self.pool.kv = new_kv
         req.prefilled = start + n
         self._prefill_tokens += n
@@ -1823,6 +1835,11 @@ class ServingEngine:
                 top_ks[i] = req.top_k
         if not active:
             return 0, 0
+        # paged-attention work share: live pages of the rows that carry
+        # a query against the slots this program's tables hold
+        self._it_live_pages += int(
+            (-(-seq_lens[q_lens > 0] // self.config.page_size)).sum())
+        self._it_page_slots += page_tables.size
         sample = any(r.top_k > 0 for _, r, _ in active)
         fn = self._step_fn(B, T, sample, verify=verify)
         t0 = time.perf_counter()

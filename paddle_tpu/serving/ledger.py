@@ -155,6 +155,11 @@ class ServeLedger:
         self._pending_stream = 0.0      # disagg handoff seconds noted
                                         # between iterations
         self.iterations = 0
+        # paged-attention work share (lifetime ints): live pages the
+        # dispatched programs' rows held against the page-table slots
+        # those programs carried (rows x max_pages_per_seq)
+        self.paged_live_pages = 0
+        self.paged_page_slots = 0
         # goodput counters (lifetime, host ints)
         self.emitted_tokens = 0
         self.delivered_tokens = 0
@@ -181,10 +186,13 @@ class ServeLedger:
     def observe_iteration(self, wall, compute=0.0, host_fetch=0.0,
                           schedule=0.0, decode_seconds=0.0,
                           kv_read_tokens=0, prefill_tokens=0,
-                          prefill_seconds=0.0, prefill_ctx_tokens=0):
+                          prefill_seconds=0.0, prefill_ctx_tokens=0,
+                          paged_live_pages=0, paged_page_slots=0):
         """One engine iteration's measured phase walls (host
         perf_counter segments — no device syncs)."""
         self.iterations += 1
+        self.paged_live_pages += int(paged_live_pages)
+        self.paged_page_slots += int(paged_page_slots)
         self._walls.append(max(float(wall), 0.0))
         self._compute.append(max(float(compute), 0.0))
         self._fetch.append(max(float(host_fetch), 0.0))
@@ -377,6 +385,16 @@ class ServeLedger:
                 'prefill_mfu':
                     (tflops / peak_t) if (peak_t and tflops) else None,
             })
+        if out is not None and self.paged_page_slots:
+            # what share of a (rows, pages_per_seq) grid holds a live
+            # page: the work the ragged kernel's page loop does against
+            # the page-table slots the programs carried
+            out.update({
+                'paged_live_pages': self.paged_live_pages,
+                'paged_page_slots': self.paged_page_slots,
+                'paged_live_page_share':
+                    self.paged_live_pages / self.paged_page_slots,
+            })
         return out
 
     # -- lifecycle -----------------------------------------------------------
@@ -390,6 +408,8 @@ class ServeLedger:
             dq.clear()
         self._pending_stream = 0.0
         self.iterations = 0
+        self.paged_live_pages = 0
+        self.paged_page_slots = 0
         self.emitted_tokens = 0
         self.delivered_tokens = 0
         self.wasted = {c: 0 for c in _WASTE_CAUSES}

@@ -259,7 +259,146 @@ def _mixed_case(dtype=np.float32, seed=0):
             H, D)
 
 
+# rows of one batch, each a shape the kernel's page loop can get wrong;
+# (name, seq_len in pages-and-slots terms, q_len policy). ps = page
+# size, W = pages per DMA wave (2 here), P = page-table slots (5: not a
+# multiple of W, so a full row ends on a partial wave)
+_RAGGED_W, _RAGGED_P = 2, 5
+_RAGGED_ROWS = {
+    'page_boundary': lambda ps: ps,
+    'page_boundary_plus_1': lambda ps: ps + 1,
+    'wave_boundary': lambda ps: _RAGGED_W * ps,
+    'wave_boundary_plus_1': lambda ps: _RAGGED_W * ps + 1,
+    'one_token': lambda ps: 1,
+    'idle': lambda ps: 1,                       # q_len 0, as the engine
+    'all_slots': lambda ps: _RAGGED_P * ps,     # pads an empty row
+    'odd_live_pages': lambda ps: 3 * ps - 3,    # 3 pages, W = 2
+    'sentinel_slots': lambda ps: ps + 5,
+    'shared_page': lambda ps: 3 * ps - 3,       # odd_live_pages' pages
+    'stale_nan_pages': lambda ps: 3 * ps,       # NaN inside its last wave
+}
+_RAGGED_KINDS = {'fp32': (np.float32, 16), 'bf16': (jnp.bfloat16, 16),
+                 'int8_ps16': (np.int8, 16), 'int8_ps32': (np.int8, 32)}
+_ragged_cache = {}
+
+
+def _ragged_case(T, kind):
+    """(kernel out, dense out, oracle out, q_lens) of the one batch of
+    _RAGGED_ROWS at query width T, computed once per (T, kind)."""
+    if (T, kind) in _ragged_cache:
+        return _ragged_cache[T, kind]
+    from paddle_tpu.ops.pallas import scaffold
+    dtype, ps = _RAGGED_KINDS[kind]
+    int8 = dtype == np.int8
+    H, D, P = 2, 8, _RAGGED_P
+    HD = H * D
+    names = list(_RAGGED_ROWS)
+    B = len(names)
+    rng = np.random.RandomState(T + ps)
+    n_good = B * P
+    # never inside any row's context, and not where a clamped sentinel
+    # lands (the dense fallback clamps ids to the pool's two ends)
+    nan_page = n_good
+    kf = rng.randn(n_good + 2, ps, HD).astype(np.float32)
+    vf = rng.randn(n_good + 2, ps, HD).astype(np.float32)
+    seq = np.asarray([_RAGGED_ROWS[n](ps) for n in names], np.int32)
+    ql = np.minimum(seq, T).astype(np.int32)
+    ql[names.index('idle')] = 0
+    ql[names.index('all_slots')] = min(T, 2)    # padded query columns
+    pt = rng.permutation(n_good).reshape(B, P).astype(np.int32)
+    live = -(-seq // ps)
+    pt[names.index('shared_page')] = pt[names.index('odd_live_pages')]
+    b = names.index('stale_nan_pages')
+    pt[b, live[b]:] = nan_page
+    b = names.index('sentinel_slots')
+    pt[b, live[b]:] = [n_good + 100, -1, 2 ** 30][:P - live[b]]
+    q = rng.randn(B, T, HD).astype(np.float32)
+    if int8:
+        kq, ks = pa.quantize_kv_rows(jnp.asarray(kf), H)
+        vq, vs = pa.quantize_kv_rows(jnp.asarray(vf), H)
+        # the reference sees the dequantized pages
+        kf = np.asarray(pa._dequant_gathered(kq[None], ks[None], H))[0]
+        vf = np.asarray(pa._dequant_gathered(vq[None], vs[None], H))[0]
+        pages = (kq, vq)
+        scales = dict(k_scales=ks, v_scales=vs)
+        q_in = jnp.asarray(q)
+    else:
+        pages = (jnp.asarray(kf, dtype), jnp.asarray(vf, dtype))
+        kf, vf = (np.asarray(x, np.float32) for x in pages)
+        scales = {}
+        q_in = jnp.asarray(q, dtype)
+        q = np.asarray(q_in, np.float32)
+    # garbage past the contexts: NaN in stored dtypes that have one
+    nan = 127 if int8 else np.nan
+    pages = tuple(x.at[nan_page].set(nan) for x in pages)
+    if int8:
+        scales = {n: x.at[nan_page].set(np.nan) for n, x in scales.items()}
+    args = (q_in,) + pages + (jnp.asarray(pt), jnp.asarray(seq),
+                              jnp.asarray(ql))
+    wave_bytes = pa._WAVE_BYTES
+    pa._WAVE_BYTES = _RAGGED_W * 2 * scaffold.block_bytes(
+        (ps, HD), pages[0].dtype)
+    try:
+        kernel = pa.ragged_paged_attention_pallas(
+            *args, num_heads=H, head_dim=D, **scales)
+    finally:
+        pa._WAVE_BYTES = wave_bytes
+    dense = pa.ragged_paged_attention_dense(
+        *args, num_heads=H, head_dim=D, **scales)
+    # the oracle walks every table slot before it cuts at seq_len
+    safe_pt = np.where((pt >= 0) & (pt < n_good), pt, 0)
+    ref = _oracle(q, kf, vf, safe_pt, seq, ql, H, D)
+    out = (np.asarray(kernel, np.float32), np.asarray(dense, np.float32),
+           ref, ql)
+    _ragged_cache[T, kind] = out
+    return out
+
+
 class TestRaggedPagedAttention:
+    @pytest.mark.parametrize('row', list(_RAGGED_ROWS))
+    @pytest.mark.parametrize('kind', list(_RAGGED_KINDS))
+    @pytest.mark.parametrize('T', [1, 3, 128])
+    def test_kernel_row_shapes_match_dense_and_oracle(self, T, kind, row):
+        # T = 1 and 3 run the batched block-diagonal products, T = 128
+        # the per-head ones; two pages a wave, five table slots
+        kernel, dense, ref, ql = _ragged_case(T, kind)
+        b = list(_RAGGED_ROWS).index(row)
+        tol = dict(rtol=2e-2, atol=2e-2) if kind == 'bf16' \
+            else dict(rtol=2e-4, atol=2e-5)
+        # padded query columns and idle rows too: nothing the row did
+        # not own (NaN pages, sentinel ids, the last wave's tail) leaks
+        assert np.isfinite(kernel[b]).all()
+        if row == 'idle':
+            assert not kernel[b].any()
+        np.testing.assert_allclose(kernel[b, :ql[b]], ref[b, :ql[b]],
+                                   **tol)
+        if row != 'stale_nan_pages':    # the dense gather multiplies
+            np.testing.assert_allclose(  # 0 * NaN: not a reference there
+                kernel[b, :ql[b]], dense[b, :ql[b]], **tol)
+
+    def test_layers_share_one_trace_of_the_kernel_body(self, monkeypatch):
+        # a model calls the kernel once a layer with the same shapes;
+        # the call is one jitted function, so the body is traced (and
+        # Mosaic-lowered) once per shape, not once per layer
+        traced = []
+        body = pa._ragged_paged_kernel
+        monkeypatch.setattr(
+            pa, '_ragged_paged_kernel',
+            lambda *a, **kw: (traced.append(1), body(*a, **kw))[1])
+        q, kp, vp, pt, sl, ql, H, D = _mixed_case()
+        # seven rows, a batch no other test uses: this shape's first trace
+        q, pt, sl, ql = (jnp.asarray(np.concatenate([x, x, x])[:7])
+                         for x in (q, pt, sl, ql))
+
+        def three_layers(q):
+            for _ in range(3):
+                q = pa.ragged_paged_attention_pallas(
+                    q, jnp.asarray(kp), jnp.asarray(vp), pt, sl, ql,
+                    num_heads=H, head_dim=D)
+            return q
+        jax.make_jaxpr(three_layers)(q)
+        assert len(traced) == 1
+
     def test_kernel_matches_oracle_fp32(self):
         q, kp, vp, pt, sl, ql, H, D = _mixed_case()
         o = pa.ragged_paged_attention_pallas(

@@ -224,6 +224,22 @@ class TestRoofline:
         assert r['prefill_mfu'] == pytest.approx(r['prefill_tflops'])
         led.unregister()
 
+    def test_paged_live_page_share_is_the_ratio_of_the_sums(
+            self, clean_registry):
+        led = ServeLedger(engine='rf4', kv_bytes_per_token=4)
+        led.observe_iteration(wall=0.01, decode_seconds=0.002,
+                              kv_read_tokens=16, paged_live_pages=3,
+                              paged_page_slots=16)
+        led.observe_iteration(wall=0.01, decode_seconds=0.002,
+                              kv_read_tokens=16, paged_live_pages=5,
+                              paged_page_slots=16)
+        r = led.roofline()
+        assert (r['paged_live_pages'], r['paged_page_slots']) == (8, 32)
+        assert r['paged_live_page_share'] == 0.25
+        led.reset()
+        assert led.paged_page_slots == 0 and led.roofline() is None
+        led.unregister()
+
     def test_none_before_any_dispatch(self, clean_registry):
         led = ServeLedger(engine='rf3')
         assert led.roofline() is None
@@ -236,6 +252,46 @@ class TestRoofline:
 # the real engine: identity under preemption + spec, trace-v4 parity,
 # ledger reconciliation, host-bound fraction, snapshot lifecycle
 # ---------------------------------------------------------------------------
+class TestPagedLivePageShare:
+    @pytest.mark.parametrize('fused_k', [1, 4])
+    def test_share_matches_the_requests_context_lengths(self, tiny_lm,
+                                                        fused_k):
+        # two requests admitted together, each prompt one chunk: the
+        # [1, C] prefill program runs once per request over its prompt,
+        # then the [B, 1] decode program (or the fused window's scan)
+        # runs while either still owes tokens; request r's row holds
+        # context L_r + t at its t-th decode iteration
+        ps, B, P = 8, 4, 6
+        prompts, new = [list(range(1, 12)), list(range(1, 20))], [9, 4]
+        eng = ServingEngine(tiny_lm, ServingConfig(
+            page_size=ps, max_batch_size=B, prefill_chunk=32,
+            max_pages_per_seq=P, prefix_cache=False, fused_k=fused_k))
+        reqs = [eng.submit(p, max_new_tokens=n, top_k=0)
+                for p, n in zip(prompts, new)]
+        while not all(r.done for r in reqs):
+            eng.step()
+        live = sum(-(-len(p) // ps) for p in prompts)
+        live += sum(-(-(len(p) + t) // ps)
+                    for p, n in zip(prompts, new) for t in range(1, n))
+        roof = eng.ledger.roofline()
+        assert roof['paged_live_pages'] == live
+        # every dispatched program carried whole tables: rows x P,
+        # once per scan iteration of a fused window
+        assert roof['paged_page_slots'] % P == 0
+        decode_programs = (roof['paged_page_slots'] - 2 * P) // (B * P)
+        if fused_k == 1:
+            assert decode_programs == max(new) - 1
+        assert max(new) - 1 <= decode_programs <= fused_k * max(new)
+        assert 0 < roof['paged_live_page_share'] <= 1
+        assert roof['paged_live_page_share'] == \
+            live / roof['paged_page_slots']
+        from paddle_tpu.serving.ledger import serve_ledger_snapshot
+        snap = serve_ledger_snapshot()['roofline'][eng.ledger_site]
+        assert snap['paged_live_page_share'] == \
+            roof['paged_live_page_share']
+        eng.shutdown()
+
+
 class TestEngineGoodput:
     def test_baseline_matches_scheduler_ground_truth(self, tiny_lm,
                                                      mixed_prompts):

@@ -81,6 +81,17 @@ def main(argv=None):
         config, traffic, args.seed, args.seconds, 1, chips=cell['chips'],
         t_start=T_START, device_kind=jax.devices()[0].device_kind,
         profile=traced)
+    # a server runner shuts its engine down, and the serving ledger
+    # with it: keep the ledger's roofline block (kv_read_tokens_mean,
+    # paged_live_page_share) as it stood at shutdown
+    from paddle_tpu.serving import engine as serving_engine
+    rooflines = []
+    shutdown = serving_engine.ServingEngine.shutdown
+
+    def shutdown_keeping_roofline(self, *a, **kw):
+        rooflines.append(self.ledger.roofline())
+        return shutdown(self, *a, **kw)
+    serving_engine.ServingEngine.shutdown = shutdown_keeping_roofline
     record = runner.run(ctx)
     facts = record['facts']
     summary = trace_summary.summarize_device_trace(
@@ -120,6 +131,13 @@ def main(argv=None):
                  f'spans sum to {check["step_span_ms_sum"]:.1f} ms')
     summary['record'] = {k: record[k] for k in ('correct', 'attempted',
                                                 'failed', 'end_to_end')}
+    if rooflines and rooflines[-1]:
+        summary['serve_ledger_roofline'] = rooflines[-1]
+        text += '\n\nserving ledger at shutdown: ' + ', '.join(
+            f'{k} {rooflines[-1][k]}' for k in (
+                'kv_read_tokens_mean', 'kv_bytes_per_token',
+                'paged_live_pages', 'paged_page_slots',
+                'paged_live_page_share') if k in rooflines[-1])
     print(text, flush=True)
     base = os.path.join(args.out, cell['name'])
     with open(base + '.summary.json', 'w') as f:
