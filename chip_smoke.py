@@ -58,6 +58,15 @@ FULL = dict(
     # multichip: depth cut so the one-chip golden run (fp32 parameters,
     # grads and AdamW moments, 16 B/param) fits a 16 GB chip
     multi_depth=4, multi_seq=1024, multi_steps=2,
+    # kernels of the sparse, grouped-query, windowed server cell
+    # (benchmarks/configs/trinity-mini.json): 32 query heads on 4 kv
+    # heads of 128, window 2048 over 528-page tables, contexts on both
+    # sides of the window and at the table's end; 128 experts top-8 of
+    # width 1024 at the decode step's and the prefill chunk's rows
+    sparse=dict(heads=32, kv_heads=4, head_dim=128, window=2048,
+                page_size=16, pages=528, batch=64, chunk=512,
+                contexts=(16, 2047, 2049, 8448),
+                experts=128, top_k=8, hidden=2048, width=1024),
 )
 
 # Tolerances, as max|got - ref| / max|ref| over a tensor.
@@ -308,6 +317,69 @@ def phase_kernels(size):
         paged_case(Bs, 1, size['page_size'], int8,
                    every_seq=P * size['page_size'])
         paged_case(Bs, 1, size['page_size'], int8, every_seq=1)
+
+    # -- kv groups + window, and the experts' grouped matmul ---------------
+    sp = size.get('sparse')
+    if sp:
+        from paddle_tpu.ops.pallas import grouped_matmul as gmm
+        Hq, Hk, Dk, ps = (sp[k] for k in ('heads', 'kv_heads', 'head_dim',
+                                           'page_size'))
+        pool = sp['batch'] * sp['pages'] // 8 + 3   # pages are shared out
+
+        def grouped_paged(Bq, T, ctx, window):
+            """Every row at context `ctx` (its last min(T, ctx) tokens
+            new), tables drawn over one pool, against the dense route."""
+            pt = rng.randint(0, pool, (Bq, sp['pages'])).astype(np.int32)
+            args = (rand((Bq, T, Hq * Dk)), rand((pool, ps, Hk * Dk)),
+                    rand((pool, ps, Hk * Dk)), jnp.asarray(pt),
+                    jnp.full((Bq,), ctx, jnp.int32),
+                    jnp.full((Bq,), min(T, ctx), jnp.int32))
+
+            def call(attention):
+                return lambda *a: attention(
+                    *a, num_heads=Hq, head_dim=Dk, num_kv_heads=Hk,
+                    window=window)
+            got = jax.jit(call(pa.ragged_paged_attention_pallas))(*args)
+            ref = ref_call(call(pa.ragged_paged_attention_dense), *args)
+            live = (np.arange(T) < min(T, ctx))[None, :, None]
+            record(f'paged_attention B={Bq} T={T} {Hq}q/{Hk}kv '
+                   f'window={window} ctx={ctx}',
+                   np.where(live, np.asarray(got, np.float32), 0),
+                   np.where(live, np.asarray(ref, np.float32), 0), TOL_BF16)
+
+        for ctx in sp['contexts']:
+            for window in (sp['window'], None):
+                grouped_paged(sp['batch'], 1, ctx, window)
+                grouped_paged(1, sp['chunk'], ctx, window)
+
+        def grouped(rows, label):
+            """`rows` (token, expert) pairs over the experts, ragged as a
+            router leaves them (a few experts empty), both halves of the
+            experts' SwiGLU against the dense route."""
+            Ex, Kx, Fx = sp['experts'], sp['hidden'], sp['width']
+            ids = rng.randint(0, Ex - 3, rows).astype(np.int32)
+            p = gmm.plan(jnp.asarray(ids), Ex,
+                         gmm.tile_rows_for(rows, Ex))
+            tiles = (p['tile_expert'], p['tile_block'], p['n_live'])
+            x = rand((rows, Kx))[p['src']]
+            w1, w3 = rand((Ex, Kx, Fx), scale=0.02), \
+                rand((Ex, Kx, Fx), scale=0.02)
+            w2 = rand((Ex, Fx, Kx), scale=0.02)
+            keep = np.zeros(x.shape[0], bool)
+            keep[np.asarray(p['dest'])] = True      # rows that hold a pair
+            for name, args in (('gated', (x, w3, *tiles, w1)),
+                               ('plain', (x[:, :Fx], w2, *tiles))):
+                got = gmm.grouped_matmul_pallas(*args)
+                ref = ref_call(gmm.grouped_matmul_dense, *args,
+                               upcast=False)
+                record(f'moe_grouped_matmul {label} rows={rows} {name}',
+                       np.where(keep[:, None],
+                                np.asarray(got, np.float32), 0),
+                       np.where(keep[:, None],
+                                np.asarray(ref, np.float32), 0), TOL_BF16)
+
+        grouped(sp['batch'] * sp['top_k'], 'decode')
+        grouped(sp['chunk'] * sp['top_k'], 'chunk')
 
     # -- fused optimizer step + grad stats vs core.bucketing.shard_update --
     n = size['opt_elems']

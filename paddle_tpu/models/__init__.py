@@ -10,3 +10,4 @@ from .gpt import (GPTConfig, GPTModel, GPTForCausalLM,
                   gpt_1p3b)
 from .bert import BertConfig, BertModel, BertForPretraining
 from .deepfm import DeepFM, deepfm_loss  # noqa: F401,E402
+from .afmoe import AfmoeConfig, AfmoeForCausalLM  # noqa: F401,E402

@@ -495,6 +495,34 @@ class GPTForCausalLM(nn.Layer):
         self.gpt = GPTModel(config)
         self.config = config
 
+    # -- the serving-model protocol (serving/protocol.py) -------------------
+    @property
+    def mp_degree(self):
+        return self.gpt.layers[0].attn.world_size
+
+    def kv_cache_spec(self):
+        from ..serving.protocol import KVLayerSpec
+        return [KVLayerSpec(l.attn.local_heads * l.attn.world_size,
+                            l.attn.head_dim, None)
+                for l in self.gpt.layers]
+
+    def lm_head_weight(self):
+        return self.gpt.embeddings.word_embeddings.weight
+
+    # every route of the engine's: gpt.forward_paged takes them all
+    paged_routes = ('plain', 'fused', 'verify', 'int8_kv', 'int8_weights',
+                    'mp')
+
+    def moe_counters(self):
+        return None
+
+    def forward_paged(self, input_ids, position_ids, kv_list, page_tables,
+                      seq_lens, q_lens, moe_counters=None):
+        h, new_kv = self.gpt.forward_paged(
+            input_ids, position_ids, kv_list, page_tables, seq_lens,
+            q_lens)
+        return h, new_kv, moe_counters
+
     def forward(self, input_ids, position_ids=None):
         hidden = self.gpt(input_ids, position_ids)
         # Megatron "copy to tensor-parallel region" (f op) in front of

@@ -59,10 +59,24 @@ kernel on TPU, a dense `lax` fallback on CPU / tiny shapes, overridable
 with FLAGS_paged_attention_kernel. On CPU the kernel still runs under
 Pallas interpret mode so CI covers the same body that lowers on TPU.
 
+Kv groups and windows (static arguments of the ONE body): with
+`num_kv_heads` < `num_heads` the pool's width is num_kv_heads * D and
+query head h reads kv head h // group. The block-diagonal rows of the
+batched product then sit in their kv head's columns (32 query heads on
+4 kv heads: [32, 512] x [512, keys]); the prefill chunk stacks the
+`group` query heads of a kv head as ROWS, [group*T, D] x [D, keys] per
+kv head, and `_SCORE_BYTES` holds one such score tile to 2 MiB (128
+keys a wave at 4096 rows). With `window` a query at position p reads
+keys p - window + 1 .. p: the row's loop opens at the page of its
+first query's oldest key (older pages are neither copied nor
+scheduled) and the mask drops older keys; the Mosaic call is then
+named `paged_attention_window`. With num_kv_heads == num_heads and no
+window the body is what it was. int8 pools take neither.
+
 Layouts:
-  q           [B, T, H*D]   new-token queries, right-padded to T per row
-  k_pages     [N_pages, page_size, H*D]   the pool's device arrays
-  v_pages     [N_pages, page_size, H*D]
+  q           [B, T, Hq*D]  new-token queries, right-padded to T per row
+  k_pages     [N_pages, page_size, H*D]   the pool's device arrays (H:
+  v_pages     [N_pages, page_size, H*D]   kv heads; Hq = H unless told)
   page_tables int32 [B, pages_per_seq]    pool page ids (slots past a
                                           row's live pages: anything)
   seq_lens    int32 [B]  context length INCLUDING this step's new tokens
@@ -119,9 +133,14 @@ _interpret = scaffold.interpret_mode
 # wave is 128 keys — one lane-dense score tile
 _WAVE_BYTES = 2 ** 20
 # scoped VMEM the wave slots may take together with the q / out blocks
-# and the fp32 accumulator (Mosaic's default limit is 16 MiB; the rest
-# is the body's fp32 score / prob / upcast tiles)
-_VMEM_BUDGET = 10 * 2 ** 20
+# and the fp32 accumulator (of scaffold.VMEM_CAP_BYTES; the rest is the
+# body's fp32 score / prob / upcast tiles). No shape with as many kv
+# heads as query heads comes near it: _WAVE_BYTES decides there.
+_VMEM_BUDGET = 48 * 2 ** 20
+# one fp32 [rows, keys] score tile may take this much: a prefill chunk
+# whose kv groups stack 8 query heads a row block (4096 rows) folds 128
+# keys a wave, a decode call is not held by it
+_SCORE_BYTES = 2 * 2 ** 20
 # up to this many (query, head) rows the heads are batched into one
 # block-diagonal score product; above it (the prefill chunk) each head
 # runs its own MXU-shaped [T, D] x [D, keys] product
@@ -133,9 +152,11 @@ def _wave_pages(page_size, HD, kv_dtype, rows, q_dtype, P, num_heads,
     """(W, vmem bytes): pages of K (and as many of V) per DMA wave —
     _WAVE_BYTES over one page's K+V bytes, shrunk until two wave slots
     fit _VMEM_BUDGET beside the double-buffered q / out blocks, the
-    fp32 accumulator and (int8) the row's scale blocks; a power of two
-    so `W * page_size` keys tile the lanes; never more than a row's
-    page-table slots."""
+    fp32 accumulator and (int8) the row's scale blocks, and until one
+    [rows, keys] score tile fits _SCORE_BYTES; a power of two so
+    `W * page_size` keys tile the lanes; never more than a row's
+    page-table slots. `HD` is the pool's width (kv heads x head_dim),
+    `num_heads` its kv heads."""
     page = 2 * scaffold.block_bytes((page_size, HD), kv_dtype)
     fixed = 4 * scaffold.block_bytes((rows, HD), q_dtype) \
         + scaffold.block_bytes((rows, HD), jnp.float32)
@@ -143,19 +164,29 @@ def _wave_pages(page_size, HD, kv_dtype, rows, q_dtype, P, num_heads,
         fixed += 4 * scaffold.block_bytes((P * page_size, num_heads),
                                           jnp.float32)
     room = max(_VMEM_BUDGET - fixed, 2 * page)
-    want = max(1, min(_WAVE_BYTES // page, room // (2 * page), P))
+    tile = max(1, _SCORE_BYTES // (4 * rows * page_size))
+    want = max(1, min(_WAVE_BYTES // page, room // (2 * page), tile, P))
     W = 1 << (want.bit_length() - 1)
-    # what the call holds: blocks, accumulator, two slots, and the
-    # body's fp32 copies of one wave of K and V plus score tiles
+    # what the call holds: blocks, accumulator, softmax state, two
+    # slots, and the body's fp32 copies of one wave of K and V plus
+    # its score / prob / mask tiles
     need = fixed + 2 * W * page \
-        + 3 * scaffold.block_bytes((W * page_size, HD), jnp.float32)
+        + 2 * scaffold.block_bytes((rows, num_heads), jnp.float32) \
+        + 3 * scaffold.block_bytes((W * page_size, HD), jnp.float32) \
+        + 4 * scaffold.block_bytes((rows, W * page_size), jnp.float32)
     return W, need
 
 
 def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, v_hbm, *rest,
                          page_size, num_heads, head_dim, wave_pages,
-                         batched, quantized=False):
+                         batched, quantized=False, group=1, window=None):
     """One batch row: a loop over the row's OWN live pages.
+
+    `num_heads` counts the pool's (kv) heads; `group` query heads
+    share each of them (1: as many kv heads as query heads). `window`
+    (static; None: every key) bounds a query at position p to the keys
+    at p - window + 1 .. p: the row's loop then opens at the page of
+    its first query's oldest key, and no older page is copied.
 
     pt_ref/ln_ref are scalar-prefetched (page tables, [B, 2] lens);
     k_hbm / v_hbm are the whole pools, left in HBM. Wave w copies pages
@@ -167,11 +198,15 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, v_hbm, *rest,
     stream over the batch. A row with q_len == 0 starts no copy and
     writes zeros.
 
-    `batched`: q_ref holds [T*H, H*D] block-diagonal rows (row t*H+h =
-    query t masked to head h's columns), so ONE product scores every
-    head against the wave and the softmax state is a dense [T*H, 1]
-    column. Otherwise q_ref is [T, H*D] and heads run as static column
-    slices with [T, H] state, as flash_attention.py's packed layout.
+    `batched`: q_ref holds [T*Hq, H*D] block-diagonal rows (row
+    t*Hq+h = query head h of token t, in the columns of ITS kv head),
+    so ONE product scores every head against the wave and the softmax
+    state is a dense [T*Hq, 1] column. Otherwise q_ref is [group*T,
+    H*D] — row g*T+t holds, in kv head j's columns, query head
+    j*group+g of token t — and the kv heads run as static column
+    slices with [group*T, H] state, as flash_attention.py's packed
+    layout: the `group` query heads of one kv head are one product's
+    rows.
     With `quantized` the pools are int8 and two more refs hold the
     row's [P*page_size, H] fp32 scales in VMEM, applied per head slice
     to the wave's upcast pages.
@@ -187,14 +222,25 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, v_hbm, *rest,
     keys = W * ps
     scale = 1.0 / math.sqrt(D)
 
+    def first_page(row):
+        """The page of the oldest key the row's first query reads; None
+        without a window (the loop opens at the table's slot 0)."""
+        if window is None:
+            return None
+        oldest = ln_ref[row, 0] - ln_ref[row, 1] - (window - 1)
+        return jnp.maximum(oldest, 0) // ps
+
     def live_pages(row):
         """Pages the row's loop visits: none without a query."""
-        return jnp.where(ln_ref[row, 1] > 0,
-                         pl.cdiv(ln_ref[row, 0], ps), 0)
+        pages = pl.cdiv(ln_ref[row, 0], ps)
+        if window is not None:
+            pages = pages - first_page(row)
+        return jnp.where(ln_ref[row, 1] > 0, pages, 0)
 
     seq_len = ln_ref[b, 0]
     q_len = ln_ref[b, 1]
     n_pages = live_pages(b)
+    base = first_page(b)
     n_waves = pl.cdiv(n_pages, W)
     # the row after this one (its first wave is started under this
     # row's last, so a row does not open on a cold copy)
@@ -208,7 +254,13 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, v_hbm, *rest,
         hold (sentinels, another request's page) is never dereferenced."""
         def page_dma(j, carry):
             # a wait needs the copy's shape, not its source
-            page_id = pt_ref[row, wave * W + j] if start else 0
+            if start:
+                at = wave * W + j
+                if window is not None:
+                    at = first_page(row) + at
+                page_id = pt_ref[row, at]
+            else:
+                page_id = 0
             for i, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
                 copy = pltpu.make_async_copy(
                     hbm.at[page_id], buf.at[slot, j], sem.at[slot, i])
@@ -268,10 +320,18 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, v_hbm, *rest,
         # is query row // H), cols = this wave's keys; causal within
         # the sequence + ragged length mask
         row = jax.lax.broadcasted_iota(jnp.int32, (R, keys), 0)
-        q_pos = seq_len - q_len + (row // H if batched else row)
+        if batched:
+            token = row // (H * group)
+        else:
+            token = row if group == 1 else row % (R // group)
+        q_pos = seq_len - q_len + token
         key_pos = w * keys + jax.lax.broadcasted_iota(
             jnp.int32, (R, keys), 1)
+        if window is not None:
+            key_pos = base * ps + key_pos
         valid = (key_pos < seq_len) & (key_pos <= q_pos)
+        if window is not None:
+            valid = valid & (key_pos > q_pos - window)
         for cols, g in groups:
             # operands in their stored dtype (bf16 products are exact
             # in the fp32 accumulation); 1/sqrt(D) on the fp32 scores
@@ -304,50 +364,77 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, v_hbm, *rest,
 def ragged_paged_attention_pallas(q, k_pages, v_pages, page_tables,
                                   seq_lens, q_lens, *, num_heads,
                                   head_dim, k_scales=None,
-                                  v_scales=None, interpret=None):
+                                  v_scales=None, interpret=None,
+                                  num_kv_heads=None, window=None):
     """Pallas route (interpret-mode on CPU). See module docstring for
-    layouts; k_scales/v_scales engage the int8 dequantizing body.
+    layouts; k_scales/v_scales engage the int8 dequantizing body;
+    `num_kv_heads` (default: num_heads) and `window` (default: every
+    key) are static.
 
     What the shapes decide is decided here; the call itself is one
     jitted function, so a model's layers — the same shapes 24 times in
     one step program — share ONE trace of the kernel body and ONE
     Mosaic lowering (jit caches both by shapes and static arguments)
     where each layer used to pay its own."""
-    T, HD = q.shape[1:]
+    T = q.shape[1]
+    kv_heads = num_kv_heads or num_heads
+    if num_heads % kv_heads:
+        raise ValueError(f'{num_heads} query heads do not divide over '
+                         f'{kv_heads} kv heads')
+    group = num_heads // kv_heads
+    if k_scales is not None and (group > 1 or window is not None):
+        raise NotImplementedError(
+            'int8 pages with kv groups or a window: the scale blocks '
+            'are laid out for one kv head a query head and read from '
+            'the table\'s slot 0')
     batched = T * num_heads <= _BATCHED_ROWS
     W, need = _wave_pages(
-        k_pages.shape[1], HD, k_pages.dtype,
-        T * num_heads if batched else T, q.dtype, page_tables.shape[1],
-        num_heads, k_scales is not None)
+        k_pages.shape[1], k_pages.shape[2], k_pages.dtype,
+        T * num_heads if batched else T * group, q.dtype,
+        page_tables.shape[1], kv_heads, k_scales is not None)
     return _paged_call(
         q, k_pages, v_pages, page_tables, seq_lens, q_lens, k_scales,
-        v_scales, num_heads=num_heads, head_dim=head_dim, wave_pages=W,
-        batched=batched, vmem_bytes=need,
+        v_scales, num_heads=kv_heads, head_dim=head_dim, wave_pages=W,
+        batched=batched, vmem_bytes=need, group=group, window=window,
         interpret=_interpret() if interpret is None else interpret)
 
 
 @functools.partial(jax.jit, static_argnames=(
     'num_heads', 'head_dim', 'wave_pages', 'batched', 'vmem_bytes',
-    'interpret'))
+    'interpret', 'group', 'window'))
 def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
                 k_scales, v_scales, *, num_heads, head_dim, wave_pages,
-                batched, vmem_bytes, interpret):
-    """The block-diagonal q (when `batched`), the Mosaic call and the
-    diagonal blocks of its output, as one jitted function of the
-    shapes and the wrapper's static choices."""
-    B, T, HD = q.shape
-    N, ps = k_pages.shape[:2]
+                batched, vmem_bytes, interpret, group=1, window=None):
+    """The block-diagonal q (when `batched`; else, with kv groups, the
+    query heads of one kv head stacked as rows), the Mosaic call and
+    the diagonal blocks of its output, as one jitted function of the
+    shapes and the wrapper's static choices. `num_heads` counts the kv
+    heads, `group` the query heads on each."""
+    B, T = q.shape[:2]
+    N, ps, HD = k_pages.shape
     P = page_tables.shape[1]
-    H, W = num_heads, wave_pages
+    H, W, D = num_heads, wave_pages, head_dim
     quantized = k_scales is not None
     pt = page_tables.astype(jnp.int32)
     lens = jnp.stack([seq_lens.astype(jnp.int32),
                       q_lens.astype(jnp.int32)], axis=1)       # [B, 2]
-    if batched:
+    if batched and group == 1:
         # row t*H+h = query t masked to head h's columns
         own = (jnp.arange(HD, dtype=jnp.int32)[None, :] // head_dim
                == jnp.arange(H, dtype=jnp.int32)[:, None])      # [H, HD]
         q = jnp.where(own, q[:, :, None, :], 0).reshape(B, T * H, HD)
+    elif batched:
+        # row t*Hq+h = query head h of token t in kv head h // group's
+        # columns
+        own = (jnp.arange(H * group, dtype=jnp.int32)[:, None] // group
+               == jnp.arange(H, dtype=jnp.int32)[None, :])     # [Hq, H]
+        q = jnp.where(own[:, :, None],
+                      q.reshape(B, T, H * group, 1, D), 0) \
+            .reshape(B, T * H * group, HD)
+    elif group > 1:
+        # row g*T+t = the g-th query head of every kv head, token t
+        q = q.reshape(B, T, H, group, D).transpose(0, 3, 1, 2, 4) \
+            .reshape(B, group * T, HD)
     R = q.shape[1]
     row_spec = pl.BlockSpec((None, R, HD), lambda b, pt, ln: (b, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pltpu.HBM)
@@ -385,7 +472,7 @@ def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
     kernel = functools.partial(
         _ragged_paged_kernel, page_size=ps, num_heads=H,
         head_dim=head_dim, wave_pages=W, batched=batched,
-        quantized=quantized)
+        quantized=quantized, group=group, window=window)
     out = scaffold.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -395,11 +482,21 @@ def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
             vmem_limit_bytes=int(min(max(vmem_bytes, 16 * 2 ** 20),
                                      scaffold.VMEM_CAP_BYTES))),
         interpret=interpret,
-        name='paged_attention',
+        # a call with a window has its own row in the profile (the
+        # `paged_attention*` readers sum both)
+        name='paged_attention' if window is None
+        else 'paged_attention_window',
     )(*inputs)
-    if batched:
+    if batched and group == 1:
         # each head's output is its own diagonal block of the rows
         out = jnp.where(own, out.reshape(B, T, H, HD), 0).sum(axis=2)
+    elif batched:
+        out = jnp.where(own[:, :, None],
+                        out.reshape(B, T, H * group, H, D), 0) \
+            .sum(axis=3).reshape(B, T, H * group * D)
+    elif group > 1:
+        out = out.reshape(B, group, T, H, D).transpose(0, 2, 3, 1, 4) \
+            .reshape(B, T, H * group * D)
     return out
 
 
@@ -414,23 +511,26 @@ def _dequant_gathered(pages, scales, H):
 
 def ragged_paged_attention_dense(q, k_pages, v_pages, page_tables,
                                  seq_lens, q_lens, *, num_heads,
-                                 head_dim, k_scales=None, v_scales=None):
+                                 head_dim, k_scales=None, v_scales=None,
+                                 num_kv_heads=None, window=None):
     """Dense lax fallback: gather each row's pages into a [B, P*ps, H*D]
     context and run masked attention. O(B * pages_per_seq * page_size)
     memory — correct everywhere (the CPU serving path and the numerics
     oracle for the kernel), not the TPU hot path. Int8 pages are
     dequantized right after the gather (same per-(slot, head) scales
     the kernel applies in VMEM)."""
-    B, T, HD = q.shape
-    ps = k_pages.shape[1]
+    B, T = q.shape[:2]
+    ps, HD = k_pages.shape[1:]
     P = page_tables.shape[1]
     D = head_dim
+    kv_heads = num_kv_heads or num_heads
+    group = num_heads // kv_heads
     pt = jnp.clip(page_tables.astype(jnp.int32), 0,
                   k_pages.shape[0] - 1)
     if k_scales is not None:
-        k = _dequant_gathered(k_pages[pt], k_scales[pt], num_heads) \
+        k = _dequant_gathered(k_pages[pt], k_scales[pt], kv_heads) \
             .reshape(B, P * ps, HD)
-        v = _dequant_gathered(v_pages[pt], v_scales[pt], num_heads) \
+        v = _dequant_gathered(v_pages[pt], v_scales[pt], kv_heads) \
             .reshape(B, P * ps, HD)
     else:
         k = k_pages[pt].reshape(B, P * ps, HD).astype(jnp.float32)
@@ -441,11 +541,14 @@ def ragged_paged_attention_dense(q, k_pages, v_pages, page_tables,
     key_pos = jnp.arange(P * ps, dtype=jnp.int32)[None, None, :]
     valid = (key_pos < seq_lens[:, None, None]) & \
             (key_pos <= q_pos[:, :, None])                     # [B, T, K]
+    if window is not None:
+        valid = valid & (key_pos > q_pos[:, :, None] - window)
     outs = []
     for h in range(num_heads):
         qh = q[:, :, h * D:(h + 1) * D].astype(jnp.float32) * scale
-        kh = k[:, :, h * D:(h + 1) * D]
-        vh = v[:, :, h * D:(h + 1) * D]
+        j = h // group          # the kv head this query head reads
+        kh = k[:, :, j * D:(j + 1) * D]
+        vh = v[:, :, j * D:(j + 1) * D]
         s = jnp.einsum('btd,bkd->btk', qh, kh,
                        preferred_element_type=jnp.float32)
         s = jnp.where(valid, s, NEG_INF)
@@ -466,16 +569,21 @@ def use_pallas_route():
 
 def ragged_paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
                            q_lens=None, *, num_heads, head_dim,
-                           k_scales=None, v_scales=None):
+                           k_scales=None, v_scales=None,
+                           num_kv_heads=None, window=None):
     """Auto-routed entry (array-level; used inside the serving engine's
-    jitted steps). Pass k_scales/v_scales for int8 pages."""
+    jitted steps). Pass k_scales/v_scales for int8 pages; num_kv_heads
+    where fewer kv heads than query heads are stored (the pool's width
+    is num_kv_heads * head_dim), window where a query reads only its
+    last `window` keys."""
     if q_lens is None:
         q_lens = jnp.full((q.shape[0],), q.shape[1], jnp.int32)
     fn = (ragged_paged_attention_pallas if use_pallas_route()
           else ragged_paged_attention_dense)
     return fn(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
               num_heads=num_heads, head_dim=head_dim,
-              k_scales=k_scales, v_scales=v_scales)
+              k_scales=k_scales, v_scales=v_scales,
+              num_kv_heads=num_kv_heads, window=window)
 
 
 def _flat_slots(page_tables, seq_lens, q_lens, T, N, ps):

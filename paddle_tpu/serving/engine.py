@@ -47,6 +47,7 @@ host-side scheduling decision the engine makes lands in the request's
 journal and the scheduler timeline, with zero extra device syncs.
 docs/serving.md covers tuning the knobs.
 """
+import collections
 import math
 import os
 import time
@@ -72,6 +73,14 @@ def _host_fetch(x):
     PR-3 numerics._host_fetch convention. Tracing must not add calls
     here (asserted in tests/test_serving_trace.py)."""
     return np.asarray(x)
+
+
+# the growing counts a sparse-expert model carries behind its rows per
+# expert (serving/protocol.py moe_counters), in their order, and the
+# counters they feed
+_MOE_COUNTERS = (('experts_touched', 'ptpu_moe_experts_touched_total'),
+                 ('rows', 'ptpu_moe_rows_total'),
+                 ('calls', 'ptpu_moe_calls_total'))
 
 
 class ServingConfig:
@@ -269,7 +278,10 @@ class ServingConfig:
 
 
 class ServingEngine:
-    """Continuous-batching inference over a GPTForCausalLM.
+    """Continuous-batching inference over a model that implements the
+    serving-model protocol (serving/protocol.py: the cache spec per
+    layer, `forward_paged`, the head's weight) — GPTForCausalLM,
+    AfmoeForCausalLM.
 
     `mesh`: an optional replica-local jax Mesh with an 'mp' axis — the
     mp-sharded serving route (ISSUE 11): attention heads (and the KV
@@ -306,17 +318,56 @@ class ServingEngine:
             or math.ceil(mcfg.max_seq_len / ps))
         num_pages = int(config.num_pages
                         or config.max_batch_size * self.max_pages_per_seq)
-        attn0 = model.gpt.layers[0].attn
-        dtype = (config.kv_dtype
-                 or model.gpt.embeddings.word_embeddings.weight.dtype)
+        # the model's side of the protocol: what a token's K/V takes
+        # in each layer's pages. One page table serves every layer, so
+        # the layers must agree on the pages' width; a layer's window
+        # bounds what its attention READS, not what the pool keeps
+        spec = list(model.kv_cache_spec())
+        if len({(s.num_kv_heads, s.head_dim) for s in spec}) != 1:
+            raise ValueError(
+                "every layer must store the same kv heads and head_dim "
+                f"(one page table serves them all), got {spec}")
+        # {window: layers that have it}; a layer without one reads a
+        # row's whole context
+        self._windows = collections.Counter(
+            s.window for s in spec if s.window is not None)
+        self._kv_layers = len(spec)
+        dtype = config.kv_dtype or model.lm_head_weight().dtype
         self.mesh = mesh
         self._mp = int(mesh.shape['mp']) if (
             mesh is not None and 'mp' in mesh.shape) else 1
+        # the routes this configuration takes through forward_paged
+        # against those the model's is written for
+        needs = {'plain'} | {route for route, on in (
+            ('fused', config.fused_k > 1), ('verify', config.spec_k > 0),
+            ('int8_kv', _np_dtype(dtype) == np.int8),
+            ('int8_weights', config.weight_dtype is not None),
+            ('mp', self._mp > 1)) if on}
+        lacking = needs - set(model.paged_routes)
+        if lacking:
+            raise NotImplementedError(
+                f"{type(model).__name__}.forward_paged lacks the "
+                f"{sorted(lacking)} route this configuration needs: it "
+                f"is written for {sorted(model.paged_routes)}")
+        # a sparse-expert model's counters (protocol.py moe_counters)
+        # ride the plain route's one fetch; a configuration that takes
+        # another route too carries none
+        self._moe_dev = model.moe_counters() if needs == {'plain'} \
+            else None
+        if self._moe_dev is not None:
+            self._moe_seen = np.zeros((self._moe_dev.shape[0], 3),
+                                      np.int64)
+        self._moe = {'rows': 0, 'experts_touched': 0, 'calls': 0}
+        # who wants the rows per expert and layer of a prompt's last
+        # chunk (the one prefill dispatch that is fetched): a callable
+        # (request, first position, tokens, int array [expert layers,
+        # experts held]), or None. The benchmark's check listens.
+        self.moe_rows_listener = None
         if self._mp > 1:
-            if attn0.world_size != self._mp:
+            if model.mp_degree != self._mp:
                 raise ValueError(
                     f"mesh mp={self._mp} but the model was built with "
-                    f"mp degree {attn0.world_size} — fleet.init (or a "
+                    f"mp degree {model.mp_degree} — fleet.init (or a "
                     f"minimal hcg) with model-parallel degree "
                     f"{self._mp} BEFORE constructing the model")
             if config.weight_dtype is not None:
@@ -329,8 +380,7 @@ class ServingEngine:
         # heads' pages — the same layout the column-sharded qkv writes
         self.pool = KVPagePool(
             num_pages, ps, num_layers=mcfg.num_layers,
-            num_heads=attn0.local_heads * self._mp,
-            head_dim=attn0.head_dim,
+            num_heads=spec[0].num_kv_heads, head_dim=spec[0].head_dim,
             dtype=dtype, prefix_cache=config.prefix_cache)
         self._kv_sharding = None
         if self._mp > 1:
@@ -514,6 +564,8 @@ class ServingEngine:
         self._it_fetch = 0.0
         self._it_decode_s = 0.0
         self._it_kv_read_tokens = 0
+        self._it_kv_window = [0, 0]
+        self._it_moe_load = None
         self._it_live_pages = 0
         self._it_page_slots = 0
         self._it_prefill_tokens = 0
@@ -753,6 +805,8 @@ class ServingEngine:
         self._it_fetch = 0.0
         self._it_decode_s = 0.0
         self._it_kv_read_tokens = 0
+        self._it_kv_window = [0, 0]
+        self._it_moe_load = None
         self._it_live_pages = 0
         self._it_page_slots = 0
         self._it_prefill_tokens = 0
@@ -845,6 +899,8 @@ class ServingEngine:
                 schedule=sched_dt / n_iter,
                 decode_seconds=self._it_decode_s / n_iter,
                 kv_read_tokens=self._it_kv_read_tokens // n_iter,
+                kv_window_tokens=self._it_kv_window if first else None,
+                moe_load=self._it_moe_load if first else None,
                 paged_live_pages=self._it_live_pages if first else 0,
                 paged_page_slots=self._it_page_slots if first else 0,
                 prefill_tokens=self._it_prefill_tokens if first else 0,
@@ -1255,8 +1311,8 @@ class ServingEngine:
             g = jnp.moveaxis(g, 0, -2)              # [..., mp, V/mp]
             return g.reshape(lg.shape[:-1] + (lg.shape[-1] * mp,))
 
-        def step(params, kv, tokens, page_tables, seq_lens, q_lens, key,
-                 ords, temps, top_ks):
+        def step(params, kv, moe, tokens, page_tables, seq_lens, q_lens,
+                 key, ords, temps, top_ks):
             # int8 pools carry (k, v, k_scales, v_scales) per layer;
             # dense pools (k, v) — forward_paged keys off the arity
             cts = [tuple(Tensor(a) for a in c) for c in kv]
@@ -1275,10 +1331,10 @@ class ServingEngine:
                 pos = (seq_lens[:, None] - q_lens[:, None]
                        + jnp.arange(T, dtype=jnp.int32)[None, :])
                 pos = jnp.clip(pos, 0, max_pos)
-                h, new_kv = model.gpt.forward_paged(
+                h, new_kv, moe = model.forward_paged(
                     Tensor(tokens), Tensor(pos), cts, page_tables,
-                    seq_lens, q_lens)
-                w = model.gpt.embeddings.word_embeddings.weight
+                    seq_lens, q_lens, moe_counters=moe)
+                w = model.lm_head_weight()
                 if verify:
                     # multi-query verify: greedy next-token at every
                     # draft position in one dispatch; padding positions
@@ -1301,7 +1357,7 @@ class ServingEngine:
                             seq_lens, temps, top_ks)
                         nxt = jnp.concatenate([nxt, samp[:, None]], 1)
                     return nxt, [tuple(t.data for t in c)
-                                 for c in new_kv]
+                                 for c in new_kv], moe
                 idx = jnp.clip(q_lens - 1, 0, T - 1).astype(jnp.int32)
                 h_last = jnp.take_along_axis(
                     h.data, idx[:, None, None], axis=1)[:, 0, :]
@@ -1314,7 +1370,11 @@ class ServingEngine:
                                          top_ks)
                 else:
                     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return nxt, [tuple(t.data for t in c) for c in new_kv]
+                if moe is not None:
+                    # the experts' counters ride behind the sampled
+                    # ids: still ONE host fetch a step (_take_moe)
+                    nxt = jnp.concatenate([nxt, moe.reshape(-1)])
+            return nxt, [tuple(t.data for t in c) for c in new_kv], moe
 
         # donation updates the pool pages in place; CPU jax has no
         # donation support and would warn every call
@@ -1330,9 +1390,9 @@ class ServingEngine:
             from jax.sharding import PartitionSpec as P
             kv_specs = [tuple(P(None, None, 'mp') for _ in layer)
                         for layer in self.pool.kv]
-            in_specs = (dict(self._param_specs), kv_specs,
+            in_specs = (dict(self._param_specs), kv_specs, None,
                         P(), P(), P(), P(), P(), P(), P(), P())
-            out_specs = (P(), kv_specs)
+            out_specs = (P(), kv_specs, None)
             step = shard_map(step, mesh=self.mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False)
         jitted = jax.jit(step, donate_argnums=donate)
@@ -1402,7 +1462,7 @@ class ServingEngine:
                 else:
                     arrs[n] = v
             with bind_arrays(model, arrs), _spmd():
-                w = model.gpt.embeddings.word_embeddings.weight
+                w = model.lm_head_weight()
 
                 def body(carry, _):
                     kv_c, tok, seq, done, emitted = carry
@@ -1410,7 +1470,7 @@ class ServingEngine:
                     q = jnp.where(alive, 1, 0).astype(jnp.int32)
                     cts = [tuple(Tensor(a) for a in c) for c in kv_c]
                     pos = jnp.clip(seq - q, 0, max_pos)[:, None]
-                    h, new_kv = model.gpt.forward_paged(
+                    h, new_kv, _ = model.forward_paged(
                         Tensor(tok[:, None]), Tensor(pos), cts,
                         page_tables, seq, q)
                     h_last = h.data[:, 0, :]
@@ -1516,8 +1576,8 @@ class ServingEngine:
                 top_ks[i] = req.top_k
                 # decode roofline: iteration j of this row reads
                 # context_len + j KV tokens
-                self._it_kv_read_tokens += \
-                    w * req.context_len + w * (w - 1) // 2
+                for j in range(w):
+                    self._count_kv_read(req.context_len + j)
                 self._it_live_pages += sum(
                     self.pool.pages_for(req.context_len + j)
                     for j in range(w))
@@ -1544,6 +1604,48 @@ class ServingEngine:
         self._it_fetch += t2 - t1
         self._decode_time += t2 - t0
         return len(rows), self._accepted(self._accept_fused, nxt, rows, K)
+
+    def _count_kv_read(self, context):
+        """One decode row's KV reads this iteration, in tokens a layer:
+        the mean over the layers of what each reads (a window layer no
+        more than its window), so tokens x kv_bytes_per_token stays the
+        bytes; and, for the window layers alone, what they read against
+        what they would without the bound."""
+        if not self._windows:
+            self._it_kv_read_tokens += context
+            return
+        total = (self._kv_layers - sum(self._windows.values())) * context
+        for w, layers in self._windows.items():
+            total += layers * min(context, w)
+            self._it_kv_window[0] += layers * min(context, w)
+            self._it_kv_window[1] += layers * context
+        self._it_kv_read_tokens += total // self._kv_layers
+
+    def _take_moe(self, packed, n, decode):
+        """Split one fetch into its `n` sampled ids and the experts'
+        counters behind them (protocol.py: rows per expert of this
+        call, then experts touched, rows and calls, only ever growing
+        and wrapping — differences are taken), and account them:
+        `ptpu_moe_*` counters, and for a decode call its load, the
+        most rows an expert took over the mean, averaged over the
+        expert layers. -> (ids, rows per expert [layers, experts])."""
+        moe = packed[n:].reshape(self._moe_dev.shape)
+        grown = moe[:, -3:].astype(np.int64)
+        delta = ((grown - self._moe_seen) % (1 << 32)).sum(axis=0)
+        self._moe_seen = grown
+        for (key, name), d in zip(_MOE_COUNTERS, delta):
+            self._moe[key] += int(d)
+            _monitor.counter(
+                name,
+                help='sparse-expert layers on the served path: experts '
+                     'a call touched, (token, expert) rows routed, '
+                     'expert-layer calls').inc(int(d))
+        if decode:
+            rows = moe[:, :-3].astype(np.float64)
+            mean = rows.mean(axis=1)
+            if (mean > 0).all():
+                self._it_moe_load = float((rows.max(axis=1) / mean).mean())
+        return packed[:n], moe[:, :-3]
 
     def _accepted(self, accept, *args):
         """Run one of the host accept loops under its `serve::accept`
@@ -1656,8 +1758,8 @@ class ServingEngine:
         tc0 = time.perf_counter()
         with RecordEvent('serve::compiled_step', event_type='serve',
                          shape='prefill'):
-            nxt, new_kv = fn(
-                self._params, self.pool.kv,
+            nxt, new_kv, self._moe_dev = fn(
+                self._params, self.pool.kv, self._moe_dev,
                 jnp.asarray([chunk], jnp.int32),
                 jnp.asarray([self._page_row(req)], jnp.int32),
                 jnp.asarray([start + n], jnp.int32),
@@ -1708,7 +1810,12 @@ class ServingEngine:
                 return n            # the budget says emit nothing
             tf0 = time.perf_counter()
             with RecordEvent('serve::sample_fetch', event_type='serve'):
-                tok = int(_host_fetch(nxt)[0])  # the sampled-token fetch
+                got = _host_fetch(nxt)          # the sampled-token fetch
+            if self._moe_dev is not None:
+                got, rows = self._take_moe(got, 1, decode=False)
+                if self.moe_rows_listener is not None:
+                    self.moe_rows_listener(req, start, n, rows.copy())
+            tok = int(got[0])
             self._it_fetch += time.perf_counter() - tf0
             with RecordEvent('serve::accept', event_type='serve',
                              req=req.id) as ev:
@@ -1821,7 +1928,7 @@ class ServingEngine:
                 drafts = proposals.get(req.id, ()) if verify else ()
                 active.append((i, req, list(drafts)))
                 # decode roofline: KV tokens this row's attention reads
-                self._it_kv_read_tokens += req.context_len + len(drafts)
+                self._count_kv_read(req.context_len + len(drafts))
                 tokens[i, 0] = (req.generated[-1] if req.generated
                                 else req.prompt[-1])
                 if drafts:
@@ -1846,8 +1953,8 @@ class ServingEngine:
         with RecordEvent('serve::compiled_step', event_type='serve',
                          shape='verify' if verify else 'decode',
                          batch=len(active)):
-            nxt, new_kv = fn(
-                self._params, self.pool.kv,
+            nxt, new_kv, self._moe_dev = fn(
+                self._params, self.pool.kv, self._moe_dev,
                 jnp.asarray(tokens), jnp.asarray(page_tables),
                 jnp.asarray(seq_lens), jnp.asarray(q_lens), self._key,
                 jnp.asarray(ords),
@@ -1856,6 +1963,8 @@ class ServingEngine:
         t1 = time.perf_counter()
         with RecordEvent('serve::sample_fetch', event_type='serve'):
             nxt = _host_fetch(nxt)              # the sampled-token fetch
+        if self._moe_dev is not None:
+            nxt, _ = self._take_moe(nxt, B, decode=True)
         t2 = time.perf_counter()
         dt = t2 - t0
         self._it_compute += t1 - t0
@@ -2075,6 +2184,12 @@ class ServingEngine:
             'decode_tokens_total': self._decode_tokens,
             'prefill_tokens_total': self._prefill_tokens,
             'prefill_chunks_total': self._prefill_chunks,
+            # sparse-expert layers (zeros for a model without them)
+            'moe_rows_total': self._moe['rows'],
+            'moe_experts_touched_total': self._moe['experts_touched'],
+            'moe_calls_total': self._moe['calls'],
+            'moe_load_sum': self.ledger.moe_load_sum,
+            'moe_load_steps': self.ledger.moe_load_steps,
             'weight_dtype': (str(self.config.weight_dtype)
                              if self.config.weight_dtype else None),
             'quantized_params': len(self._qparam_dtypes),

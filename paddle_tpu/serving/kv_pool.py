@@ -1,8 +1,10 @@
 """Block-paged KV-cache pool (vLLM-style paging, TPU-shaped).
 
 One fixed device tensor pair per decoder layer — `[num_pages,
-page_size, local_heads * head_dim]` — shared by every in-flight
-request. Sequences own pages through per-sequence page tables; a
+page_size, kv_heads * head_dim]` — shared by every in-flight
+request. The width is the model's KV heads (its cache spec,
+serving/protocol.py): 4 kv heads of 128 are 2 KB a token a layer however
+many query heads read them. Sequences own pages through per-sequence page tables; a
 host-side free-list allocator hands pages out and takes them back, so
 KV memory is O(pages actually in use) instead of the dense cache's
 O(batch * max_seq_len). The ragged paged-attention kernel gathers a
@@ -123,6 +125,7 @@ class KVPagePool:
     Device arrays are created lazily (`materialize()`) so pure
     allocator tests never touch jax; the engine materializes once at
     build. `kv[l]` is the (k_pages, v_pages) pair of layer l.
+    `num_heads` counts the heads STORED: the model's kv heads.
     """
 
     def __init__(self, num_pages, page_size, num_layers=0, num_heads=0,

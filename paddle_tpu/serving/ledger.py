@@ -160,6 +160,14 @@ class ServeLedger:
         # those programs carried (rows x max_pages_per_seq)
         self.paged_live_pages = 0
         self.paged_page_slots = 0
+        # window layers (lifetime ints, decode rows): tokens a layer
+        # they read, and what they would read without the bound
+        self.kv_read_tokens_window = 0
+        self.kv_read_tokens_full = 0
+        # sparse-expert load of the decode calls: sum and count of
+        # (most rows an expert took / mean rows), layers averaged
+        self.moe_load_sum = 0.0
+        self.moe_load_steps = 0
         # goodput counters (lifetime, host ints)
         self.emitted_tokens = 0
         self.delivered_tokens = 0
@@ -187,10 +195,17 @@ class ServeLedger:
                           schedule=0.0, decode_seconds=0.0,
                           kv_read_tokens=0, prefill_tokens=0,
                           prefill_seconds=0.0, prefill_ctx_tokens=0,
-                          paged_live_pages=0, paged_page_slots=0):
+                          paged_live_pages=0, paged_page_slots=0,
+                          kv_window_tokens=None, moe_load=None):
         """One engine iteration's measured phase walls (host
         perf_counter segments — no device syncs)."""
         self.iterations += 1
+        if kv_window_tokens:
+            self.kv_read_tokens_window += int(kv_window_tokens[0])
+            self.kv_read_tokens_full += int(kv_window_tokens[1])
+        if moe_load is not None:
+            self.moe_load_sum += float(moe_load)
+            self.moe_load_steps += 1
         self.paged_live_pages += int(paged_live_pages)
         self.paged_page_slots += int(paged_page_slots)
         self._walls.append(max(float(wall), 0.0))
@@ -395,6 +410,16 @@ class ServeLedger:
                 'paged_live_page_share':
                     self.paged_live_pages / self.paged_page_slots,
             })
+        if out is not None and self.kv_read_tokens_full:
+            # what the window layers' attention read of what it would
+            # read without the bound (decode rows)
+            out.update({
+                'kv_read_tokens_window': self.kv_read_tokens_window,
+                'kv_read_tokens_full': self.kv_read_tokens_full,
+            })
+        if out is not None and self.moe_load_steps:
+            out['moe_load_max_over_mean'] = \
+                self.moe_load_sum / self.moe_load_steps
         return out
 
     # -- lifecycle -----------------------------------------------------------
@@ -410,6 +435,10 @@ class ServeLedger:
         self.iterations = 0
         self.paged_live_pages = 0
         self.paged_page_slots = 0
+        self.kv_read_tokens_window = 0
+        self.kv_read_tokens_full = 0
+        self.moe_load_sum = 0.0
+        self.moe_load_steps = 0
         self.emitted_tokens = 0
         self.delivered_tokens = 0
         self.wasted = {c: 0 for c in _WASTE_CAUSES}

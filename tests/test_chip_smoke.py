@@ -22,12 +22,16 @@ TINY = dict(
     fused_k=2, spec_k=2,
     kernel_seq=32, opt_elems=700,
     multi_depth=2, multi_seq=32, multi_steps=2,
+    sparse=dict(heads=4, kv_heads=2, head_dim=16, window=24,
+                page_size=8, pages=8, batch=2, chunk=20,
+                contexts=(3, 23, 25, 64),
+                experts=8, top_k=2, hidden=32, width=16),
 )
 
 
 def test_kernels_phase():
     errs = chip_smoke.phase_kernels(TINY)
-    assert len(errs) >= 25
+    assert len(errs) >= 25 + 16 + 4
 
 
 def test_train_phase():

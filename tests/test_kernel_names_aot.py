@@ -161,6 +161,50 @@ def test_paged_attention_is_named(one_chip, as_on_tpu):
     assert got == {'paged_attention'}
 
 
+@pytest.mark.parametrize('rows,window,name', [
+    (64, None, 'paged_attention'), (64, 2048, 'paged_attention_window'),
+    (1, None, 'paged_attention'), (1, 2048, 'paged_attention_window')])
+def test_paged_attention_with_kv_groups_compiles_and_is_named(
+        rows, window, name, one_chip, as_on_tpu):
+    """The sparse server cell's two step shapes at its published
+    widths: 32 query heads on 4 kv heads of 128, 528-page tables over
+    28,000 pages; [64, 1] decode and the [1, 512] chunk, whose 8 query
+    heads a kv head stack as 4096 rows. A call with a window has its
+    own name."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    T = 1 if rows == 64 else 512
+    pages = ((28000, 16, 512), BF16)
+
+    def fn(q, k, v, pt, sl, ql):
+        return pa.ragged_paged_attention_pallas(
+            q, k, v, pt, sl, ql, num_heads=32, head_dim=128,
+            num_kv_heads=4, window=window)
+    got = mosaic_calls(fn, [((rows, T, 4096), BF16), pages, pages,
+                            ((rows, 528), jnp.int32), ((rows,), jnp.int32),
+                            ((rows,), jnp.int32)], one_chip)
+    assert got == {name}
+
+
+@pytest.mark.parametrize('pairs', [512, 4096])
+def test_the_experts_grouped_matmul_compiles_and_is_named(
+        pairs, one_chip, as_on_tpu):
+    """128 experts of [2048, 1024] x 3 at a decode step's 512 (token,
+    expert) pairs and a 512-token chunk's 4096: the gated half and the
+    plain half are the same Mosaic call by name."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gmm
+    tm = gmm.tile_rows_for(pairs, 128)
+    tiles = -(-pairs // tm) + 128
+    idx = ((tiles,), jnp.int32)
+
+    def fn(x, w1, w3, w2, te, tb, nl):
+        h = gmm.grouped_matmul_pallas(x, w3, te, tb, nl, w1)
+        return gmm.grouped_matmul_pallas(h, w2, te, tb, nl)
+    up, down = ((128, 2048, 1024), BF16), ((128, 1024, 2048), BF16)
+    got = mosaic_calls(fn, [((tiles * tm, 2048), BF16), up, up, down, idx,
+                            idx, ((1,), jnp.int32)], one_chip)
+    assert got == {'moe_grouped_matmul'}
+
+
 def test_the_optimizer_kernels_are_named(one_chip, as_on_tpu):
     import paddle_tpu as paddle
     from paddle_tpu.ops.pallas import fused_optimizer as fo

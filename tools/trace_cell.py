@@ -137,7 +137,9 @@ def main(argv=None):
             f'{k} {rooflines[-1][k]}' for k in (
                 'kv_read_tokens_mean', 'kv_bytes_per_token',
                 'paged_live_pages', 'paged_page_slots',
-                'paged_live_page_share') if k in rooflines[-1])
+                'paged_live_page_share', 'kv_read_tokens_window',
+                'kv_read_tokens_full', 'moe_load_max_over_mean')
+            if k in rooflines[-1])
     print(text, flush=True)
     base = os.path.join(args.out, cell['name'])
     with open(base + '.summary.json', 'w') as f:
