@@ -42,14 +42,14 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-# GPT-1.3B (bench.py's headline configuration). `depth`/`ab_depth`/
+# GPT-1.3B (the benchmark's `gpt3-1.3b` configuration). `depth`/`ab_depth`/
 # `serve_depth`/`multi_depth` are the only fields a time or memory budget
 # may cut; tests/test_chip_smoke.py passes a tiny dict of the same shape.
 FULL = dict(
     vocab=50304, hidden=2048, heads=16, seq=2048,
     # train: A microbatches of mb sequences; 1 warm-up + `steps` steps
     depth=24, ab_depth=2, A=4, mb=2, steps=3, lr=1e-4,
-    # serve: the TPU values of bench.py's serving leg
+    # serve: page and chunk of the benchmark's server cell, a batch of 8
     serve_depth=24, page_size=16, batch=8, chunk=128,
     prompt_lo=32, prompt_hi=384, new_tokens=32, requests=8,
     fused_k=4, spec_k=2,
@@ -491,7 +491,7 @@ def _single_chip_mesh():
 
 
 def _pipeline_engine(size, depth, flash=True):
-    """bench.py's headline trainer: GPT blocks through the 1F1B SPMD
+    """The benchmark's GPT trainer: GPT blocks through the 1F1B SPMD
     pipeline engine at pp=1, remat, param-dtype grad accumulation, AdamW
     with bf16-stored moments."""
     import paddle_tpu as paddle

@@ -32,13 +32,17 @@ map the same physical pages (kv_pool.py refcounts + hash-chained
 index), so cache hits skip whole prefill chunks and TTFT drops to the
 uncached tail's cost.
 
-Both run `GPTModel.forward_paged` (ragged paged attention +
-`write_kv_pages` scatter) under `jit` with the KV pool donated, sample
-the next token ON DEVICE (greedy argmax or temperature/top-k via
-jax.random), and fetch only the sampled token ids — the single
-per-token host round-trip. Idle decode slots ride along with q_len=0:
-their K/V writes are dropped by the scatter and their outputs ignored,
-so occupancy is a pure scheduling concern.
+All four come from ONE builder (`_build_step`) and are called in ONE
+place (`_dispatch`): they run the model's `forward_paged` (ragged paged
+attention + `write_kv_pages` scatter) under `jit` with the KV pool
+donated, pick the next token ON DEVICE (greedy argmax or
+temperature/top-k via jax.random), and fetch only the sampled token
+ids — the single per-token host round-trip. The dispatch sites keep
+what is theirs (prefix match and page growth; draft proposal, capacity
+and verify; window reservation and roll-back) and hand over their rows.
+Idle decode slots ride along with q_len=0: their K/V writes are dropped
+by the scatter and their outputs ignored, so occupancy is a pure
+scheduling concern.
 
 Scheduling (admit / chunk order / preempt-youngest) lives in
 scheduler.py; page accounting in kv_pool.py; ptpu_serve_* metrics in
@@ -48,6 +52,7 @@ journal and the scheduler timeline, with zero extra device syncs.
 docs/serving.md covers tuning the knobs.
 """
 import collections
+import contextlib
 import math
 import os
 import time
@@ -557,20 +562,7 @@ class ServingEngine:
             n_params=n_params, layers=mcfg.num_layers,
             hidden=mcfg.hidden_size, param_bytes=param_bytes,
             kv_bytes_per_token=self.pool.bytes_per_token())
-        # per-iteration phase accumulators step() resets and
-        # _prefill_chunk_step/_decode_step feed (host perf_counter
-        # segments — never a device sync)
-        self._it_compute = 0.0
-        self._it_fetch = 0.0
-        self._it_decode_s = 0.0
-        self._it_kv_read_tokens = 0
-        self._it_kv_window = [0, 0]
-        self._it_moe_load = None
-        self._it_live_pages = 0
-        self._it_page_slots = 0
-        self._it_prefill_tokens = 0
-        self._it_prefill_s = 0.0
-        self._it_prefill_ctx = 0
+        self._reset_iteration()
 
     # followers a budget-blocked queue head tolerates being admitted
     # past it before the admission sweep reverts to blocking at the
@@ -686,8 +678,8 @@ class ServingEngine:
         return True
 
     def ladder_history(self):
-        """Stage-transition events [{t, from, to, pressure}] — the
-        bench leg's ladder timeline."""
+        """Stage-transition events [{t, from, to, pressure}]: the
+        ladder's timeline."""
         return list(self._ladder.history) if self._ladder else []
 
     # -- request intake ------------------------------------------------------
@@ -801,17 +793,7 @@ class ServingEngine:
         completed_before = self._completed
         preempt_before = self.scheduler.preemptions
         t_begin = self._gap.dispatch_begin()
-        self._it_compute = 0.0
-        self._it_fetch = 0.0
-        self._it_decode_s = 0.0
-        self._it_kv_read_tokens = 0
-        self._it_kv_window = [0, 0]
-        self._it_moe_load = None
-        self._it_live_pages = 0
-        self._it_page_slots = 0
-        self._it_prefill_tokens = 0
-        self._it_prefill_s = 0.0
-        self._it_prefill_ctx = 0
+        self._reset_iteration()
         t_sched = time.perf_counter()
         with RecordEvent('serve::schedule', event_type='serve'):
             with RecordEvent('serve::check_stalled', event_type='serve'):
@@ -846,6 +828,23 @@ class ServingEngine:
                 decode_slots, decode_tokens,
                 self.scheduler.preemptions - preempt_before,
                 self._completed != completed_before)
+
+    def _reset_iteration(self):
+        """The iteration's phase clocks and roofline counts: step()
+        resets them, _dispatch alone feeds them (host perf_counter
+        segments — never a device sync), _step_telemetry hands them
+        to the ledger."""
+        self._it_compute = 0.0
+        self._it_fetch = 0.0
+        self._it_decode_s = 0.0
+        self._it_kv_read_tokens = 0
+        self._it_kv_window = [0, 0]
+        self._it_moe_load = None
+        self._it_live_pages = 0
+        self._it_page_slots = 0
+        self._it_prefill_tokens = 0
+        self._it_prefill_s = 0.0
+        self._it_prefill_ctx = 0
 
     def _step_telemetry(self, fused, wall, sched_dt, admitted,
                         prefill_tokens, decode_slots, decode_tokens,
@@ -1265,57 +1264,47 @@ class ServingEngine:
         return released
 
     # -- jitted steps --------------------------------------------------------
-    def _step_fn(self, B, T, sample, verify=False):
-        """sample=False compiles a greedy-argmax step — the common
-        serving mode must not pay _device_sample's full-vocab sort on
-        every decode dispatch (top_ks is traced, XLA can't elide it).
-        verify=True compiles the speculative-decode step shape
-        [max_batch, spec_k+1]: greedy argmax at EVERY query position
-        (the per-draft verdicts) instead of just the last."""
-        fn = self._step_fns.get((B, T, sample, verify))
+    def _step_fn(self, key):
+        """The compiled program under `key`, built on first use.
+        (B, T, sample, verify) is the [B, T] step: sample=False
+        compiles a greedy argmax — the common serving mode must not pay
+        _device_sample's full-vocab sort on every dispatch (top_ks is
+        traced, XLA can't elide it) — and verify=True the speculative-
+        decode shape [max_batch, spec_k+1], the greedy argmax at EVERY
+        query position (the per-draft verdicts) instead of just the
+        last. ('fused', B, K, sample) is K iterations of the [B, 1]
+        step under one lax.scan (ISSUE 19)."""
+        fn = self._step_fns.get(key)
         if fn is None:
-            fn = self._build_step(B, T, sample, verify)
-            self._step_fns[(B, T, sample, verify)] = fn
+            fn = self._step_fns[key] = self._build_step(key)
         return fn
 
-    def _build_step(self, B, T, sample, verify=False):
+    def _build_step(self, key):
+        """The one builder of compiled steps. What every shape shares
+        is written once: the parameters bound for the trace (int8
+        weights de-quantised, the mp region entered), the forward over
+        the paged pool and the pick of each row's next id, donation of
+        the pool, the shard_map specs, and the eval()/no_grad call.
+        Every program is `step(params, kv, moe, *host operands) ->
+        (ids, kv, moe)`; `moe` (the experts' counters) is None wherever
+        the model or the route carries none, and then no operand."""
         jax, jnp = self._jax, self._jnp
-        import contextlib
         model = self.model
         from ..core.tensor import Tensor
         from ..core.autograd import no_grad
         from ..jit import bind_arrays
         max_pos = model.config.max_seq_len - 1
-
         qdtypes = dict(self._qparam_dtypes)
         mp = self._mp
+        fused = key[0] == 'fused'
+        if fused:
+            _, B, K, sample = key
+            verify = False
+        else:
+            _B, _T, sample, verify = key    # shapes come with the operands
 
-        def _spmd():
-            # mp_layers key their collectives off the spmd region —
-            # without it a >1-degree model would silently run the
-            # degenerate single-rank math on sharded weights
-            if mp > 1:
-                from ..distributed import collective as C
-                return C.spmd_region(('mp',))
-            return contextlib.nullcontext()
-
-        def _full_logits(lg):
-            """Vocab-parallel logits -> full vocab: the tied LM head is
-            the VocabParallelEmbedding weight, so under mp each shard
-            computes [., V/mp] logits for its vocab rows; argmax /
-            sampling need the whole vocab, so gather over 'mp' (shard
-            i's rows are vocab block i — concat order is the identity)."""
-            if mp <= 1:
-                return lg
-            g = jax.lax.all_gather(lg, 'mp')        # [mp, ..., V/mp]
-            g = jnp.moveaxis(g, 0, -2)              # [..., mp, V/mp]
-            return g.reshape(lg.shape[:-1] + (lg.shape[-1] * mp,))
-
-        def step(params, kv, moe, tokens, page_tables, seq_lens, q_lens,
-                 key, ords, temps, top_ks):
-            # int8 pools carry (k, v, k_scales, v_scales) per layer;
-            # dense pools (k, v) — forward_paged keys off the arity
-            cts = [tuple(Tensor(a) for a in c) for c in kv]
+        @contextlib.contextmanager
+        def bound(params):
             # fused dequant of weight-only-quantized params:
             # q * (scale / 127) per out-channel, cast to storage dtype
             arrs = {}
@@ -1327,54 +1316,128 @@ class ServingEngine:
                                * s.reshape(shape)).astype(qdtypes[n])
                 else:
                     arrs[n] = v
-            with bind_arrays(model, arrs), _spmd():
-                pos = (seq_lens[:, None] - q_lens[:, None]
-                       + jnp.arange(T, dtype=jnp.int32)[None, :])
-                pos = jnp.clip(pos, 0, max_pos)
-                h, new_kv, moe = model.forward_paged(
-                    Tensor(tokens), Tensor(pos), cts, page_tables,
-                    seq_lens, q_lens, moe_counters=moe)
-                w = model.lm_head_weight()
-                if verify:
-                    # multi-query verify: greedy next-token at every
-                    # draft position in one dispatch; padding positions
-                    # (t >= q_len) produce garbage the host ignores.
-                    # Rows that sample ride along via an extra column
-                    # so the step still costs ONE host fetch.
-                    logits_all = _full_logits(jnp.einsum(
-                        'bth,vh->btv', h.data, w.data,
-                        preferred_element_type=jnp.float32))
-                    nxt = jnp.argmax(logits_all, axis=-1) \
-                        .astype(jnp.int32)                  # [B, T]
-                    if sample:
-                        idx = jnp.clip(q_lens - 1, 0,
-                                       T - 1).astype(jnp.int32)
-                        last = jnp.take_along_axis(
-                            logits_all, idx[:, None, None],
-                            axis=1)[:, 0, :]
-                        samp = _device_sample(
-                            last.astype(jnp.float32), key, ords,
-                            seq_lens, temps, top_ks)
-                        nxt = jnp.concatenate([nxt, samp[:, None]], 1)
-                    return nxt, [tuple(t.data for t in c)
-                                 for c in new_kv], moe
+            # mp_layers key their collectives off the spmd region —
+            # without it a >1-degree model would silently run the
+            # degenerate single-rank math on sharded weights
+            region = contextlib.nullcontext()
+            if mp > 1:
+                from ..distributed import collective as C
+                region = C.spmd_region(('mp',))
+            with bind_arrays(model, arrs), region:
+                yield
+
+        def full_logits(lg):
+            """Vocab-parallel logits -> full vocab: the tied LM head is
+            the VocabParallelEmbedding weight, so under mp each shard
+            computes [., V/mp] logits for its vocab rows; argmax /
+            sampling need the whole vocab, so gather over 'mp' (shard
+            i's rows are vocab block i — concat order is the identity)."""
+            if mp <= 1:
+                return lg
+            g = jax.lax.all_gather(lg, 'mp')        # [mp, ..., V/mp]
+            g = jnp.moveaxis(g, 0, -2)              # [..., mp, V/mp]
+            return g.reshape(lg.shape[:-1] + (lg.shape[-1] * mp,))
+
+        def forward_pick(kv, moe, tokens, page_tables, seq_lens, q_lens,
+                         key, ords, temps, top_ks):
+            """[B, T] query tokens through the model over the paged
+            pool, then each row's next id: the hidden state of its last
+            query -> logits -> sampled or greedy. -> (ids, kv, moe)."""
+            T = tokens.shape[1]
+            # int8 pools carry (k, v, k_scales, v_scales) per layer;
+            # dense pools (k, v) — forward_paged keys off the arity
+            cts = [tuple(Tensor(a) for a in c) for c in kv]
+            pos = (seq_lens[:, None] - q_lens[:, None]
+                   + jnp.arange(T, dtype=jnp.int32)[None, :])
+            pos = jnp.clip(pos, 0, max_pos)
+            h, new_kv, moe = model.forward_paged(
+                Tensor(tokens), Tensor(pos), cts, page_tables,
+                seq_lens, q_lens, moe_counters=moe)
+            new_kv = [tuple(t.data for t in c) for c in new_kv]
+            w = model.lm_head_weight()
+
+            def last(x):
+                # [B, T, .] -> [B, .] at each row's last query
                 idx = jnp.clip(q_lens - 1, 0, T - 1).astype(jnp.int32)
-                h_last = jnp.take_along_axis(
-                    h.data, idx[:, None, None], axis=1)[:, 0, :]
-                logits = _full_logits(jnp.einsum(
-                    'bh,vh->bv', h_last, w.data,
+                return jnp.take_along_axis(
+                    x, idx[:, None, None], axis=1)[:, 0, :]
+
+            if verify:
+                # multi-query verify: greedy next-token at every
+                # draft position in one dispatch; padding positions
+                # (t >= q_len) produce garbage the host ignores.
+                # Rows that sample ride along via an extra column
+                # so the step still costs ONE host fetch.
+                logits_all = full_logits(jnp.einsum(
+                    'bth,vh->btv', h.data, w.data,
                     preferred_element_type=jnp.float32))
+                nxt = jnp.argmax(logits_all, axis=-1) \
+                    .astype(jnp.int32)                  # [B, T]
                 if sample:
-                    nxt = _device_sample(logits.astype(jnp.float32),
-                                         key, ords, seq_lens, temps,
-                                         top_ks)
-                else:
-                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                if moe is not None:
-                    # the experts' counters ride behind the sampled
-                    # ids: still ONE host fetch a step (_take_moe)
-                    nxt = jnp.concatenate([nxt, moe.reshape(-1)])
-            return nxt, [tuple(t.data for t in c) for c in new_kv], moe
+                    samp = _device_sample(
+                        last(logits_all).astype(jnp.float32), key, ords,
+                        seq_lens, temps, top_ks)
+                    nxt = jnp.concatenate([nxt, samp[:, None]], 1)
+                return nxt, new_kv, moe
+            logits = full_logits(jnp.einsum(
+                'bh,vh->bv', last(h.data), w.data,
+                preferred_element_type=jnp.float32))
+            if sample:
+                nxt = _device_sample(logits.astype(jnp.float32), key,
+                                     ords, seq_lens, temps, top_ks)
+            else:
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return nxt, new_kv, moe
+
+        if not fused:
+            def step(params, kv, moe, tokens, page_tables, seq_lens,
+                     q_lens, key, ords, temps, top_ks):
+                with bound(params):
+                    nxt, kv, moe = forward_pick(
+                        kv, moe, tokens, page_tables, seq_lens, q_lens,
+                        key, ords, temps, top_ks)
+                    if moe is not None:
+                        # the experts' counters ride behind the sampled
+                        # ids: still ONE host fetch a step (_take_moe)
+                        nxt = jnp.concatenate([nxt, moe.reshape(-1)])
+                return nxt, kv, moe
+        else:
+            def step(params, kv, moe, tokens, page_tables, seq_lens,
+                     ords, rems, eos_ids, live, key, temps, top_ks):
+                # The carry is (kv pool, last token, seq_len, done-mask,
+                # emitted count) per row; each scan body is the [B, 1]
+                # decode step by call — same positions, same sampling
+                # key folded per (ordinal, absolute position) — so the K
+                # stacked outputs are token-identical to K serial
+                # dispatches. Rows that hit eos or their budget mid-
+                # window flip `done` and ride the remaining iterations
+                # with q_len=0 (the idle-slot mechanism: KV writes
+                # dropped by the scatter, outputs ignored by the host).
+                # The window carries no experts' counters.
+                with bound(params):
+                    def body(carry, _):
+                        kv_c, tok, seq, done, emitted = carry
+                        alive = ~done
+                        q = jnp.where(alive, 1, 0).astype(jnp.int32)
+                        nxt, new_kv, _ = forward_pick(
+                            kv_c, None, tok[:, None], page_tables, seq,
+                            q, key, ords, temps, top_ks)
+                        # serial-order accounting: the emitted token
+                        # counts BEFORE the eos/budget check (append-
+                        # then-check), so eos-in-window truncates
+                        # precisely where the one-token path stops
+                        emitted2 = emitted + q
+                        hit_eos = (eos_ids >= 0) & (nxt == eos_ids)
+                        done2 = done | hit_eos | (emitted2 >= rems)
+                        tok2 = jnp.where(alive, nxt, tok)
+                        return (new_kv, tok2, seq + q, done2,
+                                emitted2), nxt
+
+                    carry0 = (kv, tokens, seq_lens, ~live,
+                              jnp.zeros((B,), jnp.int32))
+                    (kv, _t, _s, _d, _e), ys = jax.lax.scan(
+                        body, carry0, xs=None, length=K)
+                return jnp.moveaxis(ys, 0, 1), kv, moe      # [B, K]
 
         # donation updates the pool pages in place; CPU jax has no
         # donation support and would warn every call
@@ -1390,11 +1453,12 @@ class ServingEngine:
             from jax.sharding import PartitionSpec as P
             kv_specs = [tuple(P(None, None, 'mp') for _ in layer)
                         for layer in self.pool.kv]
-            in_specs = (dict(self._param_specs), kv_specs, None,
-                        P(), P(), P(), P(), P(), P(), P(), P())
-            out_specs = (P(), kv_specs, None)
-            step = shard_map(step, mesh=self.mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
+            host_operands = step.__code__.co_argcount - 3
+            step = shard_map(
+                step, mesh=self.mesh,
+                in_specs=(dict(self._param_specs), kv_specs, None)
+                + (P(),) * host_operands,
+                out_specs=(P(), kv_specs, None), check_vma=False)
         jitted = jax.jit(step, donate_argnums=donate)
 
         def run(*args):
@@ -1408,124 +1472,105 @@ class ServingEngine:
                     model.train()
         return run
 
-    def _fused_fn(self, B, K, sample):
-        key = ('fused', B, K, sample)
-        fn = self._step_fns.get(key)
-        if fn is None:
-            fn = self._build_fused_step(B, K, sample)
-            self._step_fns[key] = fn
-        return fn
-
-    def _build_fused_step(self, B, K, sample):
-        """Fourth compiled shape (ISSUE 19): K decode iterations under
-        ONE jit via lax.scan. The carry is (kv pool, last token,
-        seq_len, done-mask, emitted count) per row; each scan body is
-        exactly the [B, 1] decode step — same forward_paged, same
-        positions, same on-device sampling with the key folded per
-        (ordinal, absolute position) — so the K stacked outputs are
-        token-identical to K serial dispatches. Rows that hit eos or
-        their budget mid-window flip `done` and ride the remaining
-        iterations with q_len=0 (the idle-slot mechanism: KV writes
-        dropped by the scatter, outputs ignored by the host)."""
-        jax, jnp = self._jax, self._jnp
-        import contextlib
-        model = self.model
-        from ..core.tensor import Tensor
-        from ..core.autograd import no_grad
-        from ..jit import bind_arrays
-        max_pos = model.config.max_seq_len - 1
-        qdtypes = dict(self._qparam_dtypes)
-        mp = self._mp
-
-        def _spmd():
-            if mp > 1:
-                from ..distributed import collective as C
-                return C.spmd_region(('mp',))
-            return contextlib.nullcontext()
-
-        def _full_logits(lg):
-            if mp <= 1:
-                return lg
-            g = jax.lax.all_gather(lg, 'mp')
-            g = jnp.moveaxis(g, 0, -2)
-            return g.reshape(lg.shape[:-1] + (lg.shape[-1] * mp,))
-
-        def step(params, kv, tokens, page_tables, seq_lens, ords,
-                 rems, eos_ids, live, key, temps, top_ks):
-            arrs = {}
-            for n, v in params.items():
-                if isinstance(v, dict):
-                    s = v['s'] * (1.0 / 127.0)
-                    shape = [1] * (v['q'].ndim - 1) + [-1]
-                    arrs[n] = (v['q'].astype(jnp.float32)
-                               * s.reshape(shape)).astype(qdtypes[n])
-                else:
-                    arrs[n] = v
-            with bind_arrays(model, arrs), _spmd():
-                w = model.lm_head_weight()
-
-                def body(carry, _):
-                    kv_c, tok, seq, done, emitted = carry
-                    alive = ~done
-                    q = jnp.where(alive, 1, 0).astype(jnp.int32)
-                    cts = [tuple(Tensor(a) for a in c) for c in kv_c]
-                    pos = jnp.clip(seq - q, 0, max_pos)[:, None]
-                    h, new_kv, _ = model.forward_paged(
-                        Tensor(tok[:, None]), Tensor(pos), cts,
-                        page_tables, seq, q)
-                    h_last = h.data[:, 0, :]
-                    logits = _full_logits(jnp.einsum(
-                        'bh,vh->bv', h_last, w.data,
-                        preferred_element_type=jnp.float32))
-                    if sample:
-                        nxt = _device_sample(
-                            logits.astype(jnp.float32), key, ords,
-                            seq, temps, top_ks)
-                    else:
-                        nxt = jnp.argmax(logits, axis=-1) \
-                            .astype(jnp.int32)
-                    # serial-order accounting: the emitted token counts
-                    # BEFORE the eos/budget check (append-then-check),
-                    # so eos-in-window truncates precisely where the
-                    # one-token path stops
-                    emitted2 = emitted + q
-                    hit_eos = (eos_ids >= 0) & (nxt == eos_ids)
-                    done2 = done | hit_eos | (emitted2 >= rems)
-                    tok2 = jnp.where(alive, nxt, tok)
-                    seq2 = seq + q
-                    new_kv = [tuple(t.data for t in c) for c in new_kv]
-                    return (new_kv, tok2, seq2, done2, emitted2), nxt
-
-                carry0 = (kv, tokens, seq_lens, ~live,
-                          jnp.zeros((B,), jnp.int32))
-                (kv, _t, _s, _d, _e), ys = jax.lax.scan(
-                    body, carry0, xs=None, length=K)
-            return jnp.moveaxis(ys, 0, 1), kv           # [B, K]
-
-        donate = (1,) if jax.default_backend() != 'cpu' else ()
-        if mp > 1:
-            from jax import shard_map
-            from jax.sharding import PartitionSpec as P
-            kv_specs = [tuple(P(None, None, 'mp') for _ in layer)
-                        for layer in self.pool.kv]
-            in_specs = (dict(self._param_specs), kv_specs,
-                        P(), P(), P(), P(), P(), P(), P(), P(), P(),
-                        P())
-            out_specs = (P(), kv_specs)
-            step = shard_map(step, mesh=self.mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-        jitted = jax.jit(step, donate_argnums=donate)
-
-        def run(*args):
-            was = model.training
-            model.eval()
-            try:
-                with no_grad():
-                    return jitted(*args)
-            finally:
-                if was:
-                    model.train()
-        return run
+    def _dispatch(self, shape, rows, B, T, fetch=True):
+        """The one place a compiled step is called. `rows` are the rows
+        that carry a query, each (slot, request, query tokens, context
+        length after them); `shape` says which program they ride:
+        'prefill' ([1, T], one prompt chunk in slot 0), 'decode'
+        ([B, 1]), 'verify' ([B, T], a token and its drafts) or 'fused'
+        (T iterations of [B, 1] in one window). Builds the host
+        operands (idle slots ride along with q_len 0), calls the
+        program, takes the new pool, fetches — the step's one host sync
+        — unless `fetch` is False (a prompt's inner chunk samples
+        nothing anyone reads), splits the experts' counters off, and
+        feeds the iteration's clocks and counts (`_it_*`). Returns the
+        fetched ids ([B]; verify [B, T], one column more with sampled
+        rows; fused [B, T]), or None without a fetch."""
+        jnp = self._jnp
+        prefill, fused = shape == 'prefill', shape == 'fused'
+        # the numpy batch assembly is a span of its own for the batched
+        # shapes; a prefill chunk's is too small to be one
+        with (contextlib.nullcontext() if prefill else
+              RecordEvent('serve::prepare', event_type='serve')):
+            tokens = np.zeros((B, 1 if fused else T), np.int32)
+            page_tables = np.zeros((B, self.max_pages_per_seq), np.int32)
+            seq_lens = np.ones((B,), np.int32)
+            q_lens = np.zeros((B,), np.int32)
+            ords = np.zeros((B,), np.int32)
+            temps = np.zeros((B,), np.float32)
+            top_ks = np.zeros((B,), np.int32)
+            if fused:
+                rems = np.zeros((B,), np.int32)
+                eos_ids = np.full((B,), -1, np.int32)
+            for i, req, query, context in rows:
+                tokens[i, :len(query)] = query
+                page_tables[i, :] = self._page_row(req)
+                seq_lens[i] = context
+                q_lens[i] = len(query)
+                ords[i] = _ord_of(req)
+                temps[i] = req.temperature
+                top_ks[i] = req.top_k
+                iterations = 1
+                if fused:
+                    rems[i] = iterations = min(
+                        T, req.max_new_tokens - len(req.generated))
+                    if req.eos_token_id is not None:
+                        eos_ids[i] = req.eos_token_id
+                # the rooflines' counts: iteration j of a row reads
+                # context + j KV tokens, in the live pages that hold
+                # them, out of the slots the program's tables have
+                for j in range(iterations):
+                    self._it_live_pages += self.pool.pages_for(context + j)
+                    if not prefill:
+                        self._count_kv_read(context + j)
+                if prefill:
+                    self._it_prefill_tokens += len(query)
+                    self._it_prefill_ctx += len(query) * context
+            self._it_page_slots += (T if fused else 1) * page_tables.size
+            sample = any(req.top_k > 0 for _, req, _, _ in rows)
+            if fused:
+                key = ('fused', B, T, sample)
+                head = (tokens[:, 0], page_tables, seq_lens, ords, rems,
+                        eos_ids, q_lens > 0)
+                tail = (temps, top_ks)
+            else:
+                key = (B, T, sample, shape == 'verify')
+                head = (tokens, page_tables, seq_lens, q_lens)
+                tail = (ords, temps, top_ks)
+        fn = self._step_fn(key)
+        span_args = {'shape': shape}
+        if not prefill:
+            span_args['batch'] = len(rows)
+        if fused:
+            span_args['k'] = T
+        t0 = time.perf_counter()
+        with RecordEvent('serve::compiled_step', event_type='serve',
+                         **span_args):
+            ids, self.pool.kv, self._moe_dev = fn(
+                self._params, self.pool.kv, self._moe_dev,
+                *map(jnp.asarray, head), self._key,
+                *map(jnp.asarray, tail))
+        t1 = time.perf_counter()
+        self._it_compute += t1 - t0
+        if prefill:
+            self._it_prefill_s += t1 - t0
+        else:
+            self._it_decode_s += t1 - t0
+        if not fetch:
+            return None
+        with RecordEvent('serve::sample_fetch', event_type='serve'):
+            ids = _host_fetch(ids)      # the sampled-token fetch
+        if self._moe_dev is not None:
+            ids, per_expert = self._take_moe(ids, B, decode=not prefill)
+            if prefill and self.moe_rows_listener is not None:
+                _, req, query, context = rows[0]
+                self.moe_rows_listener(req, context - len(query),
+                                       len(query), per_expert.copy())
+        t2 = time.perf_counter()
+        self._it_fetch += t2 - t1
+        if not prefill:
+            self._decode_time += t2 - t0
+        return ids
 
     def _fused_decode_window(self, K):
         """Up to K decode iterations in ONE dispatch + ONE host fetch.
@@ -1535,75 +1580,23 @@ class ServingEngine:
         tail handed back with the spec-style trim after the fetch.
         Returns (rows, tokens emitted), or None when a reservation
         fails and the caller should fall back to the [B, 1] step."""
-        jnp = self._jnp
-        sched = self.scheduler
-        B = self.config.max_batch_size
         rows = []
-        for i, req in enumerate(sched.slots):
+        for i, req in enumerate(self.scheduler.slots):
             if req is None or req.state != RequestState.RUNNING:
                 continue
             w = min(K, req.max_new_tokens - len(req.generated))
             if not self.pool.try_reserve(req.id, req.context_len + w):
                 # roll the earlier rows' fresh reservations back so the
                 # serial fallback sees the pool it would have seen
-                for _i, r, _w in rows:
+                for _i, r, _q, _c in rows:
                     self.pool.trim(r.id, r.context_len)
                 return None
-            rows.append((i, req, w))
+            rows.append((i, req, [_last_token(req)], req.context_len))
         if not rows:
             return 0, 0
-        with RecordEvent('serve::prepare', event_type='serve'):
-            tokens = np.zeros((B,), np.int32)
-            page_tables = np.zeros((B, self.max_pages_per_seq), np.int32)
-            seq_lens = np.ones((B,), np.int32)
-            ords = np.zeros((B,), np.int32)
-            rems = np.zeros((B,), np.int32)
-            eos_ids = np.full((B,), -1, np.int32)
-            live = np.zeros((B,), bool)
-            temps = np.zeros((B,), np.float32)
-            top_ks = np.zeros((B,), np.int32)
-            for i, req, w in rows:
-                tokens[i] = (req.generated[-1] if req.generated
-                             else req.prompt[-1])
-                page_tables[i, :] = self._page_row(req)
-                seq_lens[i] = req.context_len
-                ords[i] = _ord_of(req)
-                rems[i] = w
-                if req.eos_token_id is not None:
-                    eos_ids[i] = req.eos_token_id
-                live[i] = True
-                temps[i] = req.temperature
-                top_ks[i] = req.top_k
-                # decode roofline: iteration j of this row reads
-                # context_len + j KV tokens
-                for j in range(w):
-                    self._count_kv_read(req.context_len + j)
-                self._it_live_pages += sum(
-                    self.pool.pages_for(req.context_len + j)
-                    for j in range(w))
-            self._it_page_slots += K * page_tables.size
-        sample = any(r.top_k > 0 for _, r, _ in rows)
-        fn = self._fused_fn(B, K, sample)
-        t0 = time.perf_counter()
-        with RecordEvent('serve::compiled_step', event_type='serve',
-                         shape='fused', batch=len(rows), k=K):
-            nxt, new_kv = fn(
-                self._params, self.pool.kv,
-                jnp.asarray(tokens), jnp.asarray(page_tables),
-                jnp.asarray(seq_lens), jnp.asarray(ords),
-                jnp.asarray(rems), jnp.asarray(eos_ids),
-                jnp.asarray(live), self._key,
-                jnp.asarray(temps), jnp.asarray(top_ks))
-        self.pool.kv = new_kv
-        t1 = time.perf_counter()
-        with RecordEvent('serve::sample_fetch', event_type='serve'):
-            nxt = _host_fetch(nxt)      # ONE fetch for the whole window
-        t2 = time.perf_counter()
-        self._it_compute += t1 - t0
-        self._it_decode_s += t1 - t0
-        self._it_fetch += t2 - t1
-        self._decode_time += t2 - t0
+        nxt = self._dispatch('fused', rows, self.config.max_batch_size, K)
         return len(rows), self._accepted(self._accept_fused, nxt, rows, K)
+
 
     def _count_kv_read(self, context):
         """One decode row's KV reads this iteration, in tokens a layer:
@@ -1647,14 +1640,15 @@ class ServingEngine:
                 self._it_moe_load = float((rows.max(axis=1) / mean).mean())
         return packed[:n], moe[:, :-3]
 
-    def _accepted(self, accept, *args):
+    def _accepted(self, accept, *args, **span_args):
         """Run one of the host accept loops under its `serve::accept`
-        span (from the fetch's return to the end of the decode body);
-        returns the tokens it emitted."""
-        with RecordEvent('serve::accept', event_type='serve') as ev:
+        span (to the end of the decode or prefill body); returns the
+        tokens it emitted."""
+        with RecordEvent('serve::accept', event_type='serve',
+                         **span_args) as ev:
             done_before = self._completed
             emitted = accept(*args)
-            ev.args = {'emitted': emitted,
+            ev.args = {**span_args, 'emitted': emitted,
                        'retired': self._completed - done_before}
         return emitted
 
@@ -1668,7 +1662,7 @@ class ServingEngine:
         emitted_total = 0
         per_iter_rows = [0] * K
         accepted = {}
-        for i, req, w in rows:
+        for i, req, _query, _context in rows:
             a = 0
             for j in range(K):
                 if req.done:
@@ -1689,7 +1683,7 @@ class ServingEngine:
         self._fused_iterations += iters_run
         self._fused_tokens += emitted_total
         self.ledger.account_fused_window(K, iters_run, emitted_total)
-        for i, req, w in rows:
+        for i, req, _query, _context in rows:
             a = accepted[i]
             # every emitted token reached its request: delivered work,
             # nothing rejected (no draft columns in a fused window) —
@@ -1719,7 +1713,6 @@ class ServingEngine:
         return row + [0] * (self.max_pages_per_seq - len(row))
 
     def _prefill_chunk_step(self, req):
-        jnp = self._jnp
         C = self._effective_prefill_chunk()
         if req.state != RequestState.PREFILL:
             return 0        # preempted by an earlier request in this
@@ -1753,29 +1746,13 @@ class ServingEngine:
         if not self._ensure_or_preempt(req, start + n):
             return 0        # yielded to higher-priority pool pressure:
                             # re-queued, resumes when pressure clears
-        chunk = toks[start:start + n] + [0] * (C - n)
-        fn = self._step_fn(1, C, req.top_k > 0)
-        tc0 = time.perf_counter()
-        with RecordEvent('serve::compiled_step', event_type='serve',
-                         shape='prefill'):
-            nxt, new_kv, self._moe_dev = fn(
-                self._params, self.pool.kv, self._moe_dev,
-                jnp.asarray([chunk], jnp.int32),
-                jnp.asarray([self._page_row(req)], jnp.int32),
-                jnp.asarray([start + n], jnp.int32),
-                jnp.asarray([n], jnp.int32),
-                self._key,
-                jnp.asarray([_ord_of(req)], jnp.int32),
-                jnp.asarray([req.temperature], jnp.float32),
-                jnp.asarray([req.top_k], jnp.int32))
-        tc1 = time.perf_counter()
-        self._it_compute += tc1 - tc0
-        self._it_prefill_s += tc1 - tc0
-        self._it_prefill_tokens += n
-        self._it_prefill_ctx += n * (start + n)
-        self._it_live_pages += self.pool.pages_for(start + n)
-        self._it_page_slots += self.max_pages_per_seq
-        self.pool.kv = new_kv
+        # only the chunk that completes the prompt samples a token
+        # anyone reads; a scoring request (no budget) not even that
+        last = start + n == len(toks)
+        due = last and req.max_new_tokens > 0
+        ids = self._dispatch(
+            'prefill', [(0, req, toks[start:start + n], start + n)], 1, C,
+            fetch=due)
         req.prefilled = start + n
         self._prefill_tokens += n
         self._prefill_chunks += 1
@@ -1796,31 +1773,20 @@ class ServingEngine:
         self.pool.register_prefix(req.id, toks, req.prefilled,
                                   owner=req.tenant_id)
         extra = {'recompute_tokens': recompute} if recompute else {}
-        if req.prefilled == len(toks) and req.max_new_tokens > 0:
-            # this chunk completes (re-)prefill and samples a token off
-            # its final column below — marked so reconstruct() can tell
+        if due:
+            # this chunk completes (re-)prefill and sampled a token off
+            # its final column — marked so reconstruct() can tell
             # prefill-sampled tokens (initial AND every resume) from
             # decode-step tokens when pricing delivered work (v4)
             extra['sampled'] = 1
         self._trace(req, 'prefill_chunk', tokens=n, prefilled=start + n,
                     pages=len(self.pool.page_table(req.id)), **extra)
-        if req.prefilled == len(toks):
-            if req.max_new_tokens <= 0:
-                self._retire(req)   # prefill-only request (scoring):
-                return n            # the budget says emit nothing
-            tf0 = time.perf_counter()
-            with RecordEvent('serve::sample_fetch', event_type='serve'):
-                got = _host_fetch(nxt)          # the sampled-token fetch
-            if self._moe_dev is not None:
-                got, rows = self._take_moe(got, 1, decode=False)
-                if self.moe_rows_listener is not None:
-                    self.moe_rows_listener(req, start, n, rows.copy())
-            tok = int(got[0])
-            self._it_fetch += time.perf_counter() - tf0
-            with RecordEvent('serve::accept', event_type='serve',
-                             req=req.id) as ev:
-                self._accept_first(req, tok)
-                ev.args.update(emitted=1, retired=int(req.done))
+        if due:
+            self._accepted(self._accept_first, req, int(ids[0]),
+                           req=req.id)
+        elif last:
+            self._retire(req)   # prefill-only request (scoring): the
+                                # budget says emit nothing
         return n
 
     def _accept_first(self, req, tok):
@@ -1844,6 +1810,7 @@ class ServingEngine:
             self._retire(req)
         else:
             req.state = RequestState.RUNNING
+        return 1
 
     def _decode_step(self):
         """One batched decode dispatch. With spec_k=0 every running
@@ -1857,7 +1824,6 @@ class ServingEngine:
         the ragged kernel's seq_len mask never exposes a stale slot
         before the step that rewrites it). Returns (rows, tokens
         emitted)."""
-        jnp = self._jnp
         sched = self.scheduler
         K = self._effective_spec_k()
         if self.config.spec_k > 0 and K == 0:
@@ -1909,82 +1875,34 @@ class ServingEngine:
                         + len(proposals.get(req.id, ()))):
                     proposals.pop(req.id, None)
         B = self.config.max_batch_size
-        verify = any(
-            req is not None and req.state == RequestState.RUNNING
-            and req.id in proposals for req in sched.slots)
-        T = K + 1 if verify else 1
-        with RecordEvent('serve::prepare', event_type='serve'):
-            tokens = np.zeros((B, T), np.int32)
-            page_tables = np.zeros((B, self.max_pages_per_seq), np.int32)
-            seq_lens = np.ones((B,), np.int32)
-            q_lens = np.zeros((B,), np.int32)
-            ords = np.zeros((B,), np.int32)
-            temps = np.zeros((B,), np.float32)
-            top_ks = np.zeros((B,), np.int32)
-            active = []
-            for i, req in enumerate(sched.slots):
-                if req is None or req.state != RequestState.RUNNING:
-                    continue
-                drafts = proposals.get(req.id, ()) if verify else ()
-                active.append((i, req, list(drafts)))
-                # decode roofline: KV tokens this row's attention reads
-                self._count_kv_read(req.context_len + len(drafts))
-                tokens[i, 0] = (req.generated[-1] if req.generated
-                                else req.prompt[-1])
-                if drafts:
-                    tokens[i, 1:1 + len(drafts)] = drafts
-                row = self._page_row(req)
-                page_tables[i, :] = row
-                seq_lens[i] = req.context_len + len(drafts)
-                q_lens[i] = 1 + len(drafts)
-                ords[i] = _ord_of(req)
-                temps[i] = req.temperature
-                top_ks[i] = req.top_k
-        if not active:
+        rows = []
+        for i, req in enumerate(sched.slots):
+            if req is not None and req.state == RequestState.RUNNING:
+                drafts = proposals.get(req.id, [])
+                rows.append((i, req, [_last_token(req)] + drafts,
+                             req.context_len + len(drafts)))
+        if not rows:
             return 0, 0
-        # paged-attention work share: live pages of the rows that carry
-        # a query against the slots this program's tables hold
-        self._it_live_pages += int(
-            (-(-seq_lens[q_lens > 0] // self.config.page_size)).sum())
-        self._it_page_slots += page_tables.size
-        sample = any(r.top_k > 0 for _, r, _ in active)
-        fn = self._step_fn(B, T, sample, verify=verify)
-        t0 = time.perf_counter()
-        with RecordEvent('serve::compiled_step', event_type='serve',
-                         shape='verify' if verify else 'decode',
-                         batch=len(active)):
-            nxt, new_kv, self._moe_dev = fn(
-                self._params, self.pool.kv, self._moe_dev,
-                jnp.asarray(tokens), jnp.asarray(page_tables),
-                jnp.asarray(seq_lens), jnp.asarray(q_lens), self._key,
-                jnp.asarray(ords),
-                jnp.asarray(temps), jnp.asarray(top_ks))
-        self.pool.kv = new_kv
-        t1 = time.perf_counter()
-        with RecordEvent('serve::sample_fetch', event_type='serve'):
-            nxt = _host_fetch(nxt)              # the sampled-token fetch
-        if self._moe_dev is not None:
-            nxt, _ = self._take_moe(nxt, B, decode=True)
-        t2 = time.perf_counter()
-        dt = t2 - t0
-        self._it_compute += t1 - t0
-        self._it_decode_s += t1 - t0
-        self._it_fetch += t2 - t1
-        self._decode_time += dt
+        # without a surviving proposal the verify columns would all be
+        # padding: the [B, 1] step serves
+        verify = any(len(query) > 1 for _, _, query, _ in rows)
+        T = K + 1 if verify else 1
+        nxt = self._dispatch('verify' if verify else 'decode', rows, B, T)
         self._decode_steps += 1
-        self._occupancy_sum += len(active) / B
+        self._occupancy_sum += len(rows) / B
         self._util_sum += self.pool.utilization()
-        emitted_total = self._accepted(self._accept_decode, nxt, active,
+        emitted_total = self._accepted(self._accept_decode, nxt, rows,
                                        verify, T)
         self._decode_tokens += emitted_total
-        return len(active), emitted_total
+        return len(rows), emitted_total
 
-    def _accept_decode(self, nxt, active, verify, T):
+    def _accept_decode(self, nxt, rows, verify, T):
         """Host accept of one [B, T] decode/verify fetch: token append,
         done/EOS checks, draft rollback, prefix registration, retire.
         Returns the tokens emitted."""
         emitted_total = 0
-        for i, req, drafts in active:
+        for i, req, query, _context in rows:
+            drafts = query[1:]
             spec_m = None
             if verify:
                 if req.top_k > 0:
@@ -2258,7 +2176,7 @@ class ServingEngine:
 
     def reset_stats(self):
         """Zero the rate/occupancy accounting AND the trace/timeline
-        observatory (NOT the pool or queue) — bench legs call this
+        observatory (NOT the pool or queue) — who measures calls this
         after compile warmup so steady-state numbers aren't polluted by
         the first-dispatch compiles."""
         self._decode_time = 0.0
@@ -2371,6 +2289,12 @@ def _ngram_propose(tokens, ngram, k):
                    for t in range(1, n)):
                 return [int(t) for t in tokens[j + n:j + n + k]]
     return []
+
+
+def _last_token(req):
+    """The token a decode row feeds: the newest of its context, whose
+    K/V the step writes."""
+    return req.generated[-1] if req.generated else req.prompt[-1]
 
 
 def _ord_of(req):

@@ -1,7 +1,7 @@
 """Step-time ledger & MFU observatory (ISSUE 16): decomposition
 reconciliation, analytic FLOPs/recompute factors, peak resolution,
-gauge round-trip through the three-engine wiring, the 2-rank straggler
-subprocess leg, and the bench_compare regression verdicts."""
+gauge round-trip through the three-engine wiring and the 2-rank
+straggler subprocess leg."""
 import json
 import os
 import socket
@@ -258,73 +258,3 @@ class TestStraggler:
         assert rep['relative_wall']['1'] > rep['threshold']
         text = L.render_straggler_report(rep)
         assert 'STRAGGLER' in text and 'rank 1' in text
-
-
-# ---------------------------------------------------------------------------
-# bench_compare
-# ---------------------------------------------------------------------------
-class TestBenchCompare:
-    def _bc(self):
-        sys.path.insert(0, os.path.join(os.path.dirname(HERE), 'tools'))
-        import bench_compare
-        return bench_compare
-
-    def test_normalize_legacy_record(self):
-        bc = self._bc()
-        rec = {'metric': 'gpt1.3b_trainstep_mfu', 'value': 0.64,
-               'unit': 'fraction', 'vs_baseline': 1.4,
-               'detail': {'ms_per_step': 1256.9,
-                          'tokens_per_sec': 13035.1,
-                          'host': {'dispatch_window': 4},
-                          'bert_base_zero2_bf16': {'mfu': 0.46}}}
-        n = bc.normalize(rec)
-        assert n['schema_version'] == 1
-        head = n['legs'][bc.HEADLINE_LEG]
-        assert head['ms_per_step'] == 1256.9 and head['mfu'] == 0.64
-        assert 'host' not in n['legs']           # record, not a leg
-        assert 'bert_base_zero2_bf16' in n['legs']
-
-    def test_normalize_v2_record_finds_ledger(self):
-        bc = self._bc()
-        led = {'wall_seconds': 0.1,
-               'components': {'compute': 0.09, 'exposed_comm': 0.0,
-                              'bubble': 0.0, 'host_gap': 0.005,
-                              'residue': 0.005}}
-        rec = {'schema_version': 2, 'round': 'r06', 'metric': 'm',
-               'value': 0.5,
-               'legs': {bc.HEADLINE_LEG: {'mfu': 0.5, 'ledger': led}},
-               'detail': {}}
-        n = bc.normalize(rec)
-        assert n['round'] == 'r06' and n['ledger'] is led
-
-    def test_verdict_directions(self):
-        bc = self._bc()
-        assert bc._verdict('higher', +0.05, 0.02) == 'improvement'
-        assert bc._verdict('higher', -0.05, 0.02) == 'regression'
-        assert bc._verdict('lower', -0.05, 0.02) == 'improvement'
-        assert bc._verdict('lower', +0.05, 0.02) == 'regression'
-        assert bc._verdict('higher', 0.01, 0.02) == 'flat'
-
-    def test_legacy_driver_artifacts(self, tmp_path):
-        """Two legacy-shape driver artifacts (record under `parsed`,
-        legs nested in detail) load, normalize and compare."""
-        bc = self._bc()
-        recs = []
-        for name, doc in (('old.json', bc.legacy_fixture(0.60, 1300.0)),
-                          ('new.json', bc.legacy_fixture(0.57, 1368.4))):
-            path = tmp_path / name
-            path.write_text(json.dumps(doc))
-            recs.append(bc.normalize(bc.load_record(str(path))))
-        a, b = recs
-        assert a['schema_version'] == 1
-        assert 'lenet_mnist' in a['legs']        # error legs lift too
-        doc = bc.compare(a, b)
-        head = {m['name']: m for leg in doc['legs']
-                for m in leg['metrics'] if leg['leg'] == bc.HEADLINE_LEG}
-        assert head['mfu']['verdict'] == 'regression'
-        assert doc['regressions'] >= 1
-        assert 'regression' in bc.render(doc)
-
-    def test_selftest_entrypoint(self):
-        bc = self._bc()
-        assert bc.selftest() == 0

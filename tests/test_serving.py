@@ -1175,3 +1175,202 @@ class TestServingSurface:
         with pytest.raises(ValueError, match='rows \\[1\\] are empty'):
             pred.run([np.asarray([[5, 0, 0], [0, 0, 0]], np.int32)])
         assert pred.run([[]])[0].shape == (0, 0)
+
+
+# ---------------------------------------------------------------------------
+# the seam: one step builder, one dispatch path (ISSUE 30). What the
+# three dispatch sites did apart — which programs compile, which spans
+# open in which order with which args, when the host fetches, what the
+# ledger is fed — is pinned here as the parent commit did it.
+# ---------------------------------------------------------------------------
+def _drain(eng):
+    while eng.scheduler.has_work:
+        eng.step()
+
+
+# knobs -> the `_step_fns` keys the mixed run below compiles
+SEAM_CASES = {
+    'plain': ({}, {(1, 8, False, False), (1, 8, True, False),
+                   (3, 1, False, False), (3, 1, True, False)}),
+    'spec_k=2': ({'spec_k': 2},
+                 {(1, 8, False, False), (1, 8, True, False),
+                  (3, 1, False, False), (3, 3, False, True),
+                  (3, 3, True, True)}),
+    'fused_k=4': ({'fused_k': 4},
+                  {(1, 8, False, False), (1, 8, True, False),
+                   (3, 1, False, False), ('fused', 3, 4, False),
+                   ('fused', 3, 4, True)}),
+    'int8_weights': ({'weight_dtype': 'int8'},
+                     {(1, 8, False, False), (1, 8, True, False),
+                      (3, 1, False, False), (3, 1, True, False)}),
+}
+DEVICE_SPANS = ('serve::prepare', 'serve::compiled_step',
+                'serve::sample_fetch', 'serve::accept')
+
+
+class TestOneDispatchPath:
+    def _mixed_run(self, model, knobs, eos, monkeypatch):
+        """Four greedy prompts (three longer than a chunk) through a
+        pool too small for them — a preemption — then, on the quiet
+        engine, a sampled request beside a greedy one: with `eos` it
+        ends in the middle of a decode window."""
+        import paddle_tpu.profiler as prof
+        import paddle_tpu.serving.engine as engine_mod
+        rng = np.random.RandomState(3)
+        crowd = [list(rng.randint(1, 128, n)) for n in (19, 11, 21, 5)]
+        pair = [list(rng.randint(1, 128, n)) for n in (13, 10)]
+        fetches = []
+        real = engine_mod._host_fetch
+        monkeypatch.setattr(engine_mod, '_host_fetch',
+                            lambda x: fetches.append(1) or real(x))
+        eng = ServingEngine(model, ServingConfig(
+            page_size=8, max_batch_size=3, prefill_chunk=8, num_pages=9,
+            seed=11, **knobs))
+        mark = prof.mark()
+        reqs = [eng.submit(p, max_new_tokens=10, top_k=0) for p in crowd]
+        _drain(eng)
+        reqs.append(eng.submit(pair[0], max_new_tokens=10, top_k=8,
+                               temperature=1.5, eos_token_id=eos))
+        reqs.append(eng.submit(pair[1], max_new_tokens=10, top_k=0))
+        _drain(eng)
+        spans = sorted((s for s in prof.spans(since_id=mark)
+                        if s.name in DEVICE_SPANS),
+                       key=lambda s: s.start_ns)
+        events = {r.id: eng.tracer.events(r.id) for r in reqs}
+        out = (reqs, spans, events, set(eng._step_fns), eng.stats(),
+               len(fetches))
+        monkeypatch.setattr(engine_mod, '_host_fetch', real)
+        eng.shutdown()
+        return out
+
+    @pytest.mark.parametrize('case', list(SEAM_CASES))
+    def test_mixed_run_compiles_and_spans_as_the_parent(
+            self, tiny_lm, case, monkeypatch):
+        knobs, keys = SEAM_CASES[case]
+        reqs, *_ = self._mixed_run(tiny_lm, knobs, None, monkeypatch)
+        g = reqs[4].generated
+        # an id the sampled row first emits second or third in a window
+        e = next(e for e in (2, 3) if g[e] not in g[:e])
+        reqs, spans, events, compiled, st, fetches = self._mixed_run(
+            tiny_lm, knobs, g[e], monkeypatch)
+        assert reqs[4].generated == g[:e + 1]
+        assert [len(r.generated) for r in reqs[:4] + reqs[5:]] == [10] * 5
+        assert st['preemptions_total'] >= 1
+        assert compiled == keys
+        if 'fused_k' in knobs:
+            last = [ev for ev in events[reqs[4].id]
+                    if ev['event'] == 'fused_decode'][-1]
+            assert 0 < last['accepted'] < last['k'] == 4
+
+        # compiled_step -> sample_fetch -> accept, with the parent's args
+        names = [s.name.split('::')[1] for s in spans]
+        shapes = []
+        inner = due = 0
+        for i, s in enumerate(spans):
+            if s.name != 'serve::compiled_step':
+                continue
+            shape = s.args['shape']
+            shapes.append(shape)
+            after = names[i + 1:i + 3]
+            if shape == 'prefill':
+                assert s.args == {'shape': 'prefill'}
+                assert i == 0 or names[i - 1] != 'prepare'
+                if after[:1] == ['sample_fetch']:
+                    due += 1
+                    assert after == ['sample_fetch', 'accept']
+                    assert set(spans[i + 2].args) == {
+                        'req', 'emitted', 'retired'}
+                else:
+                    # an inner chunk of a prompt: nothing is fetched
+                    inner += 1
+                    assert after[:1] in ([], ['compiled_step'],
+                                         ['prepare'])
+                continue
+            assert names[i - 1] == 'prepare'
+            assert after == ['sample_fetch', 'accept']
+            assert set(spans[i + 2].args) == {'emitted', 'retired'}
+            assert set(s.args) == ({'shape', 'batch', 'k'}
+                                   if shape == 'fused'
+                                   else {'shape', 'batch'})
+            assert 1 <= s.args['batch'] <= 3
+            assert shape != 'fused' or s.args['k'] == 4
+        assert set(shapes) == {'prefill', 'decode'} | (
+            {'verify'} if 'spec_k' in knobs else set()) | (
+            {'fused'} if 'fused_k' in knobs else set())
+        chunks = [ev for evs in events.values() for ev in evs
+                  if ev['event'] == 'prefill_chunk']
+        assert due == sum(1 for ev in chunks if ev.get('sampled'))
+        assert inner == len(chunks) - due and inner > 0
+        assert names.count('sample_fetch') == fetches == (
+            len(shapes) - inner)
+        assert sum(s.args['emitted'] for s in spans
+                   if s.name == 'serve::accept') == sum(
+            len(r.generated) for r in reqs)
+
+    def test_fused_window_feeds_the_ledger_what_serial_steps_do(
+            self, tiny_lm):
+        """Counts, not clocks: KV tokens read, live pages, page slots
+        and prefill tokens. Three prompts of two chunks each and eight
+        decode tokens a row are two whole windows of four."""
+        rng = np.random.RandomState(5)
+        prompts = [list(rng.randint(1, 128, n)) for n in (13, 10, 15)]
+        fed = {}
+        for k in (1, 4):
+            eng = ServingEngine(tiny_lm, ServingConfig(
+                page_size=8, max_batch_size=3, prefill_chunk=8,
+                fused_k=k))
+            rows = fed[k] = []
+            observe = eng.ledger.observe_iteration
+            eng.ledger.observe_iteration = (
+                lambda _o=observe, _r=rows, **kw: _r.append(kw)
+                or _o(**kw))
+            eng.generate(prompts, max_new_tokens=9, top_k=0)
+            assert eng.stats()['fused_windows_total'] == (2 if k > 1
+                                                          else 0)
+            eng.shutdown()
+
+        def total(k, key):
+            return sum(kw[key] for kw in fed[k])
+        assert len(fed[1]) == len(fed[4])       # one record an iteration
+        for key in ('paged_live_pages', 'paged_page_slots',
+                    'prefill_tokens', 'prefill_ctx_tokens'):
+            assert total(4, key) == total(1, key) > 0, key
+        # a window's reads are spread evenly over its iterations, whole
+        # tokens each: up to k - 1 a window are dropped
+        assert 0 <= total(1, 'kv_read_tokens') - total(
+            4, 'kv_read_tokens') < 2 * 4
+        assert total(1, 'kv_read_tokens') > 0
+
+    @pytest.mark.parametrize('rows', ['greedy', 'sampled'])
+    def test_fused_body_is_the_decode_step(self, tiny_lm, rows):
+        """One decode position through the [B, 1] step and through a
+        fused window of one iteration, from one pool: the same ids and
+        the same pages."""
+        eng = ServingEngine(tiny_lm, ServingConfig(
+            page_size=8, max_batch_size=3, prefill_chunk=8, fused_k=2,
+            seed=5))
+        rng = np.random.RandomState(9)
+        for i, n in enumerate((13, 6, 10)):
+            eng.submit(list(rng.randint(1, 128, n)), max_new_tokens=8,
+                       top_k=(6 if rows == 'sampled' and i != 1 else 0),
+                       temperature=1.3)
+        while not all(r is not None and r.state == RequestState.RUNNING
+                      for r in eng.scheduler.slots):
+            eng.step()
+        batch = []
+        for i, req in enumerate(eng.scheduler.slots):
+            eng.pool.ensure_capacity(req.id, req.context_len)
+            batch.append((i, req, [req.generated[-1]], req.context_len))
+        pool0 = eng.pool.kv
+        ids = eng._dispatch('decode', batch, 3, 1)
+        pages = eng.pool.kv
+        eng.pool.kv = pool0
+        ids_fused = eng._dispatch('fused', batch, 3, 1)
+        assert {k[0] for k in eng._step_fns} >= {3, 'fused'}
+        assert ids_fused.shape == (3, 1)
+        assert ids_fused[:, 0].tolist() == ids.tolist()
+        for layer, layer_fused in zip(pages, eng.pool.kv):
+            for a, b in zip(layer, layer_fused):
+                np.testing.assert_array_equal(np.asarray(a),
+                                              np.asarray(b))
+        eng.shutdown()

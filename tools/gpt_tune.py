@@ -2,7 +2,7 @@
 
 Usage: python tools/gpt_tune.py packed|bhld
 (compare the packed transpose-free causal flash route vs the BHLD one
-on the exact bench.py configuration).
+on the configuration of the benchmark's `gpt3-1.3b.pretrain-2k` cell).
 """
 import os
 import sys

@@ -66,7 +66,7 @@
 #                      engine gauge wiring, 2-rank injected-slow-rank
 #                      straggler detection, histogram percentile
 #                      edges, metrics-docs registry consistency,
-#                      bench_compare regression verdicts, ledger CLI
+#                      ledger CLI
 #   --serve-ledger-selftest - serving goodput ledger & decode roofline
 #                      (ISSUE 17): iteration-wall decomposition with
 #                      ordered clamps, goodput identity across
@@ -141,9 +141,7 @@ case "$TIER" in
           # ledger smoke: TrainStep loop -> ledger gauges -> render
           python tools/health_dump.py ledger --selftest
           # alerts smoke: history ring -> rule fire/clear -> render
-          python tools/health_dump.py alerts --selftest
-          # bench-compare smoke: synthetic + real rounds -> verdicts
-          python tools/bench_compare.py --selftest ;;
+          python tools/health_dump.py alerts --selftest ;;
   dist)   python -m pytest tests/test_distributed.py \
             tests/test_launch_elastic.py tests/test_bert_zero_asp.py -q ;;
   native) python -m pytest tests/test_native.py tests/test_ps.py -q ;;
@@ -238,8 +236,7 @@ case "$TIER" in
           XLA_FLAGS="--xla_force_host_platform_device_count=8" \
           python -m pytest tests/test_ledger.py tests/test_monitor.py \
             tests/test_metrics_docs.py -q
-          python tools/health_dump.py ledger --selftest
-          python tools/bench_compare.py --selftest ;;
+          python tools/health_dump.py ledger --selftest ;;
   --serve-ledger-selftest)
           # the serving goodput ledger end to end (ISSUE 17): serve-
           # wall decomposition + goodput identity + roofline units,
@@ -247,8 +244,7 @@ case "$TIER" in
           # serve-gauge + bench-compare CLI smokes
           python -m pytest tests/test_serving_ledger.py \
             tests/test_metrics_docs.py -q
-          python tools/health_dump.py serve --selftest
-          python tools/bench_compare.py --selftest ;;
+          python tools/health_dump.py serve --selftest ;;
   --fused-selftest)
           # fused decode windows end to end (ISSUE 19): token-identity
           # vs serial across every truncation edge, quiescence gate,
@@ -289,7 +285,6 @@ case "$TIER" in
           python tools/health_dump.py host --selftest
           python tools/health_dump.py pp --selftest
           python tools/health_dump.py ledger --selftest
-          python tools/health_dump.py alerts --selftest
-          python tools/bench_compare.py --selftest ;;
+          python tools/health_dump.py alerts --selftest ;;
   *) echo "usage: $0 [fast|dist|native|e2e|all|--comm-selftest|--serve-selftest|--quant-selftest|--pallas-selftest|--overlap-selftest|--cluster-selftest|--remat-selftest|--async-selftest|--pp-selftest|--tenant-selftest|--ledger-selftest|--serve-ledger-selftest|--alerts-selftest|--fused-selftest|--kvtier-selftest]"; exit 1 ;;
 esac
