@@ -684,8 +684,8 @@ def phase_serve(size, require_kernels=True, meter=None):
         check(len(r.generated) == new and len(r.output_ids()) ==
               len(p) + new, f'request {r.id}: {len(r.generated)} tokens')
     B, C = size['batch'], size['chunk']
-    check(set(eng._step_fns) == {(1, C, False, False),
-                                 (B, 1, False, False)},
+    check({k[0] for k in eng._step_fns} == {'mixed', B}
+          and len(eng._step_fns) == 2,
           f'compiled step shapes {sorted(map(str, eng._step_fns))}')
     # donation is on off-CPU only: the pool's arrays are the step's
     # donated outputs and must still be alive and readable

@@ -193,20 +193,27 @@ class AfmoeAttention(_Params):
         ctx = jnp.einsum('bhgqk,bkhd->bqhgd', jax.nn.softmax(s, -1), v)
         return self._out(a, ctx.reshape(B, L, -1).astype(a.dtype))
 
-    def forward_paged(self, a, pos, kv, page_tables, seq_lens, q_lens):
+    def forward_paged(self, a, pos, kv, rows):
+        """a [1, N, H] over the dispatch's tokens; `rows` (a
+        serving/protocol.py RowGroups) takes the attention group by
+        group, everything else runs once."""
         cfg = self.cfg
         if len(kv) != 2:
             raise NotImplementedError(
                 'an int8 KV pool under kv groups and windows: the '
                 'paged kernel\'s scale blocks do not take them')
-        q, k, v = self._qkv(a, pos)
-        kp, vp = pa.write_kv_pages(kv[0], kv[1], k, v, page_tables,
-                                   seq_lens, q_lens)
-        ctx = pa.ragged_paged_attention(
-            q, kp, vp, page_tables, seq_lens, q_lens,
-            num_heads=cfg.num_heads, head_dim=cfg.head_dim,
-            num_kv_heads=cfg.num_kv_heads, window=self.window)
-        return self._out(a, ctx), (kp, vp)
+
+        def write(pool, k, v, page_tables, seq_lens, q_lens):
+            return pa.write_kv_pages(*pool, k, v, page_tables, seq_lens,
+                                     q_lens)
+
+        def read(pool, q, page_tables, seq_lens, q_lens):
+            return pa.ragged_paged_attention(
+                q, *pool, page_tables, seq_lens, q_lens,
+                num_heads=cfg.num_heads, head_dim=cfg.head_dim,
+                num_kv_heads=cfg.num_kv_heads, window=self.window)
+        ctx, kv = rows.attend(write, read, kv, *self._qkv(a, pos))
+        return self._out(a, ctx), kv
 
 
 class AfmoeMLP(_Params):
@@ -241,9 +248,11 @@ class AfmoeSparseMLP(_Params):
         self.shared = AfmoeMLP(H, F)
         self.experts = AfmoeExperts(cfg.experts_held[1], H, F)
 
-    def forward(self, m, live=None):
-        """m [N, H], live bool [N] or None (every row) -> (out [N, H],
-        rows int32 [experts held]: the live rows' pairs)."""
+    def forward(self, m, live=None, counted=None):
+        """m [N, H], live bool [N] or None (every row), counted (ids
+        int32 [N], count) or None (all rows together) -> (out [N, H],
+        rows int32 [count, experts held]: the live rows' pairs by the
+        group they are counted with)."""
         cfg = self.cfg
         with jax.named_scope('router'):
             chosen, weights = moe.route(
@@ -253,7 +262,7 @@ class AfmoeSparseMLP(_Params):
             ex = self.experts
             out, rows = moe.experts_swiglu(
                 m, chosen, weights, ex.w1.data, ex.w3.data, ex.w2.data,
-                experts_held=cfg.experts_held, live=live)
+                experts_held=cfg.experts_held, live=live, counted=counted)
         with jax.named_scope('shared_expert'):
             out = out + self.shared(m)
         return out, rows
@@ -270,10 +279,11 @@ class AfmoeDecoderLayer(_Params):
         self.mlp = AfmoeSparseMLP(cfg) if self.sparse \
             else AfmoeMLP(H, cfg.intermediate_size)
 
-    def _join(self, h, attn_out, live=None):
+    def _join(self, h, attn_out, live=None, counted=None):
         """The attention's sandwich half, then the whole MLP half:
         -> (h, rows of the expert layer or None). `live` [B, T]: the
-        positions that hold a token (None: all)."""
+        positions that hold a token (None: all); `counted`: the group
+        each position's routed rows count with (None: one)."""
         h = h + rms_norm(attn_out, self.norm2.data, self.eps)
         m = rms_norm(h, self.norm3.data, self.eps)
         rows = None
@@ -281,7 +291,7 @@ class AfmoeDecoderLayer(_Params):
             if self.sparse:
                 f, rows = self.mlp(
                     m.reshape(-1, m.shape[-1]),
-                    None if live is None else live.reshape(-1))
+                    None if live is None else live.reshape(-1), counted)
                 f = f.reshape(m.shape)
             else:
                 f = self.mlp(m)
@@ -292,15 +302,12 @@ class AfmoeDecoderLayer(_Params):
             a = self.attn(rms_norm(h, self.norm1.data, self.eps), pos)
         return self._join(h, a)
 
-    def forward_paged(self, h, pos, kv, page_tables, seq_lens, q_lens):
+    def forward_paged(self, h, pos, kv, rows):
         with jax.named_scope('attn'):
             a, new_kv = self.attn.forward_paged(
-                rms_norm(h, self.norm1.data, self.eps), pos, kv,
-                page_tables, seq_lens, q_lens)
-        T = h.shape[1]
-        live = jnp.arange(T, dtype=jnp.int32)[None, :] < q_lens[:, None]
-        h, rows = self._join(h, a, live)
-        return h, new_kv, rows
+                rms_norm(h, self.norm1.data, self.eps), pos, kv, rows)
+        h, counts = self._join(h, a, rows.live(), rows.counted())
+        return h, new_kv, counts
 
 
 def _fill(key, shapes, stds, dtype_of):
@@ -371,8 +378,7 @@ class AfmoeForCausalLM(_Params):
         return self.lm_head
 
     def moe_counters(self):
-        return jnp.zeros((len(self._sparse),
-                          self.config.experts_held[1] + 3), jnp.int32)
+        return jnp.zeros((len(self._sparse), 3), jnp.int32)
 
     def _embed(self, ids):
         h = self.embed.data[ids]
@@ -381,30 +387,31 @@ class AfmoeForCausalLM(_Params):
                 .astype(h.dtype)
         return h
 
-    def forward_paged(self, input_ids, position_ids, kv_list, page_tables,
-                      seq_lens, q_lens, moe_counters=None):
+    def forward_paged(self, input_ids, position_ids, kv_list, rows,
+                      moe_counters=None):
         """The engine's forward over the paged pool: -> (final-normed
-        hidden Tensor [B, T, H], new kv list, moe_counters)."""
+        hidden Tensor [1, N, H], new kv list, None or the experts'
+        (rows [layers, counted groups, experts held], counters))."""
         pos = position_ids.data
         with jax.named_scope('embed'):
             h = self._embed(input_ids.data)
         new_kv, counted = [], []
         for layer, kv in zip(self.layers, kv_list):
-            h, nkv, rows = layer.forward_paged(
-                h, pos, tuple(t.data for t in kv), page_tables, seq_lens,
-                q_lens)
+            h, nkv, counts = layer.forward_paged(
+                h, pos, tuple(t.data for t in kv), rows)
             new_kv.append(tuple(Tensor(a) for a in nkv))
-            if rows is not None:
-                counted.append(rows)
+            if counts is not None:
+                counted.append(counts)
         with jax.named_scope('final_norm'):
             h = rms_norm(h, self.final_norm.data, self.config.rms_norm_eps)
+        moe = None
         if moe_counters is not None:
-            rows = jnp.stack(counted)                   # [layers, C]
-            grown = moe_counters[:, -3:] + jnp.stack(
-                [jnp.sum(rows > 0, -1), jnp.sum(rows, -1),
-                 jnp.ones_like(rows[:, 0])], axis=-1)
-            moe_counters = jnp.concatenate([rows, grown], axis=-1)
-        return Tensor(h), new_kv, moe_counters
+            counts = jnp.stack(counted)             # [layers, groups, C]
+            call = jnp.sum(counts, axis=1)          # what the kernel saw
+            moe = (counts, moe_counters + jnp.stack(
+                [jnp.sum(call > 0, -1), jnp.sum(call, -1),
+                 jnp.ones_like(call[:, 0])], axis=-1))
+        return Tensor(h), new_kv, moe
 
     def forward(self, input_ids):
         """[B, L] ids -> float32 logits [B, L, V], no cache (the tests'

@@ -256,63 +256,47 @@ class GPTAttention(nn.Layer):
         out = self.out_proj(ctx)
         return out, (kc2, vc2)
 
-    def forward_paged(self, x, kv, page_tables, seq_lens, q_lens):
-        """Serving-engine path: x [B, T, H] (T new tokens per row,
-        right-padded to q_lens); kv = (k_pages, v_pages) Tensors
-        [num_pages, page_size, local_heads*hd] from the shared pool —
-        or the int8 pool's 4-tuple (k_pages, v_pages, k_scales,
-        v_scales), in which case new K/V quantize at scatter time and
-        attention dequantizes inside the kernel (kv_dtype='int8',
-        docs/serving.md#quantized-kv). Writes the new tokens' k/v into
-        the sequences' pages and runs ragged paged attention over each
-        row's page table (causal within the sequence).
-        page_tables/seq_lens/q_lens are plain int32 arrays (non-diff,
-        captured like cache_len above)."""
-        B, T, _ = x.shape
+    def forward_paged(self, x, kv, rows):
+        """Serving-engine path: x [1, N, H], the query tokens of every
+        row of the dispatch end to end (`rows`, a serving/protocol.py
+        RowGroups, says which rows: right-padded to their q_lens); kv =
+        (k_pages, v_pages) Tensors [num_pages, page_size,
+        local_heads*hd] from the shared pool — or the int8 pool's
+        4-tuple (k_pages, v_pages, k_scales, v_scales), in which case
+        new K/V quantize at scatter time and attention dequantizes
+        inside the kernel (kv_dtype='int8',
+        docs/serving.md#quantized-kv). The projections run once over
+        the N tokens; `rows.attend` writes each group's new k/v into
+        its sequences' pages and runs ragged paged attention over each
+        row's page table (causal within the sequence)."""
         qkv = self.qkv_proj(x)
         hd = self.head_dim
         nh = qkv.shape[-1] // (3 * hd)
         from ..ops.pallas import paged_attention as pa
 
-        def _split(a):
-            x5 = a.reshape(B, T, nh, 3, hd)
-            return (x5[:, :, :, 0].reshape(B, T, nh * hd),
-                    x5[:, :, :, 1].reshape(B, T, nh * hd),
-                    x5[:, :, :, 2].reshape(B, T, nh * hd))
+        def write(pool, k, v, page_tables, seq_lens, q_lens):
+            if len(pool) == 4:
+                return pa.write_kv_pages_quantized(
+                    *pool, k, v, page_tables, seq_lens, q_lens,
+                    num_heads=nh)
+            return pa.write_kv_pages(*pool, k, v, page_tables, seq_lens,
+                                     q_lens)
 
-        if len(kv) == 4:
-            k_pages, v_pages, k_scales, v_scales = kv
+        def read(pool, q, page_tables, seq_lens, q_lens):
+            scales = {'k_scales': pool[2], 'v_scales': pool[3]} \
+                if len(pool) == 4 else {}
+            return pa.ragged_paged_attention(
+                q, pool[0], pool[1], page_tables, seq_lens, q_lens,
+                num_heads=nh, head_dim=hd, **scales)
 
-            def fnq(a, kp, vp, ks, vs):
-                q, k, v = _split(a)
-                kp2, vp2, ks2, vs2 = pa.write_kv_pages_quantized(
-                    kp, vp, ks, vs, k, v, page_tables, seq_lens,
-                    q_lens, num_heads=nh)
-                ctx = pa.ragged_paged_attention(
-                    q, kp2, vp2, page_tables, seq_lens, q_lens,
-                    num_heads=nh, head_dim=hd, k_scales=ks2,
-                    v_scales=vs2)
-                return ctx, kp2, vp2, ks2, vs2
-            ctx, kp2, vp2, ks2, vs2 = run_op(
-                'paged_attention', fnq,
-                [qkv, k_pages, v_pages, k_scales, v_scales])
-            out = self.out_proj(ctx)
-            return out, (kp2, vp2, ks2, vs2)
-
-        k_pages, v_pages = kv
-
-        def fn(a, kp, vp):
-            q, k, v = _split(a)
-            kp2, vp2 = pa.write_kv_pages(kp, vp, k, v, page_tables,
-                                         seq_lens, q_lens)
-            ctx = pa.ragged_paged_attention(
-                q, kp2, vp2, page_tables, seq_lens, q_lens,
-                num_heads=nh, head_dim=hd)
-            return ctx, kp2, vp2
-        ctx, kp2, vp2 = run_op('paged_attention', fn,
-                               [qkv, k_pages, v_pages])
-        out = self.out_proj(ctx)
-        return out, (kp2, vp2)
+        def fn(a, *pool):
+            x5 = a.reshape(1, -1, nh, 3, hd)
+            q, k, v = (x5[:, :, :, i].reshape(1, -1, nh * hd)
+                       for i in range(3))
+            ctx, pool = rows.attend(write, read, pool, q, k, v)
+            return (ctx, *pool)
+        ctx, *new_kv = run_op('paged_attention', fn, [qkv, *kv])
+        return self.out_proj(ctx), tuple(new_kv)
 
 
 class GPTMLP(nn.Layer):
@@ -404,9 +388,8 @@ class GPTDecoderLayer(nn.Layer):
         x = self._join(self._norm_mlp(x), x)
         return x
 
-    def forward_paged(self, x, kv, page_tables, seq_lens, q_lens):
-        a, new_kv = self._norm_attn(x, self.attn.forward_paged, kv,
-                                    page_tables, seq_lens, q_lens)
+    def forward_paged(self, x, kv, rows):
+        a, new_kv = self._norm_attn(x, self.attn.forward_paged, kv, rows)
         x = self._join(a, x)
         x = self._join(self._norm_mlp(x), x)
         return x, new_kv
@@ -459,16 +442,14 @@ class GPTModel(nn.Layer):
             x = C._c_gather_seq_replicated(x, group=qkv.group)
         return x
 
-    def forward_paged(self, input_ids, position_ids, kv_list,
-                      page_tables, seq_lens, q_lens):
+    def forward_paged(self, input_ids, position_ids, kv_list, rows):
         """Serving-engine forward over the paged KV pool: kv_list is the
         per-layer [(k_pages, v_pages)] Tensors; returns (hidden,
         new_kv_list). See serving/engine.py for the step around it."""
         x = self.embeddings(input_ids, position_ids)
         new_kv = []
         for layer, c in zip(self.layers, kv_list):
-            x, nc = layer.forward_paged(x, c, page_tables, seq_lens,
-                                        q_lens)
+            x, nc = layer.forward_paged(x, c, rows)
             new_kv.append(nc)
         with jax.named_scope('final_norm'):
             return self.final_norm(x), new_kv
@@ -516,11 +497,10 @@ class GPTForCausalLM(nn.Layer):
     def moe_counters(self):
         return None
 
-    def forward_paged(self, input_ids, position_ids, kv_list, page_tables,
-                      seq_lens, q_lens, moe_counters=None):
-        h, new_kv = self.gpt.forward_paged(
-            input_ids, position_ids, kv_list, page_tables, seq_lens,
-            q_lens)
+    def forward_paged(self, input_ids, position_ids, kv_list, rows,
+                      moe_counters=None):
+        h, new_kv = self.gpt.forward_paged(input_ids, position_ids,
+                                           kv_list, rows)
         return h, new_kv, moe_counters
 
     def forward(self, input_ids, position_ids=None):

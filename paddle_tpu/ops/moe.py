@@ -41,7 +41,7 @@ def route(m, router_w, bias, top_k, route_scale=1.0, route_norm=True):
 
 
 def experts_swiglu(m, experts, weights, w1, w3, w2, experts_held=None,
-                   live=None):
+                   live=None, counted=None):
     """The routed experts' part of the layer's output.
 
     m [T, H]; experts / weights [T, k] from `route`; w1, w3 [C, H, F]
@@ -50,7 +50,8 @@ def experts_swiglu(m, experts, weights, w1, w3, w2, experts_held=None,
     [T], default all): a row that is padding sends its pairs to no
     expert, so they are neither computed nor counted and its output is
     0. Returns (out [T, H] in m's dtype, rows int32 [C]: pairs each
-    held expert took)."""
+    held expert took) — or, with `counted` (ids int32 [T], count): the
+    group each row's pairs are counted with, rows int32 [count, C]."""
     T, k = experts.shape
     C = w1.shape[0]
     first = 0 if experts_held is None else int(experts_held[0])
@@ -73,7 +74,21 @@ def experts_swiglu(m, experts, weights, w1, w3, w2, experts_held=None,
     # a product with 0, because a dead tile's rows are never written
     part = jnp.where(held[..., None],
                      y[rows].astype(jnp.float32) * weights[..., None], 0)
-    return jnp.sum(part, axis=1).astype(m.dtype), p['counts']
+    out = jnp.sum(part, axis=1).astype(m.dtype)
+    if counted is None:
+        return out, p['counts']
+    # pairs by (group, expert): each token's pairs per held expert (a
+    # pair left out sits at C: 0 or 1 each), summed over the tokens of a
+    # group as a product with the groups' 0/1 membership — exact in any
+    # matmul precision, the sums far below 2**24
+    ids, count = counted
+    per_token = jnp.sum(
+        local.reshape(T, k, 1) == jnp.arange(C, dtype=jnp.int32),
+        axis=1)                                             # [T, C]
+    groups = ids[None, :] == jnp.arange(count, dtype=jnp.int32)[:, None]
+    rows = jnp.dot(groups.astype(jnp.float32),
+                   per_token.astype(jnp.float32))
+    return out, rows.astype(jnp.int32)
 
 
 def swiglu(m, w1, w3, w2):

@@ -3,10 +3,20 @@
 Four compiled step shapes serve every request mix (the continuous-
 batching contract — the device never recompiles as traffic changes):
 
-  * chunked prefill  — B=1, T=prefill_chunk: one prompt chunk streams
-    through the model, its K/V landing in the sequence's pool pages;
+  * the mixed step   — ONE program for a step with prompts to prefill:
+    the B=max_batch_size decode rows of one token AND P prompt chunks
+    of T=prefill_chunk tokens ride it together. Everything token-wise
+    (embedding, norms, projections, MLP, a sparse layer's router and
+    experts) runs once over the B + P*T tokens laid end to end, so
+    every weight and every expert is read once a step; attention alone
+    goes group by group ([B, 1] and [P, T], each over its own page
+    tables), and the head runs over the B + P last-query rows. P is
+    `PREFILL_ROWS` (not configured: a padded row is not free, the chip
+    chose). More than P prompts prefilling ride further dispatches of
+    the same program with an idle decode group;
   * batched decode   — B=max_batch_size, T=1: every RUNNING request
-    advances one token in ONE dispatch;
+    advances one token in ONE dispatch (a step with no prompt to
+    prefill);
   * batched verify   — B=max_batch_size, T=spec_k+1 (only with
     speculative decoding, spec_k > 0): each greedy request carries its
     n-gram-proposed draft tokens as extra ragged query rows — the same
@@ -33,13 +43,15 @@ index), so cache hits skip whole prefill chunks and TTFT drops to the
 uncached tail's cost.
 
 All four come from ONE builder (`_build_step`) and are called in ONE
-place (`_dispatch`): they run the model's `forward_paged` (ragged paged
-attention + `write_kv_pages` scatter) under `jit` with the KV pool
-donated, pick the next token ON DEVICE (greedy argmax or
+place (`_enqueue`; `_fetch` is the one host sync): they run the model's
+`forward_paged` (ragged paged attention + `write_kv_pages` scatter)
+under `jit` with the KV pool donated, pick the next token ON DEVICE (greedy argmax or
 temperature/top-k via jax.random), and fetch only the sampled token
 ids — the single per-token host round-trip. The dispatch sites keep
 what is theirs (prefix match and page growth; draft proposal, capacity
 and verify; window reservation and roll-back) and hand over their rows.
+A request whose last prompt chunk rode a dispatch takes its first token
+from it and joins the decode rows in the NEXT step.
 Idle decode slots ride along with q_len=0: their K/V writes are dropped
 by the scatter and their outputs ignored, so occupancy is a pure
 scheduling concern.
@@ -67,6 +79,7 @@ from .request_trace import (ENGINE_REQ, RequestTracer,
                             build_serve_report, write_serve_report)
 from . import metrics as _metrics
 from .ledger import ServeLedger
+from .protocol import RowGroups
 from ..core import monitor as _monitor
 from ..core.async_step import HostGapMonitor, unregister_monitor
 from ..profiler import RecordEvent, record_span
@@ -80,12 +93,25 @@ def _host_fetch(x):
     return np.asarray(x)
 
 
-# the growing counts a sparse-expert model carries behind its rows per
-# expert (serving/protocol.py moe_counters), in their order, and the
-# counters they feed
+# the growing counts a sparse-expert model carries (serving/protocol.py
+# moe_counters), in their order, and the counters they feed
 _MOE_COUNTERS = (('experts_touched', 'ptpu_moe_experts_touched_total'),
                  ('rows', 'ptpu_moe_rows_total'),
                  ('calls', 'ptpu_moe_calls_total'))
+
+# P: the prompt chunks that ride the mixed step beside its decode rows
+# (never more than there are slots). Every row is computed in full,
+# real or padding, and past a few hundred tokens a dispatch the matmuls
+# hide behind no weight read: on the v5e a row costs the dense 1.3B
+# model 3.0 ms of a 24 ms step (chunk 128) and the sparse Trinity cut
+# 5.7 of 39 (chunk 512), against one more dispatch — every weight and
+# expert read again — for each P prompts beyond the first P. Swept on
+# both benchmark server cells (PERF.md section 6, PR 31: P 1, 2, 3, 4,
+# 5, 8): the dense model reads alike at 1 and 2 (2 the shorter gaps
+# between tokens), the sparse one at 2 and 3; a rule from the device's
+# peak FLOP/s over its HBM bandwidth said 2 and 5, and 5 read 9 % under
+# the best. One value is within 3 % of it on both, so it is a constant
+PREFILL_ROWS = 2
 
 
 class ServingConfig:
@@ -96,7 +122,8 @@ class ServingConfig:
     num_pages        pool capacity; default fits every slot at
                      max_pages_per_seq (no preemption pressure)
     max_pages_per_seq  page-table width; default covers max_seq_len
-    prefill_chunk    prompt tokens per prefill dispatch
+    prefill_chunk    prompt tokens a request prefills per step (one
+                     row of the mixed step's prefill group)
     kv_dtype         pool dtype (default: model param dtype).
                      'int8' stores block-paged K/V as int8 with one
                      abs-max fp32 scale per (token slot, head) in
@@ -360,13 +387,17 @@ class ServingEngine:
         self._moe_dev = model.moe_counters() if needs == {'plain'} \
             else None
         if self._moe_dev is not None:
-            self._moe_seen = np.zeros((self._moe_dev.shape[0], 3),
-                                      np.int64)
+            self._moe_seen = np.zeros(self._moe_dev.shape, np.int64)
         self._moe = {'rows': 0, 'experts_touched': 0, 'calls': 0}
+        # rows per expert of the last fetched dispatch, by counted
+        # group ([expert layers, groups, experts held]: group 0 the
+        # decode rows, then one per prompt chunk), or None
+        self._moe_rows = None
         # who wants the rows per expert and layer of a prompt's last
-        # chunk (the one prefill dispatch that is fetched): a callable
-        # (request, first position, tokens, int array [expert layers,
-        # experts held]), or None. The benchmark's check listens.
+        # chunk ALONE (its own group of the dispatch it rode): a
+        # callable (request, first position, tokens, int array [expert
+        # layers, experts held]), or None. The benchmark's check
+        # listens.
         self.moe_rows_listener = None
         if self._mp > 1:
             if model.mp_degree != self._mp:
@@ -481,6 +512,14 @@ class ServingEngine:
         self._util_sum = 0.0
         self._prefill_tokens = 0
         self._prefill_chunks = 0
+        # the mixed step's prefill group, and how often it engages:
+        # steps that dispatched, the programs they called, and of the
+        # mixed ones the token slots their prefill groups held
+        self._prefill_rows = min(PREFILL_ROWS, config.max_batch_size)
+        self._steps = 0
+        self._dispatches = 0
+        self._mixed_dispatches = 0
+        self._mixed_slots = 0
         # speculative decoding accounting (draft tokens proposed by
         # the n-gram proposer vs accepted by the verify step)
         self._spec_proposed = 0
@@ -637,9 +676,9 @@ class ServingEngine:
     def _effective_prefill_chunk(self):
         """Ladder stage 2+ halves the prefill chunk (floor: one page):
         new requests trade TTFT for the running set's TPOT — each
-        sweep spends less of the step on prefill FLOPs. A distinct
-        compiled shape (1, chunk//2), warmed on first use and gauged
-        via the stage transition."""
+        sweep spends less of the step on prefill FLOPs. A second chunk
+        is a second mixed program (P rows of chunk//2), warmed on first
+        use and gauged via the stage transition."""
         C = self.config.prefill_chunk
         if self._ladder is not None and self._ladder.stage >= 2:
             # never LARGER than the configured chunk: with page_size >
@@ -778,9 +817,10 @@ class ServingEngine:
 
     # -- engine iteration ----------------------------------------------------
     def step(self):
-        """One scheduler iteration: admit waiting requests, advance one
-        prefill chunk per prefilling request, then one batched decode
-        step for the running set. Records a timeline entry, runs the
+        """One scheduler iteration: admit waiting requests, reserve the
+        next prompt chunk of every prefilling request, then dispatch:
+        the running set's decode rows and the chunks in ONE program
+        (`_advance`). Records a timeline entry, runs the
         stalled-request watchdog, publishes metrics. The whole of it is
         one `serve::step` span; its children are the span table of
         docs/serving.md#spans."""
@@ -804,21 +844,25 @@ class ServingEngine:
         sched_dt = time.perf_counter() - t_sched
         prefilling = [r for r in self.scheduler.slots
                       if r is not None and r.state == RequestState.PREFILL]
-        prefill_tokens = 0
+        chunks = []
         for req in prefilling:
             with RecordEvent('serve::prefill_chunk', event_type='serve',
                              req=req.id):
-                prefill_tokens += self._prefill_chunk_step(req)
-        running = [r for r in self.scheduler.slots
-                   if r is not None and r.state == RequestState.RUNNING]
-        decode_slots = decode_tokens = 0
-        if running:
-            with RecordEvent('serve::decode', event_type='serve'):
-                # POST-preemption counts: _decode_step may preempt
-                # members of `running` under pool pressure; slots are
-                # the surviving rows, tokens what they emitted (> slots
-                # when speculative decoding accepts drafts)
-                decode_slots, decode_tokens = self._decode_step()
+                chunk = self._reserve_chunk(req, chunks)
+            if chunk is not None:
+                chunks.append(chunk)
+        decode_slots = decode_tokens = prefill_tokens = 0
+        if chunks or any(r is not None and r.state == RequestState.RUNNING
+                         for r in self.scheduler.slots):
+            called = self._dispatches
+            with RecordEvent('serve::dispatch', event_type='serve'):
+                # POST-preemption counts: _advance may preempt rows
+                # under pool pressure; slots are the surviving decode
+                # rows, tokens what they emitted (> slots when
+                # speculative decoding accepts drafts)
+                decode_slots, decode_tokens, prefill_tokens = \
+                    self._advance(chunks)
+            self._steps += self._dispatches > called
         fused = self._fused_last
         self._fused_last = None
         wall = time.perf_counter() - t_begin
@@ -831,7 +875,7 @@ class ServingEngine:
 
     def _reset_iteration(self):
         """The iteration's phase clocks and roofline counts: step()
-        resets them, _dispatch alone feeds them (host perf_counter
+        resets them, _enqueue and _fetch alone feed them (host perf_counter
         segments — never a device sync), _step_telemetry hands them
         to the ledger."""
         self._it_compute = 0.0
@@ -1272,8 +1316,10 @@ class ServingEngine:
         traced, XLA can't elide it) — and verify=True the speculative-
         decode shape [max_batch, spec_k+1], the greedy argmax at EVERY
         query position (the per-draft verdicts) instead of just the
-        last. ('fused', B, K, sample) is K iterations of the [B, 1]
-        step under one lax.scan (ISSUE 19)."""
+        last. ('mixed', B, P, C, sample) is the [B, 1] decode rows and
+        P prompt chunks of C in one program. ('fused', B, K, sample) is
+        K iterations of the [B, 1] step under one lax.scan (ISSUE
+        19)."""
         fn = self._step_fns.get(key)
         if fn is None:
             fn = self._step_fns[key] = self._build_step(key)
@@ -1297,11 +1343,17 @@ class ServingEngine:
         qdtypes = dict(self._qparam_dtypes)
         mp = self._mp
         fused = key[0] == 'fused'
+        verify = False
+        # the rows a dispatch carries, in groups of (rows, width)
         if fused:
             _, B, K, sample = key
-            verify = False
+            layout = ((B, 1),)
+        elif key[0] == 'mixed':
+            _, B, P, C, sample = key
+            layout = ((B, 1), (P, C))
         else:
-            _B, _T, sample, verify = key    # shapes come with the operands
+            B, T, sample, verify = key
+            layout = ((B, T),)
 
         @contextlib.contextmanager
         def bound(params):
@@ -1340,28 +1392,20 @@ class ServingEngine:
 
         def forward_pick(kv, moe, tokens, page_tables, seq_lens, q_lens,
                          key, ords, temps, top_ks):
-            """[B, T] query tokens through the model over the paged
-            pool, then each row's next id: the hidden state of its last
-            query -> logits -> sampled or greedy. -> (ids, kv, moe)."""
-            T = tokens.shape[1]
+            """The dispatch's query tokens ([N], `layout`'s rows end to
+            end; the per-row operands [R]) through the model over the
+            paged pool, then each row's next id: the hidden state of its
+            last query -> logits -> sampled or greedy. -> (ids, kv,
+            moe)."""
+            rows = RowGroups(layout, page_tables, seq_lens, q_lens)
             # int8 pools carry (k, v, k_scales, v_scales) per layer;
             # dense pools (k, v) — forward_paged keys off the arity
             cts = [tuple(Tensor(a) for a in c) for c in kv]
-            pos = (seq_lens[:, None] - q_lens[:, None]
-                   + jnp.arange(T, dtype=jnp.int32)[None, :])
-            pos = jnp.clip(pos, 0, max_pos)
             h, new_kv, moe = model.forward_paged(
-                Tensor(tokens), Tensor(pos), cts, page_tables,
-                seq_lens, q_lens, moe_counters=moe)
+                Tensor(tokens[None, :]), Tensor(rows.positions(max_pos)),
+                cts, rows, moe_counters=moe)
             new_kv = [tuple(t.data for t in c) for c in new_kv]
             w = model.lm_head_weight()
-
-            def last(x):
-                # [B, T, .] -> [B, .] at each row's last query
-                idx = jnp.clip(q_lens - 1, 0, T - 1).astype(jnp.int32)
-                return jnp.take_along_axis(
-                    x, idx[:, None, None], axis=1)[:, 0, :]
-
             if verify:
                 # multi-query verify: greedy next-token at every
                 # draft position in one dispatch; padding positions
@@ -1369,18 +1413,18 @@ class ServingEngine:
                 # Rows that sample ride along via an extra column
                 # so the step still costs ONE host fetch.
                 logits_all = full_logits(jnp.einsum(
-                    'bth,vh->btv', h.data, w.data,
+                    'nh,vh->nv', h.data[0], w.data,
                     preferred_element_type=jnp.float32))
                 nxt = jnp.argmax(logits_all, axis=-1) \
-                    .astype(jnp.int32)                  # [B, T]
+                    .astype(jnp.int32).reshape(layout[0])   # [B, T]
                 if sample:
                     samp = _device_sample(
-                        last(logits_all).astype(jnp.float32), key, ords,
-                        seq_lens, temps, top_ks)
+                        rows.last(logits_all[None]).astype(jnp.float32),
+                        key, ords, seq_lens, temps, top_ks)
                     nxt = jnp.concatenate([nxt, samp[:, None]], 1)
                 return nxt, new_kv, moe
             logits = full_logits(jnp.einsum(
-                'bh,vh->bv', last(h.data), w.data,
+                'bh,vh->bv', rows.last(h.data), w.data,
                 preferred_element_type=jnp.float32))
             if sample:
                 nxt = _device_sample(logits.astype(jnp.float32), key,
@@ -1397,9 +1441,12 @@ class ServingEngine:
                         kv, moe, tokens, page_tables, seq_lens, q_lens,
                         key, ords, temps, top_ks)
                     if moe is not None:
-                        # the experts' counters ride behind the sampled
-                        # ids: still ONE host fetch a step (_take_moe)
-                        nxt = jnp.concatenate([nxt, moe.reshape(-1)])
+                        # the experts' rows of this call and their
+                        # counters ride behind the sampled ids: still
+                        # ONE host fetch a step (_take_moe)
+                        counts, moe = moe
+                        nxt = jnp.concatenate(
+                            [nxt, counts.reshape(-1), moe.reshape(-1)])
                 return nxt, kv, moe
         else:
             def step(params, kv, moe, tokens, page_tables, seq_lens,
@@ -1420,8 +1467,8 @@ class ServingEngine:
                         alive = ~done
                         q = jnp.where(alive, 1, 0).astype(jnp.int32)
                         nxt, new_kv, _ = forward_pick(
-                            kv_c, None, tok[:, None], page_tables, seq,
-                            q, key, ords, temps, top_ks)
+                            kv_c, None, tok, page_tables, seq, q, key,
+                            ords, temps, top_ks)
                         # serial-order accounting: the emitted token
                         # counts BEFORE the eos/budget check (append-
                         # then-check), so eos-in-window truncates
@@ -1472,44 +1519,56 @@ class ServingEngine:
                     model.train()
         return run
 
-    def _dispatch(self, shape, rows, B, T, fetch=True):
-        """The one place a compiled step is called. `rows` are the rows
-        that carry a query, each (slot, request, query tokens, context
-        length after them); `shape` says which program they ride:
-        'prefill' ([1, T], one prompt chunk in slot 0), 'decode'
-        ([B, 1]), 'verify' ([B, T], a token and its drafts) or 'fused'
-        (T iterations of [B, 1] in one window). Builds the host
-        operands (idle slots ride along with q_len 0), calls the
-        program, takes the new pool, fetches — the step's one host sync
-        — unless `fetch` is False (a prompt's inner chunk samples
-        nothing anyone reads), splits the experts' counters off, and
-        feeds the iteration's clocks and counts (`_it_*`). Returns the
-        fetched ids ([B]; verify [B, T], one column more with sampled
-        rows; fused [B, T]), or None without a fetch."""
+    def _dispatch(self, shape, rows, B, T, chunks=(), fetch=True):
+        """One compiled step, called and (unless `fetch` is False: inner
+        chunks alone sample nothing anyone reads) fetched: `_enqueue`
+        then `_fetch`. Returns the fetched ids ([B]; mixed [B + P], the
+        decode rows' then the chunks'; verify [B, T], one column more
+        with sampled rows; fused [B, T]), or None without a fetch."""
+        queued = self._enqueue(shape, rows, B, T, chunks)
+        return self._fetch(queued) if fetch else None
+
+    def _enqueue(self, shape, rows, B, T, chunks=()):
+        """The one place a compiled step is called. `rows` are the
+        decode rows that carry a query, each (slot, request, query
+        tokens, context length after them); `shape` says which program
+        they ride: 'decode' ([B, 1]), 'verify' ([B, T], a token and its
+        drafts), 'fused' (T iterations of [B, 1] in one window) or
+        'mixed' — the [B, 1] decode rows AND `chunks`, prompt chunks of
+        up to T tokens in the rows of the program's prefill group, each
+        (row, request, query tokens, context length after them).
+        Builds the host operands (idle rows of either group ride along
+        with q_len 0), calls the program — which returns when it is
+        queued on the device —, takes the new pool and feeds the
+        iteration's clocks and counts (`_it_*`). Returns what `_fetch`
+        needs to bring the sampled ids to the host."""
         jnp = self._jnp
-        prefill, fused = shape == 'prefill', shape == 'fused'
-        # the numpy batch assembly is a span of its own for the batched
-        # shapes; a prefill chunk's is too small to be one
-        with (contextlib.nullcontext() if prefill else
-              RecordEvent('serve::prepare', event_type='serve')):
-            tokens = np.zeros((B, 1 if fused else T), np.int32)
-            page_tables = np.zeros((B, self.max_pages_per_seq), np.int32)
-            seq_lens = np.ones((B,), np.int32)
-            q_lens = np.zeros((B,), np.int32)
-            ords = np.zeros((B,), np.int32)
-            temps = np.zeros((B,), np.float32)
-            top_ks = np.zeros((B,), np.int32)
+        mixed, fused = shape == 'mixed', shape == 'fused'
+        width = T if shape == 'verify' else 1   # a decode row's queries
+        P = self._prefill_rows if mixed else 0
+        with RecordEvent('serve::prepare', event_type='serve'):
+            tokens = np.zeros((B * width + P * T,), np.int32)
+            page_tables = np.zeros((B + P, self.max_pages_per_seq),
+                                   np.int32)
+            seq_lens = np.ones((B + P,), np.int32)
+            q_lens = np.zeros((B + P,), np.int32)
+            ords = np.zeros((B + P,), np.int32)
+            temps = np.zeros((B + P,), np.float32)
+            top_ks = np.zeros((B + P,), np.int32)
             if fused:
                 rems = np.zeros((B,), np.int32)
                 eos_ids = np.full((B,), -1, np.int32)
+
+            def place(row, first, req, query, context):
+                tokens[first:first + len(query)] = query
+                page_tables[row, :] = self._page_row(req)
+                seq_lens[row] = context
+                q_lens[row] = len(query)
+                ords[row] = _ord_of(req)
+                temps[row] = req.temperature
+                top_ks[row] = req.top_k
             for i, req, query, context in rows:
-                tokens[i, :len(query)] = query
-                page_tables[i, :] = self._page_row(req)
-                seq_lens[i] = context
-                q_lens[i] = len(query)
-                ords[i] = _ord_of(req)
-                temps[i] = req.temperature
-                top_ks[i] = req.top_k
+                place(i, i * width, req, query, context)
                 iterations = 1
                 if fused:
                     rems[i] = iterations = min(
@@ -1521,28 +1580,35 @@ class ServingEngine:
                 # them, out of the slots the program's tables have
                 for j in range(iterations):
                     self._it_live_pages += self.pool.pages_for(context + j)
-                    if not prefill:
-                        self._count_kv_read(context + j)
-                if prefill:
-                    self._it_prefill_tokens += len(query)
-                    self._it_prefill_ctx += len(query) * context
+                    self._count_kv_read(context + j)
+            for row, req, query, context in chunks:
+                place(B + row, B * width + row * T, req, query, context)
+                self._it_live_pages += self.pool.pages_for(context)
+                self._it_prefill_tokens += len(query)
+                self._it_prefill_ctx += len(query) * context
             self._it_page_slots += (T if fused else 1) * page_tables.size
-            sample = any(req.top_k > 0 for _, req, _, _ in rows)
+            sample = any(req.top_k > 0 for _, req, _, _ in (*rows, *chunks))
             if fused:
                 key = ('fused', B, T, sample)
-                head = (tokens[:, 0], page_tables, seq_lens, ords, rems,
+                head = (tokens, page_tables, seq_lens, ords, rems,
                         eos_ids, q_lens > 0)
                 tail = (temps, top_ks)
             else:
-                key = (B, T, sample, shape == 'verify')
+                key = ('mixed', B, P, T, sample) if mixed \
+                    else (B, T, sample, shape == 'verify')
                 head = (tokens, page_tables, seq_lens, q_lens)
                 tail = (ords, temps, top_ks)
+        if mixed and (B, 1, sample, False) not in self._step_fns:
+            self._warm_decode(B, sample)
         fn = self._step_fn(key)
-        span_args = {'shape': shape}
-        if not prefill:
-            span_args['batch'] = len(rows)
+        span_args = {'shape': shape, 'batch': len(rows)}
         if fused:
             span_args['k'] = T
+        if mixed:
+            span_args['prefill_rows'] = len(chunks)
+            self._mixed_dispatches += 1
+            self._mixed_slots += P * T
+        self._dispatches += 1
         t0 = time.perf_counter()
         with RecordEvent('serve::compiled_step', event_type='serve',
                          **span_args):
@@ -1552,25 +1618,43 @@ class ServingEngine:
                 *map(jnp.asarray, tail))
         t1 = time.perf_counter()
         self._it_compute += t1 - t0
-        if prefill:
-            self._it_prefill_s += t1 - t0
-        else:
-            self._it_decode_s += t1 - t0
-        if not fetch:
-            return None
+        # one program's time, shared between the phases by the query
+        # tokens each brought to it
+        queries = int(q_lens.sum()) or 1
+        prefill = int(q_lens[B:].sum()) / queries
+        self._it_prefill_s += (t1 - t0) * prefill
+        self._it_decode_s += (t1 - t0) * (1.0 - prefill)
+        return ids, B + P, 1 + P, bool(rows), t0
+
+    def _fetch(self, queued):
+        """The one host sync of a dispatch: the ids `_enqueue` left on
+        the device come to the host, the experts' counters behind them
+        are split off, and the fetch's clocks are fed. -> ids."""
+        ids, n, groups, decode, t0 = queued
+        t1 = time.perf_counter()
         with RecordEvent('serve::sample_fetch', event_type='serve'):
             ids = _host_fetch(ids)      # the sampled-token fetch
         if self._moe_dev is not None:
-            ids, per_expert = self._take_moe(ids, B, decode=not prefill)
-            if prefill and self.moe_rows_listener is not None:
-                _, req, query, context = rows[0]
-                self.moe_rows_listener(req, context - len(query),
-                                       len(query), per_expert.copy())
+            ids = self._take_moe(ids, n, groups, decode)
         t2 = time.perf_counter()
         self._it_fetch += t2 - t1
-        if not prefill:
+        if decode:
             self._decode_time += t2 - t0
         return ids
+
+    def _warm_decode(self, B, sample):
+        """A server that prefills will decode: compile the [B, 1] step
+        beside the mixed program's own compile, by one call with every
+        row idle (q_len 0: nothing is written, read, fetched or
+        counted) — else the first step with no prompt to prefill stalls
+        every running request for the seconds its compile takes."""
+        def zeros(*shape, dtype=np.int32):
+            return self._jnp.asarray(np.zeros(shape, dtype))
+        _, self.pool.kv, _ = self._step_fn((B, 1, sample, False))(
+            self._params, self.pool.kv, self._moe_dev, zeros(B),
+            zeros(B, self.max_pages_per_seq),
+            self._jnp.asarray(np.ones((B,), np.int32)), zeros(B),
+            self._key, zeros(B), zeros(B, dtype=np.float32), zeros(B))
 
     def _fused_decode_window(self, K):
         """Up to K decode iterations in ONE dispatch + ONE host fetch.
@@ -1614,16 +1698,19 @@ class ServingEngine:
             self._it_kv_window[1] += layers * context
         self._it_kv_read_tokens += total // self._kv_layers
 
-    def _take_moe(self, packed, n, decode):
-        """Split one fetch into its `n` sampled ids and the experts'
-        counters behind them (protocol.py: rows per expert of this
-        call, then experts touched, rows and calls, only ever growing
-        and wrapping — differences are taken), and account them:
-        `ptpu_moe_*` counters, and for a decode call its load, the
-        most rows an expert took over the mean, averaged over the
-        expert layers. -> (ids, rows per expert [layers, experts])."""
-        moe = packed[n:].reshape(self._moe_dev.shape)
-        grown = moe[:, -3:].astype(np.int64)
+    def _take_moe(self, packed, n, groups, decode):
+        """Split one fetch into its `n` sampled ids and what the
+        experts counted behind them (protocol.py: the rows per expert
+        of this call by counted group — group 0 the decode rows, then
+        one per prompt chunk, `groups` in all — then experts touched,
+        rows and calls, only ever growing and wrapping — differences
+        are taken), and account them: `ptpu_moe_*` counters, and for a
+        call with decode rows their load, the most rows an expert took
+        from them over the mean, averaged over the expert layers. The
+        rows stay in `_moe_rows` for the chunks' accept. -> ids."""
+        layers = self._moe_dev.shape[0]
+        grown = packed[-3 * layers:].reshape(layers, 3).astype(np.int64)
+        self._moe_rows = packed[n:-3 * layers].reshape(layers, groups, -1)
         delta = ((grown - self._moe_seen) % (1 << 32)).sum(axis=0)
         self._moe_seen = grown
         for (key, name), d in zip(_MOE_COUNTERS, delta):
@@ -1634,11 +1721,11 @@ class ServingEngine:
                      'a call touched, (token, expert) rows routed, '
                      'expert-layer calls').inc(int(d))
         if decode:
-            rows = moe[:, :-3].astype(np.float64)
+            rows = self._moe_rows[:, 0].astype(np.float64)
             mean = rows.mean(axis=1)
             if (mean > 0).all():
                 self._it_moe_load = float((rows.max(axis=1) / mean).mean())
-        return packed[:n], moe[:, :-3]
+        return packed[:n]
 
     def _accepted(self, accept, *args, **span_args):
         """Run one of the host accept loops under its `serve::accept`
@@ -1712,16 +1799,36 @@ class ServingEngine:
         row = self.pool.page_table(req.id)
         return row + [0] * (self.max_pages_per_seq - len(row))
 
-    def _prefill_chunk_step(self, req):
+    def _reserve_chunk(self, req, earlier):
+        """A prefilling request's next prompt chunk, reserved: the
+        prefix match on its first, then page growth for its tokens.
+        `earlier` are the chunks already reserved in this step. ->
+        (request, first position, tokens), or None when the request
+        rides nothing this step."""
         C = self._effective_prefill_chunk()
         if req.state != RequestState.PREFILL:
-            return 0        # preempted by an earlier request in this
+            return None     # preempted by an earlier request in this
                             # same step() sweep: it re-queued slotless,
                             # allocating pages to it now would bleed the
                             # pool (and preempt live work) for a request
                             # that isn't scheduled
         toks = req.tokens
         if req.prefilled == 0 and self.pool.prefix_cache:
+            # a sibling's chunk reserved earlier in this step is about
+            # to compute the very block this prompt needs next (the
+            # same tokens from position 0 on): the chunks of one step
+            # ride one dispatch, so nothing of it is indexed yet — wait
+            # for it, and map its pages next step instead of computing
+            # them again beside it
+            ps = self.pool.page_size
+            have = self.pool.peek_prefix(toks, limit=len(toks) - 1)[0] \
+                if earlier else 0
+            if earlier and have + ps < len(toks) and any(
+                    start <= have and have + ps <= start + n
+                    and other.tokens[have:have + ps] == toks[have:have + ps]
+                    and other.tokens[:have] == toks[:have]
+                    for other, start, n in earlier):
+                return None
             # first chunk of a fresh admit (or a resume): map the
             # longest indexed prefix — full pages only, capped one
             # short of the context so the step still computes the
@@ -1744,50 +1851,65 @@ class ServingEngine:
         start = req.prefilled
         n = min(C, len(toks) - start)
         if not self._ensure_or_preempt(req, start + n):
-            return 0        # yielded to higher-priority pool pressure:
+            return None     # yielded to higher-priority pool pressure:
                             # re-queued, resumes when pressure clears
-        # only the chunk that completes the prompt samples a token
-        # anyone reads; a scoring request (no budget) not even that
-        last = start + n == len(toks)
-        due = last and req.max_new_tokens > 0
-        ids = self._dispatch(
-            'prefill', [(0, req, toks[start:start + n], start + n)], 1, C,
-            fetch=due)
-        req.prefilled = start + n
-        self._prefill_tokens += n
-        self._prefill_chunks += 1
-        req.prefill_chunks += 1
-        # goodput: positions below the request's computed high-water
-        # mark were forward-passed before (then destroyed by a
-        # preemption release) — this chunk re-derives them, priced as
-        # preempt_recompute waste. Prefix-cache resurrection advanced
-        # `start` past the cached span, so resurrected pages never
-        # bill. First-time positions are delivered prompt work.
-        prev_high = getattr(req, '_computed_high', 0)
-        recompute = max(0, min(prev_high, start + n) - start)
-        req._computed_high = max(prev_high, start + n)
-        self.ledger.account_prefill(n - recompute, recompute,
-                                    tenant_id=req.tenant_id)
-        # every prefilled token's K/V is resident: index the newly
-        # completed full pages so siblings (and our own resume) share
-        self.pool.register_prefix(req.id, toks, req.prefilled,
-                                  owner=req.tenant_id)
-        extra = {'recompute_tokens': recompute} if recompute else {}
-        if due:
-            # this chunk completes (re-)prefill and sampled a token off
-            # its final column — marked so reconstruct() can tell
-            # prefill-sampled tokens (initial AND every resume) from
-            # decode-step tokens when pricing delivered work (v4)
-            extra['sampled'] = 1
-        self._trace(req, 'prefill_chunk', tokens=n, prefilled=start + n,
-                    pages=len(self.pool.page_table(req.id)), **extra)
-        if due:
-            self._accepted(self._accept_first, req, int(ids[0]),
-                           req=req.id)
-        elif last:
-            self._retire(req)   # prefill-only request (scoring): the
-                                # budget says emit nothing
-        return n
+        return req, start, n
+
+    def _accept_chunks(self, nxt, B, riding):
+        """Host accept of the prompt chunks that rode one mixed
+        dispatch, `riding` = [(request, first position, tokens)] in the
+        rows of its prefill group; `nxt` the dispatch's fetch (the
+        chunks' ids behind the B decode rows'), or None when nothing
+        was due. Returns the tokens emitted: the first token of every
+        request whose prompt a chunk completed."""
+        emitted = 0
+        for row, (req, start, n) in enumerate(riding):
+            toks = req.tokens
+            last = start + n == len(toks)
+            due = _samples(req, start, n)
+            req.prefilled = start + n
+            self._prefill_tokens += n
+            self._prefill_chunks += 1
+            req.prefill_chunks += 1
+            # goodput: positions below the request's computed high-
+            # water mark were forward-passed before (then destroyed by
+            # a preemption release) — this chunk re-derives them,
+            # priced as preempt_recompute waste. Prefix-cache
+            # resurrection advanced `start` past the cached span, so
+            # resurrected pages never bill. First-time positions are
+            # delivered prompt work.
+            prev_high = getattr(req, '_computed_high', 0)
+            recompute = max(0, min(prev_high, start + n) - start)
+            req._computed_high = max(prev_high, start + n)
+            self.ledger.account_prefill(n - recompute, recompute,
+                                        tenant_id=req.tenant_id)
+            # every prefilled token's K/V is resident: index the newly
+            # completed full pages so siblings (and our own resume)
+            # share
+            self.pool.register_prefix(req.id, toks, req.prefilled,
+                                      owner=req.tenant_id)
+            extra = {'recompute_tokens': recompute} if recompute else {}
+            if due:
+                # this chunk completes (re-)prefill and sampled a token
+                # off its final column — marked so reconstruct() can
+                # tell prefill-sampled tokens (initial AND every
+                # resume) from decode-step tokens when pricing
+                # delivered work (v4)
+                extra['sampled'] = 1
+            self._trace(req, 'prefill_chunk', tokens=n,
+                        prefilled=start + n,
+                        pages=len(self.pool.page_table(req.id)), **extra)
+            if due:
+                if self._moe_rows is not None \
+                        and self.moe_rows_listener is not None:
+                    # what this chunk alone routed: its own group
+                    self.moe_rows_listener(
+                        req, start, n, self._moe_rows[:, 1 + row].copy())
+                emitted += self._accept_first(req, int(nxt[B + row]))
+            elif last:
+                self._retire(req)   # prefill-only request (scoring):
+                                    # the budget says emit nothing
+        return emitted
 
     def _accept_first(self, req, tok):
         """Host accept of the token a request's last prefill chunk
@@ -1812,18 +1934,24 @@ class ServingEngine:
             req.state = RequestState.RUNNING
         return 1
 
-    def _decode_step(self):
-        """One batched decode dispatch. With spec_k=0 every running
-        request advances exactly one token ([B, 1] step). With spec_k
-        > 0, greedy requests whose history yields an n-gram proposal
+    def _advance(self, chunks):
+        """The step's dispatches. The running set's decode rows and the
+        reserved prompt `chunks` ([(request, first position, tokens)])
+        ride ONE mixed program; more chunks than its prefill group has
+        rows ride further dispatches of the same program with an idle
+        decode group; no chunk at all is the [B, 1] step. With spec_k >
+        0, greedy requests whose history yields an n-gram proposal
         carry up to k draft tokens into the [B, spec_k+1] verify step:
         every draft position's greedy argmax comes back in the one
         fetch, the longest agreeing draft prefix is accepted plus the
         bonus token, and pages grown for rejected drafts are handed
         back (their slots are overwritten in place by later writes —
         the ragged kernel's seq_len mask never exposes a stale slot
-        before the step that rewrites it). Returns (rows, tokens
-        emitted)."""
+        before the step that rewrites it); the verify step and the
+        fused window keep a dispatch of their own, and the chunks ride
+        the mixed program beside an idle decode group. A request whose
+        last chunk rides here decodes from the NEXT step on. Returns
+        (decode rows, tokens they emitted, prompt tokens prefilled)."""
         sched = self.scheduler
         K = self._effective_spec_k()
         if self.config.spec_k > 0 and K == 0:
@@ -1856,15 +1984,16 @@ class ServingEngine:
         # fused window (ISSUE 19): when no verify columns ride this
         # dispatch (spec takes precedence — its drafts already amortize
         # the host fetch) and the scheduler is quiescent for a full
-        # window, scan k decode iterations on device and fetch once.
-        # A failed page reservation falls through to the serial step
-        # below rather than preempting — the window is an optimization,
-        # never a capacity decision.
+        # window (so no request is prefilling: there are no chunks),
+        # scan k decode iterations on device and fetch once. A failed
+        # page reservation falls through to the serial step below
+        # rather than preempting — the window is an optimization, never
+        # a capacity decision.
         FK = self._effective_fused_k()
         if FK > 1 and not proposals and self._fused_ok(FK):
             res = self._fused_decode_window(FK)
             if res is not None:
-                return res
+                return (*res, 0)
         # capacity first (may preempt, or yield the request itself);
         # then snapshot the running set — a yielded request left its
         # slot, so the batch build below skips it naturally
@@ -1881,20 +2010,49 @@ class ServingEngine:
                 drafts = proposals.get(req.id, [])
                 rows.append((i, req, [_last_token(req)] + drafts,
                              req.context_len + len(drafts)))
-        if not rows:
-            return 0, 0
-        # without a surviving proposal the verify columns would all be
-        # padding: the [B, 1] step serves
-        verify = any(len(query) > 1 for _, _, query, _ in rows)
-        T = K + 1 if verify else 1
-        nxt = self._dispatch('verify' if verify else 'decode', rows, B, T)
-        self._decode_steps += 1
-        self._occupancy_sum += len(rows) / B
-        self._util_sum += self.pool.utilization()
-        emitted_total = self._accepted(self._accept_decode, nxt, rows,
-                                       verify, T)
-        self._decode_tokens += emitted_total
-        return len(rows), emitted_total
+        # a reservation above (a later chunk's, a decode row's) may have
+        # preempted a prefilling request: its pages went with it, and
+        # it rides nothing
+        chunks = [c for c in chunks if c[0].state == RequestState.PREFILL]
+        decode_rows, decode_tokens = len(rows), 0
+        if rows:
+            self._decode_steps += 1
+            self._occupancy_sum += len(rows) / B
+            self._util_sum += self.pool.utilization()
+            # without a surviving proposal the verify columns would all
+            # be padding: the [B, 1] rows serve
+            verify = any(len(query) > 1 for _, _, query, _ in rows)
+            if verify or not chunks:
+                T = K + 1 if verify else 1
+                nxt = self._dispatch('verify' if verify else 'decode',
+                                     rows, B, T)
+                decode_tokens = self._accepted(self._accept_decode, nxt,
+                                               rows, verify, T)
+                rows = []
+        # every dispatch of the step is queued before the first is
+        # fetched: the device runs the next one while the host accepts
+        # this one's tokens (no chunk depends on another's result: the
+        # pages of each were reserved above)
+        C, P = self._effective_prefill_chunk(), self._prefill_rows
+        queued = []
+        for first in range(0, len(chunks), P):
+            riding = chunks[first:first + P]
+            queued.append((rows, riding, self._enqueue(
+                'mixed', rows, B, C,
+                chunks=[(row, req, req.tokens[start:start + n], start + n)
+                        for row, (req, start, n) in enumerate(riding)])))
+            rows = []
+        for rows, riding, dispatch in queued:
+            # inner chunks alone sample nothing anyone reads: no fetch
+            nxt = self._fetch(dispatch) if rows or any(
+                _samples(*c) for c in riding) else None
+            if rows:
+                decode_tokens = self._accepted(self._accept_decode, nxt,
+                                               rows, False, 1)
+            self._accepted(self._accept_chunks, nxt, B, riding,
+                           chunks=len(riding))
+        self._decode_tokens += decode_tokens
+        return decode_rows, decode_tokens, sum(n for _, _, n in chunks)
 
     def _accept_decode(self, nxt, rows, verify, T):
         """Host accept of one [B, T] decode/verify fetch: token append,
@@ -2102,6 +2260,16 @@ class ServingEngine:
             'decode_tokens_total': self._decode_tokens,
             'prefill_tokens_total': self._prefill_tokens,
             'prefill_chunks_total': self._prefill_chunks,
+            # the mixed step: programs called a step that dispatched,
+            # prompt chunks riding a mixed dispatch (of its P rows), and
+            # the share of its prefill group's token slots left padding
+            'dispatches_total': self._dispatches,
+            'dispatches_per_step': self._dispatches / max(self._steps, 1),
+            'prefill_rows_per_dispatch':
+                self._prefill_chunks / max(self._mixed_dispatches, 1),
+            'padded_prefill_token_share':
+                (1.0 - self._prefill_tokens / self._mixed_slots
+                 if self._mixed_slots else 0.0),
             # sparse-expert layers (zeros for a model without them)
             'moe_rows_total': self._moe['rows'],
             'moe_experts_touched_total': self._moe['experts_touched'],
@@ -2186,6 +2354,10 @@ class ServingEngine:
         self._util_sum = 0.0
         self._prefill_tokens = 0
         self._prefill_chunks = 0
+        self._steps = 0
+        self._dispatches = 0
+        self._mixed_dispatches = 0
+        self._mixed_slots = 0
         self._spec_proposed = 0
         self._spec_accepted = 0
         self._spec_steps = 0
@@ -2289,6 +2461,13 @@ def _ngram_propose(tokens, ngram, k):
                    for t in range(1, n)):
                 return [int(t) for t in tokens[j + n:j + n + k]]
     return []
+
+
+def _samples(req, start, n):
+    """Whether the chunk [start, start + n) of a request's prompt
+    samples a token anyone reads: only the chunk that completes the
+    prompt does, and for a scoring request (no budget) not even that."""
+    return start + n == len(req.tokens) and req.max_new_tokens > 0
 
 
 def _last_token(req):

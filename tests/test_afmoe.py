@@ -19,6 +19,7 @@ from paddle_tpu.core.tensor import Tensor  # noqa: E402
 from paddle_tpu.models.afmoe import AfmoeConfig, AfmoeForCausalLM  # noqa: E402
 from paddle_tpu.ops import moe  # noqa: E402
 from paddle_tpu.serving import ServingConfig, ServingEngine  # noqa: E402
+from paddle_tpu.serving.protocol import RowGroups  # noqa: E402
 from benchmarks.common import Manifest  # noqa: E402
 from benchmarks.reference import afmoe as reference  # noqa: E402
 
@@ -97,8 +98,9 @@ def test_paged_prefill_then_decode_matches_the_full_forward(
         pos = np.clip(done + np.arange(tok.shape[1]), 0, 127)[None]
         h, kv, _ = model.forward_paged(
             Tensor(jnp.asarray(tok)), Tensor(jnp.asarray(pos, jnp.int32)),
-            kv, table, jnp.asarray([done + n], jnp.int32),
-            jnp.asarray([n], jnp.int32))
+            kv, RowGroups([tok.shape], table,
+                          jnp.asarray([done + n], jnp.int32),
+                          jnp.asarray([n], jnp.int32)))
         got.append(np.asarray(h.data[0, :n] @ head.T))
         done += n
     np.testing.assert_allclose(np.concatenate(got), want, atol=5e-5)
@@ -189,13 +191,14 @@ def test_the_shares_of_a_layer_add_up_to_the_layer(model):
     paddle.seed(3)
     share = AfmoeForCausalLM(tiny(experts_held=(2, 2)))
     assert tuple(share.layers[1].mlp.experts.w1.shape) == (2, 64, 32)
-    assert tuple(share.moe_counters().shape) == (4, 2 + 3)
+    assert tuple(share.moe_counters().shape) == (4, 3)
 
 
 def test_padding_rows_are_routed_to_no_expert(model):
     """A chunk's or a batch's padding (`live` false): its pairs reach no
     expert, are not counted, and its output is 0; the live rows' output
-    is what it is without the mask."""
+    is what it is without the mask. Counted by group, each group's live
+    rows are counted apart."""
     sparse = model.layers[1].mlp
     m = jnp.asarray(np.random.default_rng(2).standard_normal((12, 64)),
                     jnp.float32)
@@ -213,6 +216,14 @@ def test_padding_rows_are_routed_to_no_expert(model):
     np.testing.assert_allclose(np.asarray(part[:7]), np.asarray(whole[:7]),
                                atol=1e-6)
     assert not np.asarray(part[7:]).any()
+    group = np.asarray([0, 0, 0, 1, 1, 2, 2, 2, 2, 1, 0, 2], np.int32)
+    same, by_group = moe.experts_swiglu(
+        *args, live=live, counted=(jnp.asarray(group), 3))
+    np.testing.assert_array_equal(np.asarray(same), np.asarray(part))
+    np.testing.assert_array_equal(
+        np.asarray(by_group),
+        [np.bincount(np.asarray(chosen)[:7][group[:7] == g].ravel(),
+                     minlength=8) for g in range(3)])
 
 
 def test_a_model_declares_the_routes_it_is_written_for(model):

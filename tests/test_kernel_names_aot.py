@@ -146,31 +146,34 @@ def test_a_kernel_keeps_its_name_under_every_transform(case, wrap, one_chip,
     assert mosaic_calls(fn, shapes, one_chip) == want, (case, wrap)
 
 
-def test_paged_attention_is_named(one_chip, as_on_tpu):
-    """The server's decode shape: 64 rows of one query token, 16 heads
-    of 128, pages of 16, 80 pages a sequence."""
+@pytest.mark.parametrize('rows,T', [(64, 1), (2, 128)])
+def test_paged_attention_is_named(rows, T, one_chip, as_on_tpu):
+    """The GPT server's two row groups: 64 decode rows of one query
+    token and the mixed step's 2 prompt chunks of 128, 16 heads of 128,
+    pages of 16, 80 pages a sequence."""
     from paddle_tpu.ops.pallas import paged_attention as pa
     pages = ((3072, 16, 2048), BF16)
 
     def fn(q, k, v, pt, sl, ql):
         return pa.ragged_paged_attention_pallas(
             q, k, v, pt, sl, ql, num_heads=16, head_dim=128)
-    got = mosaic_calls(fn, [((64, 1, 2048), BF16), pages, pages,
-                            ((64, 80), jnp.int32), ((64,), jnp.int32),
-                            ((64,), jnp.int32)], one_chip)
+    got = mosaic_calls(fn, [((rows, T, 2048), BF16), pages, pages,
+                            ((rows, 80), jnp.int32), ((rows,), jnp.int32),
+                            ((rows,), jnp.int32)], one_chip)
     assert got == {'paged_attention'}
 
 
 @pytest.mark.parametrize('rows,window,name', [
     (64, None, 'paged_attention'), (64, 2048, 'paged_attention_window'),
-    (1, None, 'paged_attention'), (1, 2048, 'paged_attention_window')])
+    (1, None, 'paged_attention'), (1, 2048, 'paged_attention_window'),
+    (2, None, 'paged_attention'), (2, 2048, 'paged_attention_window')])
 def test_paged_attention_with_kv_groups_compiles_and_is_named(
         rows, window, name, one_chip, as_on_tpu):
-    """The sparse server cell's two step shapes at its published
-    widths: 32 query heads on 4 kv heads of 128, 528-page tables over
-    28,000 pages; [64, 1] decode and the [1, 512] chunk, whose 8 query
-    heads a kv head stack as 4096 rows. A call with a window has its
-    own name."""
+    """The sparse server cell's row groups at its published widths: 32
+    query heads on 4 kv heads of 128, 528-page tables over 28,000
+    pages; [64, 1] decode and chunks of 512 (one, and the mixed step's
+    2 rows), whose 8 query heads a kv head stack as 4096 rows. A call
+    with a window has its own name."""
     from paddle_tpu.ops.pallas import paged_attention as pa
     T = 1 if rows == 64 else 512
     pages = ((28000, 16, 512), BF16)
@@ -185,12 +188,13 @@ def test_paged_attention_with_kv_groups_compiles_and_is_named(
     assert got == {name}
 
 
-@pytest.mark.parametrize('pairs', [512, 4096])
+@pytest.mark.parametrize('pairs', [512, 4096, (64 + 2 * 512) * 8])
 def test_the_experts_grouped_matmul_compiles_and_is_named(
         pairs, one_chip, as_on_tpu):
     """128 experts of [2048, 1024] x 3 at a decode step's 512 (token,
-    expert) pairs and a 512-token chunk's 4096: the gated half and the
-    plain half are the same Mosaic call by name."""
+    expert) pairs, a 512-token chunk's 4096 and the mixed step's 8,704
+    (64 decode rows beside 2 chunks, tiles of 128 rows): the gated half
+    and the plain half are the same Mosaic call by name."""
     from paddle_tpu.ops.pallas import grouped_matmul as gmm
     tm = gmm.tile_rows_for(pairs, 128)
     tiles = -(-pairs // tm) + 128

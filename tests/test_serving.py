@@ -1178,10 +1178,11 @@ class TestServingSurface:
 
 
 # ---------------------------------------------------------------------------
-# the seam: one step builder, one dispatch path (ISSUE 30). What the
-# three dispatch sites did apart — which programs compile, which spans
-# open in which order with which args, when the host fetches, what the
-# ledger is fed — is pinned here as the parent commit did it.
+# the seam: one step builder, one dispatch path (ISSUE 30), and since
+# ISSUE 31 one mixed program where a [1, C] prefill program and the
+# [B, 1] step took turns. Which programs compile, which spans open in
+# which order with which args, when the host fetches, what the ledger
+# is fed — pinned here.
 # ---------------------------------------------------------------------------
 def _drain(eng):
     while eng.scheduler.has_work:
@@ -1189,20 +1190,17 @@ def _drain(eng):
 
 
 # knobs -> the `_step_fns` keys the mixed run below compiles
+# the mixed program and, compiled beside it, the [B, 1] step of the same
+# sampling mode
+MIXED = {('mixed', 3, 2, 8, False), ('mixed', 3, 2, 8, True),
+         (3, 1, False, False), (3, 1, True, False)}
 SEAM_CASES = {
-    'plain': ({}, {(1, 8, False, False), (1, 8, True, False),
-                   (3, 1, False, False), (3, 1, True, False)}),
+    'plain': ({}, MIXED),
     'spec_k=2': ({'spec_k': 2},
-                 {(1, 8, False, False), (1, 8, True, False),
-                  (3, 1, False, False), (3, 3, False, True),
-                  (3, 3, True, True)}),
+                 MIXED | {(3, 3, False, True), (3, 3, True, True)}),
     'fused_k=4': ({'fused_k': 4},
-                  {(1, 8, False, False), (1, 8, True, False),
-                   (3, 1, False, False), ('fused', 3, 4, False),
-                   ('fused', 3, 4, True)}),
-    'int8_weights': ({'weight_dtype': 'int8'},
-                     {(1, 8, False, False), (1, 8, True, False),
-                      (3, 1, False, False), (3, 1, True, False)}),
+                  MIXED | {('fused', 3, 4, False), ('fused', 3, 4, True)}),
+    'int8_weights': ({'weight_dtype': 'int8'}, MIXED),
 }
 DEVICE_SPANS = ('serve::prepare', 'serve::compiled_step',
                 'serve::sample_fetch', 'serve::accept')
@@ -1244,7 +1242,7 @@ class TestOneDispatchPath:
         return out
 
     @pytest.mark.parametrize('case', list(SEAM_CASES))
-    def test_mixed_run_compiles_and_spans_as_the_parent(
+    def test_mixed_run_compiles_and_spans_as_pinned(
             self, tiny_lm, case, monkeypatch):
         knobs, keys = SEAM_CASES[case]
         reqs, *_ = self._mixed_run(tiny_lm, knobs, None, monkeypatch)
@@ -1262,50 +1260,70 @@ class TestOneDispatchPath:
                     if ev['event'] == 'fused_decode'][-1]
             assert 0 < last['accepted'] < last['k'] == 4
 
-        # compiled_step -> sample_fetch -> accept, with the parent's args
+        # a decode / verify / fused dispatch: prepare -> compiled_step ->
+        # sample_fetch -> accept. A step's mixed dispatches are all
+        # queued first (prepare -> compiled_step each), then fetched and
+        # accepted in their order: the decode rows' accept, then the
+        # chunks'; inner chunks alone are not fetched
         names = [s.name.split('::')[1] for s in spans]
         shapes = []
-        inner = due = 0
+        unfetched = 0
+        queue = []          # the mixed dispatches waiting for their turn
         for i, s in enumerate(spans):
-            if s.name != 'serve::compiled_step':
-                continue
-            shape = s.args['shape']
-            shapes.append(shape)
-            after = names[i + 1:i + 3]
-            if shape == 'prefill':
-                assert s.args == {'shape': 'prefill'}
-                assert i == 0 or names[i - 1] != 'prepare'
-                if after[:1] == ['sample_fetch']:
-                    due += 1
-                    assert after == ['sample_fetch', 'accept']
-                    assert set(spans[i + 2].args) == {
-                        'req', 'emitted', 'retired'}
-                else:
-                    # an inner chunk of a prompt: nothing is fetched
-                    inner += 1
-                    assert after[:1] in ([], ['compiled_step'],
-                                         ['prepare'])
-                continue
-            assert names[i - 1] == 'prepare'
-            assert after == ['sample_fetch', 'accept']
-            assert set(spans[i + 2].args) == {'emitted', 'retired'}
-            assert set(s.args) == ({'shape', 'batch', 'k'}
-                                   if shape == 'fused'
-                                   else {'shape', 'batch'})
-            assert 1 <= s.args['batch'] <= 3
-            assert shape != 'fused' or s.args['k'] == 4
-        assert set(shapes) == {'prefill', 'decode'} | (
+            if s.name == 'serve::compiled_step':
+                shape = s.args['shape']
+                shapes.append(shape)
+                assert names[i - 1] == 'prepare'
+                assert 0 <= s.args['batch'] <= 3
+                if shape == 'mixed':
+                    assert set(s.args) == {'shape', 'batch', 'prefill_rows'}
+                    assert 1 <= s.args['prefill_rows'] <= 2
+                    # only a step's first carries decode rows
+                    assert not (queue and s.args['batch'])
+                    queue.append(s)
+                    continue
+                assert not queue
+                assert names[i + 1:i + 3] == ['sample_fetch', 'accept']
+                assert set(spans[i + 2].args) == {'emitted', 'retired'}
+                assert set(s.args) == ({'shape', 'batch', 'k'}
+                                       if shape == 'fused'
+                                       else {'shape', 'batch'})
+                assert s.args['batch'] >= 1
+                assert shape != 'fused' or s.args['k'] == 4
+            elif s.name == 'serve::accept' and 'chunks' in s.args:
+                # the turn of the oldest queued mixed dispatch
+                d = queue.pop(0)
+                assert s.args['chunks'] == d.args['prefill_rows']
+                assert set(s.args) == {'chunks', 'emitted', 'retired'}
+                before = names[i - 2:i] if d.args['batch'] else \
+                    names[i - 1:i]
+                if d.args['batch']:
+                    assert before == ['sample_fetch', 'accept']
+                    assert set(spans[i - 1].args) == {'emitted',
+                                                      'retired'}
+                elif before != ['sample_fetch']:
+                    # inner chunks beside an idle decode group
+                    unfetched += 1
+                    assert s.args['emitted'] == 0
+        assert not queue
+        assert set(shapes) == {'mixed', 'decode'} | (
             {'verify'} if 'spec_k' in knobs else set()) | (
             {'fused'} if 'fused_k' in knobs else set())
         chunks = [ev for evs in events.values() for ev in evs
                   if ev['event'] == 'prefill_chunk']
-        assert due == sum(1 for ev in chunks if ev.get('sampled'))
-        assert inner == len(chunks) - due and inner > 0
+        assert sum(s.args['prefill_rows'] for s in spans
+                   if s.name == 'serve::compiled_step'
+                   and s.args['shape'] == 'mixed') == len(chunks)
+        assert unfetched > 0
         assert names.count('sample_fetch') == fetches == (
-            len(shapes) - inner)
+            len(shapes) - unfetched)
         assert sum(s.args['emitted'] for s in spans
                    if s.name == 'serve::accept') == sum(
             len(r.generated) for r in reqs)
+        assert st['dispatches_total'] == len(shapes)
+        assert 1.0 <= st['prefill_rows_per_dispatch'] <= 2.0
+        assert 1.0 <= st['dispatches_per_step'] < 2.0
+        assert 0.0 < st['padded_prefill_token_share'] < 1.0
 
     def test_fused_window_feeds_the_ledger_what_serial_steps_do(
             self, tiny_lm):

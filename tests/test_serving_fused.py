@@ -199,8 +199,12 @@ class TestFusedLedgerAndSyncs:
         prefill_fetches = generated - st['decode_tokens_total']
         serial_iters = (st['decode_steps_total']
                         - st['fused_iterations_total'])
-        assert n == (prefill_fetches + serial_iters
-                     + st['fused_windows_total']), (n, st)
+        # a first token costs a fetch of its own only where its chunk
+        # rode the mixed program with no decode row beside it
+        decode_fetches = serial_iters + st['fused_windows_total']
+        assert decode_fetches <= n <= min(
+            decode_fetches + prefill_fetches, st['dispatches_total']), \
+            (n, st)
         # and the budget actually shrank vs one-fetch-per-token
         assert n < prefill_fetches + st['decode_steps_total']
 

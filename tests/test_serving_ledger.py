@@ -257,8 +257,9 @@ class TestPagedLivePageShare:
     def test_share_matches_the_requests_context_lengths(self, tiny_lm,
                                                         fused_k):
         # two requests admitted together, each prompt one chunk: the
-        # [1, C] prefill program runs once per request over its prompt,
-        # then the [B, 1] decode program (or the fused window's scan)
+        # mixed program (its prefill group's two rows, its decode group
+        # idle) runs once over both prompts, then the [B, 1] decode
+        # program (or the fused window's scan)
         # runs while either still owes tokens; request r's row holds
         # context L_r + t at its t-th decode iteration
         ps, B, P = 8, 4, 6
@@ -278,7 +279,9 @@ class TestPagedLivePageShare:
         # every dispatched program carried whole tables: rows x P,
         # once per scan iteration of a fused window
         assert roof['paged_page_slots'] % P == 0
-        decode_programs = (roof['paged_page_slots'] - 2 * P) // (B * P)
+        assert eng.stats()['prefill_rows_per_dispatch'] == 2
+        decode_programs = (roof['paged_page_slots']
+                           - (B + 2) * P) // (B * P)
         if fused_k == 1:
             assert decode_programs == max(new) - 1
         assert max(new) - 1 <= decode_programs <= fused_k * max(new)
@@ -429,7 +432,9 @@ class TestEngineGoodput:
                                    monkeypatch):
         # the PR-6 sync-count harness: the full goodput/ledger/roofline
         # observatory must not add a single host fetch — the budget
-        # stays exactly one per token-yielding step
+        # stays one per dispatch that yields a token: every decode
+        # step's, and a first token's only where its chunk rode with no
+        # decode row
         counts = [0]
         real = engine_mod._host_fetch
 
@@ -457,8 +462,9 @@ class TestEngineGoodput:
         generated = sum(len(o) - len(p)
                         for o, p in zip(outs, mixed_prompts))
         prefill_fetches = generated - st['decode_tokens_total']
-        assert n_gen == st['decode_steps_total'] + prefill_fetches, \
-            (n_gen, st)
+        assert st['decode_steps_total'] <= n_gen <= min(
+            st['decode_steps_total'] + prefill_fetches,
+            st['dispatches_total']), (n_gen, st)
 
 
 # ---------------------------------------------------------------------------

@@ -444,9 +444,10 @@ class TestEngineDegradation:
         assert [h['to'] for h in ups] == [1, 2, 3], \
             eng.ladder_history()                # all three, in order
         assert eng.pool._evict_weights is not None  # stage-3 lever on
-        # stage-2 prefill shrink compiled the halved chunk shape
-        assert any(k[1] == 8 for k in eng._step_fns
-                   if k[0] == 1), sorted(eng._step_fns)
+        # stage-2 prefill shrink compiled a mixed program of the
+        # halved chunk beside the configured one's
+        assert {k[3] for k in eng._step_fns if k[0] == 'mixed'} == \
+            {16, 8}, sorted(map(str, eng._step_fns))
         # every transition is a trace event with stage + pressure
         ev = _events(eng, 'degrade_stage')
         assert len(ev) == len(eng.ladder_history())
@@ -540,11 +541,12 @@ class TestNoTenantIdentity:
         assert eng._tenants is None and eng._ladder is None
         outs = eng.generate(prompts, max_new_tokens=6, top_k=0)
         assert outs == seq                      # greedy token identity
-        # exactly the two untenanted compiled shapes: (1, chunk)
-        # prefill and (B, 1) decode — no ladder shapes, no extras
-        assert sorted(eng._step_fns) == [(1, 8, False, False),
-                                         (3, 1, False, False)], \
-            sorted(eng._step_fns)
+        # exactly the two untenanted compiled shapes: the mixed step
+        # (B decode rows beside two chunk rows) and (B, 1) decode — no
+        # ladder shapes, no extras
+        assert set(eng._step_fns) == {('mixed', 3, 2, 8, False),
+                                      (3, 1, False, False)}, \
+            sorted(map(str, eng._step_fns))
         st = eng.stats()
         assert st['quota_deferrals_total'] == 0
         assert st['degrade_stage'] == 0

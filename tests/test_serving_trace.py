@@ -428,12 +428,15 @@ class TestSyncBudget:
             tiny_lm, mixed_prompts, True, monkeypatch)
         assert outs_on == outs_off          # identical serving results
         assert n_on == n_off, (n_on, n_off)
-        # and the budget is exactly one fetch per token-yielding step:
-        # each batched decode step fetches once (len(active) tokens);
-        # each completed prefill fetches its first token — i.e. every
-        # generated token NOT accounted to a decode step
+        # and the budget is one fetch per token-yielding dispatch:
+        # each batched decode step fetches once (len(active) tokens,
+        # and the first token of a prompt whose last chunk rode the
+        # same program); a completed prefill fetches its first token
+        # itself only where no decode row rode beside it — at most
+        # every generated token NOT accounted to a decode step
         generated = sum(len(o) - len(p)
                         for o, p in zip(outs_on, mixed_prompts))
         prefill_fetches = generated - st_on['decode_tokens_total']
-        assert n_on == st_on['decode_steps_total'] + prefill_fetches, \
-            (n_on, st_on)
+        assert st_on['decode_steps_total'] <= n_on <= min(
+            st_on['decode_steps_total'] + prefill_fetches,
+            st_on['dispatches_total']), (n_on, st_on)

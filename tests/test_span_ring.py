@@ -300,8 +300,8 @@ def test_lowering_and_compiling_are_counted_apart():
 # ---------------------------------------------------------------------------
 # serving engine spans
 # ---------------------------------------------------------------------------
-STEP_CHILDREN = {'serve::schedule', 'serve::prefill_chunk', 'serve::decode',
-                 'serve::telemetry'}
+STEP_CHILDREN = {'serve::schedule', 'serve::prefill_chunk',
+                 'serve::dispatch', 'serve::telemetry'}
 
 
 @pytest.fixture(scope='module')
@@ -375,15 +375,15 @@ class TestServingSpans:
     def test_device_spans_sit_under_their_phase(self, served):
         shape, spans, _, _ = served
         by_id = {s.id: s for s in spans}
-        phases = {'serve::prefill_chunk', 'serve::decode'}
         for name in ('serve::prepare', 'serve::compiled_step',
                      'serve::sample_fetch', 'serve::accept'):
             got = [s for s in spans if s.name == name]
             assert got, name
-            assert {by_id[s.parent].name for s in got} <= phases, name
+            assert {by_id[s.parent].name for s in got} == {
+                'serve::dispatch'}, name
         shapes = {s.args['shape'] for s in spans
                   if s.name == 'serve::compiled_step'}
-        assert 'prefill' in shapes
+        assert 'mixed' in shapes
         assert {'serial': 'decode', 'fused': 'fused',
                 'spec': 'verify'}[shape] in shapes or shape == 'spec'
 
@@ -393,12 +393,15 @@ class TestServingSpans:
         assert sum(s.args['emitted'] for s in accepts) == sum(
             len(r.generated) for r in reqs)
         assert sum(s.args['retired'] for s in accepts) == len(reqs)
-        # an accept begins where its fetch returned
-        fetch_end = {s.start_ns + s.dur_ns: s for s in spans
-                     if s.name == 'serve::sample_fetch'}
+        # an accept begins where its dispatch's fetch returned (a mixed
+        # dispatch's second, the chunks', where the decode rows' ended;
+        # one after inner chunks alone where the program was queued)
+        ends = sorted(s.start_ns + s.dur_ns for s in spans
+                      if s.name in ('serve::sample_fetch', 'serve::accept',
+                                    'serve::compiled_step'))
         for a in accepts:
-            nearest = min(fetch_end, key=lambda t: abs(t - a.start_ns))
-            assert 0 <= a.start_ns - nearest < 5e6
+            before = max(t for t in ends if t <= a.start_ns)
+            assert a.start_ns - before < 5e6
 
     def test_every_request_span_carries_its_request(self, served):
         _, spans, reqs, _ = served

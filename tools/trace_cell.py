@@ -85,11 +85,17 @@ def main(argv=None):
     # with it: keep the ledger's roofline block (kv_read_tokens_mean,
     # paged_live_page_share) as it stood at shutdown
     from paddle_tpu.serving import engine as serving_engine
-    rooflines = []
+    rooflines, mixed = [], []
     shutdown = serving_engine.ServingEngine.shutdown
 
     def shutdown_keeping_roofline(self, *a, **kw):
         rooflines.append(self.ledger.roofline())
+        st = self.stats()
+        mixed.append(dict(
+            {k: st[k] for k in ('dispatches_per_step',
+                                'prefill_rows_per_dispatch',
+                                'padded_prefill_token_share')},
+            prefill_rows=self._prefill_rows))
         return shutdown(self, *a, **kw)
     serving_engine.ServingEngine.shutdown = shutdown_keeping_roofline
     record = runner.run(ctx)
@@ -140,6 +146,11 @@ def main(argv=None):
                 'paged_live_page_share', 'kv_read_tokens_window',
                 'kv_read_tokens_full', 'moe_load_max_over_mean')
             if k in rooflines[-1])
+    if mixed:
+        # how the mixed step engaged, warm phase and window together
+        summary['mixed_step'] = mixed[-1]
+        text += '\n\nmixed step at shutdown: ' + ', '.join(
+            f'{k} {v}' for k, v in mixed[-1].items())
     print(text, flush=True)
     base = os.path.join(args.out, cell['name'])
     with open(base + '.summary.json', 'w') as f:
