@@ -67,6 +67,16 @@ FULL = dict(
                 page_size=16, pages=528, batch=64, chunk=512,
                 contexts=(16, 2047, 2049, 8448),
                 experts=128, top_k=8, hidden=2048, width=1024),
+    # kernels of the state-space, differential-attention server cell
+    # (benchmarks/configs/phi4-mini-flash.json): 40 query sub-heads on
+    # 20 key sub-heads of 64, pairs of them on one 128-wide value,
+    # window 512 over 176-page tables, contexts on both sides of the
+    # window and at the table's end; the selective scan over 5120
+    # channels x 16 states at the decode rows' and the chunk's widths
+    hybrid=dict(heads=40, kv_heads=20, head_dim=64, window=512,
+                page_size=16, pages=176, batch=64, chunk=128,
+                contexts=(16, 511, 513, 2816), prefill_rows=2,
+                channels=5120, states=16),
 )
 
 # Tolerances, as max|got - ref| / max|ref| over a tensor.
@@ -380,6 +390,68 @@ def phase_kernels(size):
 
         grouped(sp['batch'] * sp['top_k'], 'decode')
         grouped(sp['chunk'] * sp['top_k'], 'chunk')
+
+    # -- differential paged attention, and the selective scan --------------
+    hy = size.get('hybrid')
+    if hy:
+        from paddle_tpu.ops import ssm
+        from paddle_tpu.ops.pallas import selective_scan as sscan
+        Hq, Hk, Dk, ps = (hy[k] for k in ('heads', 'kv_heads', 'head_dim',
+                                           'page_size'))
+        pool = hy['batch'] * hy['pages'] // 8 + 3
+
+        def diff_paged(Bq, T, ctx, window):
+            """As grouped_paged, with pairs of key sub-heads on one
+            value block: [Bq, T, Hq * 2 * Dk] against the dense route."""
+            pt = rng.randint(0, pool, (Bq, hy['pages'])).astype(np.int32)
+            args = (rand((Bq, T, Hq * Dk)), rand((pool, ps, Hk * Dk)),
+                    rand((pool, ps, Hk * Dk)), jnp.asarray(pt),
+                    jnp.full((Bq,), ctx, jnp.int32),
+                    jnp.full((Bq,), min(T, ctx), jnp.int32))
+
+            def call(attention):
+                return lambda *a: attention(
+                    *a, num_heads=Hq, head_dim=Dk, num_kv_heads=Hk,
+                    window=window, diff=2)
+            got = jax.jit(call(pa.ragged_paged_attention_pallas))(*args)
+            ref = ref_call(call(pa.ragged_paged_attention_dense), *args)
+            live = (np.arange(T) < min(T, ctx))[None, :, None]
+            record(f'paged_attention_diff B={Bq} T={T} {Hq}q/{Hk}kv '
+                   f'window={window} ctx={ctx}',
+                   np.where(live, np.asarray(got, np.float32), 0),
+                   np.where(live, np.asarray(ref, np.float32), 0), TOL_BF16)
+
+        for ctx in hy['contexts']:
+            for window in (hy['window'], None):
+                diff_paged(hy['batch'], 1, ctx, window)
+                diff_paged(hy['prefill_rows'], hy['chunk'], ctx, window)
+
+        def scan_case(R, T):
+            """R rows of T positions, ragged: a fresh row, an idle row on
+            the spare slot, the rest on their own slots of R + 1."""
+            dn, N = hy['channels'], hy['states']
+            f32 = jnp.float32
+            ql = rng.randint(1, T + 1, R).astype(np.int32)
+            ql[0] = T
+            slots = rng.permutation(R).astype(np.int32)
+            fresh = np.zeros(R, bool)
+            fresh[0] = True
+            if R > 1:
+                ql[-1], slots[-1] = 0, R
+            args = (rand((R, T, dn), f32),
+                    jax.nn.softplus(rand((R, T, dn), f32) - 3.0),
+                    rand((R, T, N), f32), rand((R, T, N), f32),
+                    -jnp.exp(rand((N, dn), f32)), rand((dn,), f32),
+                    rand((R + 1, N, dn), f32), jnp.asarray(slots),
+                    jnp.asarray(ql), jnp.asarray(fresh))
+            ref = ref_call(ssm.selective_scan_ref, *args)
+            got = jax.jit(sscan.selective_scan_pallas)(*args)
+            # the spare slot holds what idle rows left there
+            record(f'selective_scan R={R} T={T}',
+                   (got[0], got[1][:R]), (ref[0], ref[1][:R]), TOL_F32)
+
+        scan_case(hy['batch'], 1)
+        scan_case(hy['prefill_rows'], hy['chunk'])
 
     # -- fused optimizer step + grad stats vs core.bucketing.shard_update --
     n = size['opt_elems']

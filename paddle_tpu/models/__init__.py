@@ -11,3 +11,4 @@ from .gpt import (GPTConfig, GPTModel, GPTForCausalLM,
 from .bert import BertConfig, BertModel, BertForPretraining
 from .deepfm import DeepFM, deepfm_loss  # noqa: F401,E402
 from .afmoe import AfmoeConfig, AfmoeForCausalLM  # noqa: F401,E402
+from .phi4flash import Phi4FlashConfig, Phi4FlashForCausalLM  # noqa: F401,E402

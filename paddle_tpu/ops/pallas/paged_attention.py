@@ -73,6 +73,20 @@ scheduled) and the mask drops older keys; the Mosaic call is then
 named `paged_attention_window`. With num_kv_heads == num_heads and no
 window the body is what it was. int8 pools take neither.
 
+Differential attention (`diff`, static; arXiv:2410.05258 as SambaY
+lays it out): the stored kv heads are SUB-heads of width D, and `diff`
+neighbouring key sub-heads share ONE value block of width diff * D (v_g
+= [v_g,1 | v_g,2]); query sub-head (p, s) scores against key sub-head
+s of its kv block and multiplies the whole block, so the output is
+diff * D wide a query sub-head (the caller takes the difference and
+the norm). The kernel sees it as kv heads of width diff * D whose
+queries are zero outside their own sub-head's D columns: the score
+product contracts over the block and the zeros select the sub-head, the
+p.v product is diff * D wide, the scale stays 1/sqrt(D). No second read
+of K, no second body; the Mosaic call is named `paged_attention_diff`
+(`..._diff_window` with a window). With `diff` absent the body is what
+it was.
+
 Layouts:
   q           [B, T, Hq*D]  new-token queries, right-padded to T per row
   k_pages     [N_pages, page_size, H*D]   the pool's device arrays (H:
@@ -179,14 +193,17 @@ def _wave_pages(page_size, HD, kv_dtype, rows, q_dtype, P, num_heads,
 
 def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, v_hbm, *rest,
                          page_size, num_heads, head_dim, wave_pages,
-                         batched, quantized=False, group=1, window=None):
+                         batched, quantized=False, group=1, window=None,
+                         diff=1):
     """One batch row: a loop over the row's OWN live pages.
 
     `num_heads` counts the pool's (kv) heads; `group` query heads
     share each of them (1: as many kv heads as query heads). `window`
     (static; None: every key) bounds a query at position p to the keys
     at p - window + 1 .. p: the row's loop then opens at the page of
-    its first query's oldest key, and no older page is copied.
+    its first query's oldest key, and no older page is copied. `diff`
+    (static): `head_dim` holds that many key sub-heads side by side,
+    each query row zero outside its own, so only the scale differs.
 
     pt_ref/ln_ref are scalar-prefetched (page tables, [B, 2] lens);
     k_hbm / v_hbm are the whole pools, left in HBM. Wave w copies pages
@@ -220,7 +237,7 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, v_hbm, *rest,
     R = q_ref.shape[0]
     W, ps, H, D = wave_pages, page_size, num_heads, head_dim
     keys = W * ps
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D // diff)
 
     def first_page(row):
         """The page of the oldest key the row's first query reads; None
@@ -365,11 +382,12 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, page_tables,
                                   seq_lens, q_lens, *, num_heads,
                                   head_dim, k_scales=None,
                                   v_scales=None, interpret=None,
-                                  num_kv_heads=None, window=None):
+                                  num_kv_heads=None, window=None, diff=1):
     """Pallas route (interpret-mode on CPU). See module docstring for
     layouts; k_scales/v_scales engage the int8 dequantizing body;
-    `num_kv_heads` (default: num_heads) and `window` (default: every
-    key) are static.
+    `num_kv_heads` (default: num_heads), `window` (default: every key)
+    and `diff` (key sub-heads that share one value block; then the
+    output is [B, T, num_heads * diff * head_dim]) are static.
 
     What the shapes decide is decided here; the call itself is one
     jitted function, so a model's layers — the same shapes 24 times in
@@ -382,11 +400,24 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, page_tables,
         raise ValueError(f'{num_heads} query heads do not divide over '
                          f'{kv_heads} kv heads')
     group = num_heads // kv_heads
-    if k_scales is not None and (group > 1 or window is not None):
+    if k_scales is not None and (group > 1 or window is not None
+                                 or diff > 1):
         raise NotImplementedError(
-            'int8 pages with kv groups or a window: the scale blocks '
-            'are laid out for one kv head a query head and read from '
-            'the table\'s slot 0')
+            'int8 pages with kv groups, a window or value sharing: the '
+            'scale blocks are laid out for one kv head a query head and '
+            'read from the table\'s slot 0')
+    if diff > 1:
+        # kv heads of width diff * D; a query sub-head keeps its own
+        # sub-head's D columns of it and zeros elsewhere
+        if kv_heads % diff:
+            raise ValueError(f'{kv_heads} key sub-heads do not pair up '
+                             f'by {diff}')
+        B = q.shape[0]
+        own = jnp.eye(diff, dtype=bool)[:, :, None]     # [s, s', 1]
+        q = jnp.where(own, q.reshape(B, T, -1, diff, 1, head_dim), 0) \
+            .reshape(B, T, num_heads * diff * head_dim)
+        kv_heads, head_dim, group = kv_heads // diff, diff * head_dim, \
+            group * diff
     batched = T * num_heads <= _BATCHED_ROWS
     W, need = _wave_pages(
         k_pages.shape[1], k_pages.shape[2], k_pages.dtype,
@@ -396,15 +427,17 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, page_tables,
         q, k_pages, v_pages, page_tables, seq_lens, q_lens, k_scales,
         v_scales, num_heads=kv_heads, head_dim=head_dim, wave_pages=W,
         batched=batched, vmem_bytes=need, group=group, window=window,
+        diff=diff,
         interpret=_interpret() if interpret is None else interpret)
 
 
 @functools.partial(jax.jit, static_argnames=(
     'num_heads', 'head_dim', 'wave_pages', 'batched', 'vmem_bytes',
-    'interpret', 'group', 'window'))
+    'interpret', 'group', 'window', 'diff'))
 def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
                 k_scales, v_scales, *, num_heads, head_dim, wave_pages,
-                batched, vmem_bytes, interpret, group=1, window=None):
+                batched, vmem_bytes, interpret, group=1, window=None,
+                diff=1):
     """The block-diagonal q (when `batched`; else, with kv groups, the
     query heads of one kv head stacked as rows), the Mosaic call and
     the diagonal blocks of its output, as one jitted function of the
@@ -472,7 +505,7 @@ def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
     kernel = functools.partial(
         _ragged_paged_kernel, page_size=ps, num_heads=H,
         head_dim=head_dim, wave_pages=W, batched=batched,
-        quantized=quantized, group=group, window=window)
+        quantized=quantized, group=group, window=window, diff=diff)
     out = scaffold.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -482,10 +515,11 @@ def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
             vmem_limit_bytes=int(min(max(vmem_bytes, 16 * 2 ** 20),
                                      scaffold.VMEM_CAP_BYTES))),
         interpret=interpret,
-        # a call with a window has its own row in the profile (the
-        # `paged_attention*` readers sum both)
-        name='paged_attention' if window is None
-        else 'paged_attention_window',
+        # a call with a window, or with shared value blocks, has its
+        # own row in the profile (the `paged_attention*` readers sum
+        # them all)
+        name='paged_attention' + ('_diff' if diff > 1 else '')
+        + ('' if window is None else '_window'),
     )(*inputs)
     if batched and group == 1:
         # each head's output is its own diagonal block of the rows
@@ -512,7 +546,7 @@ def _dequant_gathered(pages, scales, H):
 def ragged_paged_attention_dense(q, k_pages, v_pages, page_tables,
                                  seq_lens, q_lens, *, num_heads,
                                  head_dim, k_scales=None, v_scales=None,
-                                 num_kv_heads=None, window=None):
+                                 num_kv_heads=None, window=None, diff=1):
     """Dense lax fallback: gather each row's pages into a [B, P*ps, H*D]
     context and run masked attention. O(B * pages_per_seq * page_size)
     memory — correct everywhere (the CPU serving path and the numerics
@@ -547,8 +581,15 @@ def ragged_paged_attention_dense(q, k_pages, v_pages, page_tables,
     for h in range(num_heads):
         qh = q[:, :, h * D:(h + 1) * D].astype(jnp.float32) * scale
         j = h // group          # the kv head this query head reads
+        if diff > 1:
+            # query sub-head (p, s) reads key sub-head s of kv block
+            # p // group and the block's whole diff * D of values
+            blk = h // diff // group
+            j = blk * diff + h % diff
+            vh = v[:, :, blk * diff * D:(blk + 1) * diff * D]
+        else:
+            vh = v[:, :, j * D:(j + 1) * D]
         kh = k[:, :, j * D:(j + 1) * D]
-        vh = v[:, :, j * D:(j + 1) * D]
         s = jnp.einsum('btd,bkd->btk', qh, kh,
                        preferred_element_type=jnp.float32)
         s = jnp.where(valid, s, NEG_INF)
@@ -570,12 +611,14 @@ def use_pallas_route():
 def ragged_paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
                            q_lens=None, *, num_heads, head_dim,
                            k_scales=None, v_scales=None,
-                           num_kv_heads=None, window=None):
+                           num_kv_heads=None, window=None, diff=1):
     """Auto-routed entry (array-level; used inside the serving engine's
     jitted steps). Pass k_scales/v_scales for int8 pages; num_kv_heads
     where fewer kv heads than query heads are stored (the pool's width
     is num_kv_heads * head_dim), window where a query reads only its
-    last `window` keys."""
+    last `window` keys, diff where that many neighbouring key sub-heads
+    share one value block (the output is then diff * head_dim wide a
+    query sub-head)."""
     if q_lens is None:
         q_lens = jnp.full((q.shape[0],), q.shape[1], jnp.int32)
     fn = (ragged_paged_attention_pallas if use_pallas_route()
@@ -583,7 +626,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
     return fn(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
               num_heads=num_heads, head_dim=head_dim,
               k_scales=k_scales, v_scales=v_scales,
-              num_kv_heads=num_kv_heads, window=window)
+              num_kv_heads=num_kv_heads, window=window, diff=diff)
 
 
 def _flat_slots(page_tables, seq_lens, q_lens, T, N, ps):

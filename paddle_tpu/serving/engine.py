@@ -359,11 +359,20 @@ class ServingEngine:
             raise ValueError(
                 "every layer must store the same kv heads and head_dim "
                 f"(one page table serves them all), got {spec}")
-        # {window: layers that have it}; a layer without one reads a
-        # row's whole context
+        if any(s.reads is not None and spec[s.reads].reads is not None
+               for s in spec):
+            raise ValueError(
+                f"a layer that reads another's plane must name its "
+                f"OWNER, got {spec}")
+        # {window: attending layers that have it}; a layer without one
+        # reads a row's whole context. Readers are the attending
+        # layers, planes those of them that own their pages
         self._windows = collections.Counter(
             s.window for s in spec if s.window is not None)
-        self._kv_layers = len(spec)
+        self._kv_readers = len(spec)
+        self._kv_full = len(spec) - sum(self._windows.values())
+        self._kv_planes = sum(s.reads is None for s in spec)
+        self._attn_kv_tokens = 0
         dtype = config.kv_dtype or model.lm_head_weight().dtype
         self.mesh = mesh
         self._mp = int(mesh.shape['mp']) if (
@@ -375,6 +384,36 @@ class ServingEngine:
             ('int8_kv', _np_dtype(dtype) == np.int8),
             ('int8_weights', config.weight_dtype is not None),
             ('mp', self._mp > 1)) if on}
+        # recurrent state (protocol.py state_spec): per-request arrays
+        # of a fixed size beside the pages. Nothing keeps the state of
+        # an earlier position, so whatever resumes a request anywhere
+        # but at its start or its end is refused here, by name
+        state_spec = list(model.state_spec()) \
+            if hasattr(model, 'state_spec') else []
+        self._stateful = bool(state_spec)
+        self._state_layers = int(getattr(model, 'state_layers', 0))
+        self._ssm_rows = self._ssm_tokens = 0
+        if self._stateful:
+            resumes = [what for what, on in (
+                ('prefix_cache=True (a hit resumes past its prefix)',
+                 config.prefix_cache),
+                ('a host tier (a resurrected prefix resumes past it)',
+                 config.host_tier_pages > 0),
+                ('fused_k > 1 (a window cut short resumes inside it)',
+                 config.fused_k > 1),
+                ('spec_k > 0 (a rejected draft resumes before it)',
+                 config.spec_k > 0)) if on]
+            if resumes:
+                raise NotImplementedError(
+                    f"{type(model).__name__} holds recurrent state, and "
+                    f"no state is kept at the position a request would "
+                    f"resume from: {'; '.join(resumes)}")
+            unsplit = needs & {'int8_kv', 'int8_weights', 'mp'}
+            if unsplit:
+                raise NotImplementedError(
+                    f"{type(model).__name__} holds recurrent state, "
+                    f"which has no int8 form and no mp split: "
+                    f"{sorted(unsplit)}")
         lacking = needs - set(model.paged_routes)
         if lacking:
             raise NotImplementedError(
@@ -415,14 +454,19 @@ class ServingEngine:
         # on the trailing heads*hd axis so each shard owns its local
         # heads' pages — the same layout the column-sharded qkv writes
         self.pool = KVPagePool(
-            num_pages, ps, num_layers=mcfg.num_layers,
+            num_pages, ps, num_layers=self._kv_planes,
             num_heads=spec[0].num_kv_heads, head_dim=spec[0].head_dim,
-            dtype=dtype, prefix_cache=config.prefix_cache)
+            dtype=dtype, prefix_cache=config.prefix_cache,
+            state_spec=state_spec, state_slots=config.max_batch_size)
         self._kv_sharding = None
         if self._mp > 1:
             from jax.sharding import NamedSharding, PartitionSpec as P
             self._kv_sharding = NamedSharding(mesh, P(None, None, 'mp'))
         self.pool.materialize(sharding=self._kv_sharding)
+        if self._stateful:
+            with RecordEvent('serve::state_alloc', event_type='serve',
+                             bytes=self.pool.state_bytes()):
+                self.pool.materialize_state()
         # host-RAM KV tier (ISSUE 20): pinned host buffers + one
         # background transfer thread under the pool. Spills are
         # proactive (watermark in _observe_spill_pressure) or the
@@ -1203,6 +1247,12 @@ class ServingEngine:
         Returns False when no slot is free (caller keeps it pending).
         The streamed pages join this pool's prefix index so decode-side
         siblings share them like locally-prefilled ones."""
+        if self._stateful:
+            raise NotImplementedError(
+                f"{type(self.model).__name__} holds recurrent state, and "
+                f"no state is kept at the position a request would "
+                f"resume from: adopt_request (pages stream between "
+                f"engines, the state of the prompt's end does not)")
         if self.scheduler.adopt(req) is None:
             return False
         req.prefilled = len(req.tokens)
@@ -1331,9 +1381,12 @@ class ServingEngine:
         weights de-quantised, the mp region entered), the forward over
         the paged pool and the pick of each row's next id, donation of
         the pool, the shard_map specs, and the eval()/no_grad call.
-        Every program is `step(params, kv, moe, *host operands) ->
-        (ids, kv, moe)`; `moe` (the experts' counters) is None wherever
-        the model or the route carries none, and then no operand."""
+        Every program is `step(params, kv, state, moe, *host operands)
+        -> (ids, kv, state, moe)`; `moe` (the experts' counters) is
+        None wherever the model or the route carries none, `state` (the
+        recurrent-state arrays, with each row's `slots` the last host
+        operand) wherever the model declares none — and then no
+        operand, and nothing of it in the traced program."""
         jax, jnp = self._jax, self._jnp
         model = self.model
         from ..core.tensor import Tensor
@@ -1390,20 +1443,26 @@ class ServingEngine:
             g = jnp.moveaxis(g, 0, -2)              # [..., mp, V/mp]
             return g.reshape(lg.shape[:-1] + (lg.shape[-1] * mp,))
 
-        def forward_pick(kv, moe, tokens, page_tables, seq_lens, q_lens,
-                         key, ords, temps, top_ks):
+        def forward_pick(kv, state, moe, tokens, page_tables, seq_lens,
+                         q_lens, key, ords, temps, top_ks, slots=None):
             """The dispatch's query tokens ([N], `layout`'s rows end to
             end; the per-row operands [R]) through the model over the
             paged pool, then each row's next id: the hidden state of its
             last query -> logits -> sampled or greedy. -> (ids, kv,
-            moe)."""
-            rows = RowGroups(layout, page_tables, seq_lens, q_lens)
+            state, moe)."""
+            rows = RowGroups(layout, page_tables, seq_lens, q_lens, slots)
             # int8 pools carry (k, v, k_scales, v_scales) per layer;
             # dense pools (k, v) — forward_paged keys off the arity
             cts = [tuple(Tensor(a) for a in c) for c in kv]
-            h, new_kv, moe = model.forward_paged(
+            # a model with recurrent state takes it and hands it back
+            # as a fourth result; the others are called as they were
+            stateful = {} if state is None else {
+                'state': [Tensor(a) for a in state]}
+            h, new_kv, moe, *new_state = model.forward_paged(
                 Tensor(tokens[None, :]), Tensor(rows.positions(max_pos)),
-                cts, rows, moe_counters=moe)
+                cts, rows, moe_counters=moe, **stateful)
+            if new_state:
+                state = [t.data for t in new_state[0]]
             new_kv = [tuple(t.data for t in c) for c in new_kv]
             w = model.lm_head_weight()
             if verify:
@@ -1422,7 +1481,7 @@ class ServingEngine:
                         rows.last(logits_all[None]).astype(jnp.float32),
                         key, ords, seq_lens, temps, top_ks)
                     nxt = jnp.concatenate([nxt, samp[:, None]], 1)
-                return nxt, new_kv, moe
+                return nxt, new_kv, state, moe
             logits = full_logits(jnp.einsum(
                 'bh,vh->bv', rows.last(h.data), w.data,
                 preferred_element_type=jnp.float32))
@@ -1431,15 +1490,16 @@ class ServingEngine:
                                      ords, seq_lens, temps, top_ks)
             else:
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return nxt, new_kv, moe
+            return nxt, new_kv, state, moe
 
         if not fused:
-            def step(params, kv, moe, tokens, page_tables, seq_lens,
-                     q_lens, key, ords, temps, top_ks):
+            def step(params, kv, state, moe, tokens, page_tables,
+                     seq_lens, q_lens, key, ords, temps, top_ks,
+                     slots=None):
                 with bound(params):
-                    nxt, kv, moe = forward_pick(
-                        kv, moe, tokens, page_tables, seq_lens, q_lens,
-                        key, ords, temps, top_ks)
+                    nxt, kv, state, moe = forward_pick(
+                        kv, state, moe, tokens, page_tables, seq_lens,
+                        q_lens, key, ords, temps, top_ks, slots)
                     if moe is not None:
                         # the experts' rows of this call and their
                         # counters ride behind the sampled ids: still
@@ -1447,10 +1507,11 @@ class ServingEngine:
                         counts, moe = moe
                         nxt = jnp.concatenate(
                             [nxt, counts.reshape(-1), moe.reshape(-1)])
-                return nxt, kv, moe
+                return nxt, kv, state, moe
         else:
-            def step(params, kv, moe, tokens, page_tables, seq_lens,
-                     ords, rems, eos_ids, live, key, temps, top_ks):
+            def step(params, kv, state, moe, tokens, page_tables,
+                     seq_lens, ords, rems, eos_ids, live, key, temps,
+                     top_ks):
                 # The carry is (kv pool, last token, seq_len, done-mask,
                 # emitted count) per row; each scan body is the [B, 1]
                 # decode step by call — same positions, same sampling
@@ -1460,15 +1521,16 @@ class ServingEngine:
                 # window flip `done` and ride the remaining iterations
                 # with q_len=0 (the idle-slot mechanism: KV writes
                 # dropped by the scatter, outputs ignored by the host).
-                # The window carries no experts' counters.
+                # The window carries no experts' counters and no
+                # recurrent state (refused at construction).
                 with bound(params):
                     def body(carry, _):
                         kv_c, tok, seq, done, emitted = carry
                         alive = ~done
                         q = jnp.where(alive, 1, 0).astype(jnp.int32)
-                        nxt, new_kv, _ = forward_pick(
-                            kv_c, None, tok, page_tables, seq, q, key,
-                            ords, temps, top_ks)
+                        nxt, new_kv, _, _ = forward_pick(
+                            kv_c, None, None, tok, page_tables, seq, q,
+                            key, ords, temps, top_ks)
                         # serial-order accounting: the emitted token
                         # counts BEFORE the eos/budget check (append-
                         # then-check), so eos-in-window truncates
@@ -1484,11 +1546,12 @@ class ServingEngine:
                               jnp.zeros((B,), jnp.int32))
                     (kv, _t, _s, _d, _e), ys = jax.lax.scan(
                         body, carry0, xs=None, length=K)
-                return jnp.moveaxis(ys, 0, 1), kv, moe      # [B, K]
+                return jnp.moveaxis(ys, 0, 1), kv, state, moe   # [B, K]
 
-        # donation updates the pool pages in place; CPU jax has no
-        # donation support and would warn every call
-        donate = (1,) if jax.default_backend() != 'cpu' else ()
+        # donation updates the pool pages (and the recurrent state) in
+        # place; CPU jax has no donation support and would warn every
+        # call
+        donate = (1, 2) if jax.default_backend() != 'cpu' else ()
         if mp > 1:
             # one jit(shard_map(step)) over the replica-local mesh —
             # the hybrid train step's layout applied to serving: params
@@ -1500,12 +1563,14 @@ class ServingEngine:
             from jax.sharding import PartitionSpec as P
             kv_specs = [tuple(P(None, None, 'mp') for _ in layer)
                         for layer in self.pool.kv]
-            host_operands = step.__code__.co_argcount - 3
+            # params, kv, state, moe, then the host operands (the mp
+            # route carries no `slots`: no state)
+            host_operands = step.__code__.co_argcount - 4 - (not fused)
             step = shard_map(
                 step, mesh=self.mesh,
-                in_specs=(dict(self._param_specs), kv_specs, None)
+                in_specs=(dict(self._param_specs), kv_specs, None, None)
                 + (P(),) * host_operands,
-                out_specs=(P(), kv_specs, None), check_vma=False)
+                out_specs=(P(), kv_specs, None, None), check_vma=False)
         jitted = jax.jit(step, donate_argnums=donate)
 
         def run(*args):
@@ -1555,6 +1620,11 @@ class ServingEngine:
             ords = np.zeros((B + P,), np.int32)
             temps = np.zeros((B + P,), np.float32)
             top_ks = np.zeros((B + P,), np.int32)
+            # each row's slot in the recurrent-state arrays: a decode
+            # row's is its own, a chunk's its request's; an idle row
+            # names the spare slot
+            slots = np.full((B + P,), self.config.max_batch_size,
+                            np.int32) if self._stateful else None
             if fused:
                 rems = np.zeros((B,), np.int32)
                 eos_ids = np.full((B,), -1, np.int32)
@@ -1569,6 +1639,8 @@ class ServingEngine:
                 top_ks[row] = req.top_k
             for i, req, query, context in rows:
                 place(i, i * width, req, query, context)
+                if slots is not None:
+                    slots[i] = i
                 iterations = 1
                 if fused:
                     rems[i] = iterations = min(
@@ -1580,9 +1652,12 @@ class ServingEngine:
                 # them, out of the slots the program's tables have
                 for j in range(iterations):
                     self._it_live_pages += self.pool.pages_for(context + j)
-                    self._count_kv_read(context + j)
+                    self._count_kv_read(context + j, len(query))
             for row, req, query, context in chunks:
                 place(B + row, B * width + row * T, req, query, context)
+                if slots is not None:
+                    slots[B + row] = self.scheduler.slot_of(req)
+                self._attn_kv_tokens += self._keys_read(context, len(query))
                 self._it_live_pages += self.pool.pages_for(context)
                 self._it_prefill_tokens += len(query)
                 self._it_prefill_ctx += len(query) * context
@@ -1598,6 +1673,11 @@ class ServingEngine:
                     else (B, T, sample, shape == 'verify')
                 head = (tokens, page_tables, seq_lens, q_lens)
                 tail = (ords, temps, top_ks)
+            if slots is not None:
+                tail += (slots,)
+                live = int((q_lens > 0).sum())
+                self._ssm_rows += self._state_layers * live
+                self._ssm_tokens += self._state_layers * int(q_lens.sum())
         if mixed and (B, 1, sample, False) not in self._step_fns:
             self._warm_decode(B, sample)
         fn = self._step_fn(key)
@@ -1612,9 +1692,9 @@ class ServingEngine:
         t0 = time.perf_counter()
         with RecordEvent('serve::compiled_step', event_type='serve',
                          **span_args):
-            ids, self.pool.kv, self._moe_dev = fn(
-                self._params, self.pool.kv, self._moe_dev,
-                *map(jnp.asarray, head), self._key,
+            ids, self.pool.kv, self.pool.state, self._moe_dev = fn(
+                self._params, self.pool.kv, self.pool.state,
+                self._moe_dev, *map(jnp.asarray, head), self._key,
                 *map(jnp.asarray, tail))
         t1 = time.perf_counter()
         self._it_compute += t1 - t0
@@ -1650,11 +1730,15 @@ class ServingEngine:
         every running request for the seconds its compile takes."""
         def zeros(*shape, dtype=np.int32):
             return self._jnp.asarray(np.zeros(shape, dtype))
-        _, self.pool.kv, _ = self._step_fn((B, 1, sample, False))(
-            self._params, self.pool.kv, self._moe_dev, zeros(B),
-            zeros(B, self.max_pages_per_seq),
-            self._jnp.asarray(np.ones((B,), np.int32)), zeros(B),
-            self._key, zeros(B), zeros(B, dtype=np.float32), zeros(B))
+        spare = (self._jnp.asarray(np.full((B,), B, np.int32)),) \
+            if self._stateful else ()
+        _, self.pool.kv, self.pool.state, _ = \
+            self._step_fn((B, 1, sample, False))(
+                self._params, self.pool.kv, self.pool.state,
+                self._moe_dev, zeros(B), zeros(B, self.max_pages_per_seq),
+                self._jnp.asarray(np.ones((B,), np.int32)), zeros(B),
+                self._key, zeros(B), zeros(B, dtype=np.float32), zeros(B),
+                *spare)
 
     def _fused_decode_window(self, K):
         """Up to K decode iterations in ONE dispatch + ONE host fetch.
@@ -1682,21 +1766,31 @@ class ServingEngine:
         return len(rows), self._accepted(self._accept_fused, nxt, rows, K)
 
 
-    def _count_kv_read(self, context):
-        """One decode row's KV reads this iteration, in tokens a layer:
-        the mean over the layers of what each reads (a window layer no
-        more than its window), so tokens x kv_bytes_per_token stays the
-        bytes; and, for the window layers alone, what they read against
-        what they would without the bound."""
-        if not self._windows:
-            self._it_kv_read_tokens += context
-            return
-        total = (self._kv_layers - sum(self._windows.values())) * context
+    def _keys_read(self, context, queries):
+        """The keys one row's queries may read, summed over the
+        ATTENDING layers: a full layer's the row's whole context, a
+        window layer's the window of its first query through its last
+        (what the masks let the row read: the least the paged kernel
+        must copy for it)."""
+        total = self._kv_full * context
         for w, layers in self._windows.items():
-            total += layers * min(context, w)
+            total += layers * min(context, w + queries - 1)
+        return total
+
+    def _count_kv_read(self, context, queries=1):
+        """One decode row's KV reads this iteration, in tokens a PLANE
+        (the pool's bytes per token count the planes; the readers of a
+        shared plane each read it): what the attending layers read (a
+        window layer no more than its window) over the planes, so
+        tokens x kv_bytes_per_token stays the bytes; and, for the
+        window layers alone, what they read against what they would
+        without the bound."""
+        total = self._keys_read(context, queries)
+        self._attn_kv_tokens += total
+        for w, layers in self._windows.items():
             self._it_kv_window[0] += layers * min(context, w)
             self._it_kv_window[1] += layers * context
-        self._it_kv_read_tokens += total // self._kv_layers
+        self._it_kv_read_tokens += total // self._kv_planes
 
     def _take_moe(self, packed, n, groups, decode):
         """Split one fetch into its `n` sampled ids and what the
@@ -2276,6 +2370,17 @@ class ServingEngine:
             'moe_calls_total': self._moe['calls'],
             'moe_load_sum': self.ledger.moe_load_sum,
             'moe_load_steps': self.ledger.moe_load_steps,
+            # attention over the pages: layers that attend, planes they
+            # own, and the keys their masks let the dispatched rows
+            # read (decode and chunk rows, summed over the layers)
+            'kv_readers': self._kv_readers,
+            'kv_planes': self._kv_planes,
+            'attn_kv_tokens_read_total': self._attn_kv_tokens,
+            # recurrent state (zeros for a model without it): its
+            # bytes, the (row, layer) updates and the tokens they took
+            'state_bytes': self.pool.state_bytes(),
+            'ssm_rows_total': self._ssm_rows,
+            'ssm_tokens_total': self._ssm_tokens,
             'weight_dtype': (str(self.config.weight_dtype)
                              if self.config.weight_dtype else None),
             'quantized_params': len(self._qparam_dtypes),

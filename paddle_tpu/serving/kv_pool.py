@@ -124,14 +124,29 @@ class KVPagePool:
 
     Device arrays are created lazily (`materialize()`) so pure
     allocator tests never touch jax; the engine materializes once at
-    build. `kv[l]` is the (k_pages, v_pages) pair of layer l.
+    build. `kv[l]` is the (k_pages, v_pages) pair of plane l, and
+    `num_layers` counts the PLANES: the layers that own one (a layer
+    that reads another's, or keeps no K/V at all, has none).
     `num_heads` counts the heads STORED: the model's kv heads.
+
+    Beside the pages, which grow with a request's context, the pool
+    holds a model's RECURRENT state (protocol.py `state_spec`): per
+    entry of `state_spec` one array [state_slots + 1, *shape] — a slot
+    a batch slot and a spare that idle rows name — of a fixed size a
+    request. It has no allocator: a request's slot is its batch slot,
+    and a slot is never cleared (a row that starts at position 0 starts
+    from zeros on the device).
     """
 
     def __init__(self, num_pages, page_size, num_layers=0, num_heads=0,
-                 head_dim=0, dtype=None, prefix_cache=False):
+                 head_dim=0, dtype=None, prefix_cache=False,
+                 state_spec=(), state_slots=0):
         if num_pages <= 0 or page_size <= 0:
             raise ValueError("num_pages and page_size must be positive")
+        self.state_spec = [(tuple(int(d) for d in shape), dt)
+                           for shape, dt in state_spec]
+        self.state_slots = int(state_slots)
+        self.state = None                   # [array] per state_spec entry
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         self.num_layers = int(num_layers)
@@ -250,9 +265,26 @@ class KVPagePool:
         materialized) pool arrays."""
         return self.num_pages * self.page_size * self.bytes_per_token()
 
+    def materialize_state(self):
+        """Create the recurrent-state arrays, zeroed (None: the model
+        declares none)."""
+        if self.state is None and self.state_spec:
+            import jax.numpy as jnp
+            self.state = [
+                jnp.zeros((self.state_slots + 1,) + shape, dt)
+                for shape, dt in self.state_spec]
+        return self.state
+
+    def state_bytes(self):
+        """Device bytes of the recurrent-state arrays (0: none)."""
+        return sum(
+            (self.state_slots + 1) * math.prod(shape)
+            * _np_dtype(dt).itemsize for shape, dt in self.state_spec)
+
     def drop_arrays(self):
         """Release the device buffers (engine shutdown)."""
         self.kv = None
+        self.state = None
 
     # -- allocator -----------------------------------------------------------
     def pages_for(self, n_tokens):
@@ -900,6 +932,8 @@ class KVPagePool:
                          if self.dtype is not None else 'float32'),
             'bytes_per_token': self.bytes_per_token(),
             'pool_bytes': self.pool_bytes(),
+            'kv_planes': self.num_layers,
+            'state_bytes': self.state_bytes(),
             'pages_in_use': self.pages_in_use,
             'free_pages': self.free_pages,
             'utilization': self.utilization(),

@@ -6,21 +6,28 @@ has:
     config.max_seq_len, config.num_layers, config.hidden_size
         the longest context a request may reach, and the ledger's
         sizes
-    kv_cache_spec() -> [KVLayerSpec] one per layer
+    kv_cache_spec() -> [KVLayerSpec] one per ATTENDING layer
         what a token's K/V takes in that layer's pages: kv heads (the
-        GLOBAL count under mp), head_dim, and `window` — None where a
+        GLOBAL count under mp), head_dim, `window` — None where a
         query reads every earlier key, else the number of most recent
-        keys it reads. The pool is sized by kv heads; one page table
-        serves every layer, so every layer must agree on kv heads and
+        keys it reads — and `reads`: None where the layer OWNS a plane
+        of the pool (it writes its tokens' K/V there and reads them
+        back), else the index in this list of the owning entry whose
+        plane it reads and never writes (a cross-decoder layer on a
+        shared cache). A layer that neither attends nor caches (a
+        state-space layer, a gated unit) has no entry. The pool holds
+        one plane an OWNER, sized by kv heads; one page table serves
+        every plane, so every entry must agree on kv heads and
         head_dim (window layers keep pages they no longer read: a
         window-aware allocator is ROADMAP Queue 2)
     forward_paged(tokens, positions, kv, rows, moe_counters=None)
             -> (hidden, new_kv, moe)
         tokens / positions: int Tensors [1, N], the query tokens of
         every row of the dispatch laid end to end; `rows` a `RowGroups`
-        (below) that says which rows they are; kv: per layer a tuple
-        of pool Tensors ((k, v), or the int8 pool's (k, v, k_scales,
-        v_scales)). Everything token-wise (embedding, norms,
+        (below) that says which rows they are; kv: per OWNING entry of
+        the cache spec, in its order, a tuple of pool Tensors ((k, v),
+        or the int8 pool's (k, v, k_scales, v_scales)). Everything
+        token-wise (embedding, norms,
         projections, MLP, experts) runs ONCE over the N tokens, so a
         weight is read once a dispatch; attention alone goes group by
         group (`rows.attend`). hidden is the final-normed Tensor
@@ -48,15 +55,35 @@ has:
         none). The engine packs both behind the sampled tokens so the
         step still costs ONE host fetch. On the other routes it passes
         None and nothing is counted.
+    state_spec() -> [(shape, dtype)]    (optional: a model without the
+                                         method holds no such state)
+        recurrent state: per-request arrays of a FIXED size, beside the
+        pages that grow with the context. The engine allocates each as
+        [max_batch_size + 1, *shape] (kv_pool.py: one slot a batch
+        slot, and a spare that idle rows name), passes the list into
+        `forward_paged(..., state=[Tensor])`, takes the new list back
+        as a fourth result and donates it as it donates `kv`.
+        `rows.slots` [R] names each row's slot. The slot rule is the
+        model's to keep, on the device: a row with q_len 0 writes
+        nothing (it names the spare slot); a row whose first query
+        sits at position 0 (`rows.fresh()`) starts from zeros whatever
+        its slot held — so admission, preemption and re-prefill need
+        no reset from the host and no dispatch of their own. Nothing
+        keeps the state of an earlier position: the engine refuses, for
+        such a model, whatever would resume a request anywhere but at
+        its start or its end (prefix cache, host tier, adoption, the
+        fused window, the verify step; docs/serving.md).
 
-`GPTForCausalLM` and `AfmoeForCausalLM` implement it.
+`GPTForCausalLM` and `AfmoeForCausalLM` implement it;
+`Phi4FlashForCausalLM` with shared planes and recurrent state.
 """
 import collections
 
 import jax.numpy as jnp
 
 KVLayerSpec = collections.namedtuple(
-    'KVLayerSpec', ['num_kv_heads', 'head_dim', 'window'])
+    'KVLayerSpec', ['num_kv_heads', 'head_dim', 'window', 'reads'],
+    defaults=(None,))
 
 
 class RowGroups:
@@ -68,15 +95,18 @@ class RowGroups:
     cover the R = sum(rows) rows in that order; the dispatch's N =
     sum(rows * width) tokens lie end to end the same way, row-major,
     and position t of a row holds a token iff t < its q_len (an idle
-    row rides with q_len 0). The split back into groups and the join
-    are written here once, for every model.
+    row rides with q_len 0). `slots` [R] (None for a model without
+    recurrent state) names each row's slot in the per-request state
+    arrays; an idle row names the spare one. The split back into groups
+    and the join are written here once, for every model.
     """
 
-    def __init__(self, layout, page_tables, seq_lens, q_lens):
+    def __init__(self, layout, page_tables, seq_lens, q_lens, slots=None):
         self.layout = tuple((int(b), int(t)) for b, t in layout)
         self.page_tables = page_tables
         self.seq_lens = seq_lens
         self.q_lens = q_lens
+        self.slots = slots
 
     def _groups(self):
         """(first row, first token, rows, width) of each group."""
@@ -121,7 +151,28 @@ class RowGroups:
                 count += b
         return (ids[0] if len(ids) == 1 else jnp.concatenate(ids)), count
 
-    def attend(self, write, read, pool, q, k, v):
+    def fresh(self):
+        """bool [R]: the rows whose first query sits at position 0 —
+        a request's first chunk, or its first again after a preemption:
+        recurrent state starts from zeros there."""
+        return (self.q_lens > 0) & (self.seq_lens == self.q_lens)
+
+    def groups(self, *arrays):
+        """Per group: (rows slice, width, each of `arrays` [1, N, .]
+        as the group's [b, t, .]): for what runs group by group —
+        attention below, a model's recurrence."""
+        for r, tok, b, t in self._groups():
+            yield slice(r, r + b), t, tuple(
+                a[0, tok:tok + b * t].reshape(b, t, a.shape[-1])
+                for a in arrays)
+
+    @staticmethod
+    def join(parts):
+        """Groups' [b, t, .] back to [1, N, .] in the dispatch's order."""
+        out = [p.reshape(1, -1, p.shape[-1]) for p in parts]
+        return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
+
+    def attend(self, write, read, pool, q, k=None, v=None):
         """Attention over the paged pool, group by group. q / k / v are
         [1, N, .] over the dispatch's tokens; `write(pool, k, v,
         page_tables, seq_lens, q_lens) -> pool` puts a group's new K/V
@@ -129,22 +180,16 @@ class RowGroups:
         page_tables, seq_lens, q_lens) -> [b, t, .]` attends. Every
         group writes before any reads: no request rides two groups of
         one dispatch, so the writes never meet, and the pool is updated
-        in one chain. -> (context [1, N, .], pool)."""
-        def view(a, tok, b, t):
-            return a[0, tok:tok + b * t].reshape(b, t, a.shape[-1])
-
-        def rows_of(r, b):
-            return (self.page_tables[r:r + b], self.seq_lens[r:r + b],
-                    self.q_lens[r:r + b])
-        for r, tok, b, t in self._groups():
-            pool = write(pool, view(k, tok, b, t), view(v, tok, b, t),
-                         *rows_of(r, b))
-        out = []
-        for r, tok, b, t in self._groups():
-            ctx = read(pool, view(q, tok, b, t), *rows_of(r, b))
-            out.append(ctx.reshape(1, b * t, ctx.shape[-1]))
-        return (out[0] if len(out) == 1
-                else jnp.concatenate(out, axis=1)), pool
+        in one chain. `write` None: a reader of another layer's plane,
+        which writes nothing (the pool comes back as it went in).
+        -> (context [1, N, .], pool)."""
+        def rows_of(at):
+            return self.page_tables[at], self.seq_lens[at], self.q_lens[at]
+        if write is not None:
+            for at, _, (kg, vg) in self.groups(k, v):
+                pool = write(pool, kg, vg, *rows_of(at))
+        return self.join([read(pool, qg, *rows_of(at))
+                          for at, _, (qg,) in self.groups(q)]), pool
 
     def last(self, x):
         """x [1, N, .] -> [R, .] at each row's last query."""

@@ -26,12 +26,16 @@ TINY = dict(
                 page_size=8, pages=8, batch=2, chunk=20,
                 contexts=(3, 23, 25, 64),
                 experts=8, top_k=2, hidden=32, width=16),
+    hybrid=dict(heads=4, kv_heads=2, head_dim=16, window=24,
+                page_size=8, pages=8, batch=2, chunk=20,
+                contexts=(3, 23, 25, 64), prefill_rows=2,
+                channels=128, states=16),
 )
 
 
 def test_kernels_phase():
     errs = chip_smoke.phase_kernels(TINY)
-    assert len(errs) >= 25 + 16 + 4
+    assert len(errs) >= 25 + 16 + 4 + 16 + 2
 
 
 def test_train_phase():

@@ -188,6 +188,47 @@ def test_paged_attention_with_kv_groups_compiles_and_is_named(
     assert got == {name}
 
 
+@pytest.mark.parametrize('rows,window,name', [
+    (64, None, 'paged_attention_diff'),
+    (64, 512, 'paged_attention_diff_window'),
+    (2, None, 'paged_attention_diff'),
+    (2, 512, 'paged_attention_diff_window')])
+def test_differential_paged_attention_compiles_and_is_named(
+        rows, window, name, one_chip, as_on_tpu):
+    """The state-space server cell's row groups at its published
+    widths: 40 query sub-heads on 20 key sub-heads of 64, pairs of them
+    on one 128-wide value, 176-page tables over 7,168 pages; [64, 1]
+    decode and the mixed step's 2 chunks of 128. The names start
+    `paged_attention` (the readers sum the class) and say `diff`."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    T = 1 if rows == 64 else 128
+    pages = ((7168, 16, 1280), BF16)
+
+    def fn(q, k, v, pt, sl, ql):
+        return pa.ragged_paged_attention_pallas(
+            q, k, v, pt, sl, ql, num_heads=40, head_dim=64,
+            num_kv_heads=20, window=window, diff=2)
+    got = mosaic_calls(fn, [((rows, T, 2560), BF16), pages, pages,
+                            ((rows, 176), jnp.int32), ((rows,), jnp.int32),
+                            ((rows,), jnp.int32)], one_chip)
+    assert got == {name}
+
+
+@pytest.mark.parametrize('rows,T', [(64, 1), (2, 128)])
+def test_the_selective_scan_compiles_and_is_named(rows, T, one_chip,
+                                                  as_on_tpu):
+    """The same cell's recurrence: 5120 channels x 16 states, 65 slots
+    (64 and the spare), the decode group and the chunk group."""
+    from paddle_tpu.ops.pallas import selective_scan as ss
+    f32, i32 = jnp.float32, jnp.int32
+    tok, bc = ((rows, T, 5120), f32), ((rows, T, 16), f32)
+    got = mosaic_calls(ss.selective_scan_pallas, [
+        tok, tok, bc, bc, ((16, 5120), f32), ((5120,), f32),
+        ((65, 16, 5120), f32), ((rows,), i32), ((rows,), i32),
+        ((rows,), jnp.bool_)], one_chip)
+    assert got == {'selective_scan'}
+
+
 @pytest.mark.parametrize('pairs', [512, 4096, (64 + 2 * 512) * 8])
 def test_the_experts_grouped_matmul_compiles_and_is_named(
         pairs, one_chip, as_on_tpu):
