@@ -52,6 +52,20 @@ what is theirs (prefix match and page growth; draft proposal, capacity
 and verify; window reservation and roll-back) and hand over their rows.
 A request whose last prompt chunk rode a dispatch takes its first token
 from it and joins the decode rows in the NEXT step.
+
+The engine runs ONE STEP AHEAD of the device (ISSUE 33): step n+1 is
+planned and queued (`_launch`) before the ids of step n are fetched and
+accepted (`_land`), so the host's turn between two programs runs under
+the device's work. The ids never need the host to reach the next
+program — every program hands on each slot's newest id ON the device
+(`prev_ids` -> `handoff`) and a decode row whose token has not come to
+the host reads it there (`src`) —, and the plan of n+1 is made on what
+n is KNOWN to do (`_Flight`, `_known`): a row emits one token, a chunk
+prefills its tokens. An EOS alone cannot be known: a row launched past
+it is dropped when its ids arrive. What cannot be planned so (a verify
+step, a fused window, a preemption or an abort of a request in flight)
+drains the pipe first (`_drain`), read off the engine's own state — no
+knob. Every `step()` still delivers one step's tokens.
 Idle decode slots ride along with q_len=0: their K/V writes are dropped
 by the scatter and their outputs ignored, so occupancy is a pure
 scheduling concern.
@@ -112,6 +126,31 @@ _MOE_COUNTERS = (('experts_touched', 'ptpu_moe_experts_touched_total'),
 # peak FLOP/s over its HBM bandwidth said 2 and 5, and 5 read 9 % under
 # the best. One value is within 3 % of it on both, so it is a constant
 PREFILL_ROWS = 2
+
+# why a step's ids were fetched with nothing queued behind them: the
+# next step is a verify step (its n-gram proposal reads the token) or a
+# fused window, a preemption or an abort took a request the step in
+# flight carries, another engine's request was adopted, a host-tier
+# fetch, nothing left to launch
+DRAIN_REASONS = ('verify', 'fused', 'preempt', 'abort', 'adopt', 'tier',
+                 'idle')
+
+
+class _Flight:
+    """A launched step whose sampled ids have not come to the host:
+    its dispatches as `_fetch` and the accept loops take them, and what
+    it is KNOWN to do before they arrive — a decode row emits one
+    token, a chunk prefills its tokens and, a prompt's last, emits the
+    first. (An EOS is the one thing not known: `_accept_decode`.)"""
+    __slots__ = ('dispatches', 'emits', 'prefills')
+
+    def __init__(self):
+        self.dispatches = []    # (decode rows, chunks riding, queued)
+        self.emits = set()      # ids of the requests that take a token
+        self.prefills = {}      # request id -> (request, first, tokens)
+
+    def carries(self, req):
+        return req.id in self.emits or req.id in self.prefills
 
 
 class ServingConfig:
@@ -564,6 +603,23 @@ class ServingEngine:
         self._dispatches = 0
         self._mixed_dispatches = 0
         self._mixed_slots = 0
+        # one step ahead: the launched step whose ids have not come to
+        # the host (`_Flight`, or None), the newest id of every slot as
+        # the last dispatch left it ON the device (what a decode row
+        # reads when its token never came to the host), when the last
+        # fetch returned, and the mechanism's counters — steps launched
+        # behind another, fetches with nothing queued behind them by
+        # what held the next step back, rows dropped after an EOS
+        self._flight = None
+        self._prev_ids = jnp.zeros((config.max_batch_size,), jnp.int32)
+        if self._mp > 1:    # replicated, as every program hands it back
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            self._prev_ids = jax.device_put(
+                self._prev_ids, NamedSharding(mesh, P()))
+        self._fetched_at = 0.0
+        self._pipelined = 0
+        self._drains = dict.fromkeys(DRAIN_REASONS, 0)
+        self._overrun = 0
         # speculative decoding accounting (draft tokens proposed by
         # the n-gram proposer vs accepted by the verify step)
         self._spec_proposed = 0
@@ -861,13 +917,21 @@ class ServingEngine:
 
     # -- engine iteration ----------------------------------------------------
     def step(self):
-        """One scheduler iteration: admit waiting requests, reserve the
-        next prompt chunk of every prefilling request, then dispatch:
-        the running set's decode rows and the chunks in ONE program
-        (`_advance`). Records a timeline entry, runs the
-        stalled-request watchdog, publishes metrics. The whole of it is
-        one `serve::step` span; its children are the span table of
-        docs/serving.md#spans."""
+        """One scheduler iteration, ONE STEP AHEAD of the device: admit
+        waiting requests, then plan the next step — the next prompt
+        chunk of every prefilling request, the running set's decode
+        rows — and queue its programs (`_launch`) BEFORE the ids of the
+        step in flight are fetched and accepted (`_land`), so the
+        host's turn runs under the device's work. The plan is made on
+        the host's state plus what the step in flight is KNOWN to do
+        (`_known`); a decode row's query token goes from one program to
+        the next on the device. What cannot be planned so drains the
+        pipe first (`_drain`). Every call delivers one step's tokens:
+        the first after idle launches two steps and lands one, a call
+        with nothing left to launch only lands. Records a timeline
+        entry, runs the stalled-request watchdog, publishes metrics.
+        The whole of it is one `serve::step` span; its children are the
+        span table of docs/serving.md#spans."""
         self._step_ordinal += 1
         with RecordEvent('serve::step', event_type='serve',
                          step=self._step_ordinal):
@@ -877,7 +941,6 @@ class ServingEngine:
         completed_before = self._completed
         preempt_before = self.scheduler.preemptions
         t_begin = self._gap.dispatch_begin()
-        self._reset_iteration()
         t_sched = time.perf_counter()
         with RecordEvent('serve::schedule', event_type='serve'):
             with RecordEvent('serve::check_stalled', event_type='serve'):
@@ -886,44 +949,47 @@ class ServingEngine:
                 admitted = self._admit()
                 ev.args = {'admitted': admitted}
         sched_dt = time.perf_counter() - t_sched
-        prefilling = [r for r in self.scheduler.slots
-                      if r is not None and r.state == RequestState.PREFILL]
-        chunks = []
-        for req in prefilling:
-            with RecordEvent('serve::prefill_chunk', event_type='serve',
-                             req=req.id):
-                chunk = self._reserve_chunk(req, chunks)
-            if chunk is not None:
-                chunks.append(chunk)
-        decode_slots = decode_tokens = prefill_tokens = 0
-        if chunks or any(r is not None and r.state == RequestState.RUNNING
-                         for r in self.scheduler.slots):
-            called = self._dispatches
-            with RecordEvent('serve::dispatch', event_type='serve'):
-                # POST-preemption counts: _advance may preempt rows
-                # under pool pressure; slots are the surviving decode
-                # rows, tokens what they emitted (> slots when
-                # speculative decoding accepts drafts)
-                decode_slots, decode_tokens, prefill_tokens = \
-                    self._advance(chunks)
-            self._steps += self._dispatches > called
+        if self._flight is None:
+            # nothing in flight: the serial order, on the host's own
+            # state (a verify step or a fused window lands in here)
+            self._flight = self._launch()
+        depth = 1
+        if self._flight is not None:
+            why = self._holds_back()
+            ahead = None if why else self._launch()
+            # (a preemption inside that launch has drained already)
+            if self._flight is not None:
+                if ahead is not None:
+                    depth = 2
+                    self._land(behind=True)
+                else:
+                    self._drain(why or 'idle')
+            self._flight = ahead
+            if ahead is not None and not self.scheduler.has_work:
+                # every request ended in the step that landed (an EOS):
+                # what was launched behind it carries nothing to wait
+                # for, and a caller that steps while there is work
+                # would never land it
+                self._drain()
         fused = self._fused_last
         self._fused_last = None
         wall = time.perf_counter() - t_begin
         with RecordEvent('serve::telemetry', event_type='serve'):
             self._step_telemetry(
-                fused, wall, sched_dt, admitted, prefill_tokens,
-                decode_slots, decode_tokens,
+                fused, wall, sched_dt, admitted, depth,
                 self.scheduler.preemptions - preempt_before,
                 self._completed != completed_before)
 
     def _reset_iteration(self):
-        """The iteration's phase clocks and roofline counts: step()
-        resets them, _enqueue and _fetch alone feed them (host perf_counter
-        segments — never a device sync), _step_telemetry hands them
-        to the ledger."""
+        """The iteration's phase clocks, roofline counts and landed
+        rows: _enqueue, _fetch and _land alone feed them (host
+        perf_counter segments — never a device sync), _step_telemetry
+        hands them to the ledger and resets them, so that what lands
+        between two steps (a drain for an abort) is in the next
+        record."""
         self._it_compute = 0.0
         self._it_fetch = 0.0
+        self._it_gating = 0.0
         self._it_decode_s = 0.0
         self._it_kv_read_tokens = 0
         self._it_kv_window = [0, 0]
@@ -933,14 +999,23 @@ class ServingEngine:
         self._it_prefill_tokens = 0
         self._it_prefill_s = 0.0
         self._it_prefill_ctx = 0
+        # what the landed step carried: decode rows, the tokens they
+        # emitted (> rows when speculative decoding accepts drafts),
+        # prompt tokens prefilled
+        self._it_rows = 0
+        self._it_tokens = 0
+        self._it_chunk_tokens = 0
 
-    def _step_telemetry(self, fused, wall, sched_dt, admitted,
-                        prefill_tokens, decode_slots, decode_tokens,
+    def _step_telemetry(self, fused, wall, sched_dt, admitted, depth,
                         preempted, retired):
         """Everything in a step that observes and serves nothing (the
         `serve::telemetry` span; ROADMAP D5): the timeline and ledger
         records, the degrade ladder's pressure reading, host-tier
-        close-out, the gap monitor's close and the metrics publish."""
+        close-out, the gap monitor's close and the metrics publish.
+        `depth` is how many steps were queued on the device when this
+        one's ids were fetched."""
+        prefill_tokens = self._it_chunk_tokens
+        decode_slots, decode_tokens = self._it_rows, self._it_tokens
         # one observability record per decode ITERATION: a fused
         # window runs n_iter device iterations inside one dispatch,
         # and the timeline / ladder / ledger must see the same per-
@@ -1015,9 +1090,15 @@ class ServingEngine:
         # dispatch_end zeroes the pending gating attribution, and the
         # fetch wait belongs to the span that just closed (it is
         # consumed by the NEXT dispatch_begin).
-        self._gap.dispatch_end(depth=1)
-        if self._it_fetch > 0.0:
-            self._gap.note_gating(self._it_fetch)
+        # A fetch with the next step queued behind it waits on a busy
+        # device (blocked); one with nothing behind it lets the device
+        # run dry (gating).
+        self._gap.dispatch_end(depth=depth)
+        if self._it_gating > 0.0:
+            self._gap.note_gating(self._it_gating)
+        if self._it_fetch > self._it_gating:
+            self._gap.note_blocked(self._it_fetch - self._it_gating)
+        self._reset_iteration()
         # publish cadence: retire and drain publish immediately; the
         # periodic path keys to the MONITOR's wall clock (the same
         # source gauge last_update stamps and staleness alert rules
@@ -1151,6 +1232,13 @@ class ServingEngine:
                 if victim is None:
                     break       # order is priority-sorted: nobody
                                 # later outranks the running set either
+                if self._drain('preempt'):
+                    # decided again on what the step in flight returned:
+                    # it may have retired the victim, or freed a slot
+                    victim = None if None in sched.slots else \
+                        sched.preempt_victim(below_priority=req.priority)
+                    if victim is None and None not in sched.slots:
+                        break
             # host-resurrect pages (ISSUE 20) bill the page budget one
             # allocatable page each, same as device-resurrect — but
             # their cost is a host→device TRANSFER, not prefill
@@ -1253,6 +1341,7 @@ class ServingEngine:
                 f"no state is kept at the position a request would "
                 f"resume from: adopt_request (pages stream between "
                 f"engines, the state of the prompt's end does not)")
+        self._drain('adopt')
         if self.scheduler.adopt(req) is None:
             return False
         req.prefilled = len(req.tokens)
@@ -1301,6 +1390,14 @@ class ServingEngine:
                 self.pool.ensure_capacity(req.id, n_tokens)
                 return True
             except PoolExhausted:
+                if self._drain('preempt'):
+                    # a preemption is decided on what the step in flight
+                    # returned: it may hand pages back (a retire), and
+                    # its rows' pages may not go while it writes them.
+                    # It may also have ended `req` itself (an EOS)
+                    if req.state == RequestState.FINISHED:
+                        return False    # (its retire released the pages)
+                    continue
                 if self._tenants is not None:
                     victim = sched.preempt_victim(
                         exclude=req, below_priority=req.priority)
@@ -1381,12 +1478,21 @@ class ServingEngine:
         weights de-quantised, the mp region entered), the forward over
         the paged pool and the pick of each row's next id, donation of
         the pool, the shard_map specs, and the eval()/no_grad call.
-        Every program is `step(params, kv, state, moe, *host operands)
-        -> (ids, kv, state, moe)`; `moe` (the experts' counters) is
-        None wherever the model or the route carries none, `state` (the
-        recurrent-state arrays, with each row's `slots` the last host
-        operand) wherever the model declares none — and then no
-        operand, and nothing of it in the traced program."""
+        Every program is `step(params, kv, state, moe, prev_ids, *host
+        operands) -> (ids, handoff, kv, state, moe)`; `moe` (the
+        experts' counters) is None wherever the model or the route
+        carries none, `state` (the recurrent-state arrays, with each
+        row's `slots` the last host operand) wherever the model
+        declares none — and then no operand, and nothing of it in the
+        traced program. `prev_ids` -> `handoff` ([B] int32, by batch
+        slot: the newest id of the slot's request) goes from each
+        dispatch to the next ON the device, so that a step can be
+        queued before the ids of the one before it have come to the
+        host: `src` [R] names, for a decode row, the slot of `prev_ids`
+        that holds its query token (-1: the host's, in `tokens`) and,
+        for a chunk row, the slot its sampled id is handed on in (-1:
+        an idle row). Both operands are always there — one program a
+        shape, whether a step is in flight or not."""
         jax, jnp = self._jax, self._jnp
         model = self.model
         from ..core.tensor import Tensor
@@ -1493,13 +1599,33 @@ class ServingEngine:
             return nxt, new_kv, state, moe
 
         if not fused:
-            def step(params, kv, state, moe, tokens, page_tables,
-                     seq_lens, q_lens, key, ords, temps, top_ks,
-                     slots=None):
+            def step(params, kv, state, moe, prev_ids, tokens,
+                     page_tables, seq_lens, q_lens, src, key, ords,
+                     temps, top_ks, slots=None):
                 with bound(params):
+                    if not verify:
+                        # a decode row whose newest token never came to
+                        # the host takes it where the last dispatch
+                        # left it
+                        fed = src[:B]
+                        tokens = tokens.at[:B].set(jnp.where(
+                            fed >= 0, prev_ids[jnp.maximum(fed, 0)],
+                            tokens[:B]))
                     nxt, kv, state, moe = forward_pick(
                         kv, state, moe, tokens, page_tables, seq_lens,
                         q_lens, key, ords, temps, top_ks, slots)
+                    handoff = prev_ids
+                    if not verify:
+                        # handed on by slot: a live decode row's id in
+                        # its own, a chunk row's in its request's (only
+                        # a prompt's last chunk's is ever read there)
+                        handoff = jnp.where(q_lens[:B] > 0, nxt[:B],
+                                            prev_ids)
+                        if len(layout) > 1:
+                            to = src[B:]
+                            handoff = handoff.at[
+                                jnp.where(to >= 0, to, B)].set(
+                                    nxt[B:], mode='drop')
                     if moe is not None:
                         # the experts' rows of this call and their
                         # counters ride behind the sampled ids: still
@@ -1507,11 +1633,11 @@ class ServingEngine:
                         counts, moe = moe
                         nxt = jnp.concatenate(
                             [nxt, counts.reshape(-1), moe.reshape(-1)])
-                return nxt, kv, state, moe
+                return nxt, handoff, kv, state, moe
         else:
-            def step(params, kv, state, moe, tokens, page_tables,
-                     seq_lens, ords, rems, eos_ids, live, key, temps,
-                     top_ks):
+            def step(params, kv, state, moe, prev_ids, tokens,
+                     page_tables, seq_lens, ords, rems, eos_ids, live,
+                     key, temps, top_ks):
                 # The carry is (kv pool, last token, seq_len, done-mask,
                 # emitted count) per row; each scan body is the [B, 1]
                 # decode step by call — same positions, same sampling
@@ -1546,7 +1672,10 @@ class ServingEngine:
                               jnp.zeros((B,), jnp.int32))
                     (kv, _t, _s, _d, _e), ys = jax.lax.scan(
                         body, carry0, xs=None, length=K)
-                return jnp.moveaxis(ys, 0, 1), kv, state, moe   # [B, K]
+                # (a window is never launched ahead: its ids are on
+                # the host before the next step is planned)
+                return (jnp.moveaxis(ys, 0, 1), prev_ids, kv, state,
+                        moe)                                    # [B, K]
 
         # donation updates the pool pages (and the recurrent state) in
         # place; CPU jax has no donation support and would warn every
@@ -1563,14 +1692,16 @@ class ServingEngine:
             from jax.sharding import PartitionSpec as P
             kv_specs = [tuple(P(None, None, 'mp') for _ in layer)
                         for layer in self.pool.kv]
-            # params, kv, state, moe, then the host operands (the mp
-            # route carries no `slots`: no state)
+            # params, kv, state, moe, then the replicated operands:
+            # the ids handed on and the host's (the mp route carries no
+            # `slots`: no state)
             host_operands = step.__code__.co_argcount - 4 - (not fused)
             step = shard_map(
                 step, mesh=self.mesh,
                 in_specs=(dict(self._param_specs), kv_specs, None, None)
                 + (P(),) * host_operands,
-                out_specs=(P(), kv_specs, None, None), check_vma=False)
+                out_specs=(P(), P(), kv_specs, None, None),
+                check_vma=False)
         jitted = jax.jit(step, donate_argnums=donate)
 
         def run(*args):
@@ -1584,14 +1715,13 @@ class ServingEngine:
                     model.train()
         return run
 
-    def _dispatch(self, shape, rows, B, T, chunks=(), fetch=True):
-        """One compiled step, called and (unless `fetch` is False: inner
-        chunks alone sample nothing anyone reads) fetched: `_enqueue`
-        then `_fetch`. Returns the fetched ids ([B]; mixed [B + P], the
-        decode rows' then the chunks'; verify [B, T], one column more
-        with sampled rows; fused [B, T]), or None without a fetch."""
-        queued = self._enqueue(shape, rows, B, T, chunks)
-        return self._fetch(queued) if fetch else None
+    def _dispatch(self, shape, rows, B, T):
+        """One compiled step called and fetched at once (`_enqueue`
+        then `_fetch`), nothing queued behind it: a verify step or a
+        fused window, what the host must read before it can plan on.
+        Returns the fetched ids (verify [B, T], one column more with
+        sampled rows; fused [B, T])."""
+        return self._fetch(self._enqueue(shape, rows, B, T), behind=False)
 
     def _enqueue(self, shape, rows, B, T, chunks=()):
         """The one place a compiled step is called. `rows` are the
@@ -1603,10 +1733,12 @@ class ServingEngine:
         up to T tokens in the rows of the program's prefill group, each
         (row, request, query tokens, context length after them).
         Builds the host operands (idle rows of either group ride along
-        with q_len 0), calls the program — which returns when it is
-        queued on the device —, takes the new pool and feeds the
-        iteration's clocks and counts (`_it_*`). Returns what `_fetch`
-        needs to bring the sampled ids to the host."""
+        with q_len 0; a row whose request takes a token from the step
+        in flight is fed on the device: `src`), calls the program —
+        which returns when it is queued on the device —, takes the new
+        pool and the ids handed on, and feeds the iteration's clocks
+        and counts (`_it_*`). Returns what `_fetch` needs to bring the
+        sampled ids to the host."""
         jnp = self._jnp
         mixed, fused = shape == 'mixed', shape == 'fused'
         width = T if shape == 'verify' else 1   # a decode row's queries
@@ -1620,6 +1752,10 @@ class ServingEngine:
             ords = np.zeros((B + P,), np.int32)
             temps = np.zeros((B + P,), np.float32)
             top_ks = np.zeros((B + P,), np.int32)
+            # where the ids handed on from dispatch to dispatch hold a
+            # decode row's query token, and where a chunk row's id goes
+            src = np.full((B + P,), -1, np.int32)
+            flight = self._flight
             # each row's slot in the recurrent-state arrays: a decode
             # row's is its own, a chunk's its request's; an idle row
             # names the spare slot
@@ -1639,6 +1775,8 @@ class ServingEngine:
                 top_ks[row] = req.top_k
             for i, req, query, context in rows:
                 place(i, i * width, req, query, context)
+                if flight is not None and req.id in flight.emits:
+                    src[i] = i      # its newest token is not on the host
                 if slots is not None:
                     slots[i] = i
                 iterations = 1
@@ -1655,8 +1793,9 @@ class ServingEngine:
                     self._count_kv_read(context + j, len(query))
             for row, req, query, context in chunks:
                 place(B + row, B * width + row * T, req, query, context)
+                src[B + row] = self.scheduler.slot_of(req)
                 if slots is not None:
-                    slots[B + row] = self.scheduler.slot_of(req)
+                    slots[B + row] = src[B + row]
                 self._attn_kv_tokens += self._keys_read(context, len(query))
                 self._it_live_pages += self.pool.pages_for(context)
                 self._it_prefill_tokens += len(query)
@@ -1671,7 +1810,7 @@ class ServingEngine:
             else:
                 key = ('mixed', B, P, T, sample) if mixed \
                     else (B, T, sample, shape == 'verify')
-                head = (tokens, page_tables, seq_lens, q_lens)
+                head = (tokens, page_tables, seq_lens, q_lens, src)
                 tail = (ords, temps, top_ks)
             if slots is not None:
                 tail += (slots,)
@@ -1681,7 +1820,8 @@ class ServingEngine:
         if mixed and (B, 1, sample, False) not in self._step_fns:
             self._warm_decode(B, sample)
         fn = self._step_fn(key)
-        span_args = {'shape': shape, 'batch': len(rows)}
+        span_args = {'shape': shape, 'batch': len(rows),
+                     'in_flight': int(flight is not None)}
         if fused:
             span_args['k'] = T
         if mixed:
@@ -1692,10 +1832,11 @@ class ServingEngine:
         t0 = time.perf_counter()
         with RecordEvent('serve::compiled_step', event_type='serve',
                          **span_args):
-            ids, self.pool.kv, self.pool.state, self._moe_dev = fn(
+            (ids, self._prev_ids, self.pool.kv, self.pool.state,
+             self._moe_dev) = fn(
                 self._params, self.pool.kv, self.pool.state,
-                self._moe_dev, *map(jnp.asarray, head), self._key,
-                *map(jnp.asarray, tail))
+                self._moe_dev, self._prev_ids, *map(jnp.asarray, head),
+                self._key, *map(jnp.asarray, tail))
         t1 = time.perf_counter()
         self._it_compute += t1 - t0
         # one program's time, shared between the phases by the query
@@ -1706,10 +1847,12 @@ class ServingEngine:
         self._it_decode_s += (t1 - t0) * (1.0 - prefill)
         return ids, B + P, 1 + P, bool(rows), t0
 
-    def _fetch(self, queued):
+    def _fetch(self, queued, behind):
         """The one host sync of a dispatch: the ids `_enqueue` left on
         the device come to the host, the experts' counters behind them
-        are split off, and the fetch's clocks are fed. -> ids."""
+        are split off, and the fetch's clocks are fed. `behind`: the
+        next step is queued behind this one, so the wait is the
+        device's work and not its idling. -> ids."""
         ids, n, groups, decode, t0 = queued
         t1 = time.perf_counter()
         with RecordEvent('serve::sample_fetch', event_type='serve'):
@@ -1718,8 +1861,13 @@ class ServingEngine:
             ids = self._take_moe(ids, n, groups, decode)
         t2 = time.perf_counter()
         self._it_fetch += t2 - t1
+        if not behind:
+            self._it_gating += t2 - t1
         if decode:
-            self._decode_time += t2 - t0
+            # the device ran this dispatch from its launch, or from
+            # where the one before it ended
+            self._decode_time += t2 - max(t0, self._fetched_at)
+        self._fetched_at = t2
         return ids
 
     def _warm_decode(self, B, sample):
@@ -1732,11 +1880,13 @@ class ServingEngine:
             return self._jnp.asarray(np.zeros(shape, dtype))
         spare = (self._jnp.asarray(np.full((B,), B, np.int32)),) \
             if self._stateful else ()
-        _, self.pool.kv, self.pool.state, _ = \
+        _, self._prev_ids, self.pool.kv, self.pool.state, _ = \
             self._step_fn((B, 1, sample, False))(
                 self._params, self.pool.kv, self.pool.state,
-                self._moe_dev, zeros(B), zeros(B, self.max_pages_per_seq),
+                self._moe_dev, self._prev_ids, zeros(B),
+                zeros(B, self.max_pages_per_seq),
                 self._jnp.asarray(np.ones((B,), np.int32)), zeros(B),
+                self._jnp.asarray(np.full((B,), -1, np.int32)),
                 self._key, zeros(B), zeros(B, dtype=np.float32), zeros(B),
                 *spare)
 
@@ -1762,6 +1912,7 @@ class ServingEngine:
             rows.append((i, req, [_last_token(req)], req.context_len))
         if not rows:
             return 0, 0
+        self._drains['fused'] += 1
         nxt = self._dispatch('fused', rows, self.config.max_batch_size, K)
         return len(rows), self._accepted(self._accept_fused, nxt, rows, K)
 
@@ -1907,13 +2058,16 @@ class ServingEngine:
                             # pool (and preempt live work) for a request
                             # that isn't scheduled
         toks = req.tokens
-        if req.prefilled == 0 and self.pool.prefix_cache:
-            # a sibling's chunk reserved earlier in this step is about
-            # to compute the very block this prompt needs next (the
-            # same tokens from position 0 on): the chunks of one step
-            # ride one dispatch, so nothing of it is indexed yet — wait
-            # for it, and map its pages next step instead of computing
-            # them again beside it
+        _, _, start = self._known(req)
+        if start == 0 and self.pool.prefix_cache:
+            # a sibling's chunk reserved earlier in this step — or
+            # riding the step in flight — is about to compute the very
+            # block this prompt needs next (the same tokens from
+            # position 0 on): nothing of it is indexed before its ids
+            # are accepted — wait for it, and map its pages then
+            # instead of computing them again beside it
+            if self._flight is not None:
+                earlier = [*self._flight.prefills.values(), *earlier]
             ps = self.pool.page_size
             have = self.pool.peek_prefix(toks, limit=len(toks) - 1)[0] \
                 if earlier else 0
@@ -1923,6 +2077,9 @@ class ServingEngine:
                     and other.tokens[:have] == toks[:have]
                     for other, start, n in earlier):
                 return None
+            if self._host_tier is not None and self.pool.peek_prefix(
+                    toks, limit=len(toks) - 1)[3]:
+                self._drain('tier')     # the step waits on a host fetch
             # first chunk of a fresh admit (or a resume): map the
             # longest indexed prefix — full pages only, capped one
             # short of the context so the step still computes the
@@ -1930,7 +2087,7 @@ class ServingEngine:
             cached = self.pool.match_and_map(req.id, toks,
                                              limit=len(toks) - 1)
             if cached:
-                req.prefilled = cached
+                req.prefilled = start = cached
                 self._trace(req, 'prefix_hit', cached_tokens=cached,
                             pages=len(self.pool.page_table(req.id)))
                 # host-tier resurrection rode the hit (ISSUE 20): the
@@ -1942,7 +2099,6 @@ class ServingEngine:
                 if rz:
                     self._trace(req, 'resurrect', pages=rz['pages'],
                                 tokens=rz['tokens'])
-        start = req.prefilled
         n = min(C, len(toks) - start)
         if not self._ensure_or_preempt(req, start + n):
             return None     # yielded to higher-priority pool pressure:
@@ -2028,25 +2184,132 @@ class ServingEngine:
             req.state = RequestState.RUNNING
         return 1
 
+    def _known(self, req):
+        """(state, context length, prompt tokens prefilled) of a
+        request once the step in flight is accepted, on what that step
+        is KNOWN to do (`_Flight`) — the host's own with nothing in
+        flight. Whether a token is the EOS cannot be known: a row that
+        may meet one is taken to go on, and `_accept_decode` drops what
+        it computed past it."""
+        state, made, filled = req.state, len(req.generated), req.prefilled
+        flight = self._flight
+        if flight is not None:
+            chunk = flight.prefills.get(req.id)
+            if chunk is not None:
+                filled = chunk[1] + chunk[2]
+                if filled == len(req.tokens):
+                    state = RequestState.RUNNING
+            made += req.id in flight.emits
+            if state == RequestState.RUNNING \
+                    and made >= req.max_new_tokens:
+                state = RequestState.FINISHED
+        return state, len(req.prompt) + made, filled
+
+    def _slots_in(self, state):
+        """[(slot, request, context length)] of the requests that will
+        be in `state` once the step in flight is accepted (`_known`)."""
+        out = []
+        for i, req in enumerate(self.scheduler.slots):
+            if req is not None:
+                known, context, _ = self._known(req)
+                if known == state:
+                    out.append((i, req, context))
+        return out
+
+    def _holds_back(self):
+        """Why the next step cannot be planned before the ids in flight
+        arrive, read off the engine's own state: a fused window is
+        configured ('fused'), or a greedy row will decode under
+        speculation — the n-gram proposal reads its token ('verify').
+        None: it can."""
+        if self._effective_fused_k() > 1:
+            return 'fused'
+        if self._effective_spec_k() > 0 and any(
+                req.top_k <= 0
+                for _, req, _ in self._slots_in(RequestState.RUNNING)):
+            return 'verify'
+        return None
+
+    def _launch(self):
+        """Plan one step and queue its programs: the next prompt chunk
+        of every prefilling request, reserved, then the dispatches
+        (`_advance`). -> the `_Flight` to land, or None: nothing to
+        run, or a verify step or fused window that landed at once."""
+        chunks = []
+        for _, req, _ in self._slots_in(RequestState.PREFILL):
+            with RecordEvent('serve::prefill_chunk', event_type='serve',
+                             req=req.id):
+                chunk = self._reserve_chunk(req, chunks)
+            if chunk is not None:
+                chunks.append(chunk)
+        if not chunks and not self._slots_in(RequestState.RUNNING):
+            return None
+        called = self._dispatches
+        with RecordEvent('serve::dispatch', event_type='serve'):
+            flight = self._advance(chunks)
+        self._steps += self._dispatches > called
+        return flight
+
+    def _land(self, behind):
+        """Bring the step in flight home: every dispatch's ids fetched
+        (inner chunks alone sample nothing anyone reads: no fetch) and
+        accepted, in the order they were queued — the device runs the
+        next dispatch, and with `behind` the next STEP, while the host
+        accepts this one's tokens."""
+        flight, self._flight = self._flight, None
+        B = self.config.max_batch_size
+        for rows, riding, queued in flight.dispatches:
+            nxt = self._fetch(queued, behind) if rows or any(
+                _samples(*c) for c in riding) else None
+            if rows:
+                # POST-preemption counts: the rows that rode
+                tokens = self._accepted(self._accept_decode, nxt, rows,
+                                        False, 1)
+                self._decode_tokens += tokens
+                self._it_rows += len(rows)
+                self._it_tokens += tokens
+            if riding:
+                self._accepted(self._accept_chunks, nxt, B, riding,
+                               chunks=len(riding))
+                self._it_chunk_tokens += sum(n for _, _, n in riding)
+
+    def _drain(self, reason='idle'):
+        """Empty the pipe: fetch and accept the step in flight with
+        nothing queued behind it, for `reason` (DRAIN_REASONS) — what
+        cannot be planned before its ids, or must not touch what it
+        still writes, waits here; after it the engine stands where the
+        serial order would. -> whether a step was in flight."""
+        if self._flight is None:
+            return False
+        self._drains[reason] += 1
+        self._land(behind=False)
+        return True
+
     def _advance(self, chunks):
-        """The step's dispatches. The running set's decode rows and the
-        reserved prompt `chunks` ([(request, first position, tokens)])
-        ride ONE mixed program; more chunks than its prefill group has
-        rows ride further dispatches of the same program with an idle
-        decode group; no chunk at all is the [B, 1] step. With spec_k >
-        0, greedy requests whose history yields an n-gram proposal
-        carry up to k draft tokens into the [B, spec_k+1] verify step:
-        every draft position's greedy argmax comes back in the one
-        fetch, the longest agreeing draft prefix is accepted plus the
-        bonus token, and pages grown for rejected drafts are handed
-        back (their slots are overwritten in place by later writes —
-        the ragged kernel's seq_len mask never exposes a stale slot
-        before the step that rewrites it); the verify step and the
-        fused window keep a dispatch of their own, and the chunks ride
-        the mixed program beside an idle decode group. A request whose
-        last chunk rides here decodes from the NEXT step on. Returns
-        (decode rows, tokens they emitted, prompt tokens prefilled)."""
-        sched = self.scheduler
+        """The step's dispatches, queued. The running set's decode rows
+        and the reserved prompt `chunks` ([(request, first position,
+        tokens)]) ride ONE mixed program; more chunks than its prefill
+        group has rows ride further dispatches of the same program with
+        an idle decode group; no chunk at all is the [B, 1] step. Every
+        dispatch of the step is queued here and fetched in `_land` (no
+        chunk depends on another's result: the pages of each are
+        reserved first), a step later when the next can be launched
+        before; a request whose last chunk rides here decodes from the
+        NEXT step on, its first token handed on by its slot whichever
+        dispatch made it. With spec_k > 0, greedy requests whose
+        history yields an n-gram proposal carry up to k draft tokens
+        into the [B, spec_k+1] verify step: every draft position's
+        greedy argmax comes back in the one fetch, the longest agreeing
+        draft prefix is accepted plus the bonus token, and pages grown
+        for rejected drafts are handed back (their slots are
+        overwritten in place by later writes — the ragged kernel's
+        seq_len mask never exposes a stale slot before the step that
+        rewrites it); the verify step and the fused window are fetched
+        and accepted at once, with nothing in flight (`_holds_back`),
+        and the chunks ride the mixed program beside an idle decode
+        group. Everything is planned on `_known`. Returns the `_Flight`
+        queued, or None."""
+        running = self._slots_in(RequestState.RUNNING)
         K = self._effective_spec_k()
         if self.config.spec_k > 0 and K == 0:
             # degrade stage >= 1 shed the configured draft capacity this
@@ -2054,20 +2317,17 @@ class ServingEngine:
             # remaining budget) per greedy running row) as shed
             # capacity — never computed, so outside the emitted-token
             # identity
-            for req in sched.slots:
-                if req is None or req.state != RequestState.RUNNING \
-                        or req.top_k > 0:
-                    continue
-                budget = req.max_new_tokens - len(req.generated) - 1
-                if budget > 0:
+            for _, req, context in running:
+                budget = req.max_new_tokens - 1 - (
+                    context - len(req.prompt))
+                if req.top_k <= 0 and budget > 0:
                     self.ledger.account_spec_shed(
                         min(self.config.spec_k, budget),
                         tenant_id=req.tenant_id)
         proposals = {}
-        if K > 0:
-            for req in sched.slots:
-                if req is None or req.state != RequestState.RUNNING \
-                        or req.top_k > 0:
+        if K > 0 and self._flight is None:
+            for _, req, _ in running:
+                if req.top_k > 0:
                     continue        # spec verify is greedy-only
                 budget = req.max_new_tokens - len(req.generated) - 1
                 drafts = _ngram_propose(req.tokens,
@@ -2075,6 +2335,7 @@ class ServingEngine:
                                         min(K, budget))
                 if drafts:
                     proposals[req.id] = drafts
+        B = self.config.max_batch_size
         # fused window (ISSUE 19): when no verify columns ride this
         # dispatch (spec takes precedence — its drafts already amortize
         # the host fetch) and the scheduler is quiescent for a full
@@ -2087,66 +2348,65 @@ class ServingEngine:
         if FK > 1 and not proposals and self._fused_ok(FK):
             res = self._fused_decode_window(FK)
             if res is not None:
-                return (*res, 0)
-        # capacity first (may preempt, or yield the request itself);
-        # then snapshot the running set — a yielded request left its
+                self._it_rows, self._it_tokens = res
+                return None
+        # capacity first (may preempt, or yield the request itself: a
+        # row an earlier row's growth preempted grows nothing); then
+        # snapshot the running set again — a yielded request left its
         # slot, so the batch build below skips it naturally
-        for req in list(sched.slots):
-            if req is not None and req.state == RequestState.RUNNING:
-                if not self._ensure_or_preempt(
-                        req, req.context_len
-                        + len(proposals.get(req.id, ()))):
-                    proposals.pop(req.id, None)
-        B = self.config.max_batch_size
+        for _, req, _ in running:
+            state, context, _ = self._known(req)
+            if state == RequestState.RUNNING \
+                    and not self._ensure_or_preempt(
+                        req, context + len(proposals.get(req.id, ()))):
+                proposals.pop(req.id, None)
         rows = []
-        for i, req in enumerate(sched.slots):
-            if req is not None and req.state == RequestState.RUNNING:
-                drafts = proposals.get(req.id, [])
-                rows.append((i, req, [_last_token(req)] + drafts,
-                             req.context_len + len(drafts)))
+        for i, req, context in self._slots_in(RequestState.RUNNING):
+            drafts = proposals.get(req.id, [])
+            rows.append((i, req, [_last_token(req)] + drafts,
+                         context + len(drafts)))
         # a reservation above (a later chunk's, a decode row's) may have
         # preempted a prefilling request: its pages went with it, and
         # it rides nothing
         chunks = [c for c in chunks if c[0].state == RequestState.PREFILL]
-        decode_rows, decode_tokens = len(rows), 0
+        if not rows and not chunks:
+            return None
+        flight = _Flight()
+        self._pipelined += self._flight is not None
         if rows:
             self._decode_steps += 1
             self._occupancy_sum += len(rows) / B
             self._util_sum += self.pool.utilization()
             # without a surviving proposal the verify columns would all
             # be padding: the [B, 1] rows serve
-            verify = any(len(query) > 1 for _, _, query, _ in rows)
-            if verify or not chunks:
-                T = K + 1 if verify else 1
-                nxt = self._dispatch('verify' if verify else 'decode',
-                                     rows, B, T)
-                decode_tokens = self._accepted(self._accept_decode, nxt,
-                                               rows, verify, T)
+            if any(len(query) > 1 for _, _, query, _ in rows):
+                self._drains['verify'] += 1
+                nxt = self._dispatch('verify', rows, B, K + 1)
+                self._it_rows = len(rows)
+                self._it_tokens = self._accepted(
+                    self._accept_decode, nxt, rows, True, K + 1)
+                self._decode_tokens += self._it_tokens
                 rows = []
-        # every dispatch of the step is queued before the first is
-        # fetched: the device runs the next one while the host accepts
-        # this one's tokens (no chunk depends on another's result: the
-        # pages of each were reserved above)
+            elif not chunks:
+                flight.dispatches.append(
+                    (rows, [], self._enqueue('decode', rows, B, 1)))
         C, P = self._effective_prefill_chunk(), self._prefill_rows
-        queued = []
         for first in range(0, len(chunks), P):
             riding = chunks[first:first + P]
-            queued.append((rows, riding, self._enqueue(
+            flight.dispatches.append((rows, riding, self._enqueue(
                 'mixed', rows, B, C,
                 chunks=[(row, req, req.tokens[start:start + n], start + n)
                         for row, (req, start, n) in enumerate(riding)])))
             rows = []
-        for rows, riding, dispatch in queued:
-            # inner chunks alone sample nothing anyone reads: no fetch
-            nxt = self._fetch(dispatch) if rows or any(
-                _samples(*c) for c in riding) else None
-            if rows:
-                decode_tokens = self._accepted(self._accept_decode, nxt,
-                                               rows, False, 1)
-            self._accepted(self._accept_chunks, nxt, B, riding,
-                           chunks=len(riding))
-        self._decode_tokens += decode_tokens
-        return decode_rows, decode_tokens, sum(n for _, _, n in chunks)
+        if not flight.dispatches:
+            return None
+        for rows, riding, _ in flight.dispatches:
+            flight.emits.update(req.id for _, req, _, _ in rows)
+            for chunk in riding:
+                flight.prefills[chunk[0].id] = chunk
+                if _samples(*chunk):
+                    flight.emits.add(chunk[0].id)
+        return flight
 
     def _accept_decode(self, nxt, rows, verify, T):
         """Host accept of one [B, T] decode/verify fetch: token append,
@@ -2154,6 +2414,15 @@ class ServingEngine:
         Returns the tokens emitted."""
         emitted_total = 0
         for i, req, query, _context in rows:
+            if self.scheduler.slots[i] is not req:
+                # the row was launched before the ids arrived that took
+                # its request from the slot — an EOS (or a hand-over to
+                # another engine): what it computed is dropped, never
+                # delivered; the pages it grew went with the retire,
+                # and what it wrote lies past every indexed page, where
+                # a later owner's writes come before any read
+                self._overrun += req.state == RequestState.FINISHED
+                continue
             drafts = query[1:]
             spec_m = None
             if verify:
@@ -2230,6 +2499,8 @@ class ServingEngine:
         watchdog's deadline_action='abort' path and operator kill.
         No-op (returns False) on an already-retired/aborted request —
         double accounting would poison the SLO histograms."""
+        if self._flight is not None and self._flight.carries(req):
+            self._drain('abort')    # it may finish in the step in flight
         if not self.scheduler.abort(req):
             return False
         self.pool.release(req.id)
@@ -2364,6 +2635,14 @@ class ServingEngine:
             'padded_prefill_token_share':
                 (1.0 - self._prefill_tokens / self._mixed_slots
                  if self._mixed_slots else 0.0),
+            # one step ahead: steps launched while another was in
+            # flight (of the steps that dispatched: dispatches_total /
+            # dispatches_per_step), fetches with nothing queued behind
+            # them by what held the next step back, and rows dropped
+            # because their request had met its EOS a step before
+            'pipelined_steps_total': self._pipelined,
+            'pipeline_drains_total': dict(self._drains),
+            'overrun_tokens_total': self._overrun,
             # sparse-expert layers (zeros for a model without them)
             'moe_rows_total': self._moe['rows'],
             'moe_experts_touched_total': self._moe['experts_touched'],
@@ -2463,6 +2742,9 @@ class ServingEngine:
         self._dispatches = 0
         self._mixed_dispatches = 0
         self._mixed_slots = 0
+        self._pipelined = 0
+        self._drains = dict.fromkeys(DRAIN_REASONS, 0)
+        self._overrun = 0
         self._spec_proposed = 0
         self._spec_accepted = 0
         self._spec_steps = 0
@@ -2532,11 +2814,13 @@ class ServingEngine:
         stops reporting (the PR-13 training-engine discipline —
         serve_ledger_snapshot() and the host-gap registry read live
         objects, not stale gauges)."""
+        self._drain()
         if self._host_tier is not None:
             self._host_tier.shutdown()
         self.pool.drop_arrays()
         self._step_fns.clear()
         self._params = {}
+        self._prev_ids = None
         unregister_monitor(self._gap)
         self.ledger.unregister()
         return {'released': True}

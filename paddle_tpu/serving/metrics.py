@@ -163,7 +163,15 @@ _COUNTER_NAMES = (
     'ptpu_serve_fused_windows_total',
     'ptpu_serve_fused_iterations_total',
     'ptpu_serve_fused_tokens_total',
+    # one step ahead (engine.py `_launch` / `_land`): steps queued on
+    # the device before the ids of the step before them were fetched,
+    # and decode rows dropped because their request had met its EOS in
+    # that step. `ptpu_serve_pipeline_drains_total{reason}` (below)
+    # counts the fetches with NOTHING queued behind them
+    'ptpu_serve_pipelined_steps_total',
+    'ptpu_serve_overrun_tokens_total',
 )
+_DRAINS = 'ptpu_serve_pipeline_drains_total'
 
 # scalar gauges: name -> (help, value(stats, pool)). One declarative
 # table so publish() (global registry) and scalar_series() (per-replica
@@ -299,6 +307,11 @@ def publish(stats):
         key = name[len('ptpu_serve_'):-len('_total')]
         g(name, help=f'serving {key.replace("_", " ")} (lifetime)').set(
             stats.get(key + '_total', 0))
+    for reason, n in (stats.get('pipeline_drains_total') or {}).items():
+        g(_DRAINS, help='steps whose ids were fetched with nothing queued '
+                        'behind them, by what held the next step back '
+                        '(lifetime)',
+          labelnames=('reason',)).set(n, reason=reason)
     # host-RAM tier (ISSUE 20): published only when the pool carries
     # tier stats, so tierless engines keep exactly the PR-19 gauge
     # set. Transfer totals are real counters host_tier.py owns — not
@@ -379,6 +392,10 @@ def serve_snapshot():
         if m is None:
             continue
         out[name] = m.value()
+    drains = reg.get(_DRAINS)
+    if drains is not None:
+        out[_DRAINS] = {key[0]: child.value()
+                        for key, child in drains._series().items()}
     h = reg.get('ptpu_serve_ttft_seconds')
     if h is not None:
         out['ptpu_serve_ttft_seconds'] = _histogram_view(h)

@@ -1260,38 +1260,42 @@ class TestOneDispatchPath:
                     if ev['event'] == 'fused_decode'][-1]
             assert 0 < last['accepted'] < last['k'] == 4
 
-        # a decode / verify / fused dispatch: prepare -> compiled_step ->
-        # sample_fetch -> accept. A step's mixed dispatches are all
-        # queued first (prepare -> compiled_step each), then fetched and
-        # accepted in their order: the decode rows' accept, then the
-        # chunks'; inner chunks alone are not fetched
+        # a verify / fused dispatch: prepare -> compiled_step ->
+        # sample_fetch -> accept, with nothing in flight. A step's decode
+        # and mixed dispatches are all queued first (prepare ->
+        # compiled_step each) and, where nothing holds it back, the NEXT
+        # step's behind them (`in_flight` 1); they land in their order,
+        # one step later: the decode rows' accept, then the chunks';
+        # inner chunks alone are not fetched
         names = [s.name.split('::')[1] for s in spans]
         shapes = []
         unfetched = 0
-        queue = []          # the mixed dispatches waiting for their turn
+        queue = []          # the dispatches waiting for their turn
         for i, s in enumerate(spans):
             if s.name == 'serve::compiled_step':
                 shape = s.args['shape']
                 shapes.append(shape)
                 assert names[i - 1] == 'prepare'
                 assert 0 <= s.args['batch'] <= 3
+                assert s.args['in_flight'] in (0, 1)
                 if shape == 'mixed':
-                    assert set(s.args) == {'shape', 'batch', 'prefill_rows'}
+                    assert set(s.args) == {'shape', 'batch', 'in_flight',
+                                           'prefill_rows'}
                     assert 1 <= s.args['prefill_rows'] <= 2
-                    # only a step's first carries decode rows
-                    assert not (queue and s.args['batch'])
                     queue.append(s)
                     continue
-                assert not queue
+                assert set(s.args) == {'shape', 'batch', 'in_flight'} | (
+                    {'k'} if shape == 'fused' else set())
+                assert s.args['batch'] >= 1
+                if shape == 'decode':
+                    queue.append(s)
+                    continue
+                assert not queue and not s.args['in_flight']
                 assert names[i + 1:i + 3] == ['sample_fetch', 'accept']
                 assert set(spans[i + 2].args) == {'emitted', 'retired'}
-                assert set(s.args) == ({'shape', 'batch', 'k'}
-                                       if shape == 'fused'
-                                       else {'shape', 'batch'})
-                assert s.args['batch'] >= 1
                 assert shape != 'fused' or s.args['k'] == 4
             elif s.name == 'serve::accept' and 'chunks' in s.args:
-                # the turn of the oldest queued mixed dispatch
+                # the turn of the oldest queued dispatch: a mixed one
                 d = queue.pop(0)
                 assert s.args['chunks'] == d.args['prefill_rows']
                 assert set(s.args) == {'chunks', 'emitted', 'retired'}
@@ -1305,6 +1309,28 @@ class TestOneDispatchPath:
                     # inner chunks beside an idle decode group
                     unfetched += 1
                     assert s.args['emitted'] == 0
+            elif s.name == 'serve::accept' and queue \
+                    and queue[0].args['shape'] == 'decode':
+                queue.pop(0)        # the [B, 1] step's turn
+                assert names[i - 1] == 'sample_fetch'
+                assert set(s.args) == {'emitted', 'retired'}
+        launched_behind = {s.args['in_flight'] for s in spans
+                           if s.name == 'serve::compiled_step'}
+        pipe = st['pipeline_drains_total']
+        if 'fused_k' in knobs:
+            # every step waits for the ids of the one before it
+            assert launched_behind == {0}
+            assert st['pipelined_steps_total'] == 0 < pipe['fused']
+        elif 'spec_k' in knobs:
+            # ... wherever a greedy row decodes: its proposal reads them
+            assert pipe['verify'] > st['pipelined_steps_total']
+        else:
+            assert launched_behind == {0, 1}
+            assert st['pipelined_steps_total'] > 0
+            assert pipe['preempt'] >= 1 and pipe['idle'] >= 2
+            # the sampled row was launched a step past its EOS, and
+            # what it computed there was dropped
+            assert st['overrun_tokens_total'] == 1
         assert not queue
         assert set(shapes) == {'mixed', 'decode'} | (
             {'verify'} if 'spec_k' in knobs else set()) | (

@@ -81,12 +81,15 @@ def alone(model, prompts, new, listen=None):
     return outs
 
 
-def crowd(eng, running, arriving, new):
+def crowd(eng, running, arriving, new, drained=False):
     """`running` decode until each has a token; then `arriving` are
-    submitted together, so that they prefill beside the running rows."""
+    submitted together, so that they prefill beside the running rows.
+    `drained`: step by step, with none in flight when they arrive."""
     reqs = [eng.submit(p, max_new_tokens=new, top_k=0) for p in running]
     while not all(r.state == RequestState.RUNNING for r in reqs):
         eng.step()
+        if drained:
+            eng._drain()
     reqs += [eng.submit(p, max_new_tokens=new, top_k=0) for p in arriving]
     return reqs
 
@@ -210,16 +213,21 @@ def test_a_crowded_step_dispatches_in_turns_and_fetches_what_is_due(
         return shapes, len(fetches) - before
     # step 1: rows (0, 1), (2, 3), (4): the prompts of slots 1 and 4
     # end, so the first and the third dispatch are fetched. The [B, 1]
-    # program is compiled beside the mixed one, before any row decodes
+    # program is compiled beside the mixed one, before any row decodes.
+    # The call after idle launches the NEXT step too, before that
+    # fetch, on what the first is known to do: the two rows whose
+    # prompts end in it ride with the first two of the second chunks
     assert step() == ([('mixed', 0, 2), ('mixed', 0, 2),
+                       ('mixed', 0, 1), ('mixed', 2, 2),
                        ('mixed', 0, 1)], 2)
     assert set(eng._step_fns) == {(SLOTS, 1, False, False),
                                   ('mixed', SLOTS, P, CHUNK, False)}
     assert [r.state for r in reqs] == [
         RequestState.PREFILL, RequestState.RUNNING, RequestState.PREFILL,
         RequestState.PREFILL, RequestState.RUNNING]
-    # step 2: the two running rows ride with the first two chunks
-    assert step() == ([('mixed', 2, 2), ('mixed', 0, 1)], 2)
+    # step 2: every row decodes in the step it launches; the second
+    # step lands, both of its dispatches fetched (each ends a prompt)
+    assert step() == ([('decode', 5, None)], 2)
     assert all(r.state == RequestState.RUNNING for r in reqs)
     drain(eng)
     assert all(len(r.generated) == 3 for r in reqs)
@@ -239,13 +247,14 @@ def test_a_row_preempted_after_its_reservation_leaves_no_page(dense):
     # takes a fifth; each row's 17th token needs a third page, and the
     # second row's is the seventh of six
     eng = engine(dense, num_pages=6)
-    reqs = crowd(eng, running, late, 6)
+    reqs = crowd(eng, running, late, 6, drained=True)
     victim = reqs[-1]
     mark = prof.mark()
     eng.step()
     steps = [s.args for s in prof.spans(since_id=mark)
              if s.name == 'serve::compiled_step']
-    assert [(a['shape'], a['batch']) for a in steps] == [('decode', 2)]
+    # (the step and, behind it, the next: the victim waits for pages)
+    assert [(a['shape'], a['batch']) for a in steps] == [('decode', 2)] * 2
     assert victim.state == RequestState.WAITING
     assert victim.preemptions == 1 and victim.prefilled == 0
     assert eng.pool.page_table(victim.id) == []

@@ -218,8 +218,10 @@ def _operands(eng, prompts):
 def test_two_step_programs_with_the_operands_they_had(family, model):
     """GPT and AFMoE: a mixed and a [B, 1] program, each with the 8 host
     operands they had (tokens, tables, seq and q lens, key, ordinals,
-    temperatures, top-ks), AFMoE's experts' counters besides — no state,
-    no slots. Only the stateful model adds its arrays and the slots."""
+    temperatures, top-ks) and the two that carry a decode row's token
+    from one program to the next on the device (the ids handed on, and
+    `src`: ISSUE 33), AFMoE's experts' counters besides — no state, no
+    slots. Only the stateful model adds its arrays and the slots."""
     if family == 'gpt':
         from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
         paddle.seed(0)
@@ -250,4 +252,4 @@ def test_two_step_programs_with_the_operands_they_had(family, model):
     finally:
         eng.shutdown()
     assert set(seen) == {('mixed', 4, 2, CHUNK, False), (4, 1, False, False)}
-    assert set(seen.values()) == {8 + extra}
+    assert set(seen.values()) == {10 + extra}

@@ -300,8 +300,12 @@ def test_lowering_and_compiling_are_counted_apart():
 # ---------------------------------------------------------------------------
 # serving engine spans
 # ---------------------------------------------------------------------------
+# a step's launch (`serve::prefill_chunk`, `serve::dispatch`) and the
+# landing of the step in flight (its fetch and accepts, which run one
+# program later than they were queued) are children of the step alike
 STEP_CHILDREN = {'serve::schedule', 'serve::prefill_chunk',
-                 'serve::dispatch', 'serve::telemetry'}
+                 'serve::dispatch', 'serve::sample_fetch',
+                 'serve::accept', 'serve::telemetry'}
 
 
 @pytest.fixture(scope='module')
@@ -348,7 +352,10 @@ class TestServingSpans:
         assert all(s.parent == 0 for s in step_spans)
         for s in spans:
             if s.name in STEP_CHILDREN:
-                assert by_id[s.parent].name == 'serve::step', s
+                assert by_id[s.parent].name in (
+                    ('serve::step', 'serve::dispatch')
+                    if s.name in ('serve::sample_fetch', 'serve::accept')
+                    else ('serve::step',)), s
         for step in step_spans:
             kids = [s.name for s in spans if s.parent == step.id]
             assert kids.count('serve::schedule') == 1
@@ -375,12 +382,23 @@ class TestServingSpans:
     def test_device_spans_sit_under_their_phase(self, served):
         shape, spans, _, _ = served
         by_id = {s.id: s for s in spans}
-        for name in ('serve::prepare', 'serve::compiled_step',
-                     'serve::sample_fetch', 'serve::accept'):
+        for name in ('serve::prepare', 'serve::compiled_step'):
             got = [s for s in spans if s.name == name]
             assert got, name
             assert {by_id[s.parent].name for s in got} == {
                 'serve::dispatch'}, name
+        # a launched step lands under the step itself; a verify step or
+        # a fused window is fetched and accepted where it is called
+        for name in ('serve::sample_fetch', 'serve::accept'):
+            got = [s for s in spans if s.name == name]
+            assert got, name
+            parents = {by_id[s.parent].name for s in got}
+            assert 'serve::step' in parents and parents <= {
+                'serve::step', 'serve::dispatch'}, name
+            assert ('serve::dispatch' in parents) == (shape != 'serial')
+        flights = {s.args['in_flight'] for s in spans
+                   if s.name == 'serve::compiled_step'}
+        assert flights == ({0, 1} if shape == 'serial' else {0})
         shapes = {s.args['shape'] for s in spans
                   if s.name == 'serve::compiled_step'}
         assert 'mixed' in shapes

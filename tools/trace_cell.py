@@ -94,7 +94,16 @@ def main(argv=None):
         mixed.append(dict(
             {k: st[k] for k in ('dispatches_per_step',
                                 'prefill_rows_per_dispatch',
-                                'padded_prefill_token_share')},
+                                'padded_prefill_token_share',
+                                # one step ahead: of `steps`, those
+                                # launched behind another, the fetches
+                                # with nothing behind them, rows dropped
+                                'pipelined_steps_total',
+                                'pipeline_drains_total',
+                                'overrun_tokens_total',
+                                'preemptions_total')},
+            steps=round(st['dispatches_total']
+                        / max(st['dispatches_per_step'], 1e-9)),
             prefill_rows=self._prefill_rows))
         return shutdown(self, *a, **kw)
     serving_engine.ServingEngine.shutdown = shutdown_keeping_roofline
@@ -147,9 +156,10 @@ def main(argv=None):
                 'kv_read_tokens_full', 'moe_load_max_over_mean')
             if k in rooflines[-1])
     if mixed:
-        # how the mixed step engaged, warm phase and window together
+        # how the mixed step and the pipe engaged, warm phase and window
+        # together
         summary['mixed_step'] = mixed[-1]
-        text += '\n\nmixed step at shutdown: ' + ', '.join(
+        text += '\n\nmixed step and pipe at shutdown: ' + ', '.join(
             f'{k} {v}' for k, v in mixed[-1].items())
     print(text, flush=True)
     base = os.path.join(args.out, cell['name'])
