@@ -77,6 +77,14 @@ FULL = dict(
                 page_size=16, pages=176, batch=64, chunk=128,
                 contexts=(16, 511, 513, 2816), prefill_rows=2,
                 channels=5120, states=16),
+    # the latent body of the paged kernel as the latent-attention server
+    # cell calls it (benchmarks/configs/axk1.json): 64 query heads on
+    # ONE stored row of 512 value + 64 rotary lanes in 640, pages of 64
+    # over 528-page tables, ragged contexts from one page to the
+    # table's end, at the decode rows' and the chunk's widths
+    latent=dict(heads=64, value=512, rotary=64, page_size=64, pages=528,
+                batch=64, chunk=256, prefill_rows=2,
+                contexts=(64, 700, 8448, 33792)),
 )
 
 # Tolerances, as max|got - ref| / max|ref| over a tensor.
@@ -452,6 +460,46 @@ def phase_kernels(size):
 
         scan_case(hy['batch'], 1)
         scan_case(hy['prefill_rows'], hy['chunk'])
+
+    # -- latent paged attention ---------------------------------------------
+    la = size.get('latent')
+    if la:
+        Hq, ps = la['heads'], la['page_size']
+        lanes = -(-(la['value'] + la['rotary']) // 128) * 128
+        pool = la['batch'] * la['pages'] // 8 + 3
+
+        def latent_paged(Bq, T):
+            """[Bq, T] rows of ragged contexts against the ONE array of
+            stored rows; the dense route a few rows at a time (it
+            gathers a row's whole table)."""
+            pt = rng.randint(0, pool, (Bq, la['pages'])).astype(np.int32)
+            ctx = np.resize(la['contexts'], Bq).astype(np.int32)
+            args = (rand((Bq, T, Hq * lanes), scale=0.05),
+                    rand((pool, ps, lanes)), None, jnp.asarray(pt),
+                    jnp.asarray(ctx), jnp.asarray(np.minimum(T, ctx)))
+
+            def call(attention):
+                return lambda q, pages, _, *a: attention(
+                    q, pages, None, *a, num_heads=Hq, head_dim=lanes,
+                    latent=(la['value'], la['rotary']))
+            got = np.asarray(jax.jit(call(
+                pa.ragged_paged_attention_pallas))(*args), np.float32)
+            checked = sorted({*range(min(Bq, 4)), *range(max(Bq - 4, 0), Bq)})
+            for at in range(0, len(checked), 4):
+                rows = np.asarray(checked[at:at + 4])
+                part = [a if a is None or a.shape[0] != Bq else a[rows]
+                        for a in args]
+                ref = ref_call(call(pa.ragged_paged_attention_dense), *part)
+                live = (np.arange(T)[None, :] < np.minimum(T, ctx[rows])
+                        [:, None])[..., None]
+                record(f'paged_attention_latent B={Bq} T={T} rows '
+                       f'{rows.tolist()} ctx={ctx[rows].tolist()}',
+                       np.where(live, got[rows], 0),
+                       np.where(live, np.asarray(ref, np.float32), 0),
+                       TOL_BF16)
+
+        latent_paged(la['batch'], 1)
+        latent_paged(la['prefill_rows'], la['chunk'])
 
     # -- fused optimizer step + grad stats vs core.bucketing.shard_update --
     n = size['opt_elems']

@@ -12,3 +12,4 @@ from .bert import BertConfig, BertModel, BertForPretraining
 from .deepfm import DeepFM, deepfm_loss  # noqa: F401,E402
 from .afmoe import AfmoeConfig, AfmoeForCausalLM  # noqa: F401,E402
 from .phi4flash import Phi4FlashConfig, Phi4FlashForCausalLM  # noqa: F401,E402
+from .axk1 import AxK1Config, AxK1ForCausalLM  # noqa: F401,E402

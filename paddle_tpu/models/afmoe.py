@@ -330,15 +330,18 @@ def _fill(key, shapes, stds, dtype_of):
 
 
 class AfmoeForCausalLM(_Params):
-    """Embedding, the decoder layers, final norm and the untied head."""
+    """Embedding, the decoder layers, final norm and the untied head.
+    A model of the same outline (models/axk1.py) names its own layer
+    class and the vocabulary rows it holds."""
+    decoder_layer = AfmoeDecoderLayer
 
     def __init__(self, config):
         super().__init__()
         self.config = cfg = config
-        V, H = cfg.vocab_size, cfg.hidden_size
+        V, H = getattr(cfg, 'vocab_held', cfg.vocab_size), cfg.hidden_size
         self._declare(embed=(V, H), final_norm=(H,), lm_head=(V, H))
         self.layers = nn.LayerList(
-            [AfmoeDecoderLayer(cfg, i) for i in range(cfg.num_layers)])
+            [self.decoder_layer(cfg, i) for i in range(cfg.num_layers)])
         self._sparse = [i for i, l in enumerate(self.layers) if l.sparse]
         self.reset_parameters()
 

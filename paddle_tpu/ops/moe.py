@@ -8,6 +8,13 @@ routed (token, expert) pairs only.
     weights  w_e = s_e / (sum_S s + 1e-20) * route_scale  (route_norm)
     out      = sum_{e in S} w_e * SwiGLU_e(m)
 
+Group-limited choice (`n_group` > 1; DeepSeek-V3, arXiv:2412.19437
+section 2.1.2, the sigmoid-scored form): the E experts lie in `n_group`
+groups of E / n_group neighbours, a group's score is the sum of its two
+largest s + b, and the top-k is taken inside the `topk_group` best
+groups alone — a token's experts then lie on at most that many nodes.
+`n_group` 1 is the choice above, bit for bit.
+
 The layer is told which experts it holds (`experts_held = (first,
 count)`, the chip's share under expert parallelism): it routes over ALL
 experts, computes the part of the sum its own experts give and leaves
@@ -25,15 +32,26 @@ import jax.numpy as jnp
 from .pallas import grouped_matmul as gmm
 
 
-def route(m, router_w, bias, top_k, route_scale=1.0, route_norm=True):
-    """m [T, H], router_w [H, E], bias [E] -> (experts int32 [T, k],
-    weights float32 [T, k]). The product accumulates in float32 and the
-    scores stay float32: the top-k's eighth choice hangs on the fourth
-    digit."""
+def route(m, router_w, bias, top_k, route_scale=1.0, route_norm=True,
+          n_group=1, topk_group=1):
+    """m [T, H], router_w [H, E], bias [E] or None (no balancing bias)
+    -> (experts int32 [T, k], weights float32 [T, k]). The product
+    accumulates in float32 and the scores stay float32: the top-k's
+    eighth choice hangs on the fourth digit. `n_group` > 1: only experts
+    of the `topk_group` best groups can be chosen."""
     logits = jnp.dot(m, router_w.astype(m.dtype),
                      preferred_element_type=jnp.float32)
     scores = jax.nn.sigmoid(logits)
-    _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        T, E = choice.shape
+        grouped = choice.reshape(T, n_group, E // n_group)
+        best_two, _ = jax.lax.top_k(grouped, 2)
+        _, kept = jax.lax.top_k(jnp.sum(best_two, -1), topk_group)
+        keep = jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
+        choice = jnp.where(keep[:, :, None], grouped, -jnp.inf) \
+            .reshape(T, E)
+    _, experts = jax.lax.top_k(choice, top_k)
     weights = jnp.take_along_axis(scores, experts, axis=-1)
     if route_norm:
         weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)

@@ -87,6 +87,27 @@ of K, no second body; the Mosaic call is named `paged_attention_diff`
 (`..._diff_window` with a window). With `diff` absent the body is what
 it was.
 
+Latent attention (`latent=(value lanes, rotary lanes)`, static;
+DeepSeek-V2, arXiv:2405.04434, in its absorbed form): the pool holds ONE
+array a layer, a row `[c_kv | k_pe | 0]` of `value lanes + rotary
+lanes` padded to whole 128-lane tiles (512 + 64 in 640), and every one
+of the `num_heads` query heads reads that one stored head: scores
+contract over the whole row (q is `[q' | q_pe | 0]` per head, the
+caller's softmax scale already in it — the kernel applies none), values
+are the first `value lanes` of the SAME page copy — no second pool, no
+second DMA, no second body —, p.v takes the probabilities in the pool's
+dtype (fp32 accumulation; the softmax state stays fp32) and the output
+is `value lanes` wide a head. The decode group is the batched product
+with every head a row ([64, 640] x [640, keys], then [64, keys] x
+[keys, 512]); a prompt
+chunk stacks heads as rows like a kv group does, and since 64 heads x
+256 queries of 640 lanes fit no VMEM the grid gains QUERY TILES: each
+batch row runs as `q_tiles` programs of `_LATENT_TILE_ROWS` (head,
+token) rows, every tile walking the row's pages again (a chunk's
+products outweigh its copies 30 to 1). The Mosaic call is named
+`paged_attention_latent`. With `latent` absent the body, the grid and
+the operands are what they were.
+
 Layouts:
   q           [B, T, Hq*D]  new-token queries, right-padded to T per row
   k_pages     [N_pages, page_size, H*D]   the pool's device arrays (H:
@@ -159,10 +180,14 @@ _SCORE_BYTES = 2 * 2 ** 20
 # block-diagonal score product; above it (the prefill chunk) each head
 # runs its own MXU-shaped [T, D] x [D, keys] product
 _BATCHED_ROWS = 128
+# (head, token) rows of one query tile of a latent chunk: 8 heads x 256
+# queries; q and out blocks (double-buffered) and the fp32 accumulator
+# then take 18 MiB, and a [rows, 256 keys] score tile _SCORE_BYTES
+_LATENT_TILE_ROWS = 2048
 
 
 def _wave_pages(page_size, HD, kv_dtype, rows, q_dtype, P, num_heads,
-                quantized):
+                quantized, planes=2):
     """(W, vmem bytes): pages of K (and as many of V) per DMA wave —
     _WAVE_BYTES over one page's K+V bytes, shrunk until two wave slots
     fit _VMEM_BUDGET beside the double-buffered q / out blocks, the
@@ -170,8 +195,9 @@ def _wave_pages(page_size, HD, kv_dtype, rows, q_dtype, P, num_heads,
     [rows, keys] score tile fits _SCORE_BYTES; a power of two so
     `W * page_size` keys tile the lanes; never more than a row's
     page-table slots. `HD` is the pool's width (kv heads x head_dim),
-    `num_heads` its kv heads."""
-    page = 2 * scaffold.block_bytes((page_size, HD), kv_dtype)
+    `num_heads` its kv heads, `planes` the arrays a wave copies from (2:
+    K and V; 1: a latent plane)."""
+    page = planes * scaffold.block_bytes((page_size, HD), kv_dtype)
     fixed = 4 * scaffold.block_bytes((rows, HD), q_dtype) \
         + scaffold.block_bytes((rows, HD), jnp.float32)
     if quantized:
@@ -191,10 +217,10 @@ def _wave_pages(page_size, HD, kv_dtype, rows, q_dtype, P, num_heads,
     return W, need
 
 
-def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, v_hbm, *rest,
+def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, *rest,
                          page_size, num_heads, head_dim, wave_pages,
                          batched, quantized=False, group=1, window=None,
-                         diff=1):
+                         diff=1, latent=None, q_tiles=1):
     """One batch row: a loop over the row's OWN live pages.
 
     `num_heads` counts the pool's (kv) heads; `group` query heads
@@ -204,6 +230,11 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, v_hbm, *rest,
     its first query's oldest key, and no older page is copied. `diff`
     (static): `head_dim` holds that many key sub-heads side by side,
     each query row zero outside its own, so only the scale differs.
+    `latent` (static; (value lanes, rotary lanes)): there is no V pool —
+    the values are the first `value lanes` of the wave's K copy, the
+    accumulator and the output are that wide, and the scores come
+    unscaled; `q_tiles` programs then share one batch row (program i is
+    tile i % q_tiles of row i // q_tiles), each with its own rows of q.
 
     pt_ref/ln_ref are scalar-prefetched (page tables, [B, 2] lens);
     k_hbm / v_hbm are the whole pools, left in HBM. Wave w copies pages
@@ -228,16 +259,25 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, v_hbm, *rest,
     row's [P*page_size, H] fp32 scales in VMEM, applied per head slice
     to the wave's upcast pages.
     """
-    if quantized:
-        ks_ref, vs_ref, o_ref, kbuf, vbuf, sem, nxt, m_s, l_s, acc_s = rest
+    if latent is not None:
+        o_ref, kbuf, sem, nxt, m_s, l_s, acc_s = rest
+        planes = ((k_hbm, kbuf),)
+    elif quantized:
+        (v_hbm, ks_ref, vs_ref, o_ref, kbuf, vbuf, sem, nxt, m_s, l_s,
+         acc_s) = rest
+        planes = ((k_hbm, kbuf), (v_hbm, vbuf))
     else:
-        o_ref, kbuf, vbuf, sem, nxt, m_s, l_s, acc_s = rest
-    b = pl.program_id(0)
-    B = pl.num_programs(0)
+        v_hbm, o_ref, kbuf, vbuf, sem, nxt, m_s, l_s, acc_s = rest
+        planes = ((k_hbm, kbuf), (v_hbm, vbuf))
+    # the grid's programs in order: (row, query tile); without query
+    # tiles a program IS a row
+    step = pl.program_id(0)
+    steps = pl.num_programs(0)
+    b = step if q_tiles == 1 else step // q_tiles
     R = q_ref.shape[0]
     W, ps, H, D = wave_pages, page_size, num_heads, head_dim
     keys = W * ps
-    scale = 1.0 / math.sqrt(D // diff)
+    scale = None if latent is not None else 1.0 / math.sqrt(D // diff)
 
     def first_page(row):
         """The page of the oldest key the row's first query reads; None
@@ -259,10 +299,12 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, v_hbm, *rest,
     n_pages = live_pages(b)
     base = first_page(b)
     n_waves = pl.cdiv(n_pages, W)
-    # the row after this one (its first wave is started under this
-    # row's last, so a row does not open on a cold copy)
-    after = jnp.minimum(b + 1, B - 1)
-    after_pages = jnp.where(b + 1 < B, live_pages(after), 0)
+    # the row of the program after this one (its first wave is started
+    # under this one's last, so a program does not open on a cold copy)
+    after = jnp.minimum(step + 1, steps - 1)
+    if q_tiles > 1:
+        after = after // q_tiles
+    after_pages = jnp.where(step + 1 < steps, live_pages(after), 0)
 
     def wave_dma(row, wave, slot, pages, start):
         """Start (or wait for) the copies of wave `wave` of `row`: its
@@ -278,19 +320,20 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, v_hbm, *rest,
                 page_id = pt_ref[row, at]
             else:
                 page_id = 0
-            for i, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+            for i, (hbm, buf) in enumerate(planes):
                 copy = pltpu.make_async_copy(
                     hbm.at[page_id], buf.at[slot, j], sem.at[slot, i])
                 copy.start() if start else copy.wait()
             return carry
         jax.lax.fori_loop(0, jnp.clip(pages - wave * W, 0, W), page_dma, 0)
 
-    @pl.when(b == 0)
+    @pl.when(step == 0)
     def _():
         # a partial wave leaves the slot's other pages as the last
         # wave left them: masked scores give p == 0 there, and 0 * v
         # must not meet the NaN an uninitialised VMEM word can hold
-        vbuf[...] = jnp.zeros_like(vbuf)
+        values = planes[-1][1]
+        values[...] = jnp.zeros_like(values)
         nxt[0] = 0          # the slot this row's wave 0 takes
         nxt[1] = 0          # 1: the row before has started it already
 
@@ -309,9 +352,15 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, v_hbm, *rest,
             [x[:, h * D:(h + 1) * D].astype(jnp.float32)
              * scales[:, h:h + 1] for h in range(H)], axis=-1)
 
-    # one group per score product: (q/k/v columns, state column)
-    groups = [(slice(None), 0)] if batched else \
-        [(slice(h * D, (h + 1) * D), h) for h in range(H)]
+    # one group per score product: (q/k columns, v/out columns, state
+    # column)
+    if latent is not None:
+        groups = [(slice(None), slice(0, latent[0]), 0)]
+    elif batched:
+        groups = [(slice(None), slice(None), 0)]
+    else:
+        groups = [(slice(h * D, (h + 1) * D),) * 2 + (h,)
+                  for h in range(H)]
 
     def wave_body(w, carry):
         slot = (slot0 + w) % 2
@@ -323,7 +372,7 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, v_hbm, *rest,
                  start=True)
         wave_dma(b, w, slot, n_pages, start=False)
         k = kbuf[slot].reshape(keys, H * D)
-        v = vbuf[slot].reshape(keys, H * D)
+        v = k if latent is not None else vbuf[slot].reshape(keys, H * D)
         if quantized:
             at = pl.ds(pl.multiple_of(w * keys, keys), keys)
             k = dequant(k, ks_ref[at, :])
@@ -332,7 +381,11 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, v_hbm, *rest,
             live = w * keys + jax.lax.broadcasted_iota(
                 jnp.int32, (keys, 1), 0) < seq_len
             v = dequant(v, jnp.where(live, vs_ref[at, :], 0.0))
-        v = v.astype(jnp.float32)
+        if latent is None:
+            v = v.astype(jnp.float32)
+        # (a latent call's values stay in the pool's dtype and take the
+        # probabilities in it, accumulated in fp32: its chunk is bound
+        # by its products, and an fp32 p.v costs the MXU several passes)
         # global positions: rows = this step's queries (a batched row
         # is query row // H), cols = this wave's keys; causal within
         # the sequence + ragged length mask
@@ -349,20 +402,23 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, v_hbm, *rest,
         valid = (key_pos < seq_len) & (key_pos <= q_pos)
         if window is not None:
             valid = valid & (key_pos > q_pos - window)
-        for cols, g in groups:
+        for cols, vcols, g in groups:
             # operands in their stored dtype (bf16 products are exact
             # in the fp32 accumulation); 1/sqrt(D) on the fp32 scores
+            # (a latent call's q carries its scale)
             s = jax.lax.dot_general(
                 q_ref[:, cols].astype(k.dtype), k[:, cols],
                 (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
+                preferred_element_type=jnp.float32)
+            if scale is not None:
+                s = s * scale
             s = jnp.where(valid, s, NEG_INF)
             m_prev = m_s[:, g:g + 1]
             m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
             pexp = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)
-            acc_s[:, cols] = acc_s[:, cols] * alpha + jax.lax.dot_general(
-                pexp, v[:, cols], (((1,), (0,)), ((), ())),
+            acc_s[:, vcols] = acc_s[:, vcols] * alpha + jax.lax.dot_general(
+                pexp.astype(v.dtype), v[:, vcols], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             l_s[:, g:g + 1] = alpha * l_s[:, g:g + 1] \
                 + jnp.sum(pexp, -1, keepdims=True)
@@ -373,8 +429,8 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, v_hbm, *rest,
     nxt[0] = (slot0 + n_waves) % 2
     nxt[1] = ((n_waves > 0) & (after_pages > 0)).astype(jnp.int32)
     l_safe = jnp.maximum(l_s[...], 1e-30)
-    for cols, g in groups:
-        o_ref[:, cols] = (acc_s[:, cols] / l_safe[:, g:g + 1]) \
+    for _, vcols, g in groups:
+        o_ref[:, vcols] = (acc_s[:, vcols] / l_safe[:, g:g + 1]) \
             .astype(o_ref.dtype)
 
 
@@ -382,12 +438,16 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, page_tables,
                                   seq_lens, q_lens, *, num_heads,
                                   head_dim, k_scales=None,
                                   v_scales=None, interpret=None,
-                                  num_kv_heads=None, window=None, diff=1):
+                                  num_kv_heads=None, window=None, diff=1,
+                                  latent=None):
     """Pallas route (interpret-mode on CPU). See module docstring for
     layouts; k_scales/v_scales engage the int8 dequantizing body;
-    `num_kv_heads` (default: num_heads), `window` (default: every key)
-    and `diff` (key sub-heads that share one value block; then the
-    output is [B, T, num_heads * diff * head_dim]) are static.
+    `num_kv_heads` (default: num_heads), `window` (default: every key),
+    `diff` (key sub-heads that share one value block; then the
+    output is [B, T, num_heads * diff * head_dim]) and `latent` ((value
+    lanes, rotary lanes): `v_pages` None, `head_dim` the stored row's
+    lanes, q [B, T, num_heads * head_dim] with its scale in it, the
+    output [B, T, num_heads * value lanes]) are static.
 
     What the shapes decide is decided here; the call itself is one
     jitted function, so a model's layers — the same shapes 24 times in
@@ -395,6 +455,11 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, page_tables,
     Mosaic lowering (jit caches both by shapes and static arguments)
     where each layer used to pay its own."""
     T = q.shape[1]
+    if latent is not None:
+        return _latent_call(q, k_pages, v_pages, page_tables, seq_lens,
+                            q_lens, num_heads, head_dim, latent,
+                            k_scales, num_kv_heads, window, diff,
+                            interpret)
     kv_heads = num_kv_heads or num_heads
     if num_heads % kv_heads:
         raise ValueError(f'{num_heads} query heads do not divide over '
@@ -431,18 +496,66 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, page_tables,
         interpret=_interpret() if interpret is None else interpret)
 
 
+def _check_latent(latent, head_dim, row_lanes, v_pages, k_scales,
+                  num_kv_heads, window, diff):
+    """What a latent call may be: one stored head whose row holds the
+    value and the rotary lanes, no second pool, no scales, window or
+    shared value blocks."""
+    if (v_pages is not None or k_scales is not None
+            or num_kv_heads not in (None, 1) or window is not None
+            or diff > 1):
+        raise NotImplementedError(
+            'a latent plane is ONE array read by every query head: no '
+            'v_pages, int8 scales, kv groups, window or diff with it')
+    value, rotary = latent
+    if not 0 < value <= value + rotary <= head_dim == row_lanes:
+        raise ValueError(
+            f'latent {latent}: {value} value + {rotary} rotary lanes in '
+            f'a query row of {head_dim} and a stored row of {row_lanes}')
+
+
+def _latent_call(q, pages, v_pages, page_tables, seq_lens, q_lens,
+                 num_heads, head_dim, latent, k_scales, num_kv_heads,
+                 window, diff, interpret):
+    """The latent call's static choices: the decode group is the
+    batched product (every head a row of one program); a chunk runs in
+    query tiles of whole heads, `_LATENT_TILE_ROWS` (head, token) rows
+    each."""
+    _check_latent(latent, head_dim, pages.shape[2], v_pages, k_scales,
+                  num_kv_heads, window, diff)
+    T = q.shape[1]
+    batched = T * num_heads <= _BATCHED_ROWS
+    heads = num_heads       # query heads of one tile
+    if not batched:
+        while heads > 1 and (heads * T > _LATENT_TILE_ROWS
+                             or num_heads % heads):
+            heads -= 1
+    W, need = _wave_pages(
+        pages.shape[1], head_dim, pages.dtype,
+        T * num_heads if batched else T * heads, q.dtype,
+        page_tables.shape[1], 1, False, planes=1)
+    return _paged_call(
+        q, pages, None, page_tables, seq_lens, q_lens, None, None,
+        num_heads=1, head_dim=head_dim, wave_pages=W, batched=batched,
+        vmem_bytes=need, group=num_heads, latent=tuple(latent),
+        q_tiles=1 if batched else num_heads // heads,
+        interpret=_interpret() if interpret is None else interpret)
+
+
 @functools.partial(jax.jit, static_argnames=(
     'num_heads', 'head_dim', 'wave_pages', 'batched', 'vmem_bytes',
-    'interpret', 'group', 'window', 'diff'))
+    'interpret', 'group', 'window', 'diff', 'latent', 'q_tiles'))
 def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
                 k_scales, v_scales, *, num_heads, head_dim, wave_pages,
                 batched, vmem_bytes, interpret, group=1, window=None,
-                diff=1):
+                diff=1, latent=None, q_tiles=1):
     """The block-diagonal q (when `batched`; else, with kv groups, the
     query heads of one kv head stacked as rows), the Mosaic call and
     the diagonal blocks of its output, as one jitted function of the
     shapes and the wrapper's static choices. `num_heads` counts the kv
-    heads, `group` the query heads on each."""
+    heads, `group` the query heads on each; a latent call (`v_pages`
+    None) runs each batch row as `q_tiles` programs of `group //
+    q_tiles` heads and puts out `latent[0]` lanes a head."""
     B, T = q.shape[:2]
     N, ps, HD = k_pages.shape
     P = page_tables.shape[1]
@@ -469,10 +582,22 @@ def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
         q = q.reshape(B, T, H, group, D).transpose(0, 3, 1, 2, 4) \
             .reshape(B, group * T, HD)
     R = q.shape[1]
-    row_spec = pl.BlockSpec((None, R, HD), lambda b, pt, ln: (b, 0, 0))
+    row_spec = out_spec = pl.BlockSpec((None, R, HD),
+                                       lambda b, pt, ln: (b, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pltpu.HBM)
     in_specs = [row_spec, pool_spec, pool_spec]
     inputs = [pt, lens, q, k_pages, v_pages]
+    OD = HD                 # lanes of the accumulator and the output
+    if latent is not None:
+        # no V pool; rows g*T+t of a batch row in `q_tiles` tiles of
+        # whole heads; the output as wide as the values
+        OD, R = latent[0], R // q_tiles
+
+        def tile(i, pt, ln):
+            return (i // q_tiles, i % q_tiles, 0)
+        row_spec = pl.BlockSpec((None, R, HD), tile)
+        out_spec = pl.BlockSpec((None, R, OD), tile)
+        in_specs, inputs = [row_spec, pool_spec], inputs[:-1]
     if quantized:
         # Mosaic cannot slice a DMA source whose minor dim (H) is under
         # the 128-lane tile, so the scales do not ride the page copies:
@@ -487,29 +612,31 @@ def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
         inputs += [k_scales[ids].reshape(B, Pw * ps, H),
                    v_scales[ids].reshape(B, Pw * ps, H)]
     G = 1 if batched else H
+    wave = pltpu.VMEM((2, W, ps, HD), k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B,),
+        grid=(B * q_tiles,),
         in_specs=in_specs,
-        out_specs=row_spec,
+        out_specs=out_spec,
         scratch_shapes=[
-            pltpu.VMEM((2, W, ps, HD), k_pages.dtype),     # K wave slots
-            pltpu.VMEM((2, W, ps, HD), v_pages.dtype),     # V wave slots
+            wave,                                          # K wave slots
+            *([wave] if latent is None else []),           # V wave slots
             pltpu.SemaphoreType.DMA((2, 2)),               # [slot, K|V]
             pltpu.SMEM((2,), jnp.int32),       # next wave-0 slot, started
             pltpu.VMEM((R, G), jnp.float32),               # running max
             pltpu.VMEM((R, G), jnp.float32),               # normalizer
-            pltpu.VMEM((R, HD), jnp.float32),              # accumulator
+            pltpu.VMEM((R, OD), jnp.float32),              # accumulator
         ],
     )
     kernel = functools.partial(
         _ragged_paged_kernel, page_size=ps, num_heads=H,
         head_dim=head_dim, wave_pages=W, batched=batched,
-        quantized=quantized, group=group, window=window, diff=diff)
+        quantized=quantized, group=group // q_tiles, window=window,
+        diff=diff, latent=latent, q_tiles=q_tiles)
     out = scaffold.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, R, HD), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, R * q_tiles, OD), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('arbitrary',),
             vmem_limit_bytes=int(min(max(vmem_bytes, 16 * 2 ** 20),
@@ -519,8 +646,14 @@ def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
         # own row in the profile (the `paged_attention*` readers sum
         # them all)
         name='paged_attention' + ('_diff' if diff > 1 else '')
-        + ('' if window is None else '_window'),
+        + ('' if window is None else '_window')
+        + ('' if latent is None else '_latent'),
     )(*inputs)
+    if latent is not None:
+        # rows t*Hq+h (batched) or g*T+t (tiles of whole heads)
+        if not batched:
+            out = out.reshape(B, group, T, OD).transpose(0, 2, 1, 3)
+        return out.reshape(B, T, group * OD)
     if batched and group == 1:
         # each head's output is its own diagonal block of the rows
         out = jnp.where(own, out.reshape(B, T, H, HD), 0).sum(axis=2)
@@ -546,17 +679,23 @@ def _dequant_gathered(pages, scales, H):
 def ragged_paged_attention_dense(q, k_pages, v_pages, page_tables,
                                  seq_lens, q_lens, *, num_heads,
                                  head_dim, k_scales=None, v_scales=None,
-                                 num_kv_heads=None, window=None, diff=1):
+                                 num_kv_heads=None, window=None, diff=1,
+                                 latent=None):
     """Dense lax fallback: gather each row's pages into a [B, P*ps, H*D]
     context and run masked attention. O(B * pages_per_seq * page_size)
     memory — correct everywhere (the CPU serving path and the numerics
     oracle for the kernel), not the TPU hot path. Int8 pages are
     dequantized right after the gather (same per-(slot, head) scales
-    the kernel applies in VMEM)."""
+    the kernel applies in VMEM). `latent`: every head reads the one
+    stored row whole, unscaled, and multiplies its first value lanes."""
     B, T = q.shape[:2]
     ps, HD = k_pages.shape[1:]
     P = page_tables.shape[1]
     D = head_dim
+    if latent is not None:
+        _check_latent(latent, head_dim, HD, v_pages, k_scales,
+                      num_kv_heads, window, diff)
+        num_kv_heads, v_pages = 1, k_pages
     kv_heads = num_kv_heads or num_heads
     group = num_heads // kv_heads
     pt = jnp.clip(page_tables.astype(jnp.int32), 0,
@@ -569,7 +708,7 @@ def ragged_paged_attention_dense(q, k_pages, v_pages, page_tables,
     else:
         k = k_pages[pt].reshape(B, P * ps, HD).astype(jnp.float32)
         v = v_pages[pt].reshape(B, P * ps, HD).astype(jnp.float32)
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 if latent is not None else 1.0 / math.sqrt(D)
     q_pos = (seq_lens[:, None] - q_lens[:, None]
              + jnp.arange(T, dtype=jnp.int32)[None, :])        # [B, T]
     key_pos = jnp.arange(P * ps, dtype=jnp.int32)[None, None, :]
@@ -587,6 +726,8 @@ def ragged_paged_attention_dense(q, k_pages, v_pages, page_tables,
             blk = h // diff // group
             j = blk * diff + h % diff
             vh = v[:, :, blk * diff * D:(blk + 1) * diff * D]
+        elif latent is not None:
+            vh = v[:, :, :latent[0]]
         else:
             vh = v[:, :, j * D:(j + 1) * D]
         kh = k[:, :, j * D:(j + 1) * D]
@@ -611,14 +752,17 @@ def use_pallas_route():
 def ragged_paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
                            q_lens=None, *, num_heads, head_dim,
                            k_scales=None, v_scales=None,
-                           num_kv_heads=None, window=None, diff=1):
+                           num_kv_heads=None, window=None, diff=1,
+                           latent=None):
     """Auto-routed entry (array-level; used inside the serving engine's
     jitted steps). Pass k_scales/v_scales for int8 pages; num_kv_heads
     where fewer kv heads than query heads are stored (the pool's width
     is num_kv_heads * head_dim), window where a query reads only its
     last `window` keys, diff where that many neighbouring key sub-heads
     share one value block (the output is then diff * head_dim wide a
-    query sub-head)."""
+    query sub-head), latent=(value lanes, rotary lanes) with v_pages
+    None where the pool is ONE array of rows every head reads (head_dim
+    the row's lanes, the scale in q, the output value lanes a head)."""
     if q_lens is None:
         q_lens = jnp.full((q.shape[0],), q.shape[1], jnp.int32)
     fn = (ragged_paged_attention_pallas if use_pallas_route()
@@ -626,7 +770,8 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
     return fn(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
               num_heads=num_heads, head_dim=head_dim,
               k_scales=k_scales, v_scales=v_scales,
-              num_kv_heads=num_kv_heads, window=window, diff=diff)
+              num_kv_heads=num_kv_heads, window=window, diff=diff,
+              latent=latent)
 
 
 def _flat_slots(page_tables, seq_lens, q_lens, T, N, ps):
@@ -660,6 +805,18 @@ def write_kv_pages(k_pages, v_pages, k_new, v_new, page_tables,
     v2 = v_pages.reshape(N * ps, HD).at[flat].set(
         v_new.reshape(B * T, HD).astype(v_pages.dtype), mode='drop')
     return k2.reshape(N, ps, HD), v2.reshape(N, ps, HD)
+
+
+def write_latent_pages(pages, new, page_tables, seq_lens, q_lens):
+    """write_kv_pages for a latent plane: `new` [B, T, lanes] rows into
+    the ONE array; lanes past them (the plane's padding to whole tiles)
+    are written as zeros."""
+    N, ps, HD = pages.shape
+    B, T, lanes = new.shape
+    flat = _flat_slots(page_tables, seq_lens, q_lens, T, N, ps)
+    new = jnp.pad(new.astype(pages.dtype), ((0, 0), (0, 0), (0, HD - lanes)))
+    return pages.reshape(N * ps, HD).at[flat].set(
+        new.reshape(B * T, HD), mode='drop').reshape(N, ps, HD)
 
 
 def quantize_kv_rows(x, num_heads):

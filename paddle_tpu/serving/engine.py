@@ -394,10 +394,18 @@ class ServingEngine:
         # the layers must agree on the pages' width; a layer's window
         # bounds what its attention READS, not what the pool keeps
         spec = list(model.kv_cache_spec())
-        if len({(s.num_kv_heads, s.head_dim) for s in spec}) != 1:
+        if len({(s.num_kv_heads, s.head_dim, s.value_lanes)
+                for s in spec}) != 1:
             raise ValueError(
-                "every layer must store the same kv heads and head_dim "
-                f"(one page table serves them all), got {spec}")
+                "every layer must store the same kv heads and head_dim, "
+                "and planes of one kind — (k, v) pairs, or latent rows "
+                "of the same value_lanes — (one page table serves them "
+                f"all), got {spec}")
+        latent = spec[0].value_lanes is not None
+        if latent and any(s.window is not None for s in spec):
+            raise NotImplementedError(
+                f"a window on a latent plane (the paged kernel's latent "
+                f"body has none), got {spec}")
         if any(s.reads is not None and spec[s.reads].reads is not None
                for s in spec):
             raise ValueError(
@@ -411,7 +419,9 @@ class ServingEngine:
         self._kv_readers = len(spec)
         self._kv_full = len(spec) - sum(self._windows.values())
         self._kv_planes = sum(s.reads is None for s in spec)
-        self._attn_kv_tokens = 0
+        self._attn_kv_tokens = self._attn_kv_chunks = 0
+        self._attn_qk_pairs = 0
+        self._prompt_tokens = 0
         dtype = config.kv_dtype or model.lm_head_weight().dtype
         self.mesh = mesh
         self._mp = int(mesh.shape['mp']) if (
@@ -453,6 +463,22 @@ class ServingEngine:
                     f"{type(model).__name__} holds recurrent state, "
                     f"which has no int8 form and no mp split: "
                     f"{sorted(unsplit)}")
+        if latent:
+            # a latent plane is ONE array read whole by every head: the
+            # int8 scales, the host tier's transfers and the mp split
+            # of the heads axis are written for (k, v) pairs (the pool
+            # refuses the same, kv_pool.py). The prefix cache is not
+            # among them: pages are shared whatever they hold
+            unpaired = [what for what, on in (
+                ("kv_dtype='int8'", 'int8_kv' in needs),
+                ('a host tier', config.host_tier_pages > 0),
+                ('an mp mesh', self._mp > 1)) if on]
+            if unpaired:
+                raise NotImplementedError(
+                    f"{type(model).__name__} caches latent (one-array) "
+                    f"planes, which have no int8 form, no host-tier "
+                    f"transfer and no heads axis to split: "
+                    f"{'; '.join(unpaired)}")
         lacking = needs - set(model.paged_routes)
         if lacking:
             raise NotImplementedError(
@@ -496,7 +522,8 @@ class ServingEngine:
             num_pages, ps, num_layers=self._kv_planes,
             num_heads=spec[0].num_kv_heads, head_dim=spec[0].head_dim,
             dtype=dtype, prefix_cache=config.prefix_cache,
-            state_spec=state_spec, state_slots=config.max_batch_size)
+            state_spec=state_spec, state_slots=config.max_batch_size,
+            value_lanes=spec[0].value_lanes)
         self._kv_sharding = None
         if self._mp > 1:
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1796,7 +1823,10 @@ class ServingEngine:
                 src[B + row] = self.scheduler.slot_of(req)
                 if slots is not None:
                     slots[B + row] = src[B + row]
-                self._attn_kv_tokens += self._keys_read(context, len(query))
+                keys = self._keys_read(context, len(query))
+                self._attn_kv_tokens += keys
+                self._attn_kv_chunks += keys
+                self._attn_qk_pairs += self._pairs(context, len(query))
                 self._it_live_pages += self.pool.pages_for(context)
                 self._it_prefill_tokens += len(query)
                 self._it_prefill_ctx += len(query) * context
@@ -1928,6 +1958,23 @@ class ServingEngine:
             total += layers * min(context, w + queries - 1)
         return total
 
+    def _pairs(self, context, queries):
+        """The (query, key) pairs of one row, summed over the ATTENDING
+        layers: query t of `queries` sits at position context - queries
+        + t and may read the keys up to its own — in a window layer no
+        more than the window."""
+        first = context - queries + 1       # keys the first query reads
+
+        def upto(cap):
+            """sum over the queries of min(keys it may read, cap)."""
+            ramp = max(0, min(queries, cap - first + 1))    # below cap
+            return ramp * first + ramp * (ramp - 1) // 2 \
+                + (queries - ramp) * cap
+        total = self._kv_full * upto(context)
+        for w, layers in self._windows.items():
+            total += layers * upto(w)
+        return total
+
     def _count_kv_read(self, context, queries=1):
         """One decode row's KV reads this iteration, in tokens a PLANE
         (the pool's bytes per token count the planes; the readers of a
@@ -1938,6 +1985,7 @@ class ServingEngine:
         without the bound."""
         total = self._keys_read(context, queries)
         self._attn_kv_tokens += total
+        self._attn_qk_pairs += self._pairs(context, queries)
         for w, layers in self._windows.items():
             self._it_kv_window[0] += layers * min(context, w)
             self._it_kv_window[1] += layers * context
@@ -2086,6 +2134,11 @@ class ServingEngine:
             # logits the first sampled token needs
             cached = self.pool.match_and_map(req.id, toks,
                                              limit=len(toks) - 1)
+            # what a hit is a share of: the tokens of every prompt that
+            # was looked up (a preempted request's resume counts again,
+            # as its hit does)
+            self._prompt_tokens += len(toks)
+            req.cached_tokens = cached
             if cached:
                 req.prefilled = start = cached
                 self._trace(req, 'prefix_hit', cached_tokens=cached,
@@ -2171,7 +2224,8 @@ class ServingEngine:
                 record_span('serve::request.prefill', req.admit_ns,
                             time.perf_counter_ns(), event_type='serve',
                             req=req.id, prompt_tokens=len(req.prompt),
-                            chunks=req.prefill_chunks)
+                            chunks=req.prefill_chunks,
+                            cached_tokens=req.cached_tokens)
             ttft = req.first_token_time - req.submit_time
             self._ttfts_s.append(ttft)
             self._new_ttfts_s.append(ttft)
@@ -2655,6 +2709,17 @@ class ServingEngine:
             'kv_readers': self._kv_readers,
             'kv_planes': self._kv_planes,
             'attn_kv_tokens_read_total': self._attn_kv_tokens,
+            # the chunk rows' part of it (a chunk's keys are read once
+            # for all its queries: its call is bound by its products),
+            # and the (query, key) pairs all rows' masks allow: a
+            # decode row's keys once, a chunk row's under its causal
+            # triangle
+            'attn_kv_tokens_read_chunks_total': self._attn_kv_chunks,
+            'attn_qk_pairs_total': self._attn_qk_pairs,
+            # one token's device bytes in one plane (a (k, v) pair, or
+            # a latent plane's one padded row)
+            'kv_plane_bytes_per_token':
+                self.pool.bytes_per_token() // max(self._kv_planes, 1),
             # recurrent state (zeros for a model without it): its
             # bytes, the (row, layer) updates and the tokens they took
             'state_bytes': self.pool.state_bytes(),
@@ -2668,6 +2733,9 @@ class ServingEngine:
             'prefix_hits_total': self.pool.prefix_hits,
             'prefix_misses_total': self.pool.prefix_misses,
             'prefix_hit_tokens_total': self.pool.prefix_hit_tokens,
+            # the tokens of every prompt looked up in the prefix index
+            # (0 with the cache off): what the hit tokens are a share of
+            'prompt_tokens_total': self._prompt_tokens,
             'prefix_shared_pages': self.pool.shared_pages,
             'prefix_cached_pages': self.pool.cached_pages,
             'prefix_evictions_total': self.pool.prefix_evictions,

@@ -1,10 +1,17 @@
 """Block-paged KV-cache pool (vLLM-style paging, TPU-shaped).
 
-One fixed device tensor pair per decoder layer — `[num_pages,
-page_size, kv_heads * head_dim]` — shared by every in-flight
-request. The width is the model's KV heads (its cache spec,
-serving/protocol.py): 4 kv heads of 128 are 2 KB a token a layer however
-many query heads read them. Sequences own pages through per-sequence page tables; a
+One fixed device PLANE per owning decoder layer, shared by every
+in-flight request: a pair of arrays (k, v) `[num_pages, page_size,
+kv_heads * head_dim]` or, for a model that declares `value_lanes` (its
+cache spec, serving/protocol.py), ONE array `[num_pages, page_size,
+row_lanes]` — the latent row the scores contract over, whose first
+`value_lanes` lanes are the values too (`row_lanes`: the row padded to
+whole 128-lane tiles, zeros in the padding — 576 lanes sit in 640).
+The width is the model's KV heads: 4 kv heads of 128 are 2 KB a token a
+layer and array however many query heads read them. Page table, free
+list, refcounts and prefix index know pages, not arrays: both kinds of
+plane are allocated, shared, parked and evicted by the same code.
+Sequences own pages through per-sequence page tables; a
 host-side free-list allocator hands pages out and takes them back, so
 KV memory is O(pages actually in use) instead of the dense cache's
 O(batch * max_seq_len). The ragged paged-attention kernel gathers a
@@ -124,7 +131,8 @@ class KVPagePool:
 
     Device arrays are created lazily (`materialize()`) so pure
     allocator tests never touch jax; the engine materializes once at
-    build. `kv[l]` is the (k_pages, v_pages) pair of plane l, and
+    build. `kv[l]` is plane l — the (k_pages, v_pages) pair, or the
+    one-array (rows,) of a latent plane (`value_lanes`) — and
     `num_layers` counts the PLANES: the layers that own one (a layer
     that reads another's, or keeps no K/V at all, has none).
     `num_heads` counts the heads STORED: the model's kv heads.
@@ -140,9 +148,23 @@ class KVPagePool:
 
     def __init__(self, num_pages, page_size, num_layers=0, num_heads=0,
                  head_dim=0, dtype=None, prefix_cache=False,
-                 state_spec=(), state_slots=0):
+                 state_spec=(), state_slots=0, value_lanes=None):
         if num_pages <= 0 or page_size <= 0:
             raise ValueError("num_pages and page_size must be positive")
+        # None: planes are (k, v) pairs; an int: one array a plane,
+        # whose first `value_lanes` lanes are the values
+        self.value_lanes = None if value_lanes is None else int(value_lanes)
+        if self.value_lanes is not None:
+            if not 0 < self.value_lanes <= int(num_heads) * int(head_dim):
+                raise ValueError(
+                    f"value_lanes {value_lanes} of a row of "
+                    f"{int(num_heads) * int(head_dim)} lanes")
+            if dtype is not None and _np_dtype(dtype) == _np.int8:
+                raise NotImplementedError(
+                    "an int8 pool of latent (one-array) planes: the "
+                    "per-(slot, head) scales are laid out for a (k, v) "
+                    "pair, and one scale over a row that is key and "
+                    "value at once is not written")
         self.state_spec = [(tuple(int(d) for d in shape), dt)
                            for shape, dt in state_spec]
         self.state_slots = int(state_slots)
@@ -202,6 +224,10 @@ class KVPagePool:
         """Install the host-RAM tier (host_tier.HostTier). Must happen
         before any spill; the pool never constructs one itself so pure
         allocator tests stay tier-free."""
+        if self.value_lanes is not None:
+            raise NotImplementedError(
+                "a host tier under latent (one-array) planes: spill and "
+                "fetch move (k, v) pairs")
         self.host_tier = tier
         return tier
 
@@ -213,6 +239,16 @@ class KVPagePool:
             return False
         return _np_dtype(self.dtype) == _np.int8
 
+    @property
+    def row_lanes(self):
+        """Lanes of one stored row of one array: kv heads x head_dim,
+        and for a latent plane that padded to whole 128-lane tiles (576
+        -> 640: the kernel's page copies, its scratch and its products
+        then see whole tiles, and the padding is zeros, which a score
+        can contract over; 11 % more bytes a token is the price)."""
+        hd = self.num_heads * self.head_dim
+        return hd if self.value_lanes is None else -(-hd // 128) * 128
+
     def materialize(self, sharding=None):
         """Create the device arrays. `sharding` (a NamedSharding whose
         spec splits the trailing heads*hd axis, e.g. P(None, None,
@@ -222,6 +258,10 @@ class KVPagePool:
         column-sharded qkv writes (docs/serving.md#mp-sharding)."""
         if self.kv is not None:
             return self.kv
+        if sharding is not None and self.value_lanes is not None:
+            raise NotImplementedError(
+                "latent (one-array) planes under an mp sharding: the "
+                "one stored head has no heads axis to split")
         import jax.numpy as jnp
 
         def _z(shape, dt):
@@ -241,24 +281,30 @@ class KVPagePool:
                 for _ in range(self.num_layers)]
             return self.kv
         dt = self.dtype or jnp.float32
+        shape = (self.num_pages, self.page_size, self.row_lanes)
         self.kv = [
-            (_z((self.num_pages, self.page_size, hd), dt),
-             _z((self.num_pages, self.page_size, hd), dt))
+            tuple(_z(shape, dt) for _ in range(self.arrays_per_plane))
             for _ in range(self.num_layers)]
         return self.kv
 
+    @property
+    def arrays_per_plane(self):
+        """2: a plane is the pair (k, v); 1: a latent plane."""
+        return 2 if self.value_lanes is None else 1
+
     def bytes_per_token(self):
-        """Device bytes one token's K+V occupies across all layers —
-        the capacity math of docs/serving.md#quantized-kv: int8 pages
-        cost heads*head_dim*1 + heads*4 (scale) per K and per V, dense
-        pages heads*head_dim*itemsize."""
-        hd = self.num_heads * self.head_dim
+        """Device bytes one token occupies across all planes — the
+        capacity math of docs/serving.md#quantized-kv. A paired plane
+        holds K and V: int8 pages cost heads*head_dim*1 + heads*4
+        (scale) for each, dense pages heads*head_dim*itemsize for each.
+        A latent plane holds ONE row of `row_lanes` (the padding is
+        held, and copied, like the rest)."""
         if self.quantized:
-            per = hd * 1 + self.num_heads * 4
+            per = self.row_lanes * 1 + self.num_heads * 4
         else:
             item = _np_dtype(self.dtype).itemsize if self.dtype else 4
-            per = hd * item
-        return 2 * per * self.num_layers
+            per = self.row_lanes * item
+        return self.arrays_per_plane * per * self.num_layers
 
     def pool_bytes(self):
         """Total device bytes of the materialized (or to-be-
@@ -933,6 +979,9 @@ class KVPagePool:
             'bytes_per_token': self.bytes_per_token(),
             'pool_bytes': self.pool_bytes(),
             'kv_planes': self.num_layers,
+            'arrays_per_plane': self.arrays_per_plane,
+            'row_lanes': self.row_lanes,
+            'value_lanes': self.value_lanes,
             'state_bytes': self.state_bytes(),
             'pages_in_use': self.pages_in_use,
             'free_pages': self.free_pages,

@@ -17,16 +17,26 @@ has:
         shared cache). A layer that neither attends nor caches (a
         state-space layer, a gated unit) has no entry. The pool holds
         one plane an OWNER, sized by kv heads; one page table serves
-        every plane, so every entry must agree on kv heads and
-        head_dim (window layers keep pages they no longer read: a
-        window-aware allocator is ROADMAP Queue 2)
+        every plane, so every entry must agree on kv heads, head_dim
+        and `value_lanes` (window layers keep pages they no longer
+        read: a window-aware allocator is ROADMAP Queue 2).
+        `value_lanes` None: a plane is the PAIR (k, v) of equal width.
+        An int: a LATENT plane, ONE array a layer whose row is what
+        the scores contract over (num_kv_heads * head_dim lanes:
+        latent attention's [c_kv | k_pe], one stored head) and whose
+        first `value_lanes` lanes are also the values — every entry of
+        such a model must be latent, agree on the row's width and on
+        `value_lanes`, and carry no window (the kernel's latent body
+        has none). The pool pads such a row to whole 128-lane tiles
+        (`KVPagePool.row_lanes`), zeros in the padding
     forward_paged(tokens, positions, kv, rows, moe_counters=None)
             -> (hidden, new_kv, moe)
         tokens / positions: int Tensors [1, N], the query tokens of
         every row of the dispatch laid end to end; `rows` a `RowGroups`
         (below) that says which rows they are; kv: per OWNING entry of
         the cache spec, in its order, a tuple of pool Tensors ((k, v),
-        or the int8 pool's (k, v, k_scales, v_scales)). Everything
+        the int8 pool's (k, v, k_scales, v_scales), or a latent
+        plane's (rows,)). Everything
         token-wise (embedding, norms,
         projections, MLP, experts) runs ONCE over the N tokens, so a
         weight is read once a dispatch; attention alone goes group by
@@ -75,15 +85,17 @@ has:
         fused window, the verify step; docs/serving.md).
 
 `GPTForCausalLM` and `AfmoeForCausalLM` implement it;
-`Phi4FlashForCausalLM` with shared planes and recurrent state.
+`Phi4FlashForCausalLM` with shared planes and recurrent state,
+`AxK1ForCausalLM` with latent planes.
 """
 import collections
 
 import jax.numpy as jnp
 
 KVLayerSpec = collections.namedtuple(
-    'KVLayerSpec', ['num_kv_heads', 'head_dim', 'window', 'reads'],
-    defaults=(None,))
+    'KVLayerSpec', ['num_kv_heads', 'head_dim', 'window', 'reads',
+                    'value_lanes'],
+    defaults=(None, None))
 
 
 class RowGroups:
@@ -174,9 +186,10 @@ class RowGroups:
 
     def attend(self, write, read, pool, q, k=None, v=None):
         """Attention over the paged pool, group by group. q / k / v are
-        [1, N, .] over the dispatch's tokens; `write(pool, k, v,
-        page_tables, seq_lens, q_lens) -> pool` puts a group's new K/V
-        ([b, t, .]) into its rows' pages and `read(pool, q,
+        [1, N, .] over the dispatch's tokens (v None: a latent plane,
+        whose one row `k` is; `write` then takes no v); `write(pool, k,
+        v, page_tables, seq_lens, q_lens) -> pool` puts a group's new
+        K/V ([b, t, .]) into its rows' pages and `read(pool, q,
         page_tables, seq_lens, q_lens) -> [b, t, .]` attends. Every
         group writes before any reads: no request rides two groups of
         one dispatch, so the writes never meet, and the pool is updated
@@ -186,8 +199,9 @@ class RowGroups:
         def rows_of(at):
             return self.page_tables[at], self.seq_lens[at], self.q_lens[at]
         if write is not None:
-            for at, _, (kg, vg) in self.groups(k, v):
-                pool = write(pool, kg, vg, *rows_of(at))
+            new = (k,) if v is None else (k, v)
+            for at, _, new_g in self.groups(*new):
+                pool = write(pool, *new_g, *rows_of(at))
         return self.join([read(pool, qg, *rows_of(at))
                           for at, _, (qg,) in self.groups(q)]), pool
 

@@ -353,6 +353,10 @@ class Request:
         self.submit_ns = None
         self.admit_ns = None
         self.prefill_chunks = 0
+        self.cached_tokens = 0           # prompt tokens the prefix
+                                         # cache mapped at the last
+                                         # lookup (the .prefill span's
+                                         # `cached_tokens`)
         self.admit_bypasses = 0          # followers admitted past this
                                          # request while it sat at the
                                          # queue head over-budget

@@ -214,6 +214,28 @@ def test_differential_paged_attention_compiles_and_is_named(
     assert got == {name}
 
 
+@pytest.mark.parametrize('rows,T', [(64, 1), (2, 256), (2, 128)])
+def test_latent_paged_attention_compiles_and_is_named(rows, T, one_chip,
+                                                      as_on_tpu):
+    """The latent-attention server cell's row groups at its published
+    widths: 64 query heads on ONE stored row of 512 value + 64 rotary
+    lanes in 640, 528-page tables over 9,000 pages of 64; [64, 1] decode
+    (the batched product) and the mixed step's 2 chunks of 256 (query
+    tiles of 8 heads; chunk 128: 16). The name starts `paged_attention`
+    and says `latent`; there is no V pool among the operands."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    def fn(q, pages, pt, sl, ql):
+        return pa.ragged_paged_attention_pallas(
+            q, pages, None, pt, sl, ql, num_heads=64, head_dim=640,
+            latent=(512, 64))
+    got = mosaic_calls(fn, [((rows, T, 64 * 640), BF16),
+                            ((9000, 64, 640), BF16),
+                            ((rows, 528), jnp.int32), ((rows,), jnp.int32),
+                            ((rows,), jnp.int32)], one_chip)
+    assert got == {'paged_attention_latent'}
+
+
 @pytest.mark.parametrize('rows,T', [(64, 1), (2, 128)])
 def test_the_selective_scan_compiles_and_is_named(rows, T, one_chip,
                                                   as_on_tpu):
