@@ -64,6 +64,20 @@ def _spec_for(p, axes, extra_leading_pp=False):
     return P(*spec)
 
 
+def _tree_bytes(tree):
+    return sum(int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
+               for a in jax.tree_util.tree_leaves(tree))
+
+
+def _device_bytes_limit(device):
+    """The bytes the device's allocator may hand out (None where the
+    backend does not say, as the CPU's)."""
+    try:
+        return int(device.memory_stats()['bytes_limit'])
+    except Exception:
+        return None
+
+
 class PipelineScheduleError(ValueError):
     """A pipeline-schedule configuration the engine cannot honor
     (layer/chunk divisibility, virtual stages on a schedule without
@@ -283,6 +297,10 @@ def pipeline_snapshot(engine='pipeline'):
             for labels, child in info._series().items():
                 if labels and labels[0] == engine and child.value():
                     snap['schedule'] = labels[1]
+        # what the compiled step holds for its backward (the remat
+        # reckoning: docs/performance.md#remat-policy)
+        from ..utils.recompute import held_snapshot
+        snap.update(held_snapshot(engine))
         return snap
     except Exception:
         return None
@@ -437,9 +455,10 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
         self.optimizer = optimizer
         self.A = accumulate_steps
         # tuned remat (docs/performance.md#remat-policy): a resolved
-        # policy (kwarg -> PTPU_REMAT_POLICY -> strategy) overrides the
-        # schedule-specific legacy split (full remat / save-dots) that
-        # `use_remat=True` alone picks in _make_stage_forward
+        # policy (kwarg -> PTPU_REMAT_POLICY -> strategy) overrides what
+        # `use_remat=True` alone picks: the schedule's split (full remat
+        # / save-dots) in _make_stage_forward, and at pp=1 under 1F1B
+        # the richest policy that fits the device (_fit_remat)
         from ..utils.recompute import resolve_policy as _resolve_remat
         self._remat_policy = _resolve_remat(remat_policy,
                                                        default=None)
@@ -819,6 +838,19 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
                 out = template(Tensor(x))
         return out.data
 
+    def _embed_apply(self, pe_, ids_m, k):
+        """One microbatch's ids through the embedding with bound params."""
+        with bind_arrays(self.embed, pe_):
+            with rng_mod.rng_guard(k), autograd.no_grad():
+                return self.embed(Tensor(ids_m)).data
+
+    def _head_apply(self, ph_, out, lab, k):
+        """Final activations and labels -> the microbatch's float32 loss."""
+        with bind_arrays(self.head, ph_):
+            with rng_mod.rng_guard(k), autograd.no_grad():
+                return self.head(Tensor(out), Tensor(lab)).data \
+                    .astype(jnp.float32)
+
     def _build(self):
         if self.schedule == '1F1B':
             return self._build_1f1b()
@@ -827,6 +859,19 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
         return self._build_fthenb()
 
     # -- shared tail of both schedules ---------------------------------------
+    def _remat_block(self, default):
+        """The block function under the remat policy that applies: a
+        resolved one (kwarg / PTPU_REMAT_POLICY / strategy:
+        docs/performance.md#remat-policy) wins; `use_remat=True` alone
+        takes the schedule's `default`; else the bare block."""
+        block_apply = functools.partial(self._block_apply, self.blocks[0])
+        from ..utils.recompute import apply_policy as _apply_remat
+        if self._remat_policy is not None:
+            default = self._remat_policy
+        elif not self.use_remat:
+            return block_apply
+        return _apply_remat(block_apply, default, engine='pipeline')
+
     def _make_stage_forward(self, save_dots=False):
         """(block_params_local, x, key) -> x: scan this stage's blocks.
 
@@ -836,20 +881,7 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
         Used by the activation-stashing 1F1B, whose O(pp) in-flight window
         makes the bigger residual set affordable (the reference
         SectionWorker likewise stores, not recomputes)."""
-        block_apply = functools.partial(self._block_apply, self.blocks[0])
-        from ..utils.recompute import apply_policy as _apply_remat
-        if self._remat_policy is not None:
-            # tuned policy (docs/performance.md#remat-policy) replaces
-            # the legacy schedule-specific split below
-            block_apply = _apply_remat(
-                block_apply, self._remat_policy, engine='pipeline')
-        elif self.use_remat:
-            if save_dots:
-                block_apply = _apply_remat(
-                    block_apply, 'dots', engine='pipeline')
-            else:
-                block_apply = _apply_remat(
-                    block_apply, 'full', engine='pipeline')
+        block_apply = self._remat_block('dots' if save_dots else 'full')
 
         def stage_forward(block_params_local, x, key):
             def body(carry, xs):
@@ -898,9 +930,7 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
         # executable replays these psums/pmeans every step)
         if pp > 1 or dp_on:
             from ....core.monitor import counter
-            nbytes = sum(
-                int(np.prod(g.shape or (1,))) * jnp.dtype(g.dtype).itemsize
-                for g in jax.tree_util.tree_leaves(grads))
+            nbytes = _tree_bytes(grads)
             counter('ptpu_collective_bytes_total',
                     help='payload bytes through collective APIs',
                     labelnames=('op',)).inc(nbytes, op='pipeline_grad_sync')
@@ -1196,7 +1226,7 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
         return jax.jit(mapped, donate_argnums=(0, 1))
 
     @staticmethod
-    def _split_residuals(fn, args, variant_argnums):
+    def _split_residuals(fn, args, variant_argnums, evaluate=True):
         """Taint-split the flattened outputs of ``fn(*args)`` into
         tick-VARIANT ones (those depending on the arguments named in
         ``variant_argnums``) and tick-INVARIANT ones, and evaluate the
@@ -1216,7 +1246,8 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
         Returns ``(variant_flags, values, avals)``: ``values[i]`` holds
         the invariant output value (None at variant positions); ``avals``
         are every flattened output's abstract values, so callers need no
-        second abstract trace for shapes."""
+        second abstract trace for shapes. ``evaluate=False`` classifies
+        only (``values`` is None): ``args`` may then be abstract."""
         closed = jax.make_jaxpr(fn)(*args)
         jaxpr = closed.jaxpr
         variant_flat = []
@@ -1235,6 +1266,11 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
             if any(_is_tainted(v) for v in eqn.invars):
                 tainted.update(eqn.outvars)
         flags = [_is_tainted(v) for v in jaxpr.outvars]
+        avals = [v.aval if not hasattr(v, 'val')
+                 else jax.core.get_aval(v.val)
+                 for v in jaxpr.outvars]
+        if not evaluate:
+            return flags, None, avals
 
         # dead-code-eliminate from the invariant outputs, then evaluate
         # just that sub-graph (it never touches a variant input, so this
@@ -1260,10 +1296,239 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
         for i, f in enumerate(flags):
             if not f:
                 values[i] = next(it)
-        avals = [v.aval if not hasattr(v, 'val')
-                 else jax.core.get_aval(v.val)
-                 for v in jaxpr.outvars]
         return flags, values, avals
+
+    # `_remat_reckoned`: what `_fit_remat` reckoned and chose for the
+    # step being built (None: a named policy, no remat, or another
+    # schedule); `_remat_fell`: how many of recompute.FIT_ORDER the
+    # compiler has refused (RESOURCE_EXHAUSTED in _dispatch)
+    _remat_reckoned = None
+    _remat_fell = 0
+
+    def _fit_remat(self, held_bytes, fixed, working):
+        """What `use_remat=True` saves at pp=1 when no policy is named:
+        the richest of recompute.FIT_ORDER whose stacked residuals
+        (`held_bytes(name)`, one microbatch in flight) fit the device
+        beside `fixed` (parameters, optimizer state, the accumulation
+        buffer) and `working` (one layer's / the head's transient
+        values) — `use_remat=True` has always meant "make it fit". A
+        backend that names no limit (the CPU) fits everything. Returns
+        the policy; says what it reckoned in the log."""
+        from ..utils.recompute import FIT_ORDER
+        from ..utils.log_util import log_json
+        limit = _device_bytes_limit(self.mesh.devices.flat[0])
+        order = FIT_ORDER[self._remat_fell:]
+        held = {}
+        for name in order:
+            held[name] = held_bytes(name)
+            if limit is None or name == order[-1] \
+                    or fixed + working + held[name] <= limit:
+                break
+        self._remat_reckoned = {
+            'policy': name, 'held_bytes': held, 'fixed_bytes': fixed,
+            'working_bytes': working, 'bytes_limit': limit,
+            'refused_by_compiler': self._remat_fell}
+        log_json('remat_fit', logger_name='pipeline',
+                 **self._remat_reckoned)
+        return name
+
+    def _remat_fall_back(self, err):
+        """The compiler's answer to a policy `_fit_remat` chose: on
+        RESOURCE_EXHAUSTED move to the next candidate (True: build and
+        compile again); anything else, or nothing left, is the caller's
+        to raise."""
+        from ....core.memory import is_oom_error
+        from ..utils.recompute import FIT_ORDER
+        fitted = (self._remat_reckoned or {}).get('policy')
+        if fitted in (None, FIT_ORDER[-1]) or not is_oom_error(err):
+            return False
+        self._remat_fell = FIT_ORDER.index(fitted) + 1
+        from ..utils.log_util import log_json
+        log_json('remat_fall_back', level='warning', logger_name='pipeline',
+                 refused=fitted, next=FIT_ORDER[self._remat_fell],
+                 error=str(err)[:400])
+        return True
+
+    def _build_one_stage(self):
+        """1F1B on ONE stage (pp=1, 'stash'): a tick's backward consumes
+        the same tick's forward, so nothing crosses ticks, and the tick
+        is written out: embed, a forward scan over the layers that
+        stacks each layer's pullback residuals, head, and the engine's
+        own REVERSE scan over the layers whose carry holds (dx, the
+        blocks' accumulation buffer). Layer l's pullback is rebuilt from
+        its row of residuals — the stash schedule's `_split_residuals` /
+        `tree_unflatten` way — and its weight gradient is added into
+        row l of the buffer where the matmul makes it. `jax.vjp` through
+        the layer scan would return a whole [L, ...] tree of fresh
+        gradients every tick, a second copy that lives just long enough
+        to be added; here none exists, and the memory it held keeps the
+        block's contraction outputs (`_fit_remat`), so the backward
+        recomputes LayerNorm and GELU and not the matmuls. The embedding
+        and the head keep `jax.vjp` and an add: they are small."""
+        A = self.A
+        axes = self.axes
+        embed_apply, head_apply = self._embed_apply, self._head_apply
+        dp_on = 'dp' in axes and self.mesh.shape['dp'] > 1
+        use_scaling = self._use_scaling
+        acc_param = self.grad_accum_dtype == 'param'
+        from ..utils.recompute import publish_held
+
+        def step(params, states, lr, scale, key, input_ids, labels):
+            with C.spmd_region(axes):
+                params = self._materialize_params(params)
+                mb = input_ids.shape[0] // A
+                pe, pb, ph = params['embed'], params['blocks'], params['head']
+                k0 = key
+                if dp_on:
+                    k0 = jax.random.fold_in(k0, lax.axis_index('dp'))
+                ids_mb = input_ids.reshape(A, mb, *input_ids.shape[1:])
+                labels_mb = labels.reshape(A, mb, *labels.shape[1:])
+                n_layers = jax.tree_util.tree_leaves(pb)[0].shape[0]
+
+                x_aval = jax.eval_shape(embed_apply, pe, ids_mb[0], k0)
+                probe_args = (jax.tree_util.tree_map(lambda a: a[0], pb),
+                              x_aval, k0)
+
+                def follows_input(probe, args):
+                    """Of probe's pullback leaves (its outputs after
+                    the first), those that follow args[1]: their
+                    indices and bytes."""
+                    flags, _, avals = self._split_residuals(
+                        probe, args, {1}, evaluate=False)
+                    idx = [i for i, v in enumerate(flags[1:]) if v]
+                    return idx, _tree_bytes([avals[1 + i] for i in idx])
+
+                def probed(block_fn):
+                    """(layer -> (y, its pullback's leaves), the leaves
+                    the forward scan stacks, their bytes over the
+                    layers); the probe's newest trace leaves the
+                    pullback's treedef in `probe.box`."""
+                    box = {}
+
+                    def probe(pslice, x, k):
+                        y, vjp_fn = jax.vjp(
+                            lambda p, xx: block_fn(p, xx, k), pslice, x)
+                        leaves, box['treedef'] = \
+                            jax.tree_util.tree_flatten(vjp_fn)
+                        return y, leaves
+                    probe.box = box
+                    idx, nbytes = follows_input(probe, probe_args)
+                    return probe, idx, n_layers * nbytes
+
+                gacc0 = jax.tree_util.tree_map(
+                    lambda a: jnp.zeros(
+                        a.shape, a.dtype if acc_param else jnp.float32),
+                    (pe, pb, ph))
+                if self._remat_policy is None and self.use_remat:
+                    def head_probe(ph_, o):
+                        loss, vjp_fn = jax.vjp(
+                            lambda p, oo: head_apply(p, oo, labels_mb[0],
+                                                     k0), ph_, o)
+                        return loss, jax.tree_util.tree_leaves(vjp_fn)
+
+                    # transient values: the largest single piece (the
+                    # head, or one layer with nothing recomputed) holds
+                    # its pullback's residuals, their cotangents, and
+                    # as much again in the float32 the compiler makes
+                    # them in — at 1.3B the compiler's peak is within
+                    # 1 % of this (tests/test_pipeline_step_aot.py)
+                    working = 3 * max(
+                        follows_input(head_probe, (ph, x_aval))[1],
+                        probed(functools.partial(
+                            self._block_apply, self.blocks[0]))[2]
+                        // n_layers)
+                    tried = {}
+
+                    def held_bytes(name):
+                        # the gauge follows: the last tried is the taken
+                        tried[name] = probed(self._remat_block(name))
+                        return tried[name][2]
+                    policy = self._fit_remat(
+                        held_bytes, _tree_bytes((params, states, gacc0)),
+                        working)
+                    self._ledger.remat_policy = policy
+                    layer_probe, var_idx, held = tried[policy]
+                else:
+                    self._remat_reckoned = None
+                    layer_probe, var_idx, held = probed(
+                        self._remat_block(None))
+                publish_held('pipeline', saved_boundary_bytes=held,
+                             grad_tree_bytes=0)
+
+                def grad_cot():
+                    return (scale / A).astype(jnp.float32) \
+                        if use_scaling else jnp.asarray(1.0 / A,
+                                                        jnp.float32)
+
+                def accum(acc, d):
+                    return jax.tree_util.tree_map(
+                        lambda a, g: a + g.astype(a.dtype), acc, d)
+
+                def tick(carry, m):
+                    (g_e, g_b, g_h), loss_acc = carry
+                    # keys as the pp > 1 schedule derives them at stage 0
+                    k_mb = jax.random.fold_in(k0, m)
+                    ke = jax.random.fold_in(k_mb, 17)
+                    ks = jax.random.fold_in(
+                        jax.random.fold_in(k_mb, 31), 0)
+                    kh = jax.random.fold_in(k_mb, 7919)
+                    keys = jax.random.split(ks, n_layers)
+
+                    x, embed_vjp = jax.vjp(
+                        lambda pe_: embed_apply(pe_, ids_mb[m], ke), pe)
+
+                    def fwd_layer(x, xs):
+                        y, leaves = layer_probe(xs[0], x, xs[1])
+                        return y, [leaves[i] for i in var_idx]
+
+                    out, rows = lax.scan(fwd_layer, x, (pb, keys))
+                    loss, head_vjp = jax.vjp(
+                        lambda ph_, o: head_apply(ph_, o, labels_mb[m],
+                                                  kh), ph, out)
+                    d_h, dx = head_vjp(grad_cot())
+
+                    def bwd_layer(c, xs):
+                        dx, g_b = c
+                        l, pslice, k, row = xs
+                        # the leaves that follow the weights alone are
+                        # re-derived from this layer's slice (casts,
+                        # transposes: never the block's forward)
+                        _, leaves, _ = self._split_residuals(
+                            layer_probe,
+                            (pslice, jnp.zeros(x_aval.shape, x_aval.dtype),
+                             k), {1})
+                        leaves = leaves[1:]
+                        for i, r in zip(var_idx, row):
+                            leaves[i] = r
+                        d_p, dx = jax.tree_util.tree_unflatten(
+                            layer_probe.box['treedef'], leaves)(dx)
+
+                        def add_row(g, d):
+                            old = lax.dynamic_index_in_dim(
+                                g, l, 0, keepdims=False)
+                            return lax.dynamic_update_index_in_dim(
+                                g, old + d.astype(g.dtype), l, 0)
+                        return (dx, jax.tree_util.tree_map(
+                            add_row, g_b, d_p)), None
+
+                    (dx, g_b), _ = lax.scan(
+                        bwd_layer, (dx, g_b),
+                        (jnp.arange(n_layers), pb, keys, rows),
+                        reverse=True)
+                    (d_e,) = embed_vjp(dx)
+                    return ((accum(g_e, d_e), g_b, accum(g_h, d_h)),
+                            loss_acc + loss), None
+
+                (gacc, loss_sum), _ = lax.scan(
+                    tick, (gacc0, jnp.asarray(0.0, jnp.float32)),
+                    jnp.arange(A))
+                grads = {'embed': gacc[0], 'blocks': gacc[1],
+                         'head': gacc[2]}
+                return self._reduce_and_update(
+                    params, states, loss_sum / A, grads, lr, dp_on,
+                    scale=scale if use_scaling else None)
+
+        return self._finalize(step, dp_on)
 
     def _build_1f1b(self):
         """1F1B steady-state schedule (section_worker.cc:147-184 parity).
@@ -1299,20 +1564,19 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
         """
         A, pp = self.A, self.pp
         axes = self.axes
-        embed, head = self.embed, self.head
+        embed_apply, head_apply = self._embed_apply, self._head_apply
         opt = self.optimizer
         dp_on = 'dp' in axes and self.mesh.shape['dp'] > 1
         use_scaling = self._use_scaling
         stash = self.memory_mode == 'stash'
+        if stash and pp == 1:
+            # backward always consumes the SAME tick's forward (m_b ==
+            # m_f): nothing crosses ticks, nothing is buffered
+            return self._build_one_stage()
+        from ..utils.recompute import publish_held
         B = min(A, 2 * pp - 1)
         T = A + 2 * (pp - 1)
-        # pp=1: backward always consumes the SAME tick's forward (m_b ==
-        # m_f), so nothing crosses ticks — no residual buffering, and full
-        # per-block remat stays the memory-safe choice for the single-chip
-        # memory-bound configs (the save-dots residual set there would
-        # cover the WHOLE model, not one stage)
-        save_dots = stash and pp > 1
-        stage_forward = self._make_stage_forward(save_dots=save_dots)
+        stage_forward = self._make_stage_forward(save_dots=stash)
 
         def step(params, states, lr, scale, key, input_ids, labels):
             with C.spmd_region(axes):
@@ -1327,17 +1591,6 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
 
                 ids_mb = input_ids.reshape(A, mb, *input_ids.shape[1:])
                 labels_mb = labels.reshape(A, mb, *labels.shape[1:])
-
-                def embed_apply(pe_, ids_m, k):
-                    with bind_arrays(embed, pe_):
-                        with rng_mod.rng_guard(k), autograd.no_grad():
-                            return embed(Tensor(ids_m)).data
-
-                def head_apply(ph_, out, lab, k):
-                    with bind_arrays(head, ph_):
-                        with rng_mod.rng_guard(k), autograd.no_grad():
-                            return head(Tensor(out), Tensor(lab)).data \
-                                .astype(jnp.float32)
 
                 emb_shape = jax.eval_shape(
                     embed_apply, pe, ids_mb[0], k0)
@@ -1418,11 +1671,10 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
                     # dynamic-update (no read-old + select per leaf, which
                     # would force XLA to materialize a buffer copy per tick
                     # instead of updating the loop carry in place).
-                    # pp=1: same-tick consumption — no buffers at all.
                     bufs0 = tuple(
                         jnp.zeros((B + 1,) + tuple(leaf_shapes[i].shape),
                                   leaf_shapes[i].dtype)
-                        for i in var_idx) if pp > 1 else ()
+                        for i in var_idx)
                     carry0 = (jnp.zeros(act_shape, act_dtype),  # fwd act
                               jnp.zeros(act_shape, act_dtype),  # cotangent
                               bufs0,                            # residuals
@@ -1477,9 +1729,8 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
                         # (m_b == m_f), where the just-written slot is
                         # exactly the wanted fresh data; inactive
                         # forwards write the scratch slot so they can
-                        # never clobber a pending slot. pp=1 is ALL
-                        # same-tick: take the fresh leaves directly.
-                        gathered = vleaves if pp == 1 else [
+                        # never clobber a pending slot.
+                        gathered = [
                             lax.dynamic_index_in_dim(
                                 buf, slot_b, 0, keepdims=False)
                             for buf in bufs]
@@ -1509,15 +1760,12 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
                         gacc = accum(gacc, d_p3, b_active)
                         dx = jnp.where(b_active, dx, jnp.zeros_like(dx))
 
-                        if pp > 1:
-                            nxt_act = lax.ppermute(
-                                out_f, 'pp',
-                                [(i, (i + 1) % pp) for i in range(pp)])
-                            nxt_grad = lax.ppermute(
-                                dx, 'pp',
-                                [(i, (i - 1) % pp) for i in range(pp)])
-                        else:
-                            nxt_act, nxt_grad = out_f, dx
+                        nxt_act = lax.ppermute(
+                            out_f, 'pp',
+                            [(i, (i + 1) % pp) for i in range(pp)])
+                        nxt_grad = lax.ppermute(
+                            dx, 'pp',
+                            [(i, (i - 1) % pp) for i in range(pp)])
                         return (nxt_act, nxt_grad, bufs, gacc,
                                 loss_acc), None
                 else:
@@ -1579,6 +1827,11 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
                         return (nxt_act, nxt_grad, buf, gacc,
                                 loss_acc), None
 
+                # what crosses ticks, and the fresh gradient tree every
+                # tick's vjp returns beside the accumulation buffer
+                publish_held(
+                    'pipeline', saved_boundary_bytes=_tree_bytes(carry0[2]),
+                    grad_tree_bytes=_tree_bytes((pe, pb, ph)))
                 (_, _, _, gacc, loss_sum), _ = lax.scan(
                     tick, carry0, jnp.arange(T))
                 grads = {'embed': gacc[0], 'blocks': gacc[1],
@@ -1619,7 +1872,7 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
         v == 1 degenerates to the classic 1F1B tick table."""
         A, pp, v = self.A, self.pp, self.vp
         axes = self.axes
-        embed, head = self.embed, self.head
+        embed_apply, head_apply = self._embed_apply, self._head_apply
         dp_on = 'dp' in axes and self.mesh.shape['dp'] > 1
         use_scaling = self._use_scaling
         stash = self.memory_mode == 'stash'
@@ -1647,17 +1900,6 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
 
                 ids_mb = input_ids.reshape(A, mb, *input_ids.shape[1:])
                 labels_mb = labels.reshape(A, mb, *labels.shape[1:])
-
-                def embed_apply(pe_, ids_m, k):
-                    with bind_arrays(embed, pe_):
-                        with rng_mod.rng_guard(k), autograd.no_grad():
-                            return embed(Tensor(ids_m)).data
-
-                def head_apply(ph_, out, lab, k):
-                    with bind_arrays(head, ph_):
-                        with rng_mod.rng_guard(k), autograd.no_grad():
-                            return head(Tensor(out), Tensor(lab)).data \
-                                .astype(jnp.float32)
 
                 emb_shape = jax.eval_shape(
                     embed_apply, pe, ids_mb[0], k0)
@@ -2206,8 +2448,18 @@ class SpmdPipelineEngine(A_.AsyncDispatchMixin, EngineTeardown):
             # buffer-assignment activation census
             # (ptpu_mem_activation_bytes; docs/performance.md
             # #remat-policy) for the pipeline step program
-            exe, _ = _prof.compile_with_telemetry(
-                self._compiled, 'pipeline.step', args)
+            while True:
+                try:
+                    exe, _ = _prof.compile_with_telemetry(
+                        self._compiled, 'pipeline.step', args)
+                    break
+                except Exception as e:
+                    # the fitted remat policy did not fit after all:
+                    # the next one, traced and compiled again
+                    if not self._remat_fall_back(e):
+                        raise
+                    self._compiled = self._compiled_by_mode[
+                        want_scaling] = self._build()
             self._exec_by_mode[want_scaling] = exe
         with _prof.RecordEvent('pipeline::train_step', event_type='jit'), \
                 self._step_guard(first, 'pipeline.train_step',

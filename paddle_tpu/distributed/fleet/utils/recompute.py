@@ -19,9 +19,15 @@ cheap to recompute). Named policies:
                             backward (the pre-ISSUE-12 use_remat=True);
   * 'attn_mlp_boundaries' — save ONLY the tagged contraction outputs
                             (qkv/attention-context/out-proj, fc1/fc2,
-                            the attn/MLP boundary set); layernorm, GELU,
+                            the attn/MLP boundary set, and the flash
+                            kernel's own output and logsumexp so the
+                            kernel never runs again); layernorm, GELU,
                             dropout joins, softmax internals and the
                             embedding gather recompute in the backward;
+  * 'attn_mlp_lean'       — the same without `attn_out`: out_proj is
+                            recomputed too (one [tokens, H] array a
+                            layer cheaper; the pipeline engine's first
+                            fall-back at pp=1);
   * 'dots'                — `jax.checkpoint_policies.dots_saveable`
                             (save every matmul output, tagged or not —
                             the stashing-1F1B engine default).
@@ -174,10 +180,21 @@ def recompute_jax(function):
 # checkpoint_name tags the models emit at contraction boundaries. The
 # attn_mlp_boundaries policy saves exactly these; anything else is
 # recomputed in the backward (TPP: cheap elementwise loops re-fuse).
+# `flash_o` / `flash_lse` are the flash-attention forward rule's own
+# residuals (ops/pallas/flash_attention.py): outputs of a pallas_call,
+# so unnamed they would be recomputed — the whole kernel — although the
+# context they hold is saved. Where the kernel runs, the model leaves
+# `attn_ctx` (the same values, re-laid) untagged: saved once.
 BOUNDARY_NAMES = ('attn_qkv', 'attn_ctx', 'attn_out',
-                  'mlp_fc1', 'mlp_out', 'embed_out')
+                  'mlp_fc1', 'mlp_out', 'embed_out',
+                  'flash_o', 'flash_lse')
 
-POLICY_NAMES = ('none', 'full', 'attn_mlp_boundaries', 'dots')
+POLICY_NAMES = ('none', 'full', 'attn_mlp_boundaries', 'attn_mlp_lean',
+                'dots')
+
+# richest first: what `use_remat=True` alone tries at pp=1 in the
+# pipeline engine, each only if the one before does not fit
+FIT_ORDER = ('attn_mlp_boundaries', 'attn_mlp_lean', 'full')
 
 
 def checkpoint_policy(name):
@@ -189,6 +206,9 @@ def checkpoint_policy(name):
     if name == 'attn_mlp_boundaries':
         return True, jax.checkpoint_policies.save_only_these_names(
             *BOUNDARY_NAMES)
+    if name == 'attn_mlp_lean':
+        return True, jax.checkpoint_policies.save_only_these_names(
+            *(n for n in BOUNDARY_NAMES if n != 'attn_out'))
     if name == 'dots':
         pol = getattr(jax.checkpoint_policies, 'dots_saveable', None) \
             or jax.checkpoint_policies.checkpoint_dots
@@ -291,6 +311,39 @@ def _publish_policy(engine, policy):
         pass
 
 
+_HELD = (('saved_boundary_bytes', 'ptpu_remat_saved_boundary_bytes',
+          'bytes of pullback residuals the compiled step holds for the '
+          'backward under its remat policy (trace-time reckoning)'),
+         ('grad_tree_bytes', 'ptpu_remat_grad_tree_bytes',
+          'bytes of the fresh gradient tree a backward returns beside '
+          'the accumulation buffer (0: added where they are made)'))
+
+
+def publish_held(engine, **held):
+    """The two byte counts of an engine's trace-time reckoning
+    (saved_boundary_bytes=, grad_tree_bytes=) as gauges."""
+    try:
+        from ....core.monitor import gauge
+        for key, name, help_ in _HELD:
+            gauge(name, help=help_, labelnames=('engine',)).set(
+                float(held[key]), engine=engine)
+    except Exception:
+        pass
+
+
+def held_snapshot(engine):
+    """{saved_boundary_bytes, grad_tree_bytes} of `engine` as published
+    (None each where it has not)."""
+    from ....core import monitor as _m
+    out = {}
+    for key, name, _ in _HELD:
+        m = _m.metrics().get(name)
+        vals = [child.value() for labels, child in m._series().items()
+                if labels and labels[0] == engine] if m is not None else []
+        out[key] = int(vals[0]) if vals else None
+    return out
+
+
 def boundary_counts():
     """{tag name: trace-time count} from the monitor counter."""
     try:
@@ -320,6 +373,7 @@ def snapshot():
         if not policies and not bounds:
             return None
         return {'policies': policies, 'boundaries': bounds,
-                'boundary_total': int(sum(bounds.values()))}
+                'boundary_total': int(sum(bounds.values())),
+                **held_snapshot('pipeline')}
     except Exception:
         return None

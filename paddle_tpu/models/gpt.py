@@ -191,6 +191,7 @@ class GPTAttention(nn.Layer):
             out = jnp.einsum('bhqk,bhkd->bhqd', probs, v)
             return out.transpose(0, 2, 1, 3).reshape(B, L, nh * hd)
 
+        flash = self.use_flash and L >= 512 and not _sp_active()
         if _sp_active():
             # sequence-parallel: K/V ring over the 'sp' axis (net-new vs the
             # reference — SURVEY.md §5.7)
@@ -200,7 +201,7 @@ class GPTAttention(nn.Layer):
                                      sp=topology_runtime.axis_size('sp'),
                                      dropout=self.attn_dropout_p
                                      if self.training else 0.0)
-        elif self.use_flash and L >= 512:
+        elif flash:
             # active attention dropout no longer forces the dense path:
             # the keep mask is drawn OUTSIDE the kernel at the exact
             # RNG-stream point the dense path draws (attn_key above), so
@@ -217,7 +218,10 @@ class GPTAttention(nn.Layer):
             _scaffold.record_route('flash_dropout' if attn_key is not None
                                    else 'flash_attention', False)
             ctx = run_op('fused_attention', attn, [qkv])
-        ctx = _remat_tag(ctx, 'attn_ctx')
+        if not flash:
+            # the kernel's forward rule names its own output (`flash_o`):
+            # the context, re-laid, would be the same values saved twice
+            ctx = _remat_tag(ctx, 'attn_ctx')
         out = _remat_tag(self.out_proj(ctx), 'attn_out')
         return out
 

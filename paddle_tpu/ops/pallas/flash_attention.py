@@ -720,6 +720,15 @@ def _reference_attention(q, k, v, bias=None, num_heads=1, causal=True):
     return jnp.einsum('bqk,bkd->bqd', p.astype(q.dtype), v)
 
 
+def _named_residuals(o, lse):
+    """The forward rules' own `o` and `lse` under remat names
+    (docs/performance.md#remat-policy): outputs of a pallas_call have
+    none, so `save_only_these_names` would run the whole kernel again
+    in the backward for them. An identity outside `jax.checkpoint`."""
+    from jax.ad_checkpoint import checkpoint_name
+    return checkpoint_name(o, 'flash_o'), checkpoint_name(lse, 'flash_lse')
+
+
 # -- causal, no mask (GPT path) ------------------------------------------------
 
 @jax.custom_vjp
@@ -728,7 +737,8 @@ def flash_attention_bhld(q, k, v):
 
 
 def _fa_fwd(q, k, v):
-    o, lse = _flash_forward(q, k, v, causal=True, with_lse=True)
+    o, lse = _named_residuals(
+        *_flash_forward(q, k, v, causal=True, with_lse=True))
     return o, (q, k, v, o, lse)
 
 
@@ -754,8 +764,9 @@ def _flash_attn_dropout(rate, q, k, v, mask8):
 
 
 def _fad_fwd(rate, q, k, v, mask8):
-    o, lse = _flash_forward(q, k, v, causal=True, dropout_mask=mask8,
-                            dropout=rate, with_lse=True)
+    o, lse = _named_residuals(*_flash_forward(
+        q, k, v, causal=True, dropout_mask=mask8, dropout=rate,
+        with_lse=True))
     return o, (q, k, v, mask8, o, lse)
 
 
@@ -779,8 +790,9 @@ def _flash_attn_biased(causal, num_heads, q, k, v, bias):
 
 
 def _fab_fwd(causal, num_heads, q, k, v, bias):
-    o, lse = _flash_forward(q, k, v, bias=bias, num_heads=num_heads,
-                            causal=causal, with_lse=True)
+    o, lse = _named_residuals(*_flash_forward(
+        q, k, v, bias=bias, num_heads=num_heads, causal=causal,
+        with_lse=True))
     return o, (q, k, v, bias, o, lse)
 
 
@@ -803,10 +815,9 @@ def _flash_attn_packed(causal, num_heads, head_dim, q, k, v, bias):
 
 
 def _fap_fwd(causal, num_heads, head_dim, q, k, v, bias):
-    o, lse = _flash_forward_packed(q, k, v, bias=bias,
-                                   num_heads=num_heads,
-                                   head_dim=head_dim, causal=causal,
-                                   with_lse=True)
+    o, lse = _named_residuals(*_flash_forward_packed(
+        q, k, v, bias=bias, num_heads=num_heads, head_dim=head_dim,
+        causal=causal, with_lse=True))
     return o, (q, k, v, bias, o, lse)
 
 

@@ -676,7 +676,14 @@ def compile_with_telemetry(jitted, label, args, kwargs=None):
                                 'latest compiled executable',
                            labelnames=('site',)).set(flops, site=label)
         return compiled, True
-    except Exception:
+    except Exception as e:
+        from .core.memory import is_oom_error
+        if is_oom_error(e):
+            # the program does not fit the device: the jitted fallback
+            # would only compile it again to say the same — the
+            # caller's to answer (the pipeline engine falls back to a
+            # leaner remat policy)
+            raise
         # lowering not supported for this callable/args — fall back to
         # the opaque jit path (compile time then hides in first call)
         c_num.inc(1, site=label)

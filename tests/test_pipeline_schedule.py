@@ -352,6 +352,182 @@ class TestInterleavedEquivalence:
         eng.shutdown()
 
 
+def _one_step_grads(bf16=False, **kw):
+    """One SGD step at lr 1 from the same seeded weights: the update IS
+    the gradient. Returns (loss, {param: gradient})."""
+    import jax.numpy as jnp
+    eng, blocks = _build(pp=1, opt_name='sgd', **kw)
+    eng.optimizer.set_lr(1.0)
+    eng.grad_accum_dtype = 'param' if bf16 else 'float32'
+    if bf16:
+        eng._params = {g: {n: a.astype(jnp.bfloat16) for n, a in t.items()}
+                       for g, t in eng._params.items()}
+    before = {f'{g}/{n}': np.asarray(a, np.float32)
+              for g, t in eng._params.items() for n, a in t.items()}
+    ids, labels = _data(8)
+    loss = float(eng.train_batch((Tensor(ids), Tensor(labels))))
+    grads = {k: before[k] - np.asarray(eng._params[k.split('/', 1)[0]]
+                                       [k.split('/', 1)[1]], np.float32)
+             for k in before}
+    eng.shutdown()
+    return loss, grads
+
+
+class TestOneStage:
+    """pp=1 under 'stash' (ISSUE 35): the engine's own reverse scan adds
+    each layer's weight gradient into the accumulation buffer; the
+    'recompute' memory mode still takes `jax.vjp` through the layer scan
+    and adds the fresh tree — the path every pp=1 engine took before —
+    and is the reference here, both under remat 'full'."""
+
+    REF = dict(memory_mode='recompute', remat_policy='full')
+
+    @pytest.mark.parametrize('new', [
+        dict(remat_policy='full'), dict(use_remat=True),
+        dict(remat_policy='attn_mlp_lean'), dict(use_remat=False)],
+        ids=['full', 'fitted', 'lean', 'no-remat'])
+    def test_three_steps_of_losses_bit_identical(self, new):
+        ref = _run(pp=1, **self.REF)
+        got = _run(pp=1, **new)
+        assert got[0] == ref[0]
+        for k in ref[1]:
+            np.testing.assert_allclose(got[1][k], ref[1][k], rtol=0,
+                                       atol=2e-7, err_msg=k)
+
+    def test_block_gradients_bit_identical_in_float32(self):
+        """Accumulated in float32 every block gradient is the SAME
+        number (the same pullback, the same adds in the same order).
+        The word embedding's differs in its last bits: without the
+        select, XLA folds `acc + scatter-add(0, ids, dx)` into a
+        scatter-add onto `acc`, another order of the same sums."""
+        l_ref, ref = _one_step_grads(**self.REF)
+        l_new, new = _one_step_grads(remat_policy='full')
+        assert l_new == l_ref
+        for k in ref:
+            if k.startswith('blocks/') or k.startswith('head/'):
+                np.testing.assert_array_equal(new[k], ref[k], err_msg=k)
+            else:
+                np.testing.assert_allclose(new[k], ref[k], rtol=1e-5,
+                                           atol=1e-8, err_msg=k)
+
+    def test_gradients_one_rounding_close_in_param_dtype(self):
+        """bf16 parameters, gradients accumulated in bf16: the reference
+        rounds a layer's gradient to bf16 and then adds; fused, the
+        product may be added before the one rounding. Either way one
+        bf16 rounding of the sum apart."""
+        l_ref, ref = _one_step_grads(bf16=True, **self.REF)
+        l_new, new = _one_step_grads(bf16=True, remat_policy='full')
+        assert abs(l_new - l_ref) <= 2 ** -7 * abs(l_ref)
+        for k in ref:
+            # the update is read off bf16 parameters: two of their
+            # roundings, and one of the sum's
+            room = 2 ** -7 * (np.abs(ref[k]).max() + 2 * 0.02 + 1e-6)
+            np.testing.assert_allclose(new[k], ref[k], rtol=0, atol=room,
+                                       err_msg=k)
+
+    def test_fits_the_richest_policy_the_limit_allows(self, monkeypatch):
+        """`use_remat=True` alone: the richest of FIT_ORDER whose
+        reckoned bytes fit the device's limit; no limit named (the
+        CPU) fits everything; the gauge says which was compiled."""
+        from paddle_tpu.distributed.fleet.meta_parallel import (
+            spmd_pipeline as sp)
+        from paddle_tpu.distributed.fleet.utils.recompute import (
+            FIT_ORDER, snapshot)
+        ids, labels = _data(8)
+
+        def fitted(limit):
+            monkeypatch.setattr(sp, '_device_bytes_limit',
+                                lambda dev: limit)
+            eng, _ = _build(pp=1, use_remat=True)
+            eng.train_batch((Tensor(ids), Tensor(labels)))
+            r = dict(eng._remat_reckoned)
+            assert snapshot()['policies']['pipeline'] == r['policy']
+            assert pipeline_snapshot()['saved_boundary_bytes'] == \
+                r['held_bytes'][r['policy']]
+            eng.shutdown()
+            return r
+
+        r = fitted(None)
+        assert r['policy'] == FIT_ORDER[0] == 'attn_mlp_boundaries'
+        base = r['fixed_bytes'] + r['working_bytes']
+        rich = base + r['held_bytes'][FIT_ORDER[0]]
+        assert fitted(rich)['policy'] == FIT_ORDER[0]
+        r = fitted(rich - 1)
+        assert r['policy'] == 'attn_mlp_lean'
+        lean = base + r['held_bytes']['attn_mlp_lean']
+        assert lean < rich
+        r = fitted(lean - 1)
+        assert r['policy'] == 'full' and list(r['held_bytes']) == \
+            list(FIT_ORDER)
+        assert r['held_bytes']['full'] < r['held_bytes']['attn_mlp_lean']
+        assert fitted(1)['policy'] == 'full'     # the last is taken anyway
+
+    def test_falls_back_when_the_compiler_refuses(self, monkeypatch):
+        """RESOURCE_EXHAUSTED from the AOT compile: the next policy,
+        traced and compiled again, said in the gauge; any other error,
+        and a named policy's, is raised."""
+        from paddle_tpu import profiler
+        from paddle_tpu.distributed.fleet.utils.recompute import snapshot
+        real = profiler.compile_with_telemetry
+        refused = []
+
+        def compile_(jitted, label, args, kwargs=None):
+            if len(refused) < 2:
+                jitted.lower(*args)         # the trace sets the policy
+                refused.append(snapshot()['policies']['pipeline'])
+                raise RuntimeError('RESOURCE_EXHAUSTED: Used 17.77G of '
+                                   '15.75G hbm')
+            return real(jitted, label, args, kwargs)
+        monkeypatch.setattr(profiler, 'compile_with_telemetry', compile_)
+        ids, labels = _data(8)
+        eng, _ = _build(pp=1, use_remat=True)
+        loss = float(eng.train_batch((Tensor(ids), Tensor(labels))))
+        assert refused == ['attn_mlp_boundaries', 'attn_mlp_lean']
+        assert snapshot()['policies']['pipeline'] == 'full'
+        assert eng._remat_reckoned['refused_by_compiler'] == 2
+        eng.shutdown()
+        assert loss == _run(steps=1, pp=1, **self.REF)[0][0]
+
+        refused.clear()
+        eng, _ = _build(pp=1, remat_policy='attn_mlp_boundaries')
+        with pytest.raises(RuntimeError, match='RESOURCE_EXHAUSTED'):
+            eng.train_batch((Tensor(ids), Tensor(labels)))
+        eng.shutdown()
+
+    def test_snapshot_says_what_the_step_holds(self):
+        """`grad_tree_bytes` is 0 on the one-stage path and the size of
+        the fresh gradient tree where `jax.vjp` returns one;
+        `saved_boundary_bytes` is what crosses to the backward."""
+        from paddle_tpu.profiler import StepTelemetry
+        ids, labels = _data(8)
+        held = {}
+        for name, kw in (('one', dict(pp=1, remat_policy='full')),
+                         ('rich', dict(pp=1, use_remat=True)),
+                         ('vjp', dict(pp=1, **self.REF)),
+                         ('pp2', dict(pp=2))):
+            eng, _ = _build(**kw)
+            eng.train_batch((Tensor(ids), Tensor(labels)))
+            held[name] = snap = pipeline_snapshot()
+            tel = StepTelemetry(publish=False).snapshot()
+            for key in ('saved_boundary_bytes', 'grad_tree_bytes'):
+                assert tel['remat'][key] == tel['pipeline'][key] == \
+                    snap[key]
+            n_params = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                           for t in eng._params.values()
+                           for a in t.values())
+            eng.shutdown()
+            if name != 'pp2':       # pp2's is one stage's share
+                assert snap['grad_tree_bytes'] == \
+                    (n_params if name == 'vjp' else 0)
+        # 4 layers' inputs of [2, 32, 16] float32; the boundaries more
+        assert held['one']['saved_boundary_bytes'] == 4 * 2 * 32 * 16 * 4
+        assert held['rich']['saved_boundary_bytes'] > \
+            held['one']['saved_boundary_bytes']
+        assert 0 < held['pp2']['grad_tree_bytes'] < \
+            held['vjp']['grad_tree_bytes']
+        assert held['pp2']['saved_boundary_bytes'] > 0
+
+
 @pytest.mark.slow
 class TestTwoRank:
     def test_two_rank_subprocess_equivalence(self):

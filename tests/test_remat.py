@@ -107,6 +107,64 @@ class TestPolicyResolution:
             assert on == (name != 'none')
 
 
+class TestFlashResiduals:
+    """The flash forward rules name their own `o` and `lse` (ISSUE 35):
+    outputs of a pallas_call have no name, so `save_only_these_names`
+    would run the whole kernel again in the backward for them."""
+
+    @staticmethod
+    def _entries():
+        from paddle_tpu.ops.pallas import flash_attention as fa
+        import jax.numpy as jnp
+        bias = jnp.zeros((2, 128), jnp.float32)
+        return {
+            'bhld': lambda q, k, v: fa.flash_attention_bhld(q, k, v),
+            'biased': lambda q, k, v: fa.flash_attention(
+                q, k, v, bias=bias, num_heads=1, causal=False),
+            'packed': lambda q, k, v: fa.flash_attention_packed(
+                q, k, v, 1, 64, causal=True),
+        }
+
+    @pytest.mark.parametrize('entry', ['bhld', 'biased', 'packed'])
+    @pytest.mark.parametrize('policy,kept', [
+        ('attn_mlp_boundaries', 2), ('attn_mlp_lean', 2), ('full', 0)])
+    def test_saved_by_name(self, entry, policy, kept):
+        import jax
+        import jax.numpy as jnp
+        from jax._src.ad_checkpoint import saved_residuals
+        fn = self._entries()[entry]
+        q = jnp.ones((2, 128, 64), jnp.float32)
+        f = jax.checkpoint(lambda *a: fn(*a).sum(),
+                           policy=checkpoint_policy(policy)[1])
+        saved = [(aval.shape, why) for aval, why in saved_residuals(
+            f, q, q, q) if not why.startswith(('from the argument',
+                                               'from a constant'))]
+        # o [2, 128, 64] and the logsumexp, one value a row (and head)
+        assert len(saved) == kept, saved
+        if kept:
+            assert sorted(s for s, _ in saved) in (
+                [(2, 128, 1), (2, 128, 64)], [(2, 128, 64), (2, 128, 1)])
+
+    def test_lean_is_the_boundaries_without_attn_out(self):
+        from paddle_tpu.distributed.fleet.utils.recompute import (
+            BOUNDARY_NAMES, FIT_ORDER)
+        assert FIT_ORDER == ('attn_mlp_boundaries', 'attn_mlp_lean', 'full')
+        assert {'flash_o', 'flash_lse', 'attn_out'} <= set(BOUNDARY_NAMES)
+        import jax
+        import jax.numpy as jnp
+        from jax._src.ad_checkpoint import saved_residuals
+        from jax.ad_checkpoint import checkpoint_name
+
+        def f(x):
+            y = checkpoint_name(jnp.sin(x), 'attn_out')
+            return jnp.sin(checkpoint_name(jnp.cos(y), 'mlp_fc1')).sum()
+        kept = {p: len([w for _, w in saved_residuals(
+            jax.checkpoint(f, policy=checkpoint_policy(p)[1]),
+            jnp.ones((4,))) if not w.startswith('from the argument')])
+            for p in ('attn_mlp_boundaries', 'attn_mlp_lean')}
+        assert kept == {'attn_mlp_boundaries': 2, 'attn_mlp_lean': 1}
+
+
 # ---------------------------------------------------------------------------
 # remat ON == OFF equivalence on the three engines
 # ---------------------------------------------------------------------------
