@@ -2,8 +2,9 @@
 
 Reference parity: the pybind layer (paddle/fluid/pybind — N33) for the
 runtime-services subset that stays native in the TPU rebuild: data feed
-(N19), TCP store rendezvous (N8/N9), sparse PS table (N30), host profiler
-(N4). Builds csrc/ with make at first load (g++ only — no pybind11 dependency;
+(N19), TCP store rendezvous (N8/N9), sparse PS table (N30). (The host
+profiler, N4, is the Python span ring of `paddle_tpu/profiler.py`; its
+native mirror went in PRs 25 and 36.) Builds csrc/ with make at first load (g++ only — no pybind11 dependency;
 plain C ABI + ctypes).
 """
 import ctypes
@@ -146,22 +147,6 @@ def load_native(required=False):
     lib.ptpu_dense_load.restype = ctypes.c_int
     lib.ptpu_dense_load.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
     lib.ptpu_dense_destroy.argtypes = [ctypes.c_void_p]
-
-    # profiler
-    lib.ptpu_profiler_enable.argtypes = [ctypes.c_int]
-    lib.ptpu_profiler_now.restype = ctypes.c_uint64
-    lib.ptpu_profiler_record.argtypes = [ctypes.c_char_p, ctypes.c_uint64,
-                                         ctypes.c_uint64]
-    lib.ptpu_profiler_count.restype = ctypes.c_int64
-    lib.ptpu_profiler_summary.restype = ctypes.c_int
-    lib.ptpu_profiler_summary.argtypes = [ctypes.c_char_p, ctypes.c_int]
-    lib.ptpu_profiler_export.restype = ctypes.c_int
-    lib.ptpu_profiler_export.argtypes = [ctypes.c_char_p]
-    try:      # post-v2 symbols: tolerate a stale prebuilt .so
-        lib.ptpu_profiler_dropped.restype = ctypes.c_uint64
-        lib.ptpu_profiler_set_capacity.argtypes = [ctypes.c_uint64]
-    except AttributeError:
-        pass
 
     _LIB = lib
     return lib

@@ -108,6 +108,13 @@ products outweigh its copies 30 to 1). The Mosaic call is named
 `paged_attention_latent`. With `latent` absent the body, the grid and
 the operands are what they were.
 
+The name says the group of rows too: a call whose rows carry more than
+one query (static `T` > 1: the mixed step's prompt chunks, and a verify
+step's token with its drafts) appends `_chunk` LAST —
+`paged_attention[_diff][_window][_latent]_chunk` —, the decode rows'
+call (`T` == 1) nothing, so a mixed program's two calls a layer are two
+rows of the device trace. The body is the same.
+
 Layouts:
   q           [B, T, Hq*D]  new-token queries, right-padded to T per row
   k_pages     [N_pages, page_size, H*D]   the pool's device arrays (H:
@@ -642,12 +649,16 @@ def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
             vmem_limit_bytes=int(min(max(vmem_bytes, 16 * 2 ** 20),
                                      scaffold.VMEM_CAP_BYTES))),
         interpret=interpret,
-        # a call with a window, or with shared value blocks, has its
-        # own row in the profile (the `paged_attention*` readers sum
-        # them all)
+        # a call with a window, with shared value blocks or on a
+        # latent plane has its own row in the profile, and so has the
+        # call of a group whose rows carry more than one query (`_chunk`,
+        # last: the mixed step's prompt chunks, and a verify step's
+        # token + drafts) beside the decode rows' (the
+        # `paged_attention*` readers sum them all)
         name='paged_attention' + ('_diff' if diff > 1 else '')
         + ('' if window is None else '_window')
-        + ('' if latent is None else '_latent'),
+        + ('' if latent is None else '_latent')
+        + ('_chunk' if T > 1 else ''),
     )(*inputs)
     if latent is not None:
         # rows t*Hq+h (batched) or g*T+t (tiles of whole heads)

@@ -142,15 +142,34 @@ class _Flight:
     it is KNOWN to do before they arrive — a decode row emits one
     token, a chunk prefills its tokens and, a prompt's last, emits the
     first. (An EOS is the one thing not known: `_accept_decode`.)"""
-    __slots__ = ('dispatches', 'emits', 'prefills')
+    __slots__ = ('dispatches', 'emits', 'prefills', 'step', 'steps')
 
-    def __init__(self):
+    def __init__(self, step, steps=1):
         self.dispatches = []    # (decode rows, chunks riding, queued)
         self.emits = set()      # ids of the requests that take a token
         self.prefills = {}      # request id -> (request, first, tokens)
+        self.step = step        # ordinal of the serve::step that launched
+        self.steps = steps      # 0: a verify step of it landed already
 
     def carries(self, req):
         return req.id in self.emits or req.id in self.prefills
+
+
+class _DeviceStep:
+    """What the device ran between two fetches: the `serve::device_step`
+    record being built (`ServingEngine._ran`, `_close_device_step`).
+    `since` is where the record before it ended, `launched` when the
+    first of its dispatches was queued; the counts are the record's
+    args."""
+    __slots__ = ('since', 'launched', 'steps', 'dispatches', 'decode_rows',
+                 'chunks', 'chunk_tokens', 'chunk_slots', 'emitted')
+
+    def __init__(self, since):
+        self.since = since
+        self.launched = None
+        self.steps = self.dispatches = self.decode_rows = 0
+        self.chunks = self.chunk_tokens = self.chunk_slots = 0
+        self.emitted = 0
 
 
 class ServingConfig:
@@ -530,9 +549,7 @@ class ServingEngine:
             self._kv_sharding = NamedSharding(mesh, P(None, None, 'mp'))
         self.pool.materialize(sharding=self._kv_sharding)
         if self._stateful:
-            with RecordEvent('serve::state_alloc', event_type='serve',
-                             bytes=self.pool.state_bytes()):
-                self.pool.materialize_state()
+            self.pool.materialize_state()
         # host-RAM KV tier (ISSUE 20): pinned host buffers + one
         # background transfer thread under the pool. Spills are
         # proactive (watermark in _observe_spill_pressure) or the
@@ -634,16 +651,21 @@ class ServingEngine:
         # the host (`_Flight`, or None), the newest id of every slot as
         # the last dispatch left it ON the device (what a decode row
         # reads when its token never came to the host), when the last
-        # fetch returned, and the mechanism's counters — steps launched
-        # behind another, fetches with nothing queued behind them by
-        # what held the next step back, rows dropped after an EOS
+        # fetch returned (where the last `serve::device_step` record
+        # ended) and whether its ids were there before it was asked for
+        # them, the record being built (the dispatches landed since:
+        # those that fetched nothing wait in it for the next fetch),
+        # and the mechanism's counters — steps launched behind another, fetches with
+        # nothing queued behind them by what held the next step back,
+        # rows dropped after an EOS
         self._flight = None
         self._prev_ids = jnp.zeros((config.max_batch_size,), jnp.int32)
         if self._mp > 1:    # replicated, as every program hands it back
             from jax.sharding import NamedSharding, PartitionSpec as P
             self._prev_ids = jax.device_put(
                 self._prev_ids, NamedSharding(mesh, P()))
-        self._fetched_at = 0.0
+        self._fetched_at, self._fetched_late = 0.0, False
+        self._device_step = _DeviceStep(0.0)
         self._pipelined = 0
         self._drains = dict.fromkeys(DRAIN_REASONS, 0)
         self._overrun = 0
@@ -969,12 +991,10 @@ class ServingEngine:
         preempt_before = self.scheduler.preemptions
         t_begin = self._gap.dispatch_begin()
         t_sched = time.perf_counter()
-        with RecordEvent('serve::schedule', event_type='serve'):
-            with RecordEvent('serve::check_stalled', event_type='serve'):
-                self._check_stalled()
-            with RecordEvent('serve::admit', event_type='serve') as ev:
-                admitted = self._admit()
-                ev.args = {'admitted': admitted}
+        self._check_stalled()
+        with RecordEvent('serve::admit', event_type='serve') as ev:
+            admitted = self._admit()
+            ev.args = {'admitted': admitted}
         sched_dt = time.perf_counter() - t_sched
         if self._flight is None:
             # nothing in flight: the serial order, on the host's own
@@ -1742,13 +1762,22 @@ class ServingEngine:
                     model.train()
         return run
 
-    def _dispatch(self, shape, rows, B, T):
-        """One compiled step called and fetched at once (`_enqueue`
-        then `_fetch`), nothing queued behind it: a verify step or a
-        fused window, what the host must read before it can plan on.
-        Returns the fetched ids (verify [B, T], one column more with
-        sampled rows; fused [B, T])."""
-        return self._fetch(self._enqueue(shape, rows, B, T), behind=False)
+    def _dispatch(self, shape, rows, B, T, accept, *args):
+        """One compiled step called, fetched and accepted at once
+        (`_enqueue`, `_fetch`, then `accept(ids, rows, *args)` under
+        its span), nothing queued behind it: a verify step or a fused
+        window, what the host must read before it can plan on — its
+        own `serve::device_step` record, drained for `shape`. Returns
+        the tokens emitted."""
+        self._drains[shape] += 1
+        queued = self._enqueue(shape, rows, B, T)
+        work = self._ran(queued, rows, ())
+        work.steps += 1
+        nxt = self._fetch(queued, behind=False)
+        work.emitted = self._accepted(accept, nxt, rows, *args)
+        self._close_device_step(self._step_ordinal, shape, behind=False,
+                                drain=shape)
+        return work.emitted
 
     def _enqueue(self, shape, rows, B, T, chunks=()):
         """The one place a compiled step is called. `rows` are the
@@ -1765,7 +1794,8 @@ class ServingEngine:
         which returns when it is queued on the device —, takes the new
         pool and the ids handed on, and feeds the iteration's clocks
         and counts (`_it_*`). Returns what `_fetch` needs to bring the
-        sampled ids to the host."""
+        sampled ids to the host, behind it when the call began and the
+        token slots of its prefill group (`_ran`)."""
         jnp = self._jnp
         mixed, fused = shape == 'mixed', shape == 'fused'
         width = T if shape == 'verify' else 1   # a decode row's queries
@@ -1875,7 +1905,7 @@ class ServingEngine:
         prefill = int(q_lens[B:].sum()) / queries
         self._it_prefill_s += (t1 - t0) * prefill
         self._it_decode_s += (t1 - t0) * (1.0 - prefill)
-        return ids, B + P, 1 + P, bool(rows), t0
+        return ids, B + P, 1 + P, bool(rows), t0, P * T
 
     def _fetch(self, queued, behind):
         """The one host sync of a dispatch: the ids `_enqueue` left on
@@ -1883,7 +1913,10 @@ class ServingEngine:
         are split off, and the fetch's clocks are fed. `behind`: the
         next step is queued behind this one, so the wait is the
         device's work and not its idling. -> ids."""
-        ids, n, groups, decode, t0 = queued
+        ids, n, groups, decode = queued[:4]
+        # the device was done before the host asked: the wait below is
+        # none, and what ends at its return is the host's turn
+        late = ids.is_ready()
         t1 = time.perf_counter()
         with RecordEvent('serve::sample_fetch', event_type='serve'):
             ids = _host_fetch(ids)      # the sampled-token fetch
@@ -1893,12 +1926,68 @@ class ServingEngine:
         self._it_fetch += t2 - t1
         if not behind:
             self._it_gating += t2 - t1
-        if decode:
-            # the device ran this dispatch from its launch, or from
-            # where the one before it ended
-            self._decode_time += t2 - max(t0, self._fetched_at)
-        self._fetched_at = t2
+        # the one clock of the `serve::device_step` records: where the
+        # record that holds this dispatch ends, if no later fetch of
+        # its step moves it, and where the next one begins
+        self._fetched_at, self._fetched_late = t2, late
         return ids
+
+    def _ran(self, queued, rows, riding):
+        """Count one landed dispatch into the `serve::device_step`
+        record being built — beside what earlier landings left there
+        that fetched nothing (inner chunks alone), which the device
+        ran, or still runs, since the last fetch. -> the record."""
+        *_, launched, slots = queued
+        work = self._device_step
+        if work.launched is None:
+            work.launched = launched
+        work.dispatches += 1
+        work.decode_rows += len(rows)
+        work.chunks += len(riding)
+        work.chunk_tokens += sum(n for _, _, n in riding)
+        work.chunk_slots += slots
+        return work
+
+    def _close_device_step(self, step, shape, behind, drain):
+        """One `serve::device_step` record, made where a step lands:
+        from the later of its first dispatch's launch and the end of
+        the record before it to the return of its last fetch (`_fetch`
+        read both ends: no clock is read here), and a new record begun. With a step
+        queued behind it (`behind`) the device went from the record
+        before straight into this one and on into the next, so the
+        interval is the device's own time on these dispatches. It is
+        NOT where nothing was queued behind (a drain: the next record
+        begins at its launch, and the host's turn lies between); where
+        the launch came after the last fetch (the first step after
+        idle: the interval begins with the launch's own latency); and
+        where the host came for the ids after the device had them
+        (`late` 1: the host's turn outlasted a short step — the record
+        ends with the host's arrival, the next one begins as much too
+        late, and only the sum of the two is the device's; a reader of
+        durations takes the records between two fetches that waited).
+        Dispatches that fetch nothing — a step's trailing ones, or a
+        whole step of inner chunks — are counted (`steps`,
+        `dispatches`, `chunks`, ...) in the record the NEXT fetch
+        closes, which is where the device ran them. `step` is the
+        ordinal of the `serve::step` that launched the step whose fetch
+        closed the record. `_decode_time` is the sum of the records
+        that carried decode rows. Not mirrored into a device trace (an
+        annotation cannot be backdated): `tools/trace_cell.py` holds it
+        to the device's own `XLA Modules` line."""
+        work, end = self._device_step, self._fetched_at
+        began = max(work.launched, work.since)
+        self._device_step = _DeviceStep(end)
+        if work.decode_rows:
+            self._decode_time += end - began
+        args = {'drain': drain} if drain else {}
+        record_span('serve::device_step', int(began * 1e9), int(end * 1e9),
+                    event_type='serve', step=step, steps=work.steps,
+                    dispatches=work.dispatches, shape=shape,
+                    decode_rows=work.decode_rows, chunks=work.chunks,
+                    chunk_tokens=work.chunk_tokens,
+                    chunk_slots=work.chunk_slots, emitted=work.emitted,
+                    behind=int(behind), late=int(self._fetched_late),
+                    **args)
 
     def _warm_decode(self, B, sample):
         """A server that prefills will decode: compile the [B, 1] step
@@ -1942,9 +2031,9 @@ class ServingEngine:
             rows.append((i, req, [_last_token(req)], req.context_len))
         if not rows:
             return 0, 0
-        self._drains['fused'] += 1
-        nxt = self._dispatch('fused', rows, self.config.max_batch_size, K)
-        return len(rows), self._accepted(self._accept_fused, nxt, rows, K)
+        return len(rows), self._dispatch(
+            'fused', rows, self.config.max_batch_size, K,
+            self._accept_fused, K)
 
 
     def _keys_read(self, context, queries):
@@ -2304,17 +2393,24 @@ class ServingEngine:
         self._steps += self._dispatches > called
         return flight
 
-    def _land(self, behind):
+    def _land(self, behind, drain=None):
         """Bring the step in flight home: every dispatch's ids fetched
         (inner chunks alone sample nothing anyone reads: no fetch) and
         accepted, in the order they were queued — the device runs the
         next dispatch, and with `behind` the next STEP, while the host
-        accepts this one's tokens."""
+        accepts this one's tokens. The step's last fetch closes its
+        `serve::device_step` record (`_close_device_step`); `drain` is
+        why nothing is queued behind it."""
         flight, self._flight = self._flight, None
         B = self.config.max_batch_size
-        for rows, riding, queued in flight.dispatches:
-            nxt = self._fetch(queued, behind) if rows or any(
-                _samples(*c) for c in riding) else None
+        due = [bool(rows) or any(_samples(*c) for c in riding)
+               for rows, riding, _ in flight.dispatches]
+        last = max((i for i, d in enumerate(due) if d), default=-1)
+        for i, (rows, riding, queued) in enumerate(flight.dispatches):
+            work = self._ran(queued, rows, riding)
+            if i == 0:
+                work.steps += flight.steps
+            nxt = self._fetch(queued, behind) if due[i] else None
             if rows:
                 # POST-preemption counts: the rows that rode
                 tokens = self._accepted(self._accept_decode, nxt, rows,
@@ -2322,10 +2418,16 @@ class ServingEngine:
                 self._decode_tokens += tokens
                 self._it_rows += len(rows)
                 self._it_tokens += tokens
+                work.emitted += tokens
             if riding:
-                self._accepted(self._accept_chunks, nxt, B, riding,
-                               chunks=len(riding))
+                work.emitted += self._accepted(
+                    self._accept_chunks, nxt, B, riding, chunks=len(riding))
                 self._it_chunk_tokens += sum(n for _, _, n in riding)
+            if i == last:
+                # (what follows fetches nothing: the next record's)
+                self._close_device_step(
+                    flight.step, 'mixed' if work.chunks else 'decode',
+                    behind, drain)
 
     def _drain(self, reason='idle'):
         """Empty the pipe: fetch and accept the step in flight with
@@ -2336,7 +2438,7 @@ class ServingEngine:
         if self._flight is None:
             return False
         self._drains[reason] += 1
-        self._land(behind=False)
+        self._land(behind=False, drain=reason)
         return True
 
     def _advance(self, chunks):
@@ -2425,7 +2527,7 @@ class ServingEngine:
         chunks = [c for c in chunks if c[0].state == RequestState.PREFILL]
         if not rows and not chunks:
             return None
-        flight = _Flight()
+        flight = _Flight(self._step_ordinal)
         self._pipelined += self._flight is not None
         if rows:
             self._decode_steps += 1
@@ -2434,12 +2536,12 @@ class ServingEngine:
             # without a surviving proposal the verify columns would all
             # be padding: the [B, 1] rows serve
             if any(len(query) > 1 for _, _, query, _ in rows):
-                self._drains['verify'] += 1
-                nxt = self._dispatch('verify', rows, B, K + 1)
                 self._it_rows = len(rows)
-                self._it_tokens = self._accepted(
-                    self._accept_decode, nxt, rows, True, K + 1)
+                self._it_tokens = self._dispatch(
+                    'verify', rows, B, K + 1, self._accept_decode, True,
+                    K + 1)
                 self._decode_tokens += self._it_tokens
+                flight.steps = 0    # counted in the verify step's record
                 rows = []
             elif not chunks:
                 flight.dispatches.append(

@@ -160,20 +160,24 @@ def test_paged_attention_is_named(rows, T, one_chip, as_on_tpu):
     got = mosaic_calls(fn, [((rows, T, 2048), BF16), pages, pages,
                             ((rows, 80), jnp.int32), ((rows,), jnp.int32),
                             ((rows,), jnp.int32)], one_chip)
-    assert got == {'paged_attention'}
+    # a group whose rows carry more than one query says so, last
+    assert got == {'paged_attention' + ('_chunk' if T > 1 else '')}
 
 
 @pytest.mark.parametrize('rows,window,name', [
     (64, None, 'paged_attention'), (64, 2048, 'paged_attention_window'),
-    (1, None, 'paged_attention'), (1, 2048, 'paged_attention_window'),
-    (2, None, 'paged_attention'), (2, 2048, 'paged_attention_window')])
+    (1, None, 'paged_attention_chunk'),
+    (1, 2048, 'paged_attention_window_chunk'),
+    (2, None, 'paged_attention_chunk'),
+    (2, 2048, 'paged_attention_window_chunk')])
 def test_paged_attention_with_kv_groups_compiles_and_is_named(
         rows, window, name, one_chip, as_on_tpu):
     """The sparse server cell's row groups at its published widths: 32
     query heads on 4 kv heads of 128, 528-page tables over 28,000
     pages; [64, 1] decode and chunks of 512 (one, and the mixed step's
     2 rows), whose 8 query heads a kv head stack as 4096 rows. A call
-    with a window has its own name."""
+    with a window has its own name, and `_chunk` comes after
+    `_window`."""
     from paddle_tpu.ops.pallas import paged_attention as pa
     T = 1 if rows == 64 else 512
     pages = ((28000, 16, 512), BF16)
@@ -191,8 +195,8 @@ def test_paged_attention_with_kv_groups_compiles_and_is_named(
 @pytest.mark.parametrize('rows,window,name', [
     (64, None, 'paged_attention_diff'),
     (64, 512, 'paged_attention_diff_window'),
-    (2, None, 'paged_attention_diff'),
-    (2, 512, 'paged_attention_diff_window')])
+    (2, None, 'paged_attention_diff_chunk'),
+    (2, 512, 'paged_attention_diff_window_chunk')])
 def test_differential_paged_attention_compiles_and_is_named(
         rows, window, name, one_chip, as_on_tpu):
     """The state-space server cell's row groups at its published
@@ -233,7 +237,9 @@ def test_latent_paged_attention_compiles_and_is_named(rows, T, one_chip,
                             ((9000, 64, 640), BF16),
                             ((rows, 528), jnp.int32), ((rows,), jnp.int32),
                             ((rows,), jnp.int32)], one_chip)
-    assert got == {'paged_attention_latent'}
+    # `_chunk` after `_latent`: the readers' `paged_attention_latent`
+    # prefix still matches both calls
+    assert got == {'paged_attention_latent' + ('_chunk' if T > 1 else '')}
 
 
 @pytest.mark.parametrize('rows,T', [(64, 1), (2, 128)])

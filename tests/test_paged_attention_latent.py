@@ -165,4 +165,5 @@ def test_the_mosaic_call_is_named_for_the_latent_body(monkeypatch):
         jnp.zeros((1, 1, 2 * 16)), k, k, jnp.zeros((1, P), jnp.int32),
         jnp.ones((1,), jnp.int32), jnp.ones((1,), jnp.int32), num_heads=2,
         head_dim=16, interpret=True)
-    assert names == ['paged_attention_latent', 'paged_attention']
+    # two queries a row: the chunk group's call; one: the decode rows'
+    assert names == ['paged_attention_latent_chunk', 'paged_attention']

@@ -1406,10 +1406,13 @@ class TestOneDispatchPath:
             eng.pool.ensure_capacity(req.id, req.context_len)
             batch.append((i, req, [req.generated[-1]], req.context_len))
         pool0 = eng.pool.kv
-        ids = eng._dispatch('decode', batch, 3, 1)
+        def call(shape):    # a program called and fetched at once
+            return eng._fetch(eng._enqueue(shape, batch, 3, 1),
+                              behind=False)
+        ids = call('decode')
         pages = eng.pool.kv
         eng.pool.kv = pool0
-        ids_fused = eng._dispatch('fused', batch, 3, 1)
+        ids_fused = call('fused')
         assert {k[0] for k in eng._step_fns} >= {3, 'fused'}
         assert ids_fused.shape == (3, 1)
         assert ids_fused[:, 0].tolist() == ids.tolist()

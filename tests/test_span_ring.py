@@ -303,7 +303,7 @@ def test_lowering_and_compiling_are_counted_apart():
 # a step's launch (`serve::prefill_chunk`, `serve::dispatch`) and the
 # landing of the step in flight (its fetch and accepts, which run one
 # program later than they were queued) are children of the step alike
-STEP_CHILDREN = {'serve::schedule', 'serve::prefill_chunk',
+STEP_CHILDREN = {'serve::admit', 'serve::prefill_chunk',
                  'serve::dispatch', 'serve::sample_fetch',
                  'serve::accept', 'serve::telemetry'}
 
@@ -358,7 +358,7 @@ class TestServingSpans:
                     else ('serve::step',)), s
         for step in step_spans:
             kids = [s.name for s in spans if s.parent == step.id]
-            assert kids.count('serve::schedule') == 1
+            assert kids.count('serve::admit') == 1
             assert kids.count('serve::telemetry') == 1
             assert set(kids) <= STEP_CHILDREN
             # children lie inside the step, in time
@@ -368,16 +368,20 @@ class TestServingSpans:
                         s.start_ns + s.dur_ns
                         <= step.start_ns + step.dur_ns)
 
-    def test_schedule_splits_into_check_stalled_and_admit(self, served):
+    def test_admit_counts_what_it_admitted_and_nothing_wraps_it(self,
+                                                               served):
+        """`serve::schedule` (a parent that timed nothing its children
+        did not) and `serve::check_stalled` (opened every step with the
+        watchdog off) had no reader and are gone (PR 36); so is the
+        construction-time `serve::state_alloc`."""
         _, spans, reqs, _ = served
         by_id = {s.id: s for s in spans}
-        for name in ('serve::check_stalled', 'serve::admit'):
-            got = [s for s in spans if s.name == name]
-            assert got and all(
-                by_id[s.parent].name == 'serve::schedule' for s in got)
-        admitted = sum(s.args['admitted'] for s in spans
-                       if s.name == 'serve::admit')
-        assert admitted == len(reqs)
+        got = [s for s in spans if s.name == 'serve::admit']
+        assert got and all(
+            by_id[s.parent].name == 'serve::step' for s in got)
+        assert sum(s.args['admitted'] for s in got) == len(reqs)
+        assert not {s.name for s in spans} & {
+            'serve::schedule', 'serve::check_stalled', 'serve::state_alloc'}
 
     def test_device_spans_sit_under_their_phase(self, served):
         shape, spans, _, _ = served

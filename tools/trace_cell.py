@@ -17,7 +17,12 @@ touched. It needs the chip, like run.py:
 Writes <out>/<cell>.summary.json and .txt (and leaves the xplane under
 <out>/xplane/ unless --drop-xplane), and cross-checks the two clocks:
 the span ring's host time per step x traced steps against the device
-trace's window - busy.
+trace's window - busy; and, for a server, holds the program's
+`serve::device_step` records to the device: each record's duration
+beside the summed `device_duration_ps` of the `XLA Modules` executions
+(`jit_step(...)`, one a dispatch) it covers, by kind of step — the
+records are not in the xplane (a span recorded at its end cannot be
+mirrored), so this is the one place the two are laid side by side.
 """
 import argparse
 import json
@@ -31,6 +36,143 @@ T_START = time.time()
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.join(ROOT, 'tools'))
+
+
+from benchmarks.layer_metrics import _device_steps  # noqa: E402
+
+MODULES_LINE = 'XLA Modules'
+# a step's kind, and which records are the device's own time, as the
+# benchmark's readers have them
+KINDS = (_device_steps.decode_only, _device_steps.one_chunk_dispatch,
+         _device_steps.multi_dispatch)
+# a fetch returns this long, at most, after its execution ended (read:
+# 1.6-2.6 ms, up to 10 where the host came late)
+FETCH_LAG_NS = 5e6
+
+
+def load_modules(xplane_dir):
+    """{chip: [[start_ns, device_ns]]} of the device planes' `XLA
+    Modules` line: one event per program the device executed, its
+    duration the device's own (`device_duration_ps`)."""
+    import warnings
+    from benchmarks import trace_reduce as tr
+    from jax.profiler import ProfileData
+    chips = {}
+    with warnings.catch_warnings():
+        warnings.simplefilter('ignore')
+        data = ProfileData.from_file(tr.find_xplane(xplane_dir))
+        for plane in data.planes:
+            device = tr.DEVICE_PLANE.match(plane.name)
+            for line in plane.lines if device else ():
+                if line.name == MODULES_LINE:
+                    chips[int(device.group(1))] = sorted(
+                        [float(e.start_ns),
+                         dict(e.stats)['device_duration_ps'] * 1e-3]
+                        for e in line.events)
+    return chips
+
+
+def clock_offset_ns(ring_starts, trace_starts):
+    """What to add to a time on the ring's clock (`perf_counter_ns`) to
+    get the trace's: the median difference between the starts of the
+    same spans on both (the newest of each, should one side hold more)
+    -> (offset, the widest disagreement with it)."""
+    n = min(len(ring_starts), len(trace_starts))
+    if not n:
+        return None, None
+    diffs = [t - r for r, t in zip(sorted(ring_starts)[-n:],
+                                   sorted(trace_starts)[-n:])]
+    offset = statistics.median(diffs)
+    return offset, max(abs(d - offset) for d in diffs)
+
+
+def hold_to_device(records, offset_ns, modules):
+    """The program's `serve::device_step` records beside the device's
+    own time. `records` [(args, start_ns, dur_ns)] on the ring's clock,
+    oldest first, `modules` [[start_ns, device_ns]] on the trace's. A
+    record covers the executions that END inside it (a fetch returns
+    after its execution ended, and every execution outlasts that lag);
+    records that reach outside what the trace holds (the step in flight
+    when the session began, one that ended after it) are left out. By
+    kind the medians are taken over the DEVICE-TRUE records — between
+    two fetches that waited for the device (`late` 0, `behind` 1: the
+    benchmark's readers take the same) —, the total over all. ->
+    {'kinds': {kind: {records, device_true, program_ms, device_ms:
+    medians, ratio: of the two medians, ratio_all: the same over every
+    record of the kind}}, 'total': {records, late, program_ms,
+    device_ms, ratio}, 'dispatch_mismatch': records whose `dispatches`
+    is not the number of executions they cover, 'rows': per record
+    [kind, dispatches, chunks, late, program_ms, device_ms, lag_ms —
+    from the end of its last execution to the fetch's return]}."""
+    if not records or not modules:
+        return None
+    lo, hi = modules[0][0], max(s + d for s, d in modules)
+    rows, mismatch, before = [], 0, None
+    for args, start, dur in records:
+        start += offset_ns
+        covered = [(s, d) for s, d in modules
+                   if start < s + d <= start + dur]
+        waited = _device_steps.waited(args)
+        if start >= lo and start + dur <= hi + FETCH_LAG_NS and covered:
+            mismatch += len(covered) != args['dispatches']
+            rows.append({
+                'args': args, 'true': waited and bool(before),
+                'program_ms': dur * 1e-6,
+                'device_ms': sum(d for _, d in covered) * 1e-6,
+                'lag_ms': (start + dur - sum(covered[-1])) * 1e-6})
+        before = waited
+    if not rows:
+        return None
+
+    def ratio(picked, middle):
+        return middle([r['program_ms'] for r in picked]) / middle(
+            [r['device_ms'] for r in picked])
+    out = {'kinds': {}, 'dispatch_mismatch': mismatch, 'rows': [], 'total': {
+        'records': len(rows),
+        'late': sum(bool(r['args'].get('late')) for r in rows),
+        'program_ms': sum(r['program_ms'] for r in rows),
+        'device_ms': sum(r['device_ms'] for r in rows),
+        'ratio': ratio(rows, sum)}}
+    for test in KINDS:
+        kind = test.__name__
+        picked = [r for r in rows if test(r['args'])]
+        true = [r for r in picked if r['true']]
+        if true:
+            out['kinds'][kind] = {
+                'records': len(picked), 'device_true': len(true),
+                'program_ms': statistics.median(
+                    r['program_ms'] for r in true),
+                'device_ms': statistics.median(r['device_ms'] for r in true),
+                'ratio': ratio(true, statistics.median),
+                'ratio_all': ratio(picked, statistics.median)}
+        out['rows'] += [[kind, r['args']['dispatches'], r['args']['chunks'],
+                         int(bool(r['args'].get('late'))),
+                         round(r['program_ms'], 4), round(r['device_ms'], 4),
+                         round(r['lag_ms'], 4)] for r in picked]
+    return out
+
+
+def render_device_steps(table, spread_ns):
+    total = table['total']
+    out = ['', 'serve::device_step against the device (XLA Modules, '
+           f'device_duration_ps); the two clocks agree to '
+           f'{spread_ns * 1e-3:.0f} us over the traced serve::step spans',
+           f"{'step kind':<20} {'records':>7} {'dev-true':>8} "
+           f"{'program_ms':>11} {'device_ms':>10} {'program/device':>15} "
+           f"{'(all records)':>14}"]
+    for kind, row in table['kinds'].items():
+        out.append(f"{kind:<20} {row['records']:>7} {row['device_true']:>8} "
+                   f"{row['program_ms']:>11.3f} {row['device_ms']:>10.3f} "
+                   f"{row['ratio']:>15.4f} {row['ratio_all']:>14.4f}")
+    out.append(f"{'window total':<20} {total['records']:>7} {'':>8} "
+               f"{total['program_ms']:>11.3f} {total['device_ms']:>10.3f} "
+               f"{total['ratio']:>15.4f}")
+    out.append(f"(by kind: medians over the device-true records — between "
+               f"two fetches that waited; {total['late']} of "
+               f"{total['records']} fetches came late; records whose "
+               f"`dispatches` is not the executions they cover: "
+               f"{table['dispatch_mismatch']})")
+    return '\n'.join(out)
 
 
 def main(argv=None):
@@ -109,8 +251,9 @@ def main(argv=None):
     serving_engine.ServingEngine.shutdown = shutdown_keeping_roofline
     record = runner.run(ctx)
     facts = record['facts']
-    summary = trace_summary.summarize_device_trace(
-        *trace_summary.load_device_trace(xplane_dir), top=args.top)
+    chip_ops, trace_spans = trace_summary.load_device_trace(xplane_dir)
+    summary = trace_summary.summarize_device_trace(chip_ops, trace_spans,
+                                                   top=args.top)
     text = trace_summary.render_device_trace(summary)
 
     # the two clocks: what the ring says the host did in the traced
@@ -144,6 +287,19 @@ def main(argv=None):
                  f'trace idle {check["device_idle_ms"]:.1f} ms of a '
                  f'{check["device_window_ms"]:.1f} ms window; the step '
                  f'spans sum to {check["step_span_ms_sum"]:.1f} ms')
+    modules = load_modules(xplane_dir) if facts['kind'] == 'serve' else {}
+    if steps and modules:
+        offset, spread = clock_offset_ns(
+            [s.start_ns for s in steps],
+            [start for name, start, _ in trace_spans if name == step_name])
+        table = offset is not None and hold_to_device(
+            [(s.args, s.start_ns, s.dur_ns) for s in ring
+             if s.name == 'serve::device_step'], offset,
+            modules[min(modules)])
+        if table:
+            table['clock_spread_ns'] = spread
+            summary['device_steps'] = table
+            text += '\n' + render_device_steps(table, spread)
     summary['record'] = {k: record[k] for k in ('correct', 'attempted',
                                                 'failed', 'end_to_end')}
     if rooflines and rooflines[-1]:
