@@ -81,10 +81,14 @@ FULL = dict(
     # cell calls it (benchmarks/configs/axk1.json): 64 query heads on
     # ONE stored row of 512 value + 64 rotary lanes in 640, pages of 64
     # over 528-page tables, ragged contexts from one page to the
-    # table's end, at the decode rows' and the chunk's widths
+    # table's end, at the decode rows' and the chunk's widths; the chunk
+    # rows full, then PARTLY filled (`ragged`: live tokens a row, behind
+    # the longest contexts — live, partly live and dead query tiles of
+    # 32 tokens, the second pair a full row beside an idle one)
     latent=dict(heads=64, value=512, rotary=64, page_size=64, pages=528,
                 batch=64, chunk=256, prefill_rows=2,
-                contexts=(64, 700, 8448, 33792)),
+                contexts=(64, 700, 8448, 33792),
+                ragged=((161, 33), (256, 0))),
 )
 
 # Tolerances, as max|got - ref| / max|ref| over a tensor.
@@ -468,15 +472,20 @@ def phase_kernels(size):
         lanes = -(-(la['value'] + la['rotary']) // 128) * 128
         pool = la['batch'] * la['pages'] // 8 + 3
 
-        def latent_paged(Bq, T):
+        def latent_paged(Bq, T, live=None):
             """[Bq, T] rows of ragged contexts against the ONE array of
             stored rows; the dense route a few rows at a time (it
-            gathers a row's whole table)."""
+            gathers a row's whole table). `live`: the rows' query
+            tokens (default: every slot the context has tokens for),
+            then behind the LONGEST contexts."""
             pt = rng.randint(0, pool, (Bq, la['pages'])).astype(np.int32)
-            ctx = np.resize(la['contexts'], Bq).astype(np.int32)
+            ctx = np.resize(la['contexts'] if live is None
+                            else la['contexts'][::-1], Bq).astype(np.int32)
+            q_lens = np.minimum(T if live is None else live, ctx) \
+                .astype(np.int32)
             args = (rand((Bq, T, Hq * lanes), scale=0.05),
                     rand((pool, ps, lanes)), None, jnp.asarray(pt),
-                    jnp.asarray(ctx), jnp.asarray(np.minimum(T, ctx)))
+                    jnp.asarray(ctx), jnp.asarray(q_lens))
 
             def call(attention):
                 return lambda q, pages, _, *a: attention(
@@ -490,16 +499,19 @@ def phase_kernels(size):
                 part = [a if a is None or a.shape[0] != Bq else a[rows]
                         for a in args]
                 ref = ref_call(call(pa.ragged_paged_attention_dense), *part)
-                live = (np.arange(T)[None, :] < np.minimum(T, ctx[rows])
-                        [:, None])[..., None]
+                live = (np.arange(T)[None, :]
+                        < q_lens[rows][:, None])[..., None]
                 record(f'paged_attention_latent B={Bq} T={T} rows '
-                       f'{rows.tolist()} ctx={ctx[rows].tolist()}',
+                       f'{rows.tolist()} ctx={ctx[rows].tolist()} '
+                       f'q_lens={q_lens[rows].tolist()}',
                        np.where(live, got[rows], 0),
                        np.where(live, np.asarray(ref, np.float32), 0),
                        TOL_BF16)
 
         latent_paged(la['batch'], 1)
         latent_paged(la['prefill_rows'], la['chunk'])
+        for live in la['ragged']:
+            latent_paged(la['prefill_rows'], la['chunk'], live)
 
     # -- fused optimizer step + grad stats vs core.bucketing.shard_update --
     n = size['opt_elems']

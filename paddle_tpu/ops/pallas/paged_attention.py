@@ -97,16 +97,23 @@ caller's softmax scale already in it — the kernel applies none), values
 are the first `value lanes` of the SAME page copy — no second pool, no
 second DMA, no second body —, p.v takes the probabilities in the pool's
 dtype (fp32 accumulation; the softmax state stays fp32) and the output
-is `value lanes` wide a head. The decode group is the batched product
-with every head a row ([64, 640] x [640, keys], then [64, keys] x
-[keys, 512]); a prompt
-chunk stacks heads as rows like a kv group does, and since 64 heads x
-256 queries of 640 lanes fit no VMEM the grid gains QUERY TILES: each
-batch row runs as `q_tiles` programs of `_LATENT_TILE_ROWS` (head,
-token) rows, every tile walking the row's pages again (a chunk's
-products outweigh its copies 30 to 1). The Mosaic call is named
-`paged_attention_latent`. With `latent` absent the body, the grid and
-the operands are what they were.
+is `value lanes` wide a head. Rows are q's NATURAL layout, t*Hq+h —
+every head a row of one score product ([64, 640] x [640, keys], then
+[64, keys] x [keys, 512] for a decode row), no transpose round the call
+—, and since 64 heads x 256 queries of 640 lanes fit no VMEM a prompt
+chunk's grid gains QUERY TILES over TOKENS: each batch row runs as
+`q_tiles` programs of ALL the heads x `latent_tile_tokens` tokens
+(`_LATENT_TILE_ROWS` rows: 32 tokens at 64 heads). A tile walks only
+the pages its OWN live queries can read: one whose first token lies at
+or past q_len starts no copy, folds no wave and writes zeros (as a row
+with q_len == 0 does), and a live tile's loop ends at the page of its
+last live query's position, not at seq_len's — so a 162-token chunk of
+a 256-token program costs 6 tiles of 8, a tile behind a long document
+walks it once (a chunk's products outweigh its copies 30 to 1) and a
+document's own first chunks walk a triangle. `latent_pairs_dispatched`
+is that rule as arithmetic, for the engine's counter. The Mosaic call
+is named `paged_attention_latent`. With `latent` absent the body, the
+grid and the operands are what they were.
 
 The name says the group of rows too: a call whose rows carry more than
 one query (static `T` > 1: the mixed step's prompt chunks, and a verify
@@ -187,10 +194,35 @@ _SCORE_BYTES = 2 * 2 ** 20
 # block-diagonal score product; above it (the prefill chunk) each head
 # runs its own MXU-shaped [T, D] x [D, keys] product
 _BATCHED_ROWS = 128
-# (head, token) rows of one query tile of a latent chunk: 8 heads x 256
-# queries; q and out blocks (double-buffered) and the fp32 accumulator
-# then take 18 MiB, and a [rows, 256 keys] score tile _SCORE_BYTES
+# (token, head) rows of one query tile of a latent chunk: all the heads
+# of as many whole tokens as fit (32 tokens x 64 heads); q and out blocks
+# (double-buffered) and the fp32 accumulator then take 18 MiB, and a
+# [rows, 256 keys] score tile _SCORE_BYTES
 _LATENT_TILE_ROWS = 2048
+
+
+def latent_tile_tokens(T, num_heads):
+    """Tokens of one query tile of a latent call whose rows carry `T`
+    query slots: all of them where T x heads rows fit one tile (the
+    decode rows, a verify row), else the most whole tokens of
+    `_LATENT_TILE_ROWS` rows that divide T."""
+    tile = max(1, min(T, _LATENT_TILE_ROWS // num_heads))
+    while T % tile:
+        tile -= 1
+    return tile
+
+
+def latent_pairs_dispatched(context, queries, T, num_heads):
+    """The (query, key) pairs a latent call multiplies for ONE row of
+    `queries` live tokens (of `T` slots) whose last sits at position
+    context - 1: every live tile's `latent_tile_tokens` tokens, live or
+    padding, times the keys up to its last live query's. A dead tile
+    multiplies nothing. (A wave's keys past that position, under 256 a
+    tile, are multiplied too and not counted.) This is the kernel's
+    `live_pages` in tokens; the two are held together by the tests."""
+    tile = latent_tile_tokens(T, num_heads)
+    return sum(tile * (context - queries + min(queries, first + tile))
+               for first in range(0, queries, tile))
 
 
 def _wave_pages(page_size, HD, kv_dtype, rows, q_dtype, P, num_heads,
@@ -241,7 +273,10 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, *rest,
     the values are the first `value lanes` of the wave's K copy, the
     accumulator and the output are that wide, and the scores come
     unscaled; `q_tiles` programs then share one batch row (program i is
-    tile i % q_tiles of row i // q_tiles), each with its own rows of q.
+    tile i % q_tiles of row i // q_tiles), each with all the heads of
+    its own R // group TOKENS of q, and each walking only the pages its
+    own live queries read: none where its first token lies at or past
+    q_len, else up to the page of its last live query's position.
 
     pt_ref/ln_ref are scalar-prefetched (page tables, [B, 2] lens);
     k_hbm / v_hbm are the whole pools, left in HBM. Wave w copies pages
@@ -250,8 +285,9 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, *rest,
     the wave before it is folded into the online softmax; under a
     row's last wave the NEXT row's first wave is started (`nxt`, SMEM,
     carries its slot to that row's program), so the copies form one
-    stream over the batch. A row with q_len == 0 starts no copy and
-    writes zeros.
+    stream over the batch (a latent chunk's: over the grid, dead
+    tiles in it). A row with q_len == 0 starts no copy and writes
+    zeros.
 
     `batched`: q_ref holds [T*Hq, H*D] block-diagonal rows (row
     t*Hq+h = query head h of token t, in the columns of ITS kv head),
@@ -280,8 +316,8 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, *rest,
     # tiles a program IS a row
     step = pl.program_id(0)
     steps = pl.num_programs(0)
-    b = step if q_tiles == 1 else step // q_tiles
     R = q_ref.shape[0]
+    tile_tokens = R // group        # a latent tile's tokens
     W, ps, H, D = wave_pages, page_size, num_heads, head_dim
     keys = W * ps
     scale = None if latent is not None else 1.0 / math.sqrt(D // diff)
@@ -294,24 +330,38 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, *rest,
         oldest = ln_ref[row, 0] - ln_ref[row, 1] - (window - 1)
         return jnp.maximum(oldest, 0) // ps
 
-    def live_pages(row):
-        """Pages the row's loop visits: none without a query."""
+    def program(i):
+        """(batch row, first token of the query tile) of program i."""
+        if q_tiles == 1:
+            return i, 0
+        return i // q_tiles, i % q_tiles * tile_tokens
+
+    def live_pages(row, first=0):
+        """Pages the program's loop visits: none without a query. A
+        latent tile (tokens first .. first + tile_tokens of the row)
+        has none where its first token is no query, and ends at the
+        page of its last live query's position (causal: no query of it
+        reads past that)."""
+        if latent is not None:
+            seq, queries = ln_ref[row, 0], ln_ref[row, 1]
+            ahead = jnp.maximum(queries - first - tile_tokens, 0)
+            return jnp.where(queries > first, pl.cdiv(seq - ahead, ps), 0)
         pages = pl.cdiv(ln_ref[row, 0], ps)
         if window is not None:
             pages = pages - first_page(row)
         return jnp.where(ln_ref[row, 1] > 0, pages, 0)
 
+    b, token0 = program(step)
     seq_len = ln_ref[b, 0]
     q_len = ln_ref[b, 1]
-    n_pages = live_pages(b)
+    n_pages = live_pages(b, token0)
     base = first_page(b)
     n_waves = pl.cdiv(n_pages, W)
-    # the row of the program after this one (its first wave is started
-    # under this one's last, so a program does not open on a cold copy)
-    after = jnp.minimum(step + 1, steps - 1)
-    if q_tiles > 1:
-        after = after // q_tiles
-    after_pages = jnp.where(step + 1 < steps, live_pages(after), 0)
+    # the program after this one (its first wave is started under this
+    # one's last, so a program does not open on a cold copy)
+    after, after_token0 = program(jnp.minimum(step + 1, steps - 1))
+    after_pages = jnp.where(step + 1 < steps,
+                            live_pages(after, after_token0), 0)
 
     def wave_dma(row, wave, slot, pages, start):
         """Start (or wait for) the copies of wave `wave` of `row`: its
@@ -399,6 +449,8 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, *rest,
         row = jax.lax.broadcasted_iota(jnp.int32, (R, keys), 0)
         if batched:
             token = row // (H * group)
+            if latent is not None:
+                token = token0 + token
         else:
             token = row if group == 1 else row % (R // group)
         q_pos = seq_len - q_len + token
@@ -524,28 +576,23 @@ def _check_latent(latent, head_dim, row_lanes, v_pages, k_scales,
 def _latent_call(q, pages, v_pages, page_tables, seq_lens, q_lens,
                  num_heads, head_dim, latent, k_scales, num_kv_heads,
                  window, diff, interpret):
-    """The latent call's static choices: the decode group is the
-    batched product (every head a row of one program); a chunk runs in
-    query tiles of whole heads, `_LATENT_TILE_ROWS` (head, token) rows
-    each."""
+    """The latent call's static choices: every head of a token a row
+    of the one score product (the batched layout, rows t*Hq+h), a
+    batch row in query tiles of `latent_tile_tokens` tokens — one tile
+    for the decode rows and a verify row, `_LATENT_TILE_ROWS` rows each
+    for a prompt chunk."""
     _check_latent(latent, head_dim, pages.shape[2], v_pages, k_scales,
                   num_kv_heads, window, diff)
     T = q.shape[1]
-    batched = T * num_heads <= _BATCHED_ROWS
-    heads = num_heads       # query heads of one tile
-    if not batched:
-        while heads > 1 and (heads * T > _LATENT_TILE_ROWS
-                             or num_heads % heads):
-            heads -= 1
+    tile = latent_tile_tokens(T, num_heads)
     W, need = _wave_pages(
-        pages.shape[1], head_dim, pages.dtype,
-        T * num_heads if batched else T * heads, q.dtype,
+        pages.shape[1], head_dim, pages.dtype, tile * num_heads, q.dtype,
         page_tables.shape[1], 1, False, planes=1)
     return _paged_call(
         q, pages, None, page_tables, seq_lens, q_lens, None, None,
-        num_heads=1, head_dim=head_dim, wave_pages=W, batched=batched,
+        num_heads=1, head_dim=head_dim, wave_pages=W, batched=True,
         vmem_bytes=need, group=num_heads, latent=tuple(latent),
-        q_tiles=1 if batched else num_heads // heads,
+        q_tiles=T // tile,
         interpret=_interpret() if interpret is None else interpret)
 
 
@@ -561,8 +608,9 @@ def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
     the diagonal blocks of its output, as one jitted function of the
     shapes and the wrapper's static choices. `num_heads` counts the kv
     heads, `group` the query heads on each; a latent call (`v_pages`
-    None) runs each batch row as `q_tiles` programs of `group //
-    q_tiles` heads and puts out `latent[0]` lanes a head."""
+    None) takes q as it lies (rows t*Hq+h: its one stored head has
+    every column), runs each batch row as `q_tiles` programs of `T //
+    q_tiles` tokens and puts out `latent[0]` lanes a head."""
     B, T = q.shape[:2]
     N, ps, HD = k_pages.shape
     P = page_tables.shape[1]
@@ -571,7 +619,9 @@ def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
     pt = page_tables.astype(jnp.int32)
     lens = jnp.stack([seq_lens.astype(jnp.int32),
                       q_lens.astype(jnp.int32)], axis=1)       # [B, 2]
-    if batched and group == 1:
+    if latent is not None:
+        q = q.reshape(B, T * group, HD)
+    elif batched and group == 1:
         # row t*H+h = query t masked to head h's columns
         own = (jnp.arange(HD, dtype=jnp.int32)[None, :] // head_dim
                == jnp.arange(H, dtype=jnp.int32)[:, None])      # [H, HD]
@@ -596,8 +646,8 @@ def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
     inputs = [pt, lens, q, k_pages, v_pages]
     OD = HD                 # lanes of the accumulator and the output
     if latent is not None:
-        # no V pool; rows g*T+t of a batch row in `q_tiles` tiles of
-        # whole heads; the output as wide as the values
+        # no V pool; rows t*Hq+h of a batch row in `q_tiles` tiles of
+        # whole tokens; the output as wide as the values
         OD, R = latent[0], R // q_tiles
 
         def tile(i, pt, ln):
@@ -638,7 +688,7 @@ def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
     kernel = functools.partial(
         _ragged_paged_kernel, page_size=ps, num_heads=H,
         head_dim=head_dim, wave_pages=W, batched=batched,
-        quantized=quantized, group=group // q_tiles, window=window,
+        quantized=quantized, group=group, window=window,
         diff=diff, latent=latent, q_tiles=q_tiles)
     out = scaffold.pallas_call(
         kernel,
@@ -661,9 +711,6 @@ def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
         + ('_chunk' if T > 1 else ''),
     )(*inputs)
     if latent is not None:
-        # rows t*Hq+h (batched) or g*T+t (tiles of whole heads)
-        if not batched:
-            out = out.reshape(B, group, T, OD).transpose(0, 2, 1, 3)
         return out.reshape(B, T, group * OD)
     if batched and group == 1:
         # each head's output is its own diagonal block of the rows

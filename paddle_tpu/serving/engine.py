@@ -79,6 +79,7 @@ docs/serving.md covers tuning the knobs.
 """
 import collections
 import contextlib
+import functools
 import math
 import os
 import time
@@ -439,7 +440,16 @@ class ServingEngine:
         self._kv_full = len(spec) - sum(self._windows.values())
         self._kv_planes = sum(s.reads is None for s in spec)
         self._attn_kv_tokens = self._attn_kv_chunks = 0
-        self._attn_qk_pairs = 0
+        self._attn_qk_pairs = self._attn_qk_dispatched = 0
+        # the query slots a latent row's pairs are multiplied in: the
+        # paged kernel's own tile rule, by the model's query heads (the
+        # other bodies have no query tiles: dispatched == real)
+        self._latent_pairs = None
+        if latent:
+            from ..ops.pallas import paged_attention
+            self._latent_pairs = functools.partial(
+                paged_attention.latent_pairs_dispatched,
+                num_heads=int(mcfg.num_heads))
         self._prompt_tokens = 0
         dtype = config.kv_dtype or model.lm_head_weight().dtype
         self.mesh = mesh
@@ -1847,7 +1857,7 @@ class ServingEngine:
                 # them, out of the slots the program's tables have
                 for j in range(iterations):
                     self._it_live_pages += self.pool.pages_for(context + j)
-                    self._count_kv_read(context + j, len(query))
+                    self._count_kv_read(context + j, len(query), width)
             for row, req, query, context in chunks:
                 place(B + row, B * width + row * T, req, query, context)
                 src[B + row] = self.scheduler.slot_of(req)
@@ -1856,7 +1866,7 @@ class ServingEngine:
                 keys = self._keys_read(context, len(query))
                 self._attn_kv_tokens += keys
                 self._attn_kv_chunks += keys
-                self._attn_qk_pairs += self._pairs(context, len(query))
+                self._count_pairs(context, len(query), T)
                 self._it_live_pages += self.pool.pages_for(context)
                 self._it_prefill_tokens += len(query)
                 self._it_prefill_ctx += len(query) * context
@@ -2064,7 +2074,19 @@ class ServingEngine:
             total += layers * upto(w)
         return total
 
-    def _count_kv_read(self, context, queries=1):
+    def _count_pairs(self, context, queries, slots):
+        """One row's (query, key) pairs: those its masks allow, and
+        those the kernel multiplies for them — a latent row's `slots`
+        query slots run in tiles of whole tokens, and a live tile
+        multiplies all its tokens by the keys up to its last live
+        query's (paged_attention.latent_pairs_dispatched); any other
+        row's are counted as the real ones."""
+        real = self._pairs(context, queries)
+        self._attn_qk_pairs += real
+        self._attn_qk_dispatched += real if self._latent_pairs is None \
+            else self._kv_full * self._latent_pairs(context, queries, slots)
+
+    def _count_kv_read(self, context, queries=1, slots=1):
         """One decode row's KV reads this iteration, in tokens a PLANE
         (the pool's bytes per token count the planes; the readers of a
         shared plane each read it): what the attending layers read (a
@@ -2074,7 +2096,7 @@ class ServingEngine:
         without the bound."""
         total = self._keys_read(context, queries)
         self._attn_kv_tokens += total
-        self._attn_qk_pairs += self._pairs(context, queries)
+        self._count_pairs(context, queries, slots)
         for w, layers in self._windows.items():
             self._it_kv_window[0] += layers * min(context, w)
             self._it_kv_window[1] += layers * context
@@ -2818,6 +2840,11 @@ class ServingEngine:
             # triangle
             'attn_kv_tokens_read_chunks_total': self._attn_kv_chunks,
             'attn_qk_pairs_total': self._attn_qk_pairs,
+            # the pairs the kernel MULTIPLIES for them: a latent chunk
+            # row's live query tiles whole, padding tokens included
+            # (real / dispatched: the share of its products that are
+            # not padding); equal to the real ones for any other model
+            'attn_qk_pairs_dispatched_total': self._attn_qk_dispatched,
             # one token's device bytes in one plane (a (k, v) pair, or
             # a latent plane's one padded row)
             'kv_plane_bytes_per_token':

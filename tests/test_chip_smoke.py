@@ -31,13 +31,14 @@ TINY = dict(
                 contexts=(3, 23, 25, 64), prefill_rows=2,
                 channels=128, states=16),
     latent=dict(heads=4, value=16, rotary=8, page_size=8, pages=8,
-                batch=2, chunk=40, prefill_rows=2, contexts=(3, 64)),
+                batch=2, chunk=40, prefill_rows=2, contexts=(3, 64),
+                ragged=((25, 3), (40, 0))),
 )
 
 
 def test_kernels_phase():
     errs = chip_smoke.phase_kernels(TINY)
-    assert len(errs) >= 25 + 16 + 4 + 16 + 2 + 2
+    assert len(errs) >= 25 + 16 + 4 + 16 + 2 + 2 + 2
 
 
 def test_train_phase():

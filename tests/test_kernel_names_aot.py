@@ -218,15 +218,16 @@ def test_differential_paged_attention_compiles_and_is_named(
     assert got == {name}
 
 
-@pytest.mark.parametrize('rows,T', [(64, 1), (2, 256), (2, 128)])
+@pytest.mark.parametrize('rows,T', [(64, 1), (2, 256), (2, 128), (64, 4)])
 def test_latent_paged_attention_compiles_and_is_named(rows, T, one_chip,
                                                       as_on_tpu):
     """The latent-attention server cell's row groups at its published
     widths: 64 query heads on ONE stored row of 512 value + 64 rotary
     lanes in 640, 528-page tables over 9,000 pages of 64; [64, 1] decode
-    (the batched product) and the mixed step's 2 chunks of 256 (query
-    tiles of 8 heads; chunk 128: 16). The name starts `paged_attention`
-    and says `latent`; there is no V pool among the operands."""
+    (one tile a row), the mixed step's 2 chunks of 256 (8 query tiles of
+    32 tokens x 64 heads; chunk 128: 4) and a verify step's token with 3
+    drafts (one tile of 256 rows). The name starts `paged_attention` and
+    says `latent`; there is no V pool among the operands."""
     from paddle_tpu.ops.pallas import paged_attention as pa
 
     def fn(q, pages, pt, sl, ql):

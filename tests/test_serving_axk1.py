@@ -197,6 +197,27 @@ def test_the_counters_the_documents_cell_reads(model):
     assert prefill.args['cached_tokens'] == 0 and prefill.args['chunks'] == 3
 
 
+def test_the_pairs_a_latent_chunk_dispatches(model, monkeypatch):
+    """A chunk of 40 of 64 slots in query tiles of 16 tokens (64 rows of
+    4 heads): three live tiles multiply all their 16 tokens by the keys
+    up to their last live query's — 16, 32 and 40 —, the fourth nothing;
+    the decode rows' pairs are the real ones."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    monkeypatch.setattr(pa, '_LATENT_TILE_ROWS', 64)
+    _, stats, _ = serve(model, [list(range(1, 41))], (3,), prefill_chunk=64)
+    layers, decode_keys = 3, 41 + 42
+    assert stats['attn_qk_pairs_total'] == \
+        layers * (sum(range(1, 41)) + decode_keys)
+    assert stats['attn_qk_pairs_dispatched_total'] == \
+        layers * (16 * (16 + 32 + 40) + decode_keys)
+    # one tile holds the whole chunk at these widths as they are: the
+    # 64 slots' worth of the 40 keys
+    monkeypatch.undo()
+    _, stats, _ = serve(model, [list(range(1, 41))], (3,), prefill_chunk=64)
+    assert stats['attn_qk_pairs_dispatched_total'] == \
+        layers * (64 * 40 + decode_keys)
+
+
 def test_pairs_under_a_window_are_clipped(model):
     """The pair count of a window layer: a query reads no more keys
     than the window."""
@@ -246,5 +267,13 @@ def test_the_other_models_planes_are_pairs_as_they_were(family):
         assert eng.pool.bytes_per_token() == 2 * 2 * heads * dim * item
         assert eng.stats()['kv_plane_bytes_per_token'] == \
             2 * heads * dim * item
+        # no latent plane, no query tiles: what is multiplied is counted
+        # as what the masks allow
+        eng.submit(list(range(1, 20)), max_new_tokens=3, top_k=0)
+        while eng.scheduler.has_work:
+            eng.step()
+        stats = eng.stats()
+        assert stats['attn_qk_pairs_dispatched_total'] == \
+            stats['attn_qk_pairs_total'] > 0
     finally:
         eng.shutdown()

@@ -22,7 +22,13 @@ trace's window - busy; and, for a server, holds the program's
 beside the summed `device_duration_ps` of the `XLA Modules` executions
 (`jit_step(...)`, one a dispatch) it covers, by kind of step — the
 records are not in the xplane (a span recorded at its end cannot be
-mirrored), so this is the one place the two are laid side by side.
+mirrored), so this is the one place the two are laid side by side;
+and says, over the traced steps and over the window, the (query, key)
+pairs the attention masks allowed beside those the kernel multiplied
+(`attn_qk_pairs_total`, `attn_qk_pairs_dispatched_total`: a latent
+chunk's live query tiles whole) — the share of its products that were
+not padding, and with the chunk class's device time its share of the
+peak on what it multiplies.
 """
 import argparse
 import json
@@ -45,6 +51,11 @@ MODULES_LINE = 'XLA Modules'
 # benchmark's readers have them
 KINDS = (_device_steps.decode_only, _device_steps.one_chunk_dispatch,
          _device_steps.multi_dispatch)
+# engine counters read at the ends of the traced steps and of the window:
+# real and multiplied pairs, and the keys that tell the decode rows'
+# pairs (one query a row: its keys) from the chunk rows'
+PAIRS = ('attn_qk_pairs_total', 'attn_qk_pairs_dispatched_total',
+         'attn_kv_tokens_read_total', 'attn_kv_tokens_read_chunks_total')
 # a fetch returns this long, at most, after its execution ended (read:
 # 1.6-2.6 ms, up to 10 where the host came late)
 FETCH_LAG_NS = 5e6
@@ -211,12 +222,14 @@ def main(argv=None):
         ring's ids at its two ends."""
         def __enter__(self):
             marks['lo'] = prof.mark()
+            marks['pairs_lo'] = pairs()
             self.session = jax.profiler.trace(xplane_dir)
             return self.session.__enter__()
 
         def __exit__(self, *exc):
             out = self.session.__exit__(*exc)
             marks['hi'] = prof.mark()
+            marks['pairs_hi'] = pairs()
             return out
 
     ctx = common.Context(
@@ -227,11 +240,31 @@ def main(argv=None):
     # with it: keep the ledger's roofline block (kv_read_tokens_mean,
     # paged_live_page_share) as it stood at shutdown
     from paddle_tpu.serving import engine as serving_engine
-    rooflines, mixed = [], []
+    rooflines, mixed, engines = [], [], []
     shutdown = serving_engine.ServingEngine.shutdown
+    build = serving_engine.ServingEngine.__init__
+
+    def build_noted(self, *a, **kw):
+        engines.append(self)
+        build(self, *a, **kw)
+    serving_engine.ServingEngine.__init__ = build_noted
+
+    def pairs():
+        """The runner's engine's pair counters as they stand (None for
+        a trainer, or a program without them)."""
+        st = engines[-1].stats() if engines else {}
+        return {k: st[k] for k in PAIRS} if all(k in st for k in PAIRS) \
+            else None
+    setup_done = ctx.setup_done
+
+    def setup_done_noting_pairs():
+        setup_done()
+        marks['pairs_window'] = pairs()
+    ctx.setup_done = setup_done_noting_pairs
 
     def shutdown_keeping_roofline(self, *a, **kw):
         rooflines.append(self.ledger.roofline())
+        marks['pairs_end'] = pairs()
         st = self.stats()
         mixed.append(dict(
             {k: st[k] for k in ('dispatches_per_step',
@@ -317,6 +350,22 @@ def main(argv=None):
         summary['mixed_step'] = mixed[-1]
         text += '\n\nmixed step and pipe at shutdown: ' + ', '.join(
             f'{k} {v}' for k, v in mixed[-1].items())
+    for what, lo, hi in (('traced steps', 'pairs_lo', 'pairs_hi'),
+                         ('window', 'pairs_window', 'pairs_end')):
+        if marks.get(lo) and marks.get(hi):
+            d = {k: marks[hi][k] - marks[lo][k] for k in PAIRS}
+            decode = d['attn_kv_tokens_read_total'] \
+                - d['attn_kv_tokens_read_chunks_total']
+            d['chunk_pairs'] = d['attn_qk_pairs_total'] - decode
+            d['chunk_pairs_dispatched'] = \
+                d['attn_qk_pairs_dispatched_total'] - decode
+            share = d['chunk_pairs'] / max(d['chunk_pairs_dispatched'], 1)
+            summary.setdefault('pairs', {})[what] = d
+            text += (f'\n(query, key) pairs over the {what}: real '
+                     f'{d["attn_qk_pairs_total"]}, multiplied '
+                     f'{d["attn_qk_pairs_dispatched_total"]}; of chunk '
+                     f'rows {d["chunk_pairs"]} of '
+                     f'{d["chunk_pairs_dispatched"]} = {share:.4f}')
     print(text, flush=True)
     base = os.path.join(args.out, cell['name'])
     with open(base + '.summary.json', 'w') as f:
