@@ -91,7 +91,8 @@ FULL = dict(
                 ragged=((161, 33), (256, 0))),
     # the gated-conv server cell's kernels (benchmarks/configs/
     # lfm2-24b-a2b.json): 32 query heads on 8 kv heads of 64, pages of 64
-    # over 448-page tables at contexts from one page to the table's end;
+    # over 448-page tables at contexts from one page to the table's end,
+    # and the decode rows with a 64-page run most of them share;
     # the short convolution over 2048 channels, 3 taps, 7 conv layers of
     # page state, at the decode rows' and the chunk's widths
     conv=dict(heads=32, kv_heads=8, head_dim=64, page_size=64, pages=448,
@@ -552,6 +553,38 @@ def phase_kernels(size):
         for ctx in cv['contexts']:
             conv_paged(cv['batch'], 1, ctx)
             conv_paged(cv['prefill_rows'], cv['chunk'], ctx)
+
+        def conv_shared(run):
+            """The decode rows with a leading run of `run` pages that
+            most of them map (the sessions' system prompt), read once
+            for them: one row a token past it, every 16th row on pages
+            of its own, an idle row; the answer is the dense route's."""
+            Bq = cv['batch']
+            ctx = rng.randint(run * ps + 1, min(120, cv['pages']) * ps + 1,
+                              Bq).astype(np.int32)
+            ctx[0] = run * ps + 1
+            ql = np.ones(Bq, np.int32)
+            ql[-1] = 0
+            members = np.arange(Bq) % 16 != 5
+            pt = rng.randint(run, pool, (Bq, cv['pages'])).astype(np.int32)
+            pt[members, :run] = np.arange(run)
+            run_pt = np.zeros(cv['pages'], np.int32)
+            run_pt[:run] = np.arange(run)
+            args = (rand((Bq, 1, Hq * Dk)), rand((pool, ps, Hk * Dk)),
+                    rand((pool, ps, Hk * Dk)), jnp.asarray(pt),
+                    jnp.asarray(ctx), jnp.asarray(ql))
+            got = jax.jit(lambda *a: pa.ragged_paged_attention_pallas(
+                *a[:6], num_heads=Hq, head_dim=Dk, num_kv_heads=Hk,
+                shared=a[6:]))(*args, jnp.asarray(run_pt),
+                               jnp.int32(run * ps), jnp.asarray(members))
+            ref = ref_call(lambda *a: pa.ragged_paged_attention_dense(
+                *a, num_heads=Hq, head_dim=Dk, num_kv_heads=Hk), *args)
+            live = (ql > 0)[:, None, None]
+            record(f'paged_attention B={Bq} {Hq}q/{Hk}kv shared run={run}',
+                   np.where(live, np.asarray(got, np.float32), 0),
+                   np.where(live, np.asarray(ref, np.float32), 0), TOL_BF16)
+
+        conv_shared(min(64, cv['pages'] // 2))
 
         def conv_case(R, T):
             """R rows of T positions: a fresh row, a row restored from

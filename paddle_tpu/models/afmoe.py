@@ -207,11 +207,12 @@ class AfmoeAttention(_Params):
             return pa.write_kv_pages(*pool, k, v, page_tables, seq_lens,
                                      q_lens)
 
-        def read(pool, q, page_tables, seq_lens, q_lens):
+        def read(pool, q, page_tables, seq_lens, q_lens, shared=None):
             return pa.ragged_paged_attention(
                 q, *pool, page_tables, seq_lens, q_lens,
                 num_heads=cfg.num_heads, head_dim=cfg.head_dim,
-                num_kv_heads=cfg.num_kv_heads, window=self.window)
+                num_kv_heads=cfg.num_kv_heads, window=self.window,
+                shared=shared)
         ctx, kv = rows.attend(write, read, kv, *self._qkv(a, pos))
         return self._out(a, ctx), kv
 

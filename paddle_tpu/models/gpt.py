@@ -286,12 +286,12 @@ class GPTAttention(nn.Layer):
             return pa.write_kv_pages(*pool, k, v, page_tables, seq_lens,
                                      q_lens)
 
-        def read(pool, q, page_tables, seq_lens, q_lens):
+        def read(pool, q, page_tables, seq_lens, q_lens, shared=None):
             scales = {'k_scales': pool[2], 'v_scales': pool[3]} \
                 if len(pool) == 4 else {}
             return pa.ragged_paged_attention(
                 q, pool[0], pool[1], page_tables, seq_lens, q_lens,
-                num_heads=nh, head_dim=hd, **scales)
+                num_heads=nh, head_dim=hd, shared=shared, **scales)
 
         def fn(a, *pool):
             x5 = a.reshape(1, -1, nh, 3, hd)

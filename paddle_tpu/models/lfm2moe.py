@@ -157,11 +157,11 @@ class Lfm2Attention(_Params):
             return pa.write_kv_pages(*pool, k, v, page_tables, seq_lens,
                                      q_lens)
 
-        def read(pool, q, page_tables, seq_lens, q_lens):
+        def read(pool, q, page_tables, seq_lens, q_lens, shared=None):
             return pa.ragged_paged_attention(
                 q, *pool, page_tables, seq_lens, q_lens,
                 num_heads=cfg.num_heads, head_dim=D,
-                num_kv_heads=cfg.num_kv_heads)
+                num_kv_heads=cfg.num_kv_heads, shared=shared)
         ctx, kv = rows.attend(
             write, read, kv, heads(self.q_proj, cfg.num_heads,
                                    self.q_layernorm),

@@ -122,6 +122,25 @@ step's token with its drafts) appends `_chunk` LAST —
 call (`T` == 1) nothing, so a mixed program's two calls a layer are two
 rows of the device trace. The body is the same.
 
+A shared run (`shared=(run pages, run keys, member rows)`; cascade or
+shared-prefix attention — Hydragen, arXiv:2402.05099): where several
+decode rows map the same leading pages (a system prompt the prefix
+cache shares), the decode call reads them ONCE. Its grid gains one
+program, first: it walks the run's pages with every member row's
+queries stacked as the rows of one [B*group, D] x [D, keys] product a
+kv head (the chunk layout, member rows in place of a chunk's tokens; no
+mask — the run lies before every member's query) into a softmax state
+of its own (m, l, acc: the run's output and log-sum-exp, unnormalised).
+Each member row's program then opens its online softmax AS its rows of
+that state — so folding its own pages after the run is the log-sum-exp
+merge of the two passes — and its loop opens at the page after the run,
+as a window's opens at its oldest key. Other rows read as without. A
+member's rows of the state are loaded by whole 8-row tiles (Mosaic
+takes a dynamic sublane offset only at a multiple of 8), so the query
+heads a kv head must tile 8 rows or be whole tiles of them. It engages
+on the decode rows of the plain body, with or without kv groups
+(`shared_pass`), and nowhere else; the call keeps its name.
+
 Layouts:
   q           [B, T, Hq*D]  new-token queries, right-padded to T per row
   k_pages     [N_pages, page_size, H*D]   the pool's device arrays (H:
@@ -226,7 +245,7 @@ def latent_pairs_dispatched(context, queries, T, num_heads):
 
 
 def _wave_pages(page_size, HD, kv_dtype, rows, q_dtype, P, num_heads,
-                quantized, planes=2):
+                quantized, planes=2, run_rows=0):
     """(W, vmem bytes): pages of K (and as many of V) per DMA wave —
     _WAVE_BYTES over one page's K+V bytes, shrunk until two wave slots
     fit _VMEM_BUDGET beside the double-buffered q / out blocks, the
@@ -235,15 +254,21 @@ def _wave_pages(page_size, HD, kv_dtype, rows, q_dtype, P, num_heads,
     `W * page_size` keys tile the lanes; never more than a row's
     page-table slots. `HD` is the pool's width (kv heads x head_dim),
     `num_heads` its kv heads, `planes` the arrays a wave copies from (2:
-    K and V; 1: a latent plane)."""
+    K and V; 1: a latent plane). `run_rows`: the rows of a shared run's
+    pass (its whole q block, its state and its per-kv-head score tile
+    besides the row's)."""
     page = planes * scaffold.block_bytes((page_size, HD), kv_dtype)
     fixed = 4 * scaffold.block_bytes((rows, HD), q_dtype) \
         + scaffold.block_bytes((rows, HD), jnp.float32)
     if quantized:
         fixed += 4 * scaffold.block_bytes((P * page_size, num_heads),
                                           jnp.float32)
+    if run_rows:
+        fixed += 2 * scaffold.block_bytes((run_rows, HD), q_dtype) \
+            + scaffold.block_bytes((run_rows, HD), jnp.float32) \
+            + 2 * scaffold.block_bytes((run_rows, num_heads), jnp.float32)
     room = max(_VMEM_BUDGET - fixed, 2 * page)
-    tile = max(1, _SCORE_BYTES // (4 * rows * page_size))
+    tile = max(1, _SCORE_BYTES // (4 * max(rows, run_rows) * page_size))
     want = max(1, min(_WAVE_BYTES // page, room // (2 * page), tile, P))
     W = 1 << (want.bit_length() - 1)
     # what the call holds: blocks, accumulator, softmax state, two
@@ -252,14 +277,15 @@ def _wave_pages(page_size, HD, kv_dtype, rows, q_dtype, P, num_heads,
     need = fixed + 2 * W * page \
         + 2 * scaffold.block_bytes((rows, num_heads), jnp.float32) \
         + 3 * scaffold.block_bytes((W * page_size, HD), jnp.float32) \
-        + 4 * scaffold.block_bytes((rows, W * page_size), jnp.float32)
+        + 4 * scaffold.block_bytes((max(rows, run_rows), W * page_size),
+                                   jnp.float32)
     return W, need
 
 
-def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, *rest,
-                         page_size, num_heads, head_dim, wave_pages,
-                         batched, quantized=False, group=1, window=None,
-                         diff=1, latent=None, q_tiles=1):
+def _ragged_paged_kernel(pt_ref, ln_ref, *rest, page_size, num_heads,
+                         head_dim, wave_pages, batched, quantized=False,
+                         group=1, window=None, diff=1, latent=None,
+                         q_tiles=1, shared=False):
     """One batch row: a loop over the row's OWN live pages.
 
     `num_heads` counts the pool's (kv) heads; `group` query heads
@@ -301,7 +327,28 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, *rest,
     With `quantized` the pools are int8 and two more refs hold the
     row's [P*page_size, H] fp32 scales in VMEM, applied per head slice
     to the wave's upcast pages.
+
+    `shared` (static; decode rows of the batched layout only): a third
+    scalar-prefetched ref holds a leading page run that several rows
+    map (`run_ref`: its page count, then its page ids), `ln_ref` a
+    third column (the page a row's own loop opens at: the run's length
+    for a member row, else 0), and the grid one program more. Program 0
+    walks the run's pages ONCE for every row, the members' queries
+    stacked as the rows of one [B*group, D] x [D, keys] product a kv
+    head (`qs_ref`, row b*group+g: query head j*group+g of row b in kv
+    head j's columns), into its own softmax state (m, l, acc). Program
+    i is row i-1: a member row's state opens as its rows of that state
+    (the run's (o, lse) unnormalised, so the online softmax over its
+    own pages IS the log-sum-exp merge of the two passes), and its loop
+    opens at the page after the run; every other row reads as without.
     """
+    if shared:
+        run_ref, q_ref, qs_ref, k_hbm, *rest = rest
+        (v_hbm, o_ref, kbuf, vbuf, sem, nxt, m_s, l_s, acc_s, m_sh, l_sh,
+         acc_sh) = rest
+        planes = ((k_hbm, kbuf), (v_hbm, vbuf))
+    else:
+        q_ref, k_hbm, *rest = rest
     if latent is not None:
         o_ref, kbuf, sem, nxt, m_s, l_s, acc_s = rest
         planes = ((k_hbm, kbuf),)
@@ -309,11 +356,11 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, *rest,
         (v_hbm, ks_ref, vs_ref, o_ref, kbuf, vbuf, sem, nxt, m_s, l_s,
          acc_s) = rest
         planes = ((k_hbm, kbuf), (v_hbm, vbuf))
-    else:
+    elif not shared:
         v_hbm, o_ref, kbuf, vbuf, sem, nxt, m_s, l_s, acc_s = rest
         planes = ((k_hbm, kbuf), (v_hbm, vbuf))
     # the grid's programs in order: (row, query tile); without query
-    # tiles a program IS a row
+    # tiles a program IS a row (and with `shared`, program 0 the run's)
     step = pl.program_id(0)
     steps = pl.num_programs(0)
     R = q_ref.shape[0]
@@ -323,8 +370,12 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, *rest,
     scale = None if latent is not None else 1.0 / math.sqrt(D // diff)
 
     def first_page(row):
-        """The page of the oldest key the row's first query reads; None
-        without a window (the loop opens at the table's slot 0)."""
+        """The page the row's loop opens at: with a window that of the
+        oldest key its first query reads, with `shared` the page after
+        the run it shares (0 where it shares none); None otherwise
+        (the table's slot 0)."""
+        if shared:
+            return ln_ref[row, 2]
         if window is None:
             return None
         oldest = ln_ref[row, 0] - ln_ref[row, 1] - (window - 1)
@@ -332,54 +383,71 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, *rest,
 
     def program(i):
         """(batch row, first token of the query tile) of program i."""
+        if shared:
+            return jnp.maximum(i - 1, 0), 0
         if q_tiles == 1:
             return i, 0
         return i // q_tiles, i % q_tiles * tile_tokens
 
     def live_pages(row, first=0):
-        """Pages the program's loop visits: none without a query. A
-        latent tile (tokens first .. first + tile_tokens of the row)
-        has none where its first token is no query, and ends at the
-        page of its last live query's position (causal: no query of it
-        reads past that)."""
+        """Pages the row's loop visits: none without a query. A latent
+        tile (tokens first .. first + tile_tokens of the row) has none
+        where its first token is no query, and ends at the page of its
+        last live query's position (causal: no query of it reads past
+        that)."""
         if latent is not None:
             seq, queries = ln_ref[row, 0], ln_ref[row, 1]
             ahead = jnp.maximum(queries - first - tile_tokens, 0)
             return jnp.where(queries > first, pl.cdiv(seq - ahead, ps), 0)
         pages = pl.cdiv(ln_ref[row, 0], ps)
-        if window is not None:
-            pages = pages - first_page(row)
+        opens = first_page(row)
+        if opens is not None:
+            pages = pages - opens
         return jnp.where(ln_ref[row, 1] > 0, pages, 0)
+
+    def program_pages(i):
+        """Pages program i walks (with `shared`, program 0 the run's)."""
+        pages = live_pages(*program(i))
+        if shared:
+            pages = jnp.where(i == 0, run_ref[0], pages)
+        return pages
 
     b, token0 = program(step)
     seq_len = ln_ref[b, 0]
     q_len = ln_ref[b, 1]
-    n_pages = live_pages(b, token0)
+    n_pages = program_pages(step)
     base = first_page(b)
     n_waves = pl.cdiv(n_pages, W)
     # the program after this one (its first wave is started under this
     # one's last, so a program does not open on a cold copy)
-    after, after_token0 = program(jnp.minimum(step + 1, steps - 1))
-    after_pages = jnp.where(step + 1 < steps,
-                            live_pages(after, after_token0), 0)
+    after = jnp.minimum(step + 1, steps - 1)
+    after_pages = jnp.where(step + 1 < steps, program_pages(after), 0)
 
-    def wave_dma(row, wave, slot, pages, start):
-        """Start (or wait for) the copies of wave `wave` of `row`: its
-        pages below `pages`, none when the wave lies past them. Table
-        slots at or past a row's live pages are never read: what they
-        hold (sentinels, another request's page) is never dereferenced."""
+    def wave_dma(i, wave, slot, pages, start, run=False):
+        """Start (or wait for) the copies of wave `wave` of program `i`
+        (`run`, static: of the shared run's walk, whose page ids are the
+        run's list): its pages below `pages`, none when the wave lies
+        past them. Table slots at or past a row's live pages are never
+        read: what they hold (sentinels, another request's page) is
+        never dereferenced."""
+        at = wave * W       # the wave's first slot, in the row's table
+        if start and not run:
+            row, _ = program(i)
+            opens = first_page(row)
+            if opens is not None:
+                at = opens + at
+
         def page_dma(j, carry):
             # a wait needs the copy's shape, not its source
-            if start:
-                at = wave * W + j
-                if window is not None:
-                    at = first_page(row) + at
-                page_id = pt_ref[row, at]
-            else:
+            if not start:
                 page_id = 0
-            for i, (hbm, buf) in enumerate(planes):
+            elif run:
+                page_id = run_ref[1 + at + j]
+            else:
+                page_id = pt_ref[row, at + j]
+            for n, (hbm, buf) in enumerate(planes):
                 copy = pltpu.make_async_copy(
-                    hbm.at[page_id], buf.at[slot, j], sem.at[slot, i])
+                    hbm.at[page_id], buf.at[slot, j], sem.at[slot, n])
                 copy.start() if start else copy.wait()
             return carry
         jax.lax.fori_loop(0, jnp.clip(pages - wave * W, 0, W), page_dma, 0)
@@ -394,14 +462,17 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, *rest,
         nxt[0] = 0          # the slot this row's wave 0 takes
         nxt[1] = 0          # 1: the row before has started it already
 
-    m_s[...] = jnp.full_like(m_s, NEG_INF)
-    l_s[...] = jnp.zeros_like(l_s)
-    acc_s[...] = jnp.zeros_like(acc_s)
     slot0 = nxt[0]
 
     @pl.when((n_waves > 0) & (nxt[1] == 0))
     def _():
-        wave_dma(b, 0, slot0, n_pages, start=True)
+        if shared:
+            pl.when(step == 0)(functools.partial(
+                wave_dma, step, 0, slot0, n_pages, True, run=True))
+            pl.when(step > 0)(functools.partial(
+                wave_dma, step, 0, slot0, n_pages, True))
+        else:
+            wave_dma(step, 0, slot0, n_pages, start=True)
 
     def dequant(x, scales):
         """[keys, H*D] int8 x [keys, H] fp32 -> fp32 [keys, H*D]."""
@@ -411,38 +482,82 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, *rest,
 
     # one group per score product: (q/k columns, v/out columns, state
     # column)
+    per_head = [(slice(h * D, (h + 1) * D),) * 2 + (h,) for h in range(H)]
     if latent is not None:
         groups = [(slice(None), slice(0, latent[0]), 0)]
     elif batched:
         groups = [(slice(None), slice(None), 0)]
     else:
-        groups = [(slice(h * D, (h + 1) * D),) * 2 + (h,)
-                  for h in range(H)]
+        groups = per_head
 
-    def wave_body(w, carry):
-        slot = (slot0 + w) % 2
-        # the next wave of the stream goes into the other slot: this
-        # row's wave w+1 or, under its last wave, the next row's first
-        last = w + 1 == n_waves
-        wave_dma(jnp.where(last, after, b), jnp.where(last, 0, w + 1),
-                 1 - slot, jnp.where(last, after_pages, n_pages),
-                 start=True)
-        wave_dma(b, w, slot, n_pages, start=False)
-        k = kbuf[slot].reshape(keys, H * D)
-        v = k if latent is not None else vbuf[slot].reshape(keys, H * D)
-        if quantized:
-            at = pl.ds(pl.multiple_of(w * keys, keys), keys)
-            k = dequant(k, ks_ref[at, :])
-            # past seq_len the gathered scales are some other page's:
-            # p == 0 there, and 0 * v must stay 0 whatever they hold
-            live = w * keys + jax.lax.broadcasted_iota(
-                jnp.int32, (keys, 1), 0) < seq_len
-            v = dequant(v, jnp.where(live, vs_ref[at, :], 0.0))
-        if latent is None:
-            v = v.astype(jnp.float32)
-        # (a latent call's values stay in the pool's dtype and take the
-        # probabilities in it, accumulated in fp32: its chunk is bound
-        # by its products, and an fp32 p.v costs the MXU several passes)
+    def walk(fold, run=False):
+        """The program's waves (`run`: the shared run's), each folded by
+        `fold(w, k, v)` while the next one of the stream is in flight."""
+        def wave_body(w, carry):
+            slot = (slot0 + w) % 2
+            # the next wave of the stream goes into the other slot: this
+            # program's wave w+1 or, under its last wave, the next
+            # program's first (after the run's: row 0's)
+            last = w + 1 == n_waves
+            if run:
+                pl.when(last)(functools.partial(
+                    wave_dma, after, 0, 1 - slot, after_pages, True))
+                pl.when(jnp.logical_not(last))(functools.partial(
+                    wave_dma, step, w + 1, 1 - slot, n_pages, True,
+                    run=True))
+            else:
+                wave_dma(jnp.where(last, after, step),
+                         jnp.where(last, 0, w + 1), 1 - slot,
+                         jnp.where(last, after_pages, n_pages), start=True)
+            wave_dma(step, w, slot, n_pages, start=False)
+            k = kbuf[slot].reshape(keys, H * D)
+            v = k if latent is not None else vbuf[slot].reshape(keys, H * D)
+            if quantized:
+                at = pl.ds(pl.multiple_of(w * keys, keys), keys)
+                k = dequant(k, ks_ref[at, :])
+                # past seq_len the gathered scales are some other page's:
+                # p == 0 there, and 0 * v must stay 0 whatever they hold
+                live = w * keys + jax.lax.broadcasted_iota(
+                    jnp.int32, (keys, 1), 0) < seq_len
+                v = dequant(v, jnp.where(live, vs_ref[at, :], 0.0))
+            if latent is None:
+                v = v.astype(jnp.float32)
+            # (a latent call's values stay in the pool's dtype and take
+            # the probabilities in it, accumulated in fp32: its chunk is
+            # bound by its products, and an fp32 p.v costs the MXU
+            # several passes)
+            fold(w, k, v)
+            return carry
+        jax.lax.fori_loop(0, n_waves, wave_body, 0)
+
+    def softmax_fold(q, k, v, valid, groups, m_ref, l_ref, acc_ref):
+        """One wave into the online softmax state (m, l, acc) of the
+        rows of `q`, a score product a group."""
+        for cols, vcols, g in groups:
+            # operands in their stored dtype (bf16 products are exact
+            # in the fp32 accumulation); 1/sqrt(D) on the fp32 scores
+            # (a latent call's q carries its scale)
+            s = jax.lax.dot_general(
+                q[:, cols].astype(k.dtype), k[:, cols],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if scale is not None:
+                s = s * scale
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[:, g:g + 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+            pexp = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            acc_ref[:, vcols] = acc_ref[:, vcols] * alpha \
+                + jax.lax.dot_general(
+                    pexp.astype(v.dtype), v[:, vcols],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            l_ref[:, g:g + 1] = alpha * l_ref[:, g:g + 1] \
+                + jnp.sum(pexp, -1, keepdims=True)
+            m_ref[:, g:g + 1] = m_new
+
+    def row_fold(w, k, v):
         # global positions: rows = this step's queries (a batched row
         # is query row // H), cols = this wave's keys; causal within
         # the sequence + ragged length mask
@@ -456,41 +571,73 @@ def _ragged_paged_kernel(pt_ref, ln_ref, q_ref, k_hbm, *rest,
         q_pos = seq_len - q_len + token
         key_pos = w * keys + jax.lax.broadcasted_iota(
             jnp.int32, (R, keys), 1)
-        if window is not None:
+        if base is not None:
             key_pos = base * ps + key_pos
         valid = (key_pos < seq_len) & (key_pos <= q_pos)
         if window is not None:
             valid = valid & (key_pos > q_pos - window)
-        for cols, vcols, g in groups:
-            # operands in their stored dtype (bf16 products are exact
-            # in the fp32 accumulation); 1/sqrt(D) on the fp32 scores
-            # (a latent call's q carries its scale)
-            s = jax.lax.dot_general(
-                q_ref[:, cols].astype(k.dtype), k[:, cols],
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            if scale is not None:
-                s = s * scale
-            s = jnp.where(valid, s, NEG_INF)
-            m_prev = m_s[:, g:g + 1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-            pexp = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            acc_s[:, vcols] = acc_s[:, vcols] * alpha + jax.lax.dot_general(
-                pexp.astype(v.dtype), v[:, vcols], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            l_s[:, g:g + 1] = alpha * l_s[:, g:g + 1] \
-                + jnp.sum(pexp, -1, keepdims=True)
-            m_s[:, g:g + 1] = m_new
-        return carry
+        softmax_fold(q_ref, k, v, valid, groups, m_s, l_s, acc_s)
 
-    jax.lax.fori_loop(0, n_waves, wave_body, 0)
+    def run_fold(w, k, v):
+        # every key of the run lies before every member's query: only
+        # a partial last wave's stale slots are masked
+        key_pos = w * keys + jax.lax.broadcasted_iota(
+            jnp.int32, (qs_ref.shape[0], keys), 1)
+        softmax_fold(qs_ref, k, v, key_pos < run_ref[0] * ps, per_head,
+                     m_sh, l_sh, acc_sh)
+
+    def take_run_state():
+        """A member row's state opens as its rows of the run's: row h
+        (query head h = j*group+g) takes row b*group+g of it — kv head
+        j's column of (m, l), every column of acc (the wrapper keeps
+        kv head j's)."""
+        # the member's rows, in the 8-row tile(s) that hold them (a
+        # load's first row must be a multiple of 8)
+        span = max(group, 8)
+        first = b * group // span * span
+        mine = pl.ds(pl.multiple_of(first, 8), span)
+        head = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+        at = b * group - first + head % group
+
+        def spread(x):
+            """[span, C] -> [R, C]: row h takes x[at[h]]."""
+            out = jnp.broadcast_to(x[0:1], (R, x.shape[1]))
+            for r in range(1, span):
+                out = jnp.where(at == r, x[r:r + 1], out)
+            return out
+        own = jax.lax.broadcasted_iota(jnp.int32, (R, H), 1) \
+            == head // group
+        for src, dst in ((m_sh, m_s), (l_sh, l_s)):
+            dst[...] = jnp.sum(jnp.where(own, spread(src[mine, :]), 0.0),
+                               -1, keepdims=True)
+        acc_s[...] = spread(acc_sh[mine, :])
+
+    def row_program():
+        m_s[...] = jnp.full_like(m_s, NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+        if shared:
+            pl.when(base > 0)(take_run_state)
+        walk(row_fold)
+        l_safe = jnp.maximum(l_s[...], 1e-30)
+        for _, vcols, g in groups:
+            o_ref[:, vcols] = (acc_s[:, vcols] / l_safe[:, g:g + 1]) \
+                .astype(o_ref.dtype)
+
+    if shared:
+        @pl.when((step == 0) & (n_waves > 0))
+        def _():
+            m_sh[...] = jnp.full_like(m_sh, NEG_INF)
+            l_sh[...] = jnp.zeros_like(l_sh)
+            acc_sh[...] = jnp.zeros_like(acc_sh)
+            walk(run_fold, run=True)
+        # (program 0 writes no output: its block is row 0's, which
+        # program 1 writes before the block moves on)
+        pl.when(step > 0)(row_program)
+    else:
+        row_program()
     nxt[0] = (slot0 + n_waves) % 2
     nxt[1] = ((n_waves > 0) & (after_pages > 0)).astype(jnp.int32)
-    l_safe = jnp.maximum(l_s[...], 1e-30)
-    for _, vcols, g in groups:
-        o_ref[:, vcols] = (acc_s[:, vcols] / l_safe[:, g:g + 1]) \
-            .astype(o_ref.dtype)
 
 
 def ragged_paged_attention_pallas(q, k_pages, v_pages, page_tables,
@@ -498,7 +645,7 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, page_tables,
                                   head_dim, k_scales=None,
                                   v_scales=None, interpret=None,
                                   num_kv_heads=None, window=None, diff=1,
-                                  latent=None):
+                                  latent=None, shared=None):
     """Pallas route (interpret-mode on CPU). See module docstring for
     layouts; k_scales/v_scales engage the int8 dequantizing body;
     `num_kv_heads` (default: num_heads), `window` (default: every key),
@@ -506,7 +653,9 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, page_tables,
     output is [B, T, num_heads * diff * head_dim]) and `latent` ((value
     lanes, rotary lanes): `v_pages` None, `head_dim` the stored row's
     lanes, q [B, T, num_heads * head_dim] with its scale in it, the
-    output [B, T, num_heads * value lanes]) are static.
+    output [B, T, num_heads * value lanes]) are static. `shared` (see
+    `ragged_paged_attention`) is read once for its member rows where
+    `shared_pass` takes it, and ignored elsewhere.
 
     What the shapes decide is decided here; the call itself is one
     jitted function, so a model's layers — the same shapes 24 times in
@@ -543,16 +692,35 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, page_tables,
         kv_heads, head_dim, group = kv_heads // diff, diff * head_dim, \
             group * diff
     batched = T * num_heads <= _BATCHED_ROWS
+    if not shared_pass(T, num_heads, num_kv_heads, k_scales, window, diff,
+                       latent):
+        shared = None
     W, need = _wave_pages(
         k_pages.shape[1], k_pages.shape[2], k_pages.dtype,
         T * num_heads if batched else T * group, q.dtype,
-        page_tables.shape[1], kv_heads, k_scales is not None)
+        page_tables.shape[1], kv_heads, k_scales is not None,
+        run_rows=0 if shared is None else -(-q.shape[0] * group // 8) * 8)
     return _paged_call(
         q, k_pages, v_pages, page_tables, seq_lens, q_lens, k_scales,
         v_scales, num_heads=kv_heads, head_dim=head_dim, wave_pages=W,
         batched=batched, vmem_bytes=need, group=group, window=window,
-        diff=diff,
+        diff=diff, shared=shared,
         interpret=_interpret() if interpret is None else interpret)
+
+
+def shared_pass(T, num_heads, num_kv_heads=None, k_scales=None,
+                window=None, diff=1, latent=None):
+    """Whether a call of these static arguments reads a shared run once
+    for its member rows: decode rows (T == 1) of the plain body, with
+    or without kv groups (query heads a kv head that tile 8 rows or are
+    whole tiles of them). A window call (a row reads only its last
+    keys), a differential or latent call, an int8 pool and a chunk (its
+    keys are read once for all its queries already) read every row's
+    own table."""
+    group = num_heads // (num_kv_heads or num_heads)
+    return (T == 1 and num_heads <= _BATCHED_ROWS and k_scales is None
+            and window is None and diff == 1 and latent is None
+            and (8 % group == 0 or group % 8 == 0))
 
 
 def _check_latent(latent, head_dim, row_lanes, v_pages, k_scales,
@@ -602,7 +770,7 @@ def _latent_call(q, pages, v_pages, page_tables, seq_lens, q_lens,
 def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
                 k_scales, v_scales, *, num_heads, head_dim, wave_pages,
                 batched, vmem_bytes, interpret, group=1, window=None,
-                diff=1, latent=None, q_tiles=1):
+                diff=1, latent=None, q_tiles=1, shared=None):
     """The block-diagonal q (when `batched`; else, with kv groups, the
     query heads of one kv head stacked as rows), the Mosaic call and
     the diagonal blocks of its output, as one jitted function of the
@@ -610,15 +778,37 @@ def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
     heads, `group` the query heads on each; a latent call (`v_pages`
     None) takes q as it lies (rows t*Hq+h: its one stored head has
     every column), runs each batch row as `q_tiles` programs of `T //
-    q_tiles` tokens and puts out `latent[0]` lanes a head."""
+    q_tiles` tokens and puts out `latent[0]` lanes a head. `shared`
+    (decode rows of the batched layout): (run pages [P], run keys, member
+    rows [B]) -> a program before the rows' that reads the run once."""
     B, T = q.shape[:2]
     N, ps, HD = k_pages.shape
     P = page_tables.shape[1]
     H, W, D = num_heads, wave_pages, head_dim
     quantized = k_scales is not None
     pt = page_tables.astype(jnp.int32)
-    lens = jnp.stack([seq_lens.astype(jnp.int32),
-                      q_lens.astype(jnp.int32)], axis=1)       # [B, 2]
+    lens = [seq_lens.astype(jnp.int32), q_lens.astype(jnp.int32)]
+    prefetch = [pt]
+    if shared is not None:
+        run_pt, run_keys, members = shared
+        run_pages = jnp.asarray(run_keys, jnp.int32).reshape(()) // ps
+        # a member's loop opens after the run; a row with no query, or
+        # whose context does not reach past the run, is no member
+        lens.append(jnp.where(
+            members.astype(bool) & (lens[1] > 0)
+            & (lens[0] > run_pages * ps), run_pages, 0))
+        prefetch.append(jnp.stack(lens, axis=1))
+        prefetch.append(jnp.concatenate(
+            [run_pages[None], run_pt.astype(jnp.int32)]))       # [1 + P]
+        # the members' queries as rows b*group+g, query head j*group+g
+        # in kv head j's columns: one product a kv head
+        # (whole 8-row tiles: a member's rows are loaded by the tile)
+        run_rows = -(-B * group // 8) * 8
+        q_run = q.reshape(B, H, group, D).transpose(0, 2, 1, 3) \
+            .reshape(B * group, HD)
+        q_run = jnp.pad(q_run, ((0, run_rows - B * group), (0, 0)))
+    else:
+        prefetch.append(jnp.stack(lens, axis=1))                # [B, 2]
     if latent is not None:
         q = q.reshape(B, T * group, HD)
     elif batched and group == 1:
@@ -640,17 +830,29 @@ def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
             .reshape(B, group * T, HD)
     R = q.shape[1]
     row_spec = out_spec = pl.BlockSpec((None, R, HD),
-                                       lambda b, pt, ln: (b, 0, 0))
+                                       lambda b, *_: (b, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pltpu.HBM)
     in_specs = [row_spec, pool_spec, pool_spec]
-    inputs = [pt, lens, q, k_pages, v_pages]
+    inputs = [q, k_pages, v_pages]
+    run_scratch = []
+    if shared is not None:
+        # program 0 is the run's, program i the row i-1's
+        row_spec = out_spec = pl.BlockSpec(
+            (None, R, HD), lambda i, *_: (jnp.maximum(i - 1, 0), 0, 0))
+        in_specs = [row_spec, pl.BlockSpec((run_rows, HD),
+                                           lambda i, *_: (0, 0)),
+                    pool_spec, pool_spec]
+        inputs.insert(1, q_run)
+        run_scratch = [pltpu.VMEM((run_rows, H), jnp.float32),
+                       pltpu.VMEM((run_rows, H), jnp.float32),
+                       pltpu.VMEM((run_rows, HD), jnp.float32)]
     OD = HD                 # lanes of the accumulator and the output
     if latent is not None:
         # no V pool; rows t*Hq+h of a batch row in `q_tiles` tiles of
         # whole tokens; the output as wide as the values
         OD, R = latent[0], R // q_tiles
 
-        def tile(i, pt, ln):
+        def tile(i, *_):
             return (i // q_tiles, i % q_tiles, 0)
         row_spec = pl.BlockSpec((None, R, HD), tile)
         out_spec = pl.BlockSpec((None, R, OD), tile)
@@ -664,15 +866,15 @@ def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
         Pw = -(-P // W) * W
         ids = jnp.pad(jnp.clip(pt, 0, N - 1), ((0, 0), (0, Pw - P)))
         scale_spec = pl.BlockSpec((None, Pw * ps, H),
-                                  lambda b, pt, ln: (b, 0, 0))
+                                  lambda b, *_: (b, 0, 0))
         in_specs += [scale_spec, scale_spec]
         inputs += [k_scales[ids].reshape(B, Pw * ps, H),
                    v_scales[ids].reshape(B, Pw * ps, H)]
     G = 1 if batched else H
     wave = pltpu.VMEM((2, W, ps, HD), k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B * q_tiles,),
+        num_scalar_prefetch=len(prefetch),
+        grid=(B * q_tiles + (shared is not None),),
         in_specs=in_specs,
         out_specs=out_spec,
         scratch_shapes=[
@@ -683,13 +885,15 @@ def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
             pltpu.VMEM((R, G), jnp.float32),               # running max
             pltpu.VMEM((R, G), jnp.float32),               # normalizer
             pltpu.VMEM((R, OD), jnp.float32),              # accumulator
+            *run_scratch,               # the run's max, normalizer, acc
         ],
     )
     kernel = functools.partial(
         _ragged_paged_kernel, page_size=ps, num_heads=H,
         head_dim=head_dim, wave_pages=W, batched=batched,
         quantized=quantized, group=group, window=window,
-        diff=diff, latent=latent, q_tiles=q_tiles)
+        diff=diff, latent=latent, q_tiles=q_tiles,
+        shared=shared is not None)
     out = scaffold.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -709,7 +913,7 @@ def _paged_call(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
         + ('' if window is None else '_window')
         + ('' if latent is None else '_latent')
         + ('_chunk' if T > 1 else ''),
-    )(*inputs)
+    )(*prefetch, *inputs)
     if latent is not None:
         return out.reshape(B, T, group * OD)
     if batched and group == 1:
@@ -811,7 +1015,7 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
                            q_lens=None, *, num_heads, head_dim,
                            k_scales=None, v_scales=None,
                            num_kv_heads=None, window=None, diff=1,
-                           latent=None):
+                           latent=None, shared=None):
     """Auto-routed entry (array-level; used inside the serving engine's
     jitted steps). Pass k_scales/v_scales for int8 pages; num_kv_heads
     where fewer kv heads than query heads are stored (the pool's width
@@ -820,16 +1024,29 @@ def ragged_paged_attention(q, k_pages, v_pages, page_tables, seq_lens,
     share one value block (the output is then diff * head_dim wide a
     query sub-head), latent=(value lanes, rotary lanes) with v_pages
     None where the pool is ONE array of rows every head reads (head_dim
-    the row's lanes, the scale in q, the output value lanes a head)."""
+    the row's lanes, the scale in q, the output value lanes a head).
+
+    `shared` = (run pages int32 [pages_per_seq], run keys int32, member
+    rows bool [B]) or None: a leading page run that every member row's
+    table maps to the same pool pages, whole pages, each before that
+    row's last page. The kernel reads it ONCE for all the members and
+    each member's own pages after it, merged in one softmax; rows that
+    are not members read as without. Its answer is the same attention:
+    the dense route, and every call `shared_pass` refuses, read each
+    row's whole table."""
     if q_lens is None:
         q_lens = jnp.full((q.shape[0],), q.shape[1], jnp.int32)
-    fn = (ragged_paged_attention_pallas if use_pallas_route()
-          else ragged_paged_attention_dense)
-    return fn(q, k_pages, v_pages, page_tables, seq_lens, q_lens,
-              num_heads=num_heads, head_dim=head_dim,
-              k_scales=k_scales, v_scales=v_scales,
-              num_kv_heads=num_kv_heads, window=window, diff=diff,
-              latent=latent)
+    if not use_pallas_route():
+        return ragged_paged_attention_dense(
+            q, k_pages, v_pages, page_tables, seq_lens, q_lens,
+            num_heads=num_heads, head_dim=head_dim, k_scales=k_scales,
+            v_scales=v_scales, num_kv_heads=num_kv_heads, window=window,
+            diff=diff, latent=latent)
+    return ragged_paged_attention_pallas(
+        q, k_pages, v_pages, page_tables, seq_lens, q_lens,
+        num_heads=num_heads, head_dim=head_dim, k_scales=k_scales,
+        v_scales=v_scales, num_kv_heads=num_kv_heads, window=window,
+        diff=diff, latent=latent, shared=shared)
 
 
 def _flat_slots(page_tables, seq_lens, q_lens, T, N, ps):

@@ -164,7 +164,7 @@ class _DeviceStep:
     args."""
     __slots__ = ('since', 'launched', 'steps', 'dispatches', 'decode_rows',
                  'chunks', 'chunk_tokens', 'chunk_slots', 'emitted',
-                 'snapshots')
+                 'snapshots', 'shared_rows', 'shared_keys')
 
     def __init__(self, since):
         self.since = since
@@ -172,6 +172,7 @@ class _DeviceStep:
         self.steps = self.dispatches = self.decode_rows = 0
         self.chunks = self.chunk_tokens = self.chunk_slots = 0
         self.emitted = self.snapshots = 0
+        self.shared_rows = self.shared_keys = 0
 
 
 class ServingConfig:
@@ -518,6 +519,22 @@ class ServingEngine:
                     f"planes, which have no int8 form, no host-tier "
                     f"transfer and no heads axis to split: "
                     f"{'; '.join(unpaired)}")
+        # decode rows that map one leading page run read it once (the
+        # paged kernel's `shared`, found in their tables: shared_run):
+        # built where the prefix cache can share pages and the kernel's
+        # decode call takes a run — (k, v) planes in the model's dtype,
+        # no mp split, full layers to read it in
+        from ..ops.pallas import paged_attention
+        self._shared_runs = bool(
+            config.prefix_cache and not latent and self._mp == 1
+            and _np_dtype(dtype) != np.int8 and self._kv_full > 0
+            and paged_attention.shared_pass(1, int(mcfg.num_heads),
+                                            spec[0].num_kv_heads))
+        # the decode rows' keys the run served (a member row's run keys
+        # in each full layer: the unit of _attn_kv_tokens), the
+        # dispatches that carried a run and the member rows they had
+        self._shared_keys = self._shared_run_count = self._shared_rows = 0
+        self._no_runs = {}
         lacking = needs - set(model.paged_routes)
         if lacking:
             raise NotImplementedError(
@@ -1619,14 +1636,19 @@ class ServingEngine:
 
         def forward_pick(kv, state, moe, tokens, page_tables, seq_lens,
                          q_lens, key, ords, temps, top_ks, slots=None,
-                         restore=None):
+                         restore=None, shared=None):
             """The dispatch's query tokens ([N], `layout`'s rows end to
             end; the per-row operands [R]) through the model over the
             paged pool, then each row's next id: the hidden state of its
             last query -> logits -> sampled or greedy. -> (ids, kv,
             state, moe)."""
+            if shared is not None:
+                # int32 [1 + pages + B]: the run's keys, its page ids,
+                # its member rows
+                P = page_tables.shape[1]
+                shared = (shared[1:1 + P], shared[0], shared[1 + P:] > 0)
             rows = RowGroups(layout, page_tables, seq_lens, q_lens, slots,
-                             restore)
+                             restore, shared)
             # int8 pools carry (k, v, k_scales, v_scales) per layer;
             # dense pools (k, v) — forward_paged keys off the arity
             cts = [tuple(Tensor(a) for a in c) for c in kv]
@@ -1671,7 +1693,7 @@ class ServingEngine:
         if not fused:
             def step(params, kv, state, moe, prev_ids, tokens,
                      page_tables, seq_lens, q_lens, src, key, ords,
-                     temps, top_ks, slots=None, restore=None):
+                     temps, top_ks, slots=None, restore=None, shared=None):
                 with bound(params):
                     if not verify:
                         # a decode row whose newest token never came to
@@ -1683,7 +1705,8 @@ class ServingEngine:
                             tokens[:B]))
                     nxt, kv, state, moe = forward_pick(
                         kv, state, moe, tokens, page_tables, seq_lens,
-                        q_lens, key, ords, temps, top_ks, slots, restore)
+                        q_lens, key, ords, temps, top_ks, slots, restore,
+                        shared)
                     handoff = prev_ids
                     if not verify:
                         # handed on by slot: a live decode row's id in
@@ -1764,8 +1787,8 @@ class ServingEngine:
                         for layer in self.pool.kv]
             # params, kv, state, moe, then the replicated operands:
             # the ids handed on and the host's (the mp route carries no
-            # `slots` and no `restore`: no state)
-            host_operands = step.__code__.co_argcount - 4 - 2 * (not fused)
+            # `slots` and no `restore`: no state; and no `shared` run)
+            host_operands = step.__code__.co_argcount - 4 - 3 * (not fused)
             step = shard_map(
                 step, mesh=self.mesh,
                 in_specs=(dict(self._param_specs), kv_specs, None, None)
@@ -1919,6 +1942,23 @@ class ServingEngine:
             if restore is not None:
                 tail += (restore,)
                 self._snapshots += snapshots
+            shared_rows = shared_keys = 0
+            if self._shared_runs and shape in ('decode', 'mixed'):
+                # the decode rows' shared run, packed, after the state
+                # operands (None where the model has none); no run: the
+                # zeros already on the device
+                run, pages, members = shared_run(
+                    page_tables[:B], seq_lens[:B], q_lens[:B], ps)
+                shared_rows = int(members.sum())
+                shared_keys = self._kv_full * pages * ps * shared_rows
+                packed = self._no_run(B)
+                if shared_rows:
+                    self._shared_run_count += 1
+                    self._shared_rows += shared_rows
+                    self._shared_keys += shared_keys
+                    packed = np.concatenate([[pages * ps], run, members]) \
+                        .astype(np.int32)
+                tail = tail[:3] + (slots, restore, packed)
         if mixed and (B, 1, sample, False) not in self._step_fns:
             self._warm_decode(B, sample)
         fn = self._step_fn(key)
@@ -1938,7 +1978,8 @@ class ServingEngine:
              self._moe_dev) = fn(
                 self._params, self.pool.kv, self.pool.state,
                 self._moe_dev, self._prev_ids, *map(jnp.asarray, head),
-                self._key, *map(jnp.asarray, tail))
+                self._key, *(None if x is None else jnp.asarray(x)
+                             for x in tail))
         t1 = time.perf_counter()
         self._it_compute += t1 - t0
         # one program's time, shared between the phases by the query
@@ -1947,7 +1988,17 @@ class ServingEngine:
         prefill = int(q_lens[B:].sum()) / queries
         self._it_prefill_s += (t1 - t0) * prefill
         self._it_decode_s += (t1 - t0) * (1.0 - prefill)
-        return ids, B + P, 1 + P, bool(rows), snapshots, t0, P * T
+        return (ids, B + P, 1 + P, bool(rows), shared_rows, shared_keys,
+                snapshots, t0, P * T)
+
+    def _no_run(self, B):
+        """The packed shared-run operand of a dispatch without a run
+        (all zero), put on the device once a batch size."""
+        zeros = self._no_runs.get(B)
+        if zeros is None:
+            zeros = self._no_runs[B] = self._jnp.asarray(
+                np.zeros((1 + self.max_pages_per_seq + B,), np.int32))
+        return zeros
 
     def _fetch(self, queued, behind):
         """The one host sync of a dispatch: the ids `_enqueue` left on
@@ -1979,9 +2030,11 @@ class ServingEngine:
         record being built — beside what earlier landings left there
         that fetched nothing (inner chunks alone), which the device
         ran, or still runs, since the last fetch. -> the record."""
-        *_, snapshots, launched, slots = queued
+        *_, shared_rows, shared_keys, snapshots, launched, slots = queued
         work = self._device_step
         work.snapshots += snapshots
+        work.shared_rows += shared_rows
+        work.shared_keys += shared_keys
         if work.launched is None:
             work.launched = launched
         work.dispatches += 1
@@ -2025,6 +2078,11 @@ class ServingEngine:
         args = {'drain': drain} if drain else {}
         if self._page_state:
             args['snapshots'] = work.snapshots
+        if self._shared_runs:
+            # the decode rows that read a shared run, and the keys it
+            # served them (the unit of attn_kv_tokens_shared_total)
+            args['shared_rows'] = work.shared_rows
+            args['shared_keys'] = work.shared_keys
         record_span('serve::device_step', int(began * 1e9), int(end * 1e9),
                     event_type='serve', step=step, steps=work.steps,
                     dispatches=work.dispatches, shape=shape,
@@ -2042,10 +2100,15 @@ class ServingEngine:
         every running request for the seconds its compile takes."""
         def zeros(*shape, dtype=np.int32):
             return self._jnp.asarray(np.zeros(shape, dtype))
-        spare = (self._jnp.asarray(np.full((B,), B, np.int32)),) \
-            if self._stateful else ()
-        if self._page_state:
-            spare += (self._jnp.asarray(np.zeros((B,), bool)),)
+        # the state operands and the shared run, where the program has
+        # them (None before one it has)
+        spare = [
+            self._jnp.asarray(np.full((B,), B, np.int32))
+            if self._stateful else None,
+            zeros(B, dtype=bool) if self._page_state else None,
+            self._no_run(B) if self._shared_runs else None]
+        while spare and spare[-1] is None:
+            spare.pop()
         _, self._prev_ids, self.pool.kv, self.pool.state, _ = \
             self._step_fn((B, 1, sample, False))(
                 self._params, self.pool.kv, self.pool.state,
@@ -2889,6 +2952,13 @@ class ServingEngine:
             # (real / dispatched: the share of its products that are
             # not padding); equal to the real ones for any other model
             'attn_qk_pairs_dispatched_total': self._attn_qk_dispatched,
+            # the decode rows' keys a shared run served (read ONCE for
+            # its member rows by the paged kernel; a part of
+            # attn_kv_tokens_read_total, same unit), the dispatches
+            # that carried a run and their member rows
+            'attn_kv_tokens_shared_total': self._shared_keys,
+            'shared_runs_total': self._shared_run_count,
+            'shared_run_rows_total': self._shared_rows,
             # one token's device bytes in one plane (a (k, v) pair, or
             # a latent plane's one padded row)
             'kv_plane_bytes_per_token':
@@ -3116,6 +3186,52 @@ def _last_token(req):
     """The token a decode row feeds: the newest of its context, whose
     K/V the step writes."""
     return req.generated[-1] if req.generated else req.prompt[-1]
+
+
+def shared_run(page_tables, seq_lens, q_lens, page_size):
+    """The leading page run the paged kernel reads ONCE for several
+    decode rows (ops/pallas/paged_attention.py `shared`), found in
+    their page tables [B, P]: rows are grouped by their first page; in
+    a group, a row reaches as many whole leading pages as it maps to
+    the same pool pages as the group's first row and that lie strictly
+    before its own last page (so it keeps the page holding its query's
+    key); the run is the length, among the rows' reaches, that saves
+    the most keys — its rows x its pages — over the rows that reach it,
+    two rows at least. Vectorised: the rows sorted by first page, a
+    [B, P] compare against each group's first row (its first mismatch
+    ends a row's reach), then by reach within a group (the k-th longest
+    is reached by k + 1 rows). -> (run page ids int32 [P], zeros past
+    the run; its pages; member rows bool [B]), all zero where no run
+    saves a key."""
+    B, P = page_tables.shape
+    run, members = np.zeros((P,), np.int32), np.zeros((B,), bool)
+    room = np.where((q_lens > 0) & (seq_lens > 0),
+                    (seq_lens - 1) // page_size, 0)
+    rows = np.flatnonzero(room)     # a whole page before their last
+    by_first = np.argsort(page_tables[rows, 0], kind='stable')
+    first = page_tables[rows[by_first], 0]
+    opens = np.ones(rows.size, bool)        # a group's first row, sorted
+    opens[1:] = first[1:] != first[:-1]
+    if opens.all():
+        return run, 0, members
+    group = np.empty(rows.size, np.intp)
+    group[by_first] = np.cumsum(opens) - 1
+    lead = by_first[opens]                  # each group's first row
+    cols = page_tables[rows, :room.max()]
+    same = cols == cols[lead[group]]
+    reach = np.minimum(room[rows], np.where(
+        same.all(axis=1), cols.shape[1], np.argmin(same, axis=1)))
+    order = np.lexsort((-reach, group))
+    by_group = group[order]
+    k = np.arange(rows.size) - np.searchsorted(by_group, by_group)
+    saved = np.where(k >= 1, reach[order] * (k + 1), 0)
+    best = int(np.argmax(saved))
+    if saved[best] == 0:
+        return run, 0, members
+    pages, g = int(reach[order][best]), by_group[best]
+    run[:pages] = page_tables[rows[lead[g]], :pages]
+    members[rows[(group == g) & (reach >= pages)]] = True
+    return run, pages, members
 
 
 def _ord_of(req):

@@ -101,6 +101,13 @@ has:
         refusal above (the host tier moves K/V pages alone; adoption,
         the fused window and the verify step resume inside a page).
 
+A model whose `read` (`RowGroups.attend`) can be handed a shared run
+takes it as `shared=` and passes it to `ragged_paged_attention`: the
+engine builds one (`engine.shared_run`) wherever the prefix cache is on
+over (k, v) planes in the model's dtype without an mp split — so every
+model served so must — and never for latent planes or a model whose
+state refuses the prefix cache.
+
 `GPTForCausalLM` and `AfmoeForCausalLM` implement it;
 `Phi4FlashForCausalLM` with shared planes and recurrent state,
 `AxK1ForCausalLM` with latent planes, `Lfm2MoeForCausalLM` with
@@ -130,18 +137,24 @@ class RowGroups:
     arrays; an idle row names the spare one. `restore` [R] bool (None
     for a model that keeps no state a page) marks the rows that resume
     after a mapped prefix: their state comes from the page before their
-    first query. The split back into groups and the join are written
-    here once, for every model.
+    first query. `shared` (None: no run) is the first group's leading
+    page run that several of its rows map — (run pages int32 [pages],
+    run keys int32, member rows bool [b]), the engine's `shared_run` —
+    handed to that group's `read` to pass on to the paged kernel
+    (`ragged_paged_attention(..., shared=)`, which reads the run once
+    for its members). The split back into groups and the join are
+    written here once, for every model.
     """
 
     def __init__(self, layout, page_tables, seq_lens, q_lens, slots=None,
-                 restore=None):
+                 restore=None, shared=None):
         self.layout = tuple((int(b), int(t)) for b, t in layout)
         self.page_tables = page_tables
         self.seq_lens = seq_lens
         self.q_lens = q_lens
         self.slots = slots
         self.restore = restore
+        self.shared = shared
 
     def _groups(self):
         """(first row, first token, rows, width) of each group."""
@@ -213,7 +226,9 @@ class RowGroups:
         whose one row `k` is; `write` then takes no v); `write(pool, k,
         v, page_tables, seq_lens, q_lens) -> pool` puts a group's new
         K/V ([b, t, .]) into its rows' pages and `read(pool, q,
-        page_tables, seq_lens, q_lens) -> [b, t, .]` attends. Every
+        page_tables, seq_lens, q_lens) -> [b, t, .]` attends — called
+        with `shared=` the run as well for the group that has one (a
+        model that can be handed one passes it to the kernel). Every
         group writes before any reads: no request rides two groups of
         one dispatch, so the writes never meet, and the pool is updated
         in one chain. `write` None: a reader of another layer's plane,
@@ -225,8 +240,11 @@ class RowGroups:
             new = (k,) if v is None else (k, v)
             for at, _, new_g in self.groups(*new):
                 pool = write(pool, *new_g, *rows_of(at))
-        return self.join([read(pool, qg, *rows_of(at))
-                          for at, _, (qg,) in self.groups(q)]), pool
+        out = []
+        for i, (at, _, (qg,)) in enumerate(self.groups(q)):
+            run = {} if i or self.shared is None else {'shared': self.shared}
+            out.append(read(pool, qg, *rows_of(at), **run))
+        return self.join(out), pool
 
     def last(self, x):
         """x [1, N, .] -> [R, .] at each row's last query."""

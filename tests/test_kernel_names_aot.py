@@ -277,6 +277,31 @@ def test_paged_attention_at_head_dim_64_compiles_and_is_named(
     assert got == {'paged_attention' + ('_chunk' if T > 1 else '')}
 
 
+@pytest.mark.parametrize('rows,heads,kv_heads,head_dim,ps,pages,pool', [
+    (128, 32, 8, 64, 64, 448, 10240), (64, 16, 16, 128, 16, 80, 3072),
+    (64, 32, 4, 128, 16, 528, 28000)])
+def test_the_shared_run_compiles_into_the_decode_call(
+        rows, heads, kv_heads, head_dim, ps, pages, pool, one_chip,
+        as_on_tpu):
+    """The decode rows' call with a shared run at the gated-conv, GPT and
+    sparse server cells' widths: the run's program joins the rows' in
+    ONE Mosaic call, named as the decode call is (the member rows' state
+    is loaded by whole 8-row tiles: a load that is not compiles nowhere)."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    pool_shape = ((pool, ps, kv_heads * head_dim), BF16)
+
+    def fn(q, k, v, pt, sl, ql, run, keys, members):
+        return pa.ragged_paged_attention_pallas(
+            q, k, v, pt, sl, ql, num_heads=heads, head_dim=head_dim,
+            num_kv_heads=kv_heads, shared=(run, keys, members))
+    got = mosaic_calls(fn, [
+        ((rows, 1, heads * head_dim), BF16), pool_shape, pool_shape,
+        ((rows, pages), jnp.int32), ((rows,), jnp.int32),
+        ((rows,), jnp.int32), ((pages,), jnp.int32), ((), jnp.int32),
+        ((rows,), jnp.bool_)], one_chip)
+    assert got == {'paged_attention'}
+
+
 @pytest.mark.parametrize('rows,T', [(128, 1), (2, 256)])
 def test_the_short_conv_compiles_and_is_named(rows, T, one_chip, as_on_tpu):
     """The same cell's gated short convolution: [B | C | u] of 3 x 2048

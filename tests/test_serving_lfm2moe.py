@@ -356,5 +356,6 @@ def test_two_step_programs_with_the_operands_they_had(model):
         eng.shutdown()
     assert set(seen) == {('mixed', 4, 2, CHUNK, False), (4, 1, False, False)}
     # 10 host operands, the experts' counters, 3 tails + the plane, the
-    # slots and the restore flags
-    assert set(seen.values()) == {10 + 1 + 4 + 1 + 1}
+    # slots, the restore flags and the decode rows' shared run (its keys,
+    # pages and member rows in one array: the prefix cache is on)
+    assert set(seen.values()) == {10 + 1 + 4 + 1 + 1 + 1}

@@ -28,7 +28,11 @@ pairs the attention masks allowed beside those the kernel multiplied
 (`attn_qk_pairs_total`, `attn_qk_pairs_dispatched_total`: a latent
 chunk's live query tiles whole) — the share of its products that were
 not padding, and with the chunk class's device time its share of the
-peak on what it multiplies.
+peak on what it multiplies; the decode rows' keys a shared run served
+(`attn_kv_tokens_shared_total`: read once by the paged kernel for all
+its member rows) over their keys, with the runs and member rows; and
+the median `serve::prepare` over the traced steps (where the host finds
+the run).
 """
 import argparse
 import json
@@ -56,6 +60,10 @@ KINDS = (_device_steps.decode_only, _device_steps.one_chunk_dispatch,
 # pairs (one query a row: its keys) from the chunk rows'
 PAIRS = ('attn_qk_pairs_total', 'attn_qk_pairs_dispatched_total',
          'attn_kv_tokens_read_total', 'attn_kv_tokens_read_chunks_total')
+# and the decode rows' shared run: the keys it served, the dispatches
+# that carried one and their member rows (a program without it: none)
+SHARED = ('attn_kv_tokens_shared_total', 'shared_runs_total',
+          'shared_run_rows_total')
 # a fetch returns this long, at most, after its execution ended (read:
 # 1.6-2.6 ms, up to 10 where the host came late)
 FETCH_LAG_NS = 5e6
@@ -256,8 +264,8 @@ def main(argv=None):
         """The runner's engine's pair counters as they stand (None for
         a trainer, or a program without them)."""
         st = engines[-1].stats() if engines else {}
-        return {k: st[k] for k in PAIRS} if all(k in st for k in PAIRS) \
-            else None
+        return {k: st[k] for k in PAIRS + SHARED if k in st} \
+            if all(k in st for k in PAIRS) else None
     setup_done = ctx.setup_done
 
     def setup_done_noting_pairs():
@@ -323,6 +331,12 @@ def main(argv=None):
                  f'trace idle {check["device_idle_ms"]:.1f} ms of a '
                  f'{check["device_window_ms"]:.1f} ms window; the step '
                  f'spans sum to {check["step_span_ms_sum"]:.1f} ms')
+    prepare = [s.dur_ns * 1e-6 for s in ring if s.name == 'serve::prepare']
+    if prepare:
+        summary['prepare_ms_median'] = statistics.median(prepare)
+        text += (f'\nserve::prepare over the traced steps: median '
+                 f'{summary["prepare_ms_median"]:.3f} ms of '
+                 f'{len(prepare)}')
     modules = load_modules(xplane_dir) if facts['kind'] == 'serve' else {}
     if steps and modules:
         offset, spread = clock_offset_ns(
@@ -369,6 +383,16 @@ def main(argv=None):
                      f'{d["attn_qk_pairs_dispatched_total"]}; of chunk '
                      f'rows {d["chunk_pairs"]} of '
                      f'{d["chunk_pairs_dispatched"]} = {share:.4f}')
+            if SHARED[0] in marks[hi]:
+                for k in SHARED:
+                    d[k] = marks[hi][k] - marks[lo][k]
+                d['decode_shared_key_share'] = \
+                    100.0 * d[SHARED[0]] / max(decode, 1)
+                text += (f'\ndecode rows\' keys a shared run served over '
+                         f'the {what}: {d[SHARED[0]]} of {decode} = '
+                         f'{d["decode_shared_key_share"]:.2f} % '
+                         f'({d["shared_runs_total"]} runs, '
+                         f'{d["shared_run_rows_total"]} member rows)')
     print(text, flush=True)
     base = os.path.join(args.out, cell['name'])
     with open(base + '.summary.json', 'w') as f:
